@@ -15,8 +15,8 @@ import sys
 
 import jax
 
-from megatron_tpu.utils.platform import ensure_env_platform
-ensure_env_platform()
+from megatron_tpu.utils.compile_cache import ensure_compile_cache
+ensure_compile_cache()
 
 
 def build_data(cfg, tokenizer, consumed_samples: int, mesh=None):
@@ -77,12 +77,26 @@ def build_data(cfg, tokenizer, consumed_samples: int, mesh=None):
             make_iter(test_ds, 0))
 
 
+def init_state(cfg, mesh, rng):
+    """The fresh TrainState, born where it will live. With a mesh every
+    leaf is created already sharded as the train step wants it: a model
+    that needs the mesh does not fit its first device, where an eager
+    init would put parameters and both Adam moments whole."""
+    from megatron_tpu.training import init_train_state
+    if mesh is None:
+        return init_train_state(rng, cfg)
+    from megatron_tpu.training.train_step import state_shardings
+    shapes = jax.eval_shape(lambda: init_train_state(rng, cfg))
+    return jax.jit(
+        lambda r: init_train_state(r, cfg),
+        out_shardings=state_shardings(cfg, mesh, shapes.params))(rng)
+
+
 def main(argv=None):
     from megatron_tpu.arguments import parse_cli
     from megatron_tpu.config import MegatronConfig
     from megatron_tpu.data import build_tokenizer, restore_data_state
     from megatron_tpu.parallel.mesh import build_mesh
-    from megatron_tpu.training import init_train_state
     from megatron_tpu.training import checkpointing as ckpt
     from megatron_tpu.training.loop import train
     from megatron_tpu.utils.logging import print_rank_0
@@ -119,7 +133,7 @@ def main(argv=None):
             cfg.model, vocab_size=tokenizer.vocab_size))
 
     rng = jax.random.PRNGKey(cfg.training.seed)
-    state = init_train_state(rng, cfg)
+    state = init_state(cfg, mesh, rng)
     start_iteration, consumed = 0, 0
     data_state, quarantine = None, []
     load_dir = cfg.training.load_dir or cfg.training.checkpoint_dir

@@ -1,11 +1,1 @@
-"""megatron_tpu: TPU-native Megatron-capability LLM training framework.
-
-Importing the package installs jax compatibility shims for older jax
-releases (utils/jax_compat.py) — a no-op on current jax — so the
-parallelism code's `jax.set_mesh` / `jax.shard_map` call sites work
-across the jax versions the deployment images actually carry.
-"""
-from megatron_tpu.utils.jax_compat import ensure_jax_compat
-
-ensure_jax_compat()
-del ensure_jax_compat
+"""megatron_tpu: TPU-native Megatron-capability LLM training framework."""

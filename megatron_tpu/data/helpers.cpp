@@ -6,7 +6,8 @@
 // extern "C" shared library consumed through ctypes (pybind11 is not in this
 // image). Compiled on demand by megatron_tpu/data/helpers.py.
 //
-// Build: g++ -O3 -shared -fPIC -o _helpers.so helpers.cpp
+// Build: helpers.py does it on first use (g++ -O3 -shared -fPIC), into
+// _helpers_<sha256 of this file>.so
 
 #include <cstdint>
 

@@ -8,6 +8,7 @@ pybind11 is not available in this image.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -16,15 +17,27 @@ import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "helpers.cpp")
-_SO = os.path.join(_HERE, "_helpers.so")
 _lock = threading.Lock()
 _lib = None
 
 
-def _compile():
+def _so_path() -> str:
+    """The binary is keyed by a hash of its source: a binary left on
+    disk by another version of helpers.cpp (git ignores *.so, a copy of
+    the tree does not) is never loaded, whatever its mtime says."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_HERE, f"_helpers_{digest}.so")
+
+
+def _compile(so: str):
+    # build beside the target and rename: several processes (pytest
+    # workers) may build at once, and none may load a half-written file
+    tmp = f"{so}.{os.getpid()}.tmp"
     subprocess.run(
-        ["g++", "-O3", "-shared", "-fPIC", "-o", _SO, _SRC],
+        ["g++", "-O3", "-shared", "-fPIC", "-o", tmp, _SRC],
         check=True, capture_output=True)
+    os.replace(tmp, so)
 
 
 def _load():
@@ -32,15 +45,10 @@ def _load():
     with _lock:
         if _lib is not None:
             return _lib
-        if (not os.path.exists(_SO)
-                or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-            _compile()
-        try:
-            lib = ctypes.CDLL(_SO)
-        except OSError:
-            # stale artifact from a different arch/libc: rebuild from source
-            _compile()
-            lib = ctypes.CDLL(_SO)
+        so = _so_path()
+        if not os.path.exists(so):
+            _compile(so)
+        lib = ctypes.CDLL(so)
         lib.build_sample_idx.argtypes = [
             ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int32),
             ctypes.c_int64, ctypes.c_int32, ctypes.c_int32, ctypes.c_int64,

@@ -45,8 +45,8 @@ resume replays the tail — no duplicated or missing tokens).
 The reference needs a rank-0 Flask thread that broadcasts a GENERATE/BEAM
 signal to all other ranks sitting in a receive loop
 (ref: text_generation_server.py:22-31); single-controller JAX needs none of
-that — one process serves and drives all chips. Flask is used when
-available, else the stdlib http.server (this image has no flask).
+that — one process serves and drives all chips, over the standard
+library's http.server.
 """
 from __future__ import annotations
 
@@ -85,10 +85,10 @@ def _is_stream_body(body) -> bool:
 
 
 def validate_generate_payload(payload) -> Optional[str]:
-    """Shared request validator for both transport backends: returns an
-    error message (→ HTTP 400) or None. Mirrors the reference's checks
+    """Request validator: returns an error message (→ HTTP 400) or
+    None. Mirrors the reference's checks
     (ref: text_generation_server.py:31-228), which it answered with
-    200 + {"message": ...} under flask."""
+    200 + {"message": ...}."""
     if not isinstance(payload, dict):
         return "request body must be a JSON object"
     has_text = "prompts" in payload
@@ -1302,62 +1302,7 @@ class MegatronServer:
         return merged
 
     def run(self, host: str = "0.0.0.0", port: int = 5000):
-        try:
-            self._run_flask(host, port)
-        except ImportError:
-            self._run_stdlib(host, port)
-
-    def _run_flask(self, host, port):
-        from flask import Flask, jsonify, request
-        app = Flask(__name__)
-        server = self
-
-        @app.route("/api", methods=["PUT"])
-        def api():
-            status, body = server.handle(request.get_json(silent=True),
-                                         headers=request.headers)
-            if _is_stream_body(body):
-                from flask import Response
-                return Response(body, status=status,
-                                mimetype="text/event-stream",
-                                headers={"Cache-Control": "no-cache",
-                                         "X-Accel-Buffering": "no"})
-            return (jsonify(body), status,
-                    server.response_headers(body))
-
-        @app.route("/admin", methods=["PUT"])
-        def admin():
-            status, body = server.handle_admin(
-                request.get_json(silent=True))
-            return jsonify(body), status
-
-        @app.route("/metrics", methods=["GET"])
-        def metrics():
-            return jsonify(server.metrics_snapshot()), 200
-
-        @app.route("/healthz", methods=["GET"])
-        def healthz():
-            status, body = server.healthz()
-            return jsonify(body), status
-
-        @app.route("/invariants", methods=["GET"])
-        def invariants():
-            strict = request.args.get("strict", "0") \
-                not in ("0", "", "false")
-            return jsonify(server.invariant_report(strict=strict)), 200
-
-        @app.route("/affinity", methods=["GET"])
-        def affinity():
-            return jsonify(server.affinity_digest()), 200
-
-        print_rank_0(f"serving (flask) on {host}:{port}/api")
-        # flask's dev server has no programmatic shutdown, and the
-        # drain callback runs on a worker thread where signal.signal()
-        # would raise — once the engine is drained there is nothing
-        # left to clean up, so exit the process directly
-        import os as _os
-        self.install_sigterm_drain(shutdown_cb=lambda: _os._exit(0))
-        app.run(host=host, port=port, threaded=True)
+        self._run_stdlib(host, port)
 
     def _run_stdlib(self, host, port):
         from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer

@@ -93,7 +93,6 @@ def _bn_kernel(map_ref, len_ref, q_ref, k_ref, v_ref, *refs, scale,
     si = pl.program_id(0)
     j = pl.program_id(1)
     B = block_size
-    G = nkv * g * w
     length = len_ref[si]
 
     @pl.when(j == 0)
@@ -110,53 +109,53 @@ def _bn_kernel(map_ref, len_ref, q_ref, k_ref, v_ref, *refs, scale,
 
     @pl.when(live)
     def _body():
-        # q positions per group row r: the query index is r % w (rows
-        # are (kv_head, group, query)-major), so row r's query sits at
-        # position length + (r % w) — decode (w == 1) degenerates to
-        # every row at `length`
-        row_q = jax.lax.broadcasted_iota(jnp.int32, (G, B), 0)
+        # every (kv_head, group, query) row of one kv head is a
+        # contiguous row range [h*g*w, (h+1)*g*w) — a TRACE CONSTANT —
+        # so each head runs its own online-softmax update straight on
+        # static slices of the q block and the (m, l, acc) scratch.
+        # Nothing is assembled across heads: Mosaic has no
+        # dynamic_update_slice / dynamic_slice on values, and static
+        # ref slices at sublane offsets that are not multiples of 8
+        # (g*w == 1 for MHA decode) lower to masked loads/stores.
+        gw = g * w
+        # query index of row r within a head is r % w (rows are
+        # (group, query)-major), so it sits at position length + r % w
+        # — decode (w == 1) degenerates to every row at `length`
+        row_q = jax.lax.broadcasted_iota(jnp.int32, (gw, B), 0)
         q_pos = length + jax.lax.rem(row_q, w)
-        kv_pos = j * B + jax.lax.broadcasted_iota(jnp.int32, (G, B), 1)
+        kv_pos = j * B + jax.lax.broadcasted_iota(jnp.int32, (gw, B), 1)
         keep = q_pos >= kv_pos  # causal incl. the partial tail block
-        s_full = jnp.zeros((G, B), jnp.float32)
         for h in range(nkv):  # static GQA loop: nkv is a trace constant
-            qh = q_ref[0, h * g * w:(h + 1) * g * w, :] \
-                .astype(jnp.float32) * scale                  # [g*w, hd]
-            kh = k_ref[0][:, h * hd:(h + 1) * hd] \
+            rows = slice(h * gw, (h + 1) * gw)
+            qh = q_ref[0, rows, :].astype(jnp.float32) * scale  # [gw, hd]
+            kh = k_ref[0, :, h * hd:(h + 1) * hd] \
+                .astype(jnp.float32)                          # [B, hd]
+            vh = v_ref[0, :, h * hd:(h + 1) * hd] \
                 .astype(jnp.float32)                          # [B, hd]
             if quant:
-                kh = kh * ks_ref[0][:, h:h + 1].astype(jnp.float32)
+                kh = kh * ks_ref[0, :, h:h + 1].astype(jnp.float32)
+                vh = vh * vs_ref[0, :, h:h + 1].astype(jnp.float32)
             sh = jax.lax.dot_general(
                 qh, kh, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)           # [g*w, B]
-            s_full = jax.lax.dynamic_update_slice(
-                s_full, sh, (h * g * w, 0))
-        s_full = jnp.where(keep, s_full, NEG_INF)
+                preferred_element_type=jnp.float32)           # [gw, B]
+            sh = jnp.where(keep, sh, NEG_INF)
 
-        m_prev = m_ref[:, :1]                                 # [G, 1]
-        m_cur = jnp.max(s_full, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        # MASK_CLAMP: a verify window's earliest query can be fully
-        # masked in a block only its later queries made live —
-        # exp(NEG_INF - NEG_INF) == 1 would attend those masked keys
-        p = jnp.exp(s_full - jnp.maximum(m_new, MASK_CLAMP))
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1,
-                                              keepdims=True)
-        pacc = jnp.zeros((G, hd), jnp.float32)
-        for h in range(nkv):
-            vh = v_ref[0][:, h * hd:(h + 1) * hd] \
-                .astype(jnp.float32)                          # [B, hd]
-            if quant:
-                vh = vh * vs_ref[0][:, h:h + 1].astype(jnp.float32)
-            ph = jax.lax.dynamic_slice(p, (h * g * w, 0), (g * w, B))
+            m_prev = m_ref[rows, :1]                          # [gw, 1]
+            m_cur = jnp.max(sh, axis=-1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            # MASK_CLAMP: a verify window's earliest query can be fully
+            # masked in a block only its later queries made live —
+            # exp(NEG_INF - NEG_INF) == 1 would attend those masked keys
+            p = jnp.exp(sh - jnp.maximum(m_new, MASK_CLAMP))
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[rows, :] = l_ref[rows, :] * alpha + jnp.sum(
+                p, axis=-1, keepdims=True)
             oh = jax.lax.dot_general(
-                ph, vh, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)           # [g*w, hd]
-            pacc = jax.lax.dynamic_update_slice(pacc, oh,
-                                                (h * g * w, 0))
-        acc_ref[:] = acc_ref[:] * alpha + pacc
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+                p, vh, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)           # [gw, hd]
+            acc_ref[rows, :] = acc_ref[rows, :] * alpha + oh
+            m_ref[rows, :] = jnp.broadcast_to(m_new,
+                                              (gw, m_ref.shape[1]))
 
     @pl.when(j == nb - 1)
     def _finalize():

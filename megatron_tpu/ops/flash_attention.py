@@ -30,9 +30,6 @@ logger = logging.getLogger(__name__)
 _warned_shapes = set()
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "scale", "block_kv",
-                                             "use_pallas", "sliding_window",
-                                             "dropout_rate"))
 def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
                     block_kv: int = DEFAULT_BLOCK_KV, use_pallas: bool | None = None,
                     segment_ids=None, sliding_window: int | None = None,
@@ -52,7 +49,13 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
     exactly softmax-then-dropout like the dot path, O(block) mask
     memory, unbiased (E[out] == no-dropout out). Mask bits are drawn
     per kv-block from `dropout_rng` folded with the block index, so
-    the backward (jax AD through the scan) sees identical masks."""
+    the backward (jax AD through the scan) sees identical masks.
+
+    Under a dp/tp mesh (the activation-sharding context the sharded
+    train step and the serving mesh trace in) the Pallas kernel runs
+    inside an explicit shard_map — batch over 'dp', heads over 'tp':
+    XLA cannot partition a Mosaic custom call, and attention is
+    independent per (batch row, head), so no collective is needed."""
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     if use_pallas and (q.shape[1] % 128 != 0 or k.shape[1] % 128 != 0):
@@ -70,29 +73,90 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
     if dropout_rate > 0.0:
         assert dropout_rng is not None, (
             "flash_attention: dropout_rate > 0 needs dropout_rng")
+    static = dict(causal=causal, scale=scale, block_kv=block_kv,
+                  use_pallas=use_pallas, sliding_window=sliding_window,
+                  dropout_rate=dropout_rate)
+    mesh = None
     if use_pallas:
-        try:
-            from megatron_tpu.ops.flash_attention_pallas import pallas_flash_attention
-            # positional: custom_vjp functions reject keyword arguments;
-            # ids go in as floats so every diff arg is float
-            from megatron_tpu.ops.flash_attention_pallas import (
-                DEFAULT_BLOCK_KV as PBKV, DEFAULT_BLOCK_Q as PBQ,
-                STAT_LANES)
-            seg = (segment_ids.astype(jnp.float32)
-                   if segment_ids is not None else None)
-            seed = None
-            if dropout_rate > 0.0:
-                # the kernel's counter-based hash takes one integer seed
-                # (<= 2^24 so the f32 plumbing is exact); per-block
-                # streams come from hashing it with the block coords
-                seed = jax.random.randint(
-                    dropout_rng, (1, STAT_LANES), 0,
-                    1 << 23).astype(jnp.float32)
-            return pallas_flash_attention(
-                q, k, v, causal, scale, PBQ, PBKV, False, seg, seg,
-                sliding_window, dropout_rate, seed)
-        except ImportError:
-            pass
+        from megatron_tpu.parallel.sharding import active_kernel_mesh
+        mesh = active_kernel_mesh()
+    if mesh is None:
+        return _flash_attention(q, k, v, segment_ids, dropout_rng, **static)
+    return _mesh_flash_attention(mesh, q, k, v, segment_ids, dropout_rng,
+                                 static)
+
+
+def _mesh_flash_attention(mesh, q, k, v, segment_ids, dropout_rng, static):
+    """The kernel under shard_map on `mesh`: each device runs it on its
+    own batch rows and heads. K/V heads shard over 'tp' when tp divides
+    them; a single kv head (MQA) is replicated, every shard's query
+    heads read it."""
+    from jax.sharding import PartitionSpec as P
+    from megatron_tpu.parallel.mesh import DATA_AXIS, TENSOR_AXIS
+    dp = mesh.shape.get(DATA_AXIS, 1)  # the serving mesh has no 'dp'
+    tp = mesh.shape.get(TENSOR_AXIS, 1)
+    b, nq, nkv = q.shape[0], q.shape[2], k.shape[2]
+    assert nq % tp == 0, f"tp={tp} must divide the query heads ({nq})"
+    if nkv % tp == 0:
+        kv_heads = TENSOR_AXIS
+    elif nkv == 1:
+        kv_heads = None
+    else:
+        raise NotImplementedError(
+            f"flash attention under tp={tp}: {nkv} kv heads neither "
+            "divide by tp nor are a single shared head")
+    rows = DATA_AXIS if dp > 1 and b % dp == 0 else None
+    heads = TENSOR_AXIS if tp > 1 else None
+    q_spec = P(rows, None, heads, None)
+    kv_spec = P(rows, None, kv_heads if tp > 1 else None, None)
+    args, specs = [q, k, v], [q_spec, kv_spec, kv_spec]
+    if segment_ids is not None:
+        args.append(segment_ids)
+        specs.append(P(rows, None))
+    if dropout_rng is not None:
+        args.append(dropout_rng)
+        specs.append(P())
+
+    def local(q_, k_, v_, *rest):
+        rest = list(rest)
+        seg = rest.pop(0) if segment_ids is not None else None
+        rng = rest.pop(0) if dropout_rng is not None else None
+        if rng is not None:  # every shard its own dropout stream
+            for axis in (rows, heads):
+                if axis is not None:
+                    rng = jax.random.fold_in(rng, jax.lax.axis_index(axis))
+        return _flash_attention(q_, k_, v_, seg, rng, **static)
+
+    return jax.shard_map(local, mesh=mesh, in_specs=tuple(specs),
+                         out_specs=q_spec, check_vma=False)(*args)
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "scale", "block_kv",
+                                             "use_pallas", "sliding_window",
+                                             "dropout_rate"))
+def _flash_attention(q, k, v, segment_ids, dropout_rng, *, causal, scale,
+                     block_kv, use_pallas, sliding_window, dropout_rate):
+    if use_pallas:
+        # no fallback here: a kernel that cannot be imported or that the
+        # compiler refuses raises, it does not drop to the XLA path
+        from megatron_tpu.ops.flash_attention_pallas import (
+            DEFAULT_BLOCK_KV as PBKV, DEFAULT_BLOCK_Q as PBQ, STAT_LANES,
+            pallas_flash_attention)
+        # positional: custom_vjp functions reject keyword arguments;
+        # ids go in as floats so every diff arg is float
+        seg = (segment_ids.astype(jnp.float32)
+               if segment_ids is not None else None)
+        seed = None
+        if dropout_rate > 0.0:
+            # the kernel's counter-based hash takes one integer seed
+            # (<= 2^24 so the f32 plumbing is exact); per-block
+            # streams come from hashing it with the block coords
+            seed = jax.random.randint(
+                dropout_rng, (1, STAT_LANES), 0,
+                1 << 23).astype(jnp.float32)
+        return pallas_flash_attention(
+            q, k, v, causal, scale, PBQ, PBKV, False, seg, seg,
+            sliding_window, dropout_rate, seed)
     return _blockwise_attention(q, k, v, causal=causal, scale=scale,
                                 block_kv=block_kv, segment_ids=segment_ids,
                                 sliding_window=sliding_window,

@@ -49,6 +49,21 @@ def _pad_rows(xr, br: int):
     return xr
 
 
+# Each grid step's weight-grad partial is written as a block of 8 rows
+# (row 0 real, the rest zero): the TPU lowering wants an output block's
+# second-last dimension divisible by the 8-row sublane tile, and a
+# (1, h) block over a (grid, h) array is refused. The zero rows vanish
+# in the sum over partials outside the kernel.
+PART_ROWS = 8
+
+
+def _partial_rows(part):
+    """[1, h] per-block partial -> [PART_ROWS, h], row 0 real."""
+    h = part.shape[-1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (PART_ROWS, h), 0)
+    return jnp.where(row == 0, jnp.broadcast_to(part, (PART_ROWS, h)), 0.0)
+
+
 # ---------------------------------------------------------------------------
 # RMSNorm
 # ---------------------------------------------------------------------------
@@ -68,7 +83,7 @@ def _rms_bwd_kernel(x_ref, s_ref, dy_ref, dx_ref, ds_ref, *, eps):
     g = dy * s
     c = jnp.mean(g * xh, axis=-1, keepdims=True)
     dx_ref[...] = (r * (g - xh * c)).astype(dx_ref.dtype)
-    ds_ref[...] = jnp.sum(dy * xh, axis=0, keepdims=True)
+    ds_ref[...] = _partial_rows(jnp.sum(dy * xh, axis=0, keepdims=True))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
@@ -118,9 +133,9 @@ def _rms_bwd(eps, interpret, res, dy):
                   pl.BlockSpec((1, h), lambda i: (0, 0)),
                   pl.BlockSpec((br, h), lambda i: (i, 0))],
         out_specs=[pl.BlockSpec((br, h), lambda i: (i, 0)),
-                   pl.BlockSpec((1, h), lambda i: (i, 0))],
+                   pl.BlockSpec((PART_ROWS, h), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((rows_p, h), x.dtype),
-                   jax.ShapeDtypeStruct((grid, h), jnp.float32)],
+                   jax.ShapeDtypeStruct((grid * PART_ROWS, h), jnp.float32)],
         interpret=interpret,
     )(xr, scale.reshape(1, h), dyr)
     ds = jnp.sum(ds_part, axis=0).astype(scale.dtype)
@@ -155,8 +170,8 @@ def _ln_bwd_kernel(x_ref, s_ref, dy_ref, dx_ref, ds_ref, db_ref, *, eps):
     gm = jnp.mean(g, axis=-1, keepdims=True)
     c = jnp.mean(g * xh, axis=-1, keepdims=True)
     dx_ref[...] = (r * (g - gm - xh * c)).astype(dx_ref.dtype)
-    ds_ref[...] = jnp.sum(dy * xh, axis=0, keepdims=True)
-    db_ref[...] = jnp.sum(dy, axis=0, keepdims=True)
+    ds_ref[...] = _partial_rows(jnp.sum(dy * xh, axis=0, keepdims=True))
+    db_ref[...] = _partial_rows(jnp.sum(dy, axis=0, keepdims=True))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -208,11 +223,11 @@ def _ln_bwd(eps, interpret, res, dy):
                   pl.BlockSpec((1, h), lambda i: (0, 0)),
                   pl.BlockSpec((br, h), lambda i: (i, 0))],
         out_specs=[pl.BlockSpec((br, h), lambda i: (i, 0)),
-                   pl.BlockSpec((1, h), lambda i: (i, 0)),
-                   pl.BlockSpec((1, h), lambda i: (i, 0))],
+                   pl.BlockSpec((PART_ROWS, h), lambda i: (i, 0)),
+                   pl.BlockSpec((PART_ROWS, h), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((rows_p, h), x.dtype),
-                   jax.ShapeDtypeStruct((grid, h), jnp.float32),
-                   jax.ShapeDtypeStruct((grid, h), jnp.float32)],
+                   jax.ShapeDtypeStruct((grid * PART_ROWS, h), jnp.float32),
+                   jax.ShapeDtypeStruct((grid * PART_ROWS, h), jnp.float32)],
         interpret=interpret,
     )(xr, scale.reshape(1, h), dyr)
     ds = jnp.sum(ds_part, axis=0).astype(scale.dtype)

@@ -130,11 +130,7 @@ def with_sharding(x, mesh: Mesh, logical_axes: tuple, rules):
     dot_general consuming it unchanged (e.g. post-LN models feed a layer
     output straight into the next QKV matmul) raises a mesh-mismatch."""
     spec = logical_to_spec(logical_axes, rules)
-    try:
-        cur = jax.sharding.get_abstract_mesh()
-    except AttributeError:  # older jax: no ambient-mesh API
-        cur = None
-    if cur is not None and not cur.empty:
+    if not jax.sharding.get_abstract_mesh().empty:
         return jax.lax.with_sharding_constraint(x, spec)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
@@ -193,6 +189,25 @@ def active_tp_mesh():
     if TENSOR_AXIS in mesh.shape and mesh.shape[TENSOR_AXIS] > 1:
         return mesh
     return None
+
+
+def active_kernel_mesh():
+    """The activation-sharding context's mesh when a hand-written kernel
+    traced now has to be wrapped in an explicit shard_map over it: the
+    mesh shards batch rows ('dp') or heads ('tp') over more than one
+    device, and the trace is not already inside a manual region (the
+    pipeline's and the context-parallel rings' own shard_maps hand their
+    kernels per-device blocks). None otherwise — single-device traces
+    lower the bare kernel."""
+    cur = getattr(_ACT_CTX, "cur", None)
+    if cur is None:
+        return None
+    mesh = cur[0]
+    if mesh.shape.get(TENSOR_AXIS, 1) * mesh.shape.get(DATA_AXIS, 1) <= 1:
+        return None
+    if jax.sharding.get_abstract_mesh().manual_axes:
+        return None
+    return mesh
 
 
 def distributed_opt_sharding(mesh: Mesh, logical_axes: tuple, rules,

@@ -591,7 +591,7 @@ class ServingEngine:
         # re-uploaded only when the dirty flags say so instead of
         # jnp.asarray'ing 4 host arrays every decode step. Between
         # churns the lengths chain device-side through the decode calls.
-        self._d_lengths = jnp.asarray(self._lengths)
+        self._d_lengths = self._upload_chained(self._lengths)
         self._d_temps = jnp.asarray(self._temps)
         self._d_top_ks = jnp.asarray(self._top_ks)
         self._d_top_ps = jnp.asarray(self._top_ps)
@@ -600,7 +600,7 @@ class ServingEngine:
         # host mirror is exact at sync boundaries (it rides the window
         # fetch) and re-uploads with the lengths on slot churn
         self._reject = np.full(S, -1, np.int32)
-        self._d_reject = jnp.asarray(self._reject)
+        self._d_reject = self._upload_chained(self._reject)
         # per-slot adapter bank row (0 = identity): changes only on
         # slot churn, re-uploaded with the lengths; idle rows ride the
         # identity adapter so their garbage decode is the base model's
@@ -1192,6 +1192,20 @@ class ServingEngine:
     def queue_depth(self) -> int:
         return self.scheduler.depth()
 
+    def _upload_chained(self, host):
+        """Device copy of a per-slot array that CHAINS through the
+        decode/verify calls (lengths, the residual carry): between
+        uploads the programs' own outputs feed the next call, and on a
+        serving mesh those are committed to it. A plain jnp.asarray is
+        an uncommitted single-device array — a different input placement,
+        so the call after an upload would trace and compile the program
+        a second time. Uploads therefore land where the outputs live:
+        replicated on the decode mesh."""
+        if self.topo is None or self.topo.serving_pp > 1:
+            return jnp.asarray(host)
+        return jax.device_put(
+            host, self.topo.replicated(self.topo.decode_mesh))
+
     def drain(self, timeout: Optional[float] = None) -> bool:
         """Graceful shutdown: stop admitting (queued-but-unstarted
         requests fail immediately with a retry-later error; new submits
@@ -1217,8 +1231,12 @@ class ServingEngine:
         if drained:
             if self._watchdog is not None:
                 self._watchdog.stop()
-            print_rank_0("serving engine drained: all in-flight "
-                         "requests completed")
+            print_rank_0(
+                "serving engine drained: all in-flight requests "
+                f"completed (program traces: decode={self._decode_traces}"
+                f" prefill={self._prefill_traces}"
+                f" chunk={self._chunk_traces}"
+                f" verify={self._verify_traces})")
         return drained
 
     def __enter__(self):
@@ -1586,6 +1604,7 @@ class ServingEngine:
             return self._compile_pp_programs()
         S, Vp = self.num_slots, self.cfg.padded_vocab_size
         self._decode_traces = 0  # trace count — MUST stay 1 in steady state
+        self._prefill_traces = 0  # one per (batch, prompt-length) bucket
         # lengths (arg 4) chains device-side but is NOT donated: it is
         # [S] int32 (nothing to save), and donating a buffer that the
         # next chained call consumes while the previous one is still in
@@ -1736,6 +1755,7 @@ class ServingEngine:
         # identically), and the per-stage lists pin ONE compile per
         # stage per program
         self._decode_traces = 0
+        self._prefill_traces = 0
         self._verify_traces = 0
         self._chunk_traces = 0
         self._pp_decode_traces = [0] * S_pp
@@ -2558,6 +2578,7 @@ class ServingEngine:
         `aidxs` [B]: per-ROW adapter bank rows — mixed-adapter
         admissions batch into ONE prefill call (indices are data), so
         adapter diversity never fragments the prefill coalescing."""
+        self._prefill_traces += 1
         adapters = (lora, aidxs) if self._adapters_on else None
         bkv = None
         if self._blocks_on and not self._kernel_on:
@@ -3010,7 +3031,7 @@ class ServingEngine:
         self._lengths[:] = 0
         self._active[:] = False
         self._reject[:] = -1
-        self._d_reject = jnp.asarray(self._reject)
+        self._d_reject = self._upload_chained(self._reject)
         # every slotted request failed, so no adapter pin survives; the
         # bank's device arrays DO (they are never donated), so resident
         # adapters stay warm across the restart
@@ -4142,11 +4163,11 @@ class ServingEngine:
             # active grids also re-park idle rows each window (at 0 for
             # hard-freed slots, at their final length for retained
             # ones) so their device-side drift stays bounded by K
-            self._d_lengths = jnp.asarray(self._lengths)
+            self._d_lengths = self._upload_chained(self._lengths)
             # the residual carry re-uploads with the lengths: the host
             # mirror is exact at boundaries (it rides the window fetch)
             # and churn sites rewrite it before setting the dirty flag
-            self._d_reject = jnp.asarray(self._reject)
+            self._d_reject = self._upload_chained(self._reject)
             # per-slot adapter rows change only on the same churn
             self._d_adapter_idx = jnp.asarray(self._adapter_idx)
             self._lengths_dirty = False

@@ -462,20 +462,11 @@ def _restore_from_dir(
             # partial_restore: unwanted subtrees (optimizer moments for
             # finetune / inference loads) are never read off disk — a 70B
             # Adam state must not materialize just to be discarded.
-            # Older orbax (< 0.9) has no partial_restore kwarg: its
-            # transforms-mode restore with an empty transforms dict is
-            # the same contract (item is the target structure; on-disk
-            # leaves absent from it are never read)
-            restore_kwargs = dict(
+            args = ocp.args.PyTreeRestore(
                 item=target,
-                restore_args=jax.tree.map(_restore_args, target))
+                restore_args=jax.tree.map(_restore_args, target),
+                partial_restore=True)
             with ocp.PyTreeCheckpointer() as ckptr:
-                try:
-                    args = ocp.args.PyTreeRestore(partial_restore=True,
-                                                  **restore_kwargs)
-                except TypeError:
-                    args = ocp.args.PyTreeRestore(transforms={},
-                                                  **restore_kwargs)
                 return ckptr.restore(state_path, args=args)
 
         try:
@@ -581,12 +572,9 @@ def load_params_host(ckpt_dir: str, example_params):
             example_params)}
         restore_args = jax.tree.map(
             lambda _: ocp.RestoreArgs(restore_type=np.ndarray), target)
-        kw = dict(item=target, restore_args=restore_args)
+        args = ocp.args.PyTreeRestore(
+            item=target, restore_args=restore_args, partial_restore=True)
         with ocp.PyTreeCheckpointer() as ckptr:
-            try:
-                args = ocp.args.PyTreeRestore(partial_restore=True, **kw)
-            except TypeError:  # orbax < 0.9: transforms={} contract
-                args = ocp.args.PyTreeRestore(transforms={}, **kw)
             restored = ckptr.restore(state_path, args=args)
         flat_ex = jax.tree.leaves(example_params)
         flat_got = jax.tree.leaves(restored["params"])

@@ -320,18 +320,14 @@ def state_shardings(cfg: MegatronConfig, mesh, param_shapes, rules=None,
 
 class _MeshContextStep:
     """Callable wrapping a jitted step so each call runs with the ambient
-    mesh set (required by the partial-manual shard_map inside). Older
-    jax (< 0.6) has no `jax.set_mesh`; entering the Mesh itself sets
-    the same thread-local mesh context there."""
+    mesh set (required by the partial-manual shard_map inside)."""
 
     def __init__(self, fn, mesh):
         self._fn = fn
         self._mesh = mesh
 
     def __call__(self, *args, **kwargs):
-        set_mesh = getattr(jax, "set_mesh", None)
-        ctx = set_mesh(self._mesh) if set_mesh is not None else self._mesh
-        with ctx:
+        with jax.set_mesh(self._mesh):
             return self._fn(*args, **kwargs)
 
 

@@ -18,8 +18,8 @@ import sys
 
 import jax
 
-from megatron_tpu.utils.platform import ensure_env_platform
-ensure_env_platform()
+from megatron_tpu.utils.compile_cache import ensure_compile_cache
+ensure_compile_cache()
 
 
 

@@ -16,7 +16,7 @@ from __future__ import annotations
 import argparse
 import json
 
-from megatron_tpu.utils.platform import ensure_env_platform
+from megatron_tpu.utils.compile_cache import ensure_compile_cache
 
 
 def get_tasks_parser() -> argparse.ArgumentParser:
@@ -300,7 +300,7 @@ def run_task(args) -> dict:
 
 
 def main():
-    ensure_env_platform()
+    ensure_compile_cache()
     args = get_tasks_parser().parse_args()
     if args.task in ("MNLI", "QQP", "RACE"):
         run_finetune_task(args)

@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import argparse
 
-from megatron_tpu.utils.platform import ensure_env_platform
+from megatron_tpu.utils.compile_cache import ensure_compile_cache
 
 
 def get_parser() -> argparse.ArgumentParser:
@@ -43,7 +43,7 @@ def get_parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None) -> int:
-    ensure_env_platform()
+    ensure_compile_cache()
     args = get_parser().parse_args(argv)
     if args.task == "MSDP-PROMPT":
         assert args.sample_input_file and args.prompt_file, \

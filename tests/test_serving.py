@@ -291,6 +291,38 @@ class TestSlotKVPool:
         # CPU backend exposes no memory stats -> requested unchanged
         assert fit_num_slots(cfg, 64, requested=8) == 8
 
+    @pytest.mark.parametrize("case", ["fits", "weights_pending",
+                                      "sharded", "tpu_without_stats"])
+    def test_fit_num_slots_budget(self, tiny_model, monkeypatch, case):
+        """The pool's budget is what the device has left AFTER the
+        weights still staged on the host; each tp shard holds 1/shards
+        of both; a TPU that reports no stats is an error, not 8 slots."""
+        import types
+
+        import jax
+        from megatron_tpu.serving.kv_pool import fit_num_slots, slot_nbytes
+        _, cfg = tiny_model
+        slot = slot_nbytes(cfg, 64)
+        stats = {"bytes_limit": 100 * slot, "bytes_in_use": 0}
+        dev = types.SimpleNamespace(
+            platform="tpu", device_kind="fake",
+            memory_stats=lambda: None if case == "tpu_without_stats"
+            else stats)
+        monkeypatch.setattr(jax, "local_devices", lambda: [dev])
+        if case == "fits":
+            assert fit_num_slots(cfg, 64, requested=8) == 8
+            assert fit_num_slots(cfg, 64, requested=200) == 80  # headroom
+        elif case == "weights_pending":
+            assert fit_num_slots(cfg, 64, requested=200,
+                                 pending_bytes=90 * slot) == 8
+        elif case == "sharded":
+            # 4 shards: a quarter of the weights and of every slot each
+            assert fit_num_slots(cfg, 64, requested=500, shards=4,
+                                 pending_bytes=200 * slot) == 160
+        else:
+            with pytest.raises(RuntimeError, match="no memory stats"):
+                fit_num_slots(cfg, 64, requested=8)
+
     def test_rolling_pool_caps_to_window(self):
         cfg = tiny_cfg(sliding_window=16, attention_impl="flash",
                        seq_length=64, max_position_embeddings=64)

@@ -26,7 +26,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from megatron_tpu.utils.platform import ensure_env_platform
+from megatron_tpu.utils.compile_cache import ensure_compile_cache
 
 # HBM bandwidth by device kind (public spec sheets), bytes/s
 _HBM_BW = {
@@ -41,7 +41,7 @@ _HBM_BW = {
 
 
 def main(argv=None):
-    ensure_env_platform()
+    ensure_compile_cache()
     p = argparse.ArgumentParser("bench_decode", description=__doc__)
     p.add_argument("--out", default="/tmp/bench_decode.log")
     p.add_argument("--batch", type=int, default=8)

@@ -38,7 +38,7 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from megatron_tpu.utils.platform import ensure_env_platform
+from megatron_tpu.utils.compile_cache import ensure_compile_cache
 from tools import chaos_common as cc
 
 # the asymmetric arms need decode_tp + prefill_tp = 3 chips; force the
@@ -49,7 +49,7 @@ N_DEVICES = 4
 
 def main(argv=None):
     cc.force_host_devices(N_DEVICES)
-    ensure_env_platform()
+    ensure_compile_cache()
     p = argparse.ArgumentParser("bench_phase_topology",
                                 description=__doc__)
     p.add_argument("--out", default="/tmp/bench_phase_topology.log")

@@ -37,7 +37,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from megatron_tpu.utils.platform import ensure_env_platform
+from megatron_tpu.utils.compile_cache import ensure_compile_cache
 from tools import chaos_common as cc
 
 # the staged arms need serving_pp=2 chips; force the 2-virtual-device
@@ -106,7 +106,7 @@ def _run_pp_arm(gen, prompts, args, **sv_overrides) -> dict:
 
 def main(argv=None):
     cc.force_host_devices(N_DEVICES)
-    ensure_env_platform()
+    ensure_compile_cache()
     p = argparse.ArgumentParser("bench_pp_serving", description=__doc__)
     p.add_argument("--out", default="/tmp/bench_pp_serving.log")
     p.add_argument("--smoke", action="store_true",

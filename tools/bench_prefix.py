@@ -44,7 +44,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from megatron_tpu.utils.platform import ensure_env_platform
+from megatron_tpu.utils.compile_cache import ensure_compile_cache
 
 
 def _build(args):
@@ -189,7 +189,7 @@ def _run_multiturn_arm(gen, args, block) -> dict:
 
 
 def main(argv=None):
-    ensure_env_platform()
+    ensure_compile_cache()
     p = argparse.ArgumentParser("bench_prefix", description=__doc__)
     p.add_argument("--out", default="/tmp/bench_prefix.log")
     p.add_argument("--requests", type=int, default=12)

@@ -39,7 +39,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from megatron_tpu.utils.platform import ensure_env_platform
+from megatron_tpu.utils.compile_cache import ensure_compile_cache
 
 
 def _build(args):
@@ -121,7 +121,7 @@ def _run_arm(gen, prompts, args, k: int) -> dict:
 
 
 def main(argv=None):
-    ensure_env_platform()
+    ensure_compile_cache()
     p = argparse.ArgumentParser("bench_spec", description=__doc__)
     p.add_argument("--out", default="/tmp/bench_spec.log")
     p.add_argument("--requests", type=int, default=8)

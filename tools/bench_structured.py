@@ -39,7 +39,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from megatron_tpu.utils.platform import ensure_env_platform
+from megatron_tpu.utils.compile_cache import ensure_compile_cache
 
 # bounded grammar over the identity token table (token i <-> chr(i)):
 # digits only, 2-6 chars — enough FSM states that masks actually
@@ -194,7 +194,7 @@ def _arm_fanout(eng, prompts, args) -> dict:
 
 
 def main(argv=None):
-    ensure_env_platform()
+    ensure_compile_cache()
     p = argparse.ArgumentParser("bench_structured", description=__doc__)
     p.add_argument("--out", default="/tmp/bench_structured.log")
     p.add_argument("--smoke", action="store_true",

@@ -32,7 +32,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from megatron_tpu.utils.platform import ensure_env_platform
+from megatron_tpu.utils.compile_cache import ensure_compile_cache
 
 
 def _bench_training(args) -> dict:
@@ -163,7 +163,7 @@ def _bench_serving(args) -> dict:
 
 
 def main(argv=None):
-    ensure_env_platform()
+    ensure_compile_cache()
     p = argparse.ArgumentParser("bench_sync", description=__doc__)
     p.add_argument("--out", default="/tmp/bench_sync.log")
     p.add_argument("--iters", type=int, default=24)

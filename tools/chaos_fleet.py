@@ -59,7 +59,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from megatron_tpu.utils.platform import ensure_env_platform
+from megatron_tpu.utils.compile_cache import ensure_compile_cache
 from tools.chaos_common import (IntTokenizer, emit_record, free_port,
                                 invariant_sweep,
                                 resolve_exact as _resolve_exact,
@@ -80,9 +80,8 @@ REPLICA_SERVING = dict(num_slots=4, max_queue=64,
 # ---------------------------------------------------------------------
 def serve_replica(port: int) -> int:
     """`--serve_replica`: run ONE tiny engine as a standalone
-    `--replica_mode` server process on 127.0.0.1:port (stdlib
-    transport for determinism — no flask dependency in the drill
-    path). The parent talks to it exclusively over HTTP."""
+    `--replica_mode` server process on 127.0.0.1:port. The parent
+    talks to it exclusively over HTTP."""
     from megatron_tpu.config import ServingConfig
     from megatron_tpu.inference.server import MegatronServer
     cfg = tiny_model_cfg()
@@ -477,7 +476,11 @@ def run_chaos(seed: int, n_replicas: int, new_tokens: int,
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--serve_replica", action="store_true",
-                    help="child mode: run ONE replica server process")
+                    help="child mode: run ONE replica server process. "
+                         "Every replica process wants a device of its "
+                         "own: run the drill with one device per "
+                         "replica, or on the CPU (JAX_PLATFORMS=cpu) — "
+                         "two replicas cannot share one chip")
     ap.add_argument("--port", type=int, default=0,
                     help="child mode: port to serve on")
     ap.add_argument("--seed", type=int, default=0,
@@ -494,7 +497,7 @@ def main(argv=None) -> int:
                     help="also write the JSON record here")
     args = ap.parse_args(argv)
 
-    ensure_env_platform()
+    ensure_compile_cache()
     if args.serve_replica:
         if not args.port:
             ap.error("--serve_replica requires --port")

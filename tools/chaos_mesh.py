@@ -64,7 +64,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from megatron_tpu.utils.platform import ensure_env_platform
+from megatron_tpu.utils.compile_cache import ensure_compile_cache
 from tools import chaos_common as cc
 
 N_DEVICES = 4  # forced host platform: disagg/tp configs need 2x2
@@ -810,7 +810,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     cc.force_host_devices(N_DEVICES)
-    ensure_env_platform()
+    ensure_compile_cache()
     require = tuple(t for t in args.require.split(",") if t)
 
     if args.minutes is not None:

@@ -76,7 +76,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from megatron_tpu.utils.platform import ensure_env_platform
+from megatron_tpu.utils.compile_cache import ensure_compile_cache
 from tools.chaos_common import (emit_record, force_host_devices,
                                 invariant_sweep,
                                 resolve_exact as _resolve_exact,
@@ -466,7 +466,7 @@ def main(argv=None) -> int:
     # the disaggregated kill-half drills need 4 devices (2 replicas x
     # 2 chip groups)
     force_host_devices(4)
-    ensure_env_platform()
+    ensure_compile_cache()
     if args.smoke:
         args.new_tokens, args.watchdog_s, args.stall_s = 12, 1.0, 2.5
 

@@ -40,7 +40,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from megatron_tpu.utils.platform import ensure_env_platform
+from megatron_tpu.utils.compile_cache import ensure_compile_cache
 from tools.chaos_common import (emit_record, invariant_sweep,
                                 make_adapters as _make_adapters,
                                 pool_mode as _pool_mode,
@@ -392,7 +392,7 @@ def main(argv=None) -> int:
                     help="also write the JSON record here")
     args = ap.parse_args(argv)
 
-    ensure_env_platform()
+    ensure_compile_cache()
     if args.smoke:
         args.new_tokens, args.watchdog_s, args.stall_s = 16, 1.0, 2.5
 

@@ -59,7 +59,7 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-from megatron_tpu.utils.platform import ensure_env_platform  # noqa: E402
+from megatron_tpu.utils.compile_cache import ensure_compile_cache  # noqa: E402
 from tools import chaos_common as cc  # noqa: E402
 
 N_DEVICES = 4
@@ -529,7 +529,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     cc.force_host_devices(N_DEVICES)
-    ensure_env_platform()
+    ensure_compile_cache()
     require = tuple(t for t in args.require.split(",") if t)
     arms = tuple(float(a) for a in args.arms.split(","))
 

@@ -34,7 +34,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from megatron_tpu.utils.platform import ensure_env_platform
+from megatron_tpu.utils.compile_cache import ensure_compile_cache
 
 
 class _SyntheticDataset:
@@ -213,7 +213,7 @@ def main(argv=None) -> int:
                     help="also write the JSON record here")
     args = ap.parse_args(argv)
 
-    ensure_env_platform()
+    ensure_compile_cache()
     if args.smoke:
         args.train_iters, args.hidden_size = 8, 32
         args.faults = "write_error@2,nan@3,nan@4"

@@ -55,7 +55,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from megatron_tpu.utils.platform import ensure_env_platform
+from megatron_tpu.utils.compile_cache import ensure_compile_cache
 from tools.chaos_common import (corrupt_payload as _corrupt_payload,
                                 emit_record, force_host_devices,
                                 invariant_sweep,
@@ -388,7 +388,7 @@ def main(argv=None) -> int:
     # the disaggregated race drill needs 4 devices (2 replicas x 2 chip
     # groups)
     force_host_devices(4)
-    ensure_env_platform()
+    ensure_compile_cache()
     if args.smoke:
         args.new_tokens = 8
 

@@ -21,8 +21,8 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from megatron_tpu.utils.platform import ensure_env_platform
-ensure_env_platform()
+from megatron_tpu.utils.compile_cache import ensure_compile_cache
+ensure_compile_cache()
 
 
 def _model_cfg(family: str, size: str):

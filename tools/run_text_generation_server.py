@@ -14,8 +14,8 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from megatron_tpu.utils.platform import ensure_env_platform
-ensure_env_platform()
+from megatron_tpu.utils.compile_cache import ensure_compile_cache
+ensure_compile_cache()
 
 
 def main(argv=None):
@@ -213,6 +213,7 @@ def main(argv=None):
     import jax.numpy as jnp
 
     staged_version = None
+    pending_bytes = 0  # weights staged host-side, not on the device yet
     if args.serial or args.int8_weights:
         # serial fallback needs device params anyway; the int8 path
         # quantizes on device and drops the fp originals below
@@ -242,6 +243,7 @@ def main(argv=None):
         staged = stage_latest(args.load, example.params)
         params = staged.params
         staged_version = staged.version
+        pending_bytes = sum(x.nbytes for x in jax.tree.leaves(params))
         print_rank_0(f"serving: staged weights host-side "
                      f"(version {staged_version.label}); device "
                      "residency = the engine's placement only")
@@ -251,13 +253,17 @@ def main(argv=None):
     from megatron_tpu.config import ServingConfig
     num_slots = args.num_slots
     if num_slots is None and not args.serial:
-        # size the eager slot-grid pool to the memory the weights left
-        # free (a fixed 8-slot default OOMs 7B-class serving on a v5e)
+        # size the eager slot-grid pool to the memory the weights leave
+        # free (a fixed 8-slot default OOMs 7B-class serving on a v5e):
+        # resident weights show in the device's bytes_in_use, staged
+        # ones are subtracted by their byte count
         from megatron_tpu.serving.kv_pool import fit_num_slots
         from megatron_tpu.utils.logging import print_rank_0
         num_slots = fit_num_slots(
             mcfg, args.serving_max_len or mcfg.max_position_embeddings,
-            dtype=jnp.int8 if args.int8_kv else jnp.bfloat16)
+            dtype=jnp.int8 if args.int8_kv else jnp.bfloat16,
+            block_size=args.kv_block_size,
+            pending_bytes=pending_bytes, shards=args.serving_tp)
         print_rank_0(f"serving: auto-sized num_slots={num_slots} "
                      "(override with --num_slots)")
     if num_slots is None:  # serial fallback: engine never built
@@ -282,6 +288,10 @@ def main(argv=None):
                             ).validate(mcfg)
     server = MegatronServer(gen, tokenizer, serving=serving,
                             weight_version=staged_version)
+    # what the weights and the KV pool took, device by device: under
+    # --serving_tp every device should hold about 1/tp of both
+    from megatron_tpu.utils.logging import report_memory
+    report_memory("serving")
     if args.adapter_dir:
         # pre-register every exported adapter: adapter_id = file stem,
         # validated eagerly (a corrupt export fails the server start,

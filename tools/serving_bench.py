@@ -35,7 +35,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from megatron_tpu.utils.platform import ensure_env_platform
+from megatron_tpu.utils.compile_cache import ensure_compile_cache
 
 
 def _percentile(vals, q):
@@ -252,7 +252,7 @@ def _bench_url(args) -> dict:
 
 
 def main(argv=None):
-    ensure_env_platform()
+    ensure_compile_cache()
     p = argparse.ArgumentParser("serving_bench", description=__doc__)
     p.add_argument("--out", default="/tmp/serving_bench.log")
     p.add_argument("--url", default=None,

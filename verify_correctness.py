@@ -22,8 +22,8 @@ import sys
 
 import numpy as np
 
-from megatron_tpu.utils.platform import ensure_env_platform
-ensure_env_platform()
+from megatron_tpu.utils.compile_cache import ensure_compile_cache
+ensure_compile_cache()
 
 
 def compare_llama(hf_model, cfg, tokens: np.ndarray,
