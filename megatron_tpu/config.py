@@ -294,12 +294,14 @@ class TrainingConfig:
     # log_interval-1 steps late; rollback restores a checkpoint either
     # way, so decisions are identical — see docs/resilience.md). True
     # restores the step-exact fetch-every-iteration behavior for
-    # debugging; profile=True implies it so trace windows stay
-    # step-aligned.
+    # debugging. profile=True does NOT imply it: a trace shows the loop
+    # the job runs.
     sync_metrics: bool = False
     # jax.profiler trace capture over a step window (SURVEY.md §5: the TPU
-    # equivalent of the reference's named-span-only profiling). Traces are
-    # viewable in TensorBoard / Perfetto.
+    # equivalent of the reference's named-span-only profiling), Python
+    # tracer off, with the loop's own `mtpu/train/...` spans
+    # (utils/tracing.py) beside the device's events. Traces are viewable
+    # in TensorBoard / Perfetto.
     profile: bool = False
     profile_step_start: int = 10
     profile_step_end: int = 12

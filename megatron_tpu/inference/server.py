@@ -221,6 +221,7 @@ class MegatronServer:
         # is a replay of the tail, not recomputation)
         self._streams: dict = {}
         self._streams_lock = threading.Lock()
+        self._trace_lock = threading.Lock()  # one profiler session at a time
         self.engine = None
         if self.serving.fleet:
             # fleet front tier (docs/serving.md "Front door"): the SAME
@@ -1158,9 +1159,11 @@ class MegatronServer:
         ops a remote front tier drives over the wire — swap_weights
         (each replica stages itself from shared storage; a router-
         fronted process runs its own rolling_upgrade), register_adapter
-        (path-only: factors cannot cross the process boundary), drain.
+        (path-only: factors cannot cross the process boundary), drain,
+        trace (the operator's profiler capture, `_capture_trace`).
         Refusals stay typed: 409 for a rejected swap (the process
-        keeps serving its old weights), 400 for bad requests."""
+        keeps serving its old weights) or a second trace, 400 for bad
+        requests."""
         if self.engine is None:
             return 400, {"message": "admin ops require the serving "
                                     "engine (serial_fallback has no "
@@ -1210,8 +1213,51 @@ class MegatronServer:
             drained = self.engine.drain(
                 float(timeout) if timeout is not None else 120.0)
             return 200, {"drained": bool(drained)}
+        if op == "trace":
+            return self._capture_trace(payload)
         return 400, {"message": f"unknown admin op {op!r} (swap_weights"
-                                " | register_adapter | drain)"}
+                                " | register_adapter | drain | trace)"}
+
+    TRACE_MAX_S = 30.0
+
+    def _capture_trace(self, payload: dict) -> Tuple[int, dict]:
+        """`{"op": "trace", "seconds": s, "dir": d}`: one
+        `jax.profiler` session of `s` seconds (at most TRACE_MAX_S: the
+        trace of a busy engine grows by some 1.5 MB a second) written
+        under `d`, Python
+        tracer off, while the engine keeps serving. The reply comes when
+        the trace is written. The engine's `mtpu/serve/...` spans
+        (utils/tracing.py) land in it beside the device's events. The
+        profiler is one per process, so a second capture is refused."""
+        import time as _time
+        import jax
+        from megatron_tpu.utils.tracing import start_trace
+        out = payload.get("dir")
+        if not out or not isinstance(out, str):
+            return 400, {"message": "trace requires dir"}
+        try:
+            seconds = float(payload.get("seconds", 5.0))
+        except (TypeError, ValueError):
+            seconds = float("nan")
+        if not 0.0 < seconds < math.inf:
+            return 400, {"message": "trace seconds must be a positive "
+                                    "number"}
+        seconds = min(seconds, self.TRACE_MAX_S)
+        if not self._trace_lock.acquire(blocking=False):
+            return 409, {"message": "a trace is already being captured"}
+        try:
+            try:
+                start_trace(out)
+            except RuntimeError as e:
+                # a session this server did not start (jax allows one)
+                return 409, {"message": str(e)}
+            try:
+                _time.sleep(seconds)
+            finally:
+                jax.profiler.stop_trace()
+        finally:
+            self._trace_lock.release()
+        return 200, {"dir": out, "seconds": seconds}
 
     def invariant_report(self, strict: bool = False) -> dict:
         """`GET /invariants`: this process runs its OWN sweep

@@ -115,6 +115,7 @@ from __future__ import annotations
 import math
 import threading
 import time
+import traceback
 from typing import List, Optional, Sequence
 
 import jax
@@ -146,6 +147,7 @@ from megatron_tpu.serving.spec_decode import (NGramDrafter,
 from megatron_tpu.serving.structured import (GrammarCompileError,
                                              compile_response_format)
 from megatron_tpu.utils.logging import print_rank_0
+from megatron_tpu.utils.tracing import span
 
 from megatron_tpu.config import SERVING_KV_DTYPES as _KV_DTYPES
 
@@ -736,6 +738,16 @@ class ServingEngine:
         leader's prompt KV blocks copy-on-write (one prefill per
         fan-out on prefix-cache engines). Each child is token-exact vs
         a serial run at its own seed."""
+        with span("serve/submit") as sp:
+            req = self._submit(prompt, max_new_tokens, sampling, seed,
+                               priority, deadline_s, arrival_id,
+                               adapter_id, response_format, n, best_of)
+            sp.set_metadata(rid=req.id)
+        return req
+
+    def _submit(self, prompt, max_new_tokens, sampling, seed, priority,
+                deadline_s, arrival_id, adapter_id, response_format, n,
+                best_of):
         if self._broken:
             # pre-admission gate: the breaker bounces callers before
             # the request is even constructed — deliberately OUTSIDE
@@ -2788,11 +2800,12 @@ class ServingEngine:
                 if self._session():
                     return
             except Exception as e:  # noqa: BLE001 — supervise, not hang
-                import os, traceback
-                if os.environ.get("MTPU_DEBUG_LOOP"):
-                    traceback.print_exc()
                 msg = repr(e)
+                tb = traceback.format_exc()
                 if self._restarts >= self._max_restarts:
+                    print_rank_0(
+                        f"serving engine: loop failed ({msg}); no "
+                        f"restarts left\n{tb}")
                     self._trip_breaker(msg)
                     return
                 self._restarts += 1
@@ -2800,7 +2813,7 @@ class ServingEngine:
                 self.metrics.count("engine_restarts")
                 print_rank_0(
                     f"serving engine: loop failed ({msg}); restarting "
-                    f"({self._restarts}/{self._max_restarts})")
+                    f"({self._restarts}/{self._max_restarts})\n{tb}")
                 try:
                     # suspend the watchdog across the reset: in the
                     # CRASH path (unlike the hang path) it has not
@@ -2824,19 +2837,17 @@ class ServingEngine:
         iteration — the supervisor decides what survives."""
         while True:
             with self._cond:
-                while (not self._stop and not self._draining
-                       and not self._wedged
-                       and self._pending_swap is None
-                       and self.scheduler.depth() == 0
-                       and not self._active.any()
-                       and not self._prefilling):
-                    self._cond.wait(timeout=self._idle_wait)
-                    self._heartbeat()  # idleness is not a hang
-                    # the brownout ladder must step DOWN on an idle
-                    # engine too — after a storm drains, the level
-                    # reverts without needing new traffic to drive
-                    # loop iterations (the monotone-revert law)
-                    self._evaluate_degrade()
+                if self._nothing_to_do():
+                    with span("serve/idle_wait"):
+                        while self._nothing_to_do():
+                            self._cond.wait(timeout=self._idle_wait)
+                            self._heartbeat()  # idleness is not a hang
+                            # the brownout ladder must step DOWN on an
+                            # idle engine too — after a storm drains,
+                            # the level reverts without needing new
+                            # traffic to drive loop iterations (the
+                            # monotone-revert law)
+                            self._evaluate_degrade()
                 if self._stop:
                     return True
                 if (self._draining and not self._active.any()
@@ -2850,6 +2861,22 @@ class ServingEngine:
                     "engine iteration exceeded the watchdog deadline "
                     f"({self.serving.engine_step_timeout_s}s); "
                     "in-flight requests were failed by the watchdog")
+            with span("serve/iteration", active=int(self._active.sum()),
+                      queued=self.scheduler.depth()):
+                self._iteration()
+
+    def _nothing_to_do(self) -> bool:
+        return (not self._stop and not self._draining
+                and not self._wedged
+                and self._pending_swap is None
+                and self.scheduler.depth() == 0
+                and not self._active.any()
+                and not self._prefilling)
+
+    def _iteration(self):
+        """One pass of the engine loop's body: reap, admit (or apply a
+        pending swap), one prefill chunk, one decode window."""
+        with span("serve/reap"):
             self._maybe_decay_restarts()
             self._reap_cancelled()
             self._reap_expired()
@@ -2857,43 +2884,47 @@ class ServingEngine:
             # decode window apart — the dwell counts are calibrated in
             # these units)
             self._evaluate_degrade()
-            if self._pending_swap is not None:
-                # SWAP BARRIER (docs/serving.md "Live weights"): hold
-                # NEW admissions — queued work simply WAITS, nothing is
-                # rejected — while in-flight slots and pending prefills
-                # run to completion under the CURRENT weights. Once the
-                # grid is quiet the swap applies between iterations:
-                # pre-swap admissions are pure version N, post-swap
-                # admissions pure N+1 (the token-exactness pin).
-                if not self._active.any() and not self._prefilling:
-                    with self._cond:
-                        ticket = self._pending_swap
-                        if ticket is not None:
-                            ticket.taken = True
-                            self._pending_swap = None
+        if self._pending_swap is not None:
+            # SWAP BARRIER (docs/serving.md "Live weights"): hold
+            # NEW admissions — queued work simply WAITS, nothing is
+            # rejected — while in-flight slots and pending prefills
+            # run to completion under the CURRENT weights. Once the
+            # grid is quiet the swap applies between iterations:
+            # pre-swap admissions are pure version N, post-swap
+            # admissions pure N+1 (the token-exactness pin).
+            if not self._active.any() and not self._prefilling:
+                with self._cond:
+                    ticket = self._pending_swap
                     if ticket is not None:
+                        ticket.taken = True
+                        self._pending_swap = None
+                if ticket is not None:
+                    with span("serve/swap"):
                         self._apply_swap(ticket)
-                    self._heartbeat()
-                    continue
-            else:
+                self._heartbeat()
+                return
+        else:
+            with span("serve/admit") as sp:
                 self._preempt_for_priority()
-                self._admit()
-            # ONE chunk per iteration (Sarathi-Serve): prefill work
-            # is interleaved with the decode step below, so running
-            # slots keep emitting tokens while a long prompt lands
-            self._advance_prefill()
-            self._heartbeat()  # admit/prefill may compile; decode is
-            #                    the op the deadline protects
-            if self._active.any():
-                self._step()
-            if self._watchdog is not None:
-                if not self._watchdog.started:
-                    # arm only after a full iteration completed — the
-                    # first one includes the jit compiles, whose
-                    # duration is unrelated to steady-state health
-                    self._watchdog.start()
-                else:
-                    self._watchdog.heartbeat()
+                sp.set_metadata(popped=self._admit())
+        # ONE chunk per iteration (Sarathi-Serve): prefill work
+        # is interleaved with the decode step below, so running
+        # slots keep emitting tokens while a long prompt lands
+        self._advance_prefill()
+        self._heartbeat()  # admit/prefill may compile; decode is
+        #                    the op the deadline protects
+        if self._active.any():
+            with span("serve/step",
+                      active=int(self._active.sum())) as sp:
+                sp.set_metadata(K=self._step())
+        if self._watchdog is not None:
+            if not self._watchdog.started:
+                # arm only after a full iteration completed — the
+                # first one includes the jit compiles, whose
+                # duration is unrelated to steady-state health
+                self._watchdog.start()
+            else:
+                self._watchdog.heartbeat()
 
     def _evaluate_degrade(self):
         """One brownout-ladder evaluation (engine thread only — the
@@ -3156,10 +3187,11 @@ class ServingEngine:
         req.state = RequestState.QUEUED
         self.scheduler.requeue(req)
 
-    def _admit(self):
+    def _admit(self) -> int:
+        """Place what the scheduler pops; returns how many it popped."""
         popped = self.scheduler.pop_ready(self.pool.free_count())
         if not popped:
-            return
+            return 0
         pending = list(popped)
         # expose the not-yet-placed pops to the watchdog: a wedge
         # inside a prefill dispatch below leaves them in neither
@@ -3238,7 +3270,9 @@ class ServingEngine:
                     groupable,
                     lambda rr: self._prefill_bucket(len(rr.prompt)),
                     self._prefill_max_batch):
-                self._prefill_group(reqs, padded)
+                with span("serve/prefill", n=len(reqs), padded=padded,
+                          rid=reqs[0].id):
+                    self._prefill_group(reqs, padded)
                 for r in reqs:
                     pending.remove(r)
         except Exception as e:
@@ -3252,6 +3286,7 @@ class ServingEngine:
             raise
         finally:
             self._admitting = []
+        return len(popped)
 
     def _acquire_adapter(self, req: GenRequest) -> str:
         """Resolve req.adapter_id to a pinned bank row (req.bank_idx)
@@ -3649,6 +3684,12 @@ class ServingEngine:
         if not self._prefilling:
             return
         st = self._prefilling[0]
+        with span("serve/prefill_chunk", rid=st.req.id) as sp:
+            sp.set_metadata(tokens=self._prefill_one_chunk(st))
+
+    def _prefill_one_chunk(self, st: _PendingPrefill) -> int:
+        """`_advance_prefill`'s dispatch; returns the real tokens
+        forwarded."""
         plen = len(st.tokens)
         n = plen - st.pos
         if self._chunk is not None:
@@ -3701,6 +3742,7 @@ class ServingEngine:
         if st.pos >= plen:
             self._prefilling.pop(0)
             self._activate_pending(st, plen)
+        return n
 
     def _activate_pending(self, st: _PendingPrefill, plen: int):
         slot, req = st.slot, st.req
@@ -4113,7 +4155,9 @@ class ServingEngine:
         tokens per window — the host pre-walks the draft masks along
         the drafter's guess (spec_decode.build_draft_rounds) — so
         throughput recovery under grammar comes from `speculative_k`,
-        not from the sync interval."""
+        not from the sync interval.
+
+        Returns K, the dispatches this window chained."""
         structured_on = bool((self._mask_state >= 0).any())
         K = 1 if structured_on else self._sync_interval
         inj = get_fault_injector()
@@ -4143,34 +4187,35 @@ class ServingEngine:
                     s = int(act[ordinal % len(act)])
                     self._last_logits = self._last_logits.at[s].set(
                         jnp.nan)
-        if self._sampling_dirty:
-            self._d_temps = jnp.asarray(self._temps)
-            self._d_top_ks = jnp.asarray(self._top_ks)
-            self._d_top_ps = jnp.asarray(self._top_ps)
-            self._sampling_dirty = False
-            self.metrics.count("sampling_uploads")
-        if self._masks_dirty:
-            # grammar masks upload ONLY when some slot's FSM state
-            # actually changed since the last window (_set_slot_mask /
-            # eviction hygiene) — a self-loop state (e.g. `a*`
-            # mid-run) re-uses the resident device mask, which is the
-            # `mask_uploads` counter pin (tests/test_structured.py)
-            self._d_masks = jnp.asarray(self._masks)
-            self._masks_dirty = False
-            self.metrics.count("mask_uploads")
-        if self._lengths_dirty or not self._active.all():
-            # churn re-syncs positions from the host truth; partially
-            # active grids also re-park idle rows each window (at 0 for
-            # hard-freed slots, at their final length for retained
-            # ones) so their device-side drift stays bounded by K
-            self._d_lengths = self._upload_chained(self._lengths)
-            # the residual carry re-uploads with the lengths: the host
-            # mirror is exact at boundaries (it rides the window fetch)
-            # and churn sites rewrite it before setting the dirty flag
-            self._d_reject = self._upload_chained(self._reject)
-            # per-slot adapter rows change only on the same churn
-            self._d_adapter_idx = jnp.asarray(self._adapter_idx)
-            self._lengths_dirty = False
+        with span("serve/step.upload"):
+            if self._sampling_dirty:
+                self._d_temps = jnp.asarray(self._temps)
+                self._d_top_ks = jnp.asarray(self._top_ks)
+                self._d_top_ps = jnp.asarray(self._top_ps)
+                self._sampling_dirty = False
+                self.metrics.count("sampling_uploads")
+            if self._masks_dirty:
+                # grammar masks upload ONLY when some slot's FSM state
+                # actually changed since the last window (_set_slot_mask /
+                # eviction hygiene) — a self-loop state (e.g. `a*`
+                # mid-run) re-uses the resident device mask, which is the
+                # `mask_uploads` counter pin (tests/test_structured.py)
+                self._d_masks = jnp.asarray(self._masks)
+                self._masks_dirty = False
+                self.metrics.count("mask_uploads")
+            if self._lengths_dirty or not self._active.all():
+                # churn re-syncs positions from the host truth; partially
+                # active grids also re-park idle rows each window (at 0 for
+                # hard-freed slots, at their final length for retained
+                # ones) so their device-side drift stays bounded by K
+                self._d_lengths = self._upload_chained(self._lengths)
+                # the residual carry re-uploads with the lengths: the host
+                # mirror is exact at boundaries (it rides the window fetch)
+                # and churn sites rewrite it before setting the dirty flag
+                self._d_reject = self._upload_chained(self._reject)
+                # per-slot adapter rows change only on the same churn
+                self._d_adapter_idx = jnp.asarray(self._adapter_idx)
+                self._lengths_dirty = False
         spec_k = self._spec_k
         if spec_k and self.degrade is not None \
                 and self.degrade.spec_disabled():
@@ -4188,72 +4233,84 @@ class ServingEngine:
         grids = None
         guesses = None
         if spec_k:
-            # draft proposal (host, once per window): per-slot
-            # committed history -> per-round [S, spec_k] grids. Draft
-            # state lives only inside this window — droppable by
-            # construction. Hand the drafter only the tail it can use
-            # (its scan_window, when it declares one): rebuilding the
-            # FULL prompt+generated list per slot per window would be
-            # O(context) python work on the dispatch thread at long
-            # contexts, for tokens the drafter immediately discards.
-            win = getattr(self.drafter, "scan_window", None)
-            histories: List[Optional[List[int]]] = \
-                [None] * self.num_slots
-            for slot in np.nonzero(self._active)[0]:
-                req = self._slot_req[slot]
-                if win is not None and len(req.generated) >= win:
-                    histories[slot] = req.generated[-win:]
-                elif win is not None:
-                    histories[slot] = (
-                        req.prompt[-(win - len(req.generated)):]
-                        + req.generated)
+            with span("serve/step.draft"):
+                # draft proposal (host, once per window): per-slot
+                # committed history -> per-round [S, spec_k] grids. Draft
+                # state lives only inside this window — droppable by
+                # construction. Hand the drafter only the tail it can use
+                # (its scan_window, when it declares one): rebuilding the
+                # FULL prompt+generated list per slot per window would be
+                # O(context) python work on the dispatch thread at long
+                # contexts, for tokens the drafter immediately discards.
+                win = getattr(self.drafter, "scan_window", None)
+                histories: List[Optional[List[int]]] = \
+                    [None] * self.num_slots
+                for slot in np.nonzero(self._active)[0]:
+                    req = self._slot_req[slot]
+                    if win is not None and len(req.generated) >= win:
+                        histories[slot] = req.generated[-win:]
+                    elif win is not None:
+                        histories[slot] = (
+                            req.prompt[-(win - len(req.generated)):]
+                            + req.generated)
+                    else:
+                        histories[slot] = req.prompt + req.generated
+                grids, spec_round, guesses = build_draft_rounds(
+                    histories, self.drafter, spec_k, K)
+        with span("serve/step.dispatch"):
+            # adapter bank args: the stacked factor pytree + per-slot rows
+            # (None/None with adapters off — the empty-pytree args lower to
+            # exactly the pre-adapter graph)
+            lora = self.adapters.stacked if self._adapters_on else None
+            d_aidx = self._d_adapter_idx if self._adapters_on else None
+            tok_steps, lp_steps, acc_steps = [], [], []
+            for r in range(K):
+                if spec_round[r]:
+                    if structured_on:
+                        # host pre-walk: step each structured row's FSM
+                        # along [guess0, d_1..d_k] into per-position
+                        # verify masks (truncates grids[r] in place at
+                        # the first illegal draft — do this BEFORE the
+                        # grid uploads)
+                        d_dm, d_g0 = self._build_round_masks(
+                            grids[r], guesses[r], spec_k)
+                    else:
+                        d_dm, d_g0 = self._d_free_dmask, self._d_no_guess
+                    out = self._verify(
+                        self._p_dec, self.pool.caches,
+                        self._last_logits, self._rngs, self._d_lengths,
+                        self._d_temps, self._d_top_ks, self._d_top_ps,
+                        jnp.asarray(grids[r]), self._d_reject,
+                        self._d_masks, d_dm, d_g0, lora, d_aidx)
+                    acc_steps.append(out[5])
+                    self.metrics.count("spec_rounds")
                 else:
-                    histories[slot] = req.prompt + req.generated
-            grids, spec_round, guesses = build_draft_rounds(
-                histories, self.drafter, spec_k, K)
-        # adapter bank args: the stacked factor pytree + per-slot rows
-        # (None/None with adapters off — the empty-pytree args lower to
-        # exactly the pre-adapter graph)
-        lora = self.adapters.stacked if self._adapters_on else None
-        d_aidx = self._d_adapter_idx if self._adapters_on else None
-        tok_steps, lp_steps, acc_steps = [], [], []
-        for r in range(K):
-            if spec_round[r]:
-                if structured_on:
-                    # host pre-walk: step each structured row's FSM
-                    # along [guess0, d_1..d_k] into per-position
-                    # verify masks (truncates grids[r] in place at
-                    # the first illegal draft — do this BEFORE the
-                    # grid uploads)
-                    d_dm, d_g0 = self._build_round_masks(
-                        grids[r], guesses[r], spec_k)
-                else:
-                    d_dm, d_g0 = self._d_free_dmask, self._d_no_guess
-                out = self._verify(
-                    self._p_dec, self.pool.caches,
-                    self._last_logits, self._rngs, self._d_lengths,
-                    self._d_temps, self._d_top_ks, self._d_top_ps,
-                    jnp.asarray(grids[r]), self._d_reject,
-                    self._d_masks, d_dm, d_g0, lora, d_aidx)
-                acc_steps.append(out[5])
-                self.metrics.count("spec_rounds")
-            else:
-                out = self._decode(
-                    self._p_dec, self.pool.caches,
-                    self._last_logits, self._rngs, self._d_lengths,
-                    self._d_temps, self._d_top_ks, self._d_top_ps,
-                    self._d_reject, self._d_masks, lora, d_aidx)
-                acc_steps.append(None)
-                if spec_k:
-                    self.metrics.count("spec_fallback_steps")
-            (self.pool.caches, self._last_logits, self._rngs) = out[:3]
-            self._d_lengths = out[-2]
-            self._d_reject = out[-1]
-            tok_steps.append(out[3])
-            lp_steps.append(out[4])
-        fetched = self._fetch(
-            (tok_steps, lp_steps,
-             [x for x in acc_steps if x is not None], self._d_reject))
+                    out = self._decode(
+                        self._p_dec, self.pool.caches,
+                        self._last_logits, self._rngs, self._d_lengths,
+                        self._d_temps, self._d_top_ks, self._d_top_ps,
+                        self._d_reject, self._d_masks, lora, d_aidx)
+                    acc_steps.append(None)
+                    if spec_k:
+                        self.metrics.count("spec_fallback_steps")
+                (self.pool.caches, self._last_logits, self._rngs) = out[:3]
+                self._d_lengths = out[-2]
+                self._d_reject = out[-1]
+                tok_steps.append(out[3])
+                lp_steps.append(out[4])
+        with span("serve/step.fetch"):
+            fetched = self._fetch(
+                (tok_steps, lp_steps,
+                 [x for x in acc_steps if x is not None], self._d_reject))
+        with span("serve/step.commit") as sp:
+            sp.set_metadata(
+                tokens=self._commit(fetched, K, spec_round, grids))
+        return K
+
+    def _commit(self, fetched, K: int, spec_round, grids) -> int:
+        """The host's half of a decode window, after the fetch: append
+        each slot's tokens in order, step its FSM, evict what finished,
+        set the gauges. Returns the tokens delivered."""
         self.metrics.count("host_syncs")
         if self._wedged:
             # the watchdog flagged THIS iteration while it was in
@@ -4454,3 +4511,4 @@ class ServingEngine:
         if self._writer is not None and \
                 self._steps % self._report_interval < K:
             self.metrics.report(self._writer, self._steps)
+        return int(consumed.sum())

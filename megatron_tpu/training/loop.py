@@ -21,8 +21,11 @@ Differences by design:
   step-exact path, at most log_interval-1 steps late (rollback restores
   a checkpoint either way). Input batches are lifted to the dp-sharded
   device layout in the prefetch producer thread (batch N+1's transfer
-  overlaps step N). `--sync_metrics` (or profile=True) restores the
-  fetch-every-step behavior.
+  overlaps step N). `--sync_metrics` restores the fetch-every-step
+  behavior; `--profile` traces whichever of the two the job runs.
+- Host spans (`utils/tracing.py`): `mtpu/train/data_next`, `step`,
+  `flush`, `eval`, `save` land in a profiler trace beside the device's
+  events; with no profiler session they cost nothing.
 """
 from __future__ import annotations
 
@@ -48,6 +51,7 @@ from megatron_tpu.data.samplers import PrefetchIterator
 from megatron_tpu.training.microbatches import MicrobatchCalculator
 from megatron_tpu.utils.logging import make_writer, print_rank_0
 from megatron_tpu.utils.timers import Timers
+from megatron_tpu.utils.tracing import span, start_trace, step_span
 
 
 def _device_fetch(tree):
@@ -93,7 +97,8 @@ class _MetricsWindow:
         window. One `_device_fetch` regardless of window length."""
         if not self._its:
             return []
-        vals = _device_fetch(self._metrics)
+        with span("train/flush"):
+            vals = _device_fetch(self._metrics)
         out = [(it, {k: float(v) for k, v in m.items()})
                for it, m in zip(self._its, vals)]
         self._its, self._metrics = [], []
@@ -298,9 +303,9 @@ def train(
     (resilience/faults.py) can poison batches / stall steps here — the
     chaos-test entry points."""
     # async by default: the loop blocks once per log window (the
-    # metrics flush), not per step; sync_metrics / profile restore the
+    # metrics flush), not per step; sync_metrics restores the
     # step-exact barriers (docstring "Host/device overlap")
-    sync_metrics = cfg.training.sync_metrics or cfg.training.profile
+    sync_metrics = cfg.training.sync_metrics
     # Dispatch overlap (run-ahead + committed device_put input lift) is
     # gated to non-cpu backends: CPU jax 0.4.x recycles donated buffers
     # of an in-flight step while they are still referenced — observed
@@ -458,26 +463,30 @@ def train(
                 # deferred iterator exhaustion
                 stop_exc, pending_stop = pending_stop, None
             else:
-                try:
-                    batch = next(train_iterator)
-                except StopIteration as stop:
-                    # exhausted mid-window: the steps already dispatched
-                    # must still reach the guard and the skip/NaN
-                    # counters (the step-exact path observed every one
-                    # of them before this raise) — skip the step, fall
-                    # through to the flush, then re-raise below
-                    stop_exc = stop
-                else:
-                    if injector is not None:
-                        step_call = injector.next_step_call()
-                        injector.maybe_delay(step_call)
-                        batch = injector.corrupt_batch(batch, step_call)
-                    if lift_fn is not None:
-                        batch = lift_fn(batch)
-                    elif batch_sh is not None:
-                        from megatron_tpu.parallel.multihost import \
-                            make_global_batch
-                        batch = make_global_batch(batch, mesh, batch_sh)
+                with span("train/data_next"):
+                    try:
+                        batch = next(train_iterator)
+                    except StopIteration as stop:
+                        # exhausted mid-window: the steps already
+                        # dispatched must still reach the guard and the
+                        # skip/NaN counters (the step-exact path
+                        # observed every one of them before this raise)
+                        # — skip the step, fall through to the flush,
+                        # then re-raise below
+                        stop_exc = stop
+                    else:
+                        if injector is not None:
+                            step_call = injector.next_step_call()
+                            injector.maybe_delay(step_call)
+                            batch = injector.corrupt_batch(batch,
+                                                           step_call)
+                        if lift_fn is not None:
+                            batch = lift_fn(batch)
+                        elif batch_sh is not None:
+                            from megatron_tpu.parallel.multihost import \
+                                make_global_batch
+                            batch = make_global_batch(batch, mesh,
+                                                      batch_sh)
             if stop_exc is None and save_fn is not None:
                 # snapshot the iterator at THIS step's batch, before the
                 # look-ahead pull below advances it — a checkpoint at
@@ -487,14 +496,14 @@ def train(
                 step_rng = jax.random.fold_in(rng, iteration)
                 if (cfg.training.profile and not trace_active
                         and iteration == cfg.training.profile_step_start):
-                    jax.profiler.start_trace(
-                        cfg.training.profile_dir
-                        or cfg.training.tensorboard_dir
-                        or "/tmp/megatron_tpu_trace")
+                    start_trace(cfg.training.profile_dir
+                                or cfg.training.tensorboard_dir
+                                or "/tmp/megatron_tpu_trace")
                     trace_active = True
                 t_step = timers("train-step", log_level=0)
                 t_step.ensure_started()  # async: ONE span per window
-                state, metrics = step_fn(state, batch, step_rng)
+                with step_span("train/step", iteration):
+                    state, metrics = step_fn(state, batch, step_rng)
                 if sync_metrics:
                     # exact-sync path: block on this step's result
                     # before closing the span (the old per-step
@@ -534,7 +543,8 @@ def train(
                     # turn so a finite iterator still serves its last
                     # batch.
                     try:
-                        pending_batch = lift_fn(next(train_iterator))
+                        with span("train/data_next"):
+                            pending_batch = lift_fn(next(train_iterator))
                     except StopIteration as stop:
                         pending_stop = stop
 
@@ -754,7 +764,7 @@ def train(
                 # eval time is unrelated to step health: suspend the
                 # step deadline for its duration
                 with (watchdog.suspend() if watchdog is not None
-                      else _nullcontext()):
+                      else _nullcontext()), span("train/eval"):
                     results = evaluate(state, valid_iterator,
                                        eval_step_fn,
                                        cfg.training.eval_iters,
@@ -790,7 +800,7 @@ def train(
                 # a slow sync save is not a hung STEP — suspend the
                 # deadline while it runs
                 with (watchdog.suspend() if watchdog is not None
-                      else _nullcontext()):
+                      else _nullcontext()), span("train/save"):
                     _call_save_fn(save_fn, state, iteration,
                                   consumed_samples, data_state_now,
                                   quarantine_log)

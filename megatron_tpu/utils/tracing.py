@@ -1,0 +1,67 @@
+"""The program's host spans: `jax.profiler.TraceAnnotation`s named `mtpu/...`.
+
+A span exists only while a profiler session is on (`--profile` on a training
+job, `PUT /admin {"op": "trace"}` on a server, or whoever calls
+`jax.profiler.start_trace` round the code). It then lands in the profiler's
+trace beside the device's events, on the same clock. With no session it is a
+C++ "is anyone tracing" check. There is no recorder, flag or option here.
+
+A span is a `with` block on the thread that does the work; nesting gives the
+parent. Names are constant strings, stats are integers: keyword arguments for
+what is known on entry, `set_metadata(...)` on the span for what is known only
+on exit (marked + below). `rid` is `GenRequest.id`: the spans of one request
+share it.
+
+Serving (`serving/engine.py`, engine thread unless said):
+
+| span | round what | stats |
+|---|---|---|
+| `mtpu/serve/idle_wait` | the `_cond.wait` loop at the top of `_session`: nothing queued, active or prefilling | |
+| `mtpu/serve/iteration` | one pass of the loop's body, `_iteration`; parent of all below but `submit` | `active`, `queued` |
+| `mtpu/serve/reap` | `_maybe_decay_restarts`, `_reap_cancelled`, `_reap_expired`, `_evaluate_degrade` | |
+| `mtpu/serve/admit` | `_preempt_for_priority` + `_admit` (pop, adapter, prefix lookup, grouping) | `popped`+ |
+| `mtpu/serve/prefill` | each `_prefill_group` call (host arrays + the dispatch), child of `admit` | `n`, `padded`, `rid` of the first |
+| `mtpu/serve/prefill_chunk` | `_advance_prefill` when it dispatches, `_activate_pending` included | `rid`, `tokens`+ |
+| `mtpu/serve/swap` | `_apply_swap` | |
+| `mtpu/serve/step` | `_step`; parent of the five below | `active`, `K`+ |
+| `mtpu/serve/step.upload` | the dirty sampling / mask / lengths / adapter-row uploads | |
+| `mtpu/serve/step.draft` | `build_draft_rounds` (only entered with `speculative_k`) | |
+| `mtpu/serve/step.dispatch` | the chain of K `_decode` / `_verify` calls | |
+| `mtpu/serve/step.fetch` | `self._fetch(...)`: the host waits, the device works | |
+| `mtpu/serve/step.commit` | `_commit`, everything after the fetch: per-slot token append, FSM, evictions, gauges, writer | `tokens`+ |
+| `mtpu/serve/submit` | `submit()`, on the caller's thread | `rid`+ |
+
+Training (`training/loop.py`, main thread):
+
+| span | round what | stats |
+|---|---|---|
+| `mtpu/train/data_next` | each pull from the iterator with its lift, the in-step pull and the look-ahead pull | |
+| `mtpu/train/step` | the `step_fn(...)` dispatch; a `StepTraceAnnotation` | `step_num` |
+| `mtpu/train/flush` | the metrics window's `_device_fetch` | |
+| `mtpu/train/eval` | `evaluate(...)` | |
+| `mtpu/train/save` | `save_fn(...)` | |
+
+Which benchmark metric reads which span: PERF.md section 3. How an operator
+reads an idle gap off a trace: docs/serving.md "Observability & drills".
+"""
+from __future__ import annotations
+
+import jax
+
+
+def span(name: str, **stats):
+    return jax.profiler.TraceAnnotation("mtpu/" + name, **stats)
+
+
+def step_span(name: str, step: int):
+    return jax.profiler.StepTraceAnnotation("mtpu/" + name, step_num=step)
+
+
+def start_trace(trace_dir: str) -> None:
+    """`jax.profiler.start_trace` with the Python tracer off: it records
+    every Python call of every thread (543 k events in 5 s of serving,
+    PERF.md section 5) and slows the host loop whose gaps the trace is
+    read for. The spans above and the device's events are kept."""
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
