@@ -46,8 +46,10 @@ KV_CACHE_AXES = ("layers", None, None, "kv_heads", None)
 
 # Generator.generate rounds the prefill length DOWN to this multiple
 # (jit-cache bucketing); the serving engine's seeded-determinism burn
-# (serving/engine.py _initial_rng) counts the serial path's in-prompt
-# RNG splits from the SAME constant — change it in one place only.
+# counts the serial path's in-prompt RNG splits from the SAME constant
+# (serving/engine.py: _rng_burn on the host, and the length of the
+# masked loop in _burned_key, the one compiled call per prefill group
+# that makes the burned keys) — change it in one place only.
 PREFILL_BUCKET = 16
 
 

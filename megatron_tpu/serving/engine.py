@@ -122,8 +122,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from megatron_tpu.inference.generation import (Generator, prefill_chunk,
-                                               verify_tokens)
+from megatron_tpu.inference.generation import (PREFILL_BUCKET, Generator,
+                                               prefill_chunk, verify_tokens)
 from megatron_tpu.inference.sampling import (sample_batched,
                                              verify_draft_probs)
 from megatron_tpu.models import language_model as lm
@@ -150,6 +150,24 @@ from megatron_tpu.utils.logging import print_rank_0
 from megatron_tpu.utils.tracing import span
 
 from megatron_tpu.config import SERVING_KV_DTYPES as _KV_DTYPES
+
+
+def _burned_key(seed, burn):
+    """`PRNGKey(seed)`, then `split(key)[0]` applied `burn` times.
+    `burn` is DATA (a masked update under a fixed-length loop), so the
+    jitted forms below compile once per batch shape and never per burn
+    count; the `jax.random` calls are the serial chain's own, so the
+    key is bit-identical to it."""
+    return jax.lax.fori_loop(
+        0, PREFILL_BUCKET - 1,
+        lambda i, key: jnp.where(i < burn, jax.random.split(key)[0], key),
+        jax.random.PRNGKey(seed))
+
+
+# one device call per request / per prefill group; the result stays on
+# the device, uncommitted, and feeds _insert / _prefill as it is
+_burned_key_jit = jax.jit(_burned_key)
+_burned_keys_jit = jax.jit(jax.vmap(_burned_key))
 
 
 class EngineHungError(RuntimeError):
@@ -2755,19 +2773,34 @@ class ServingEngine:
         return b
 
     @staticmethod
+    def _rng_burn(plen):
+        """Splits the SERIAL path spends on a `plen`-token prompt
+        before its first generated token: Generator.generate rounds the
+        prefill down to a PREFILL_BUCKET multiple and consumes the
+        remaining prompt tokens through decode steps, splitting once
+        per step. One length or an array of them."""
+        return plen - np.maximum(
+            (plen // PREFILL_BUCKET) * PREFILL_BUCKET, 1)
+
+    @staticmethod
     def _initial_rng(seed: int, plen: int):
-        """Per-request key, advanced past the splits the SERIAL path
-        spends on its bucketed in-prompt steps (Generator.generate
-        rounds the prefill down to a PREFILL_BUCKET multiple and
-        consumes the remaining prompt tokens through decode steps,
-        splitting once per step) — so a seeded engine request reproduces
-        the serial output bit-for-bit from the first generated token."""
-        from megatron_tpu.inference.generation import PREFILL_BUCKET
-        key = jax.random.PRNGKey(seed)
-        burn = plen - max((plen // PREFILL_BUCKET) * PREFILL_BUCKET, 1)
-        for _ in range(burn):
-            key = jax.random.split(key)[0]
-        return key
+        """Per-request key, advanced past the serial path's in-prompt
+        splits (`_rng_burn`) — so a seeded engine request reproduces
+        the serial output bit-for-bit from the first generated token.
+        Made by ONE compiled call (`_burned_key`), never by an eager
+        `jax.random.split` per burned step; the one-row form of
+        `_initial_rngs`."""
+        # np.int64 is what PRNGKey makes of a Python int seed
+        return _burned_key_jit(np.int64(seed),
+                               np.int32(ServingEngine._rng_burn(plen)))
+
+    @staticmethod
+    def _initial_rngs(seeds: Sequence[int], plens: Sequence[int]):
+        """`_initial_rng` for a whole prefill group: keys[B, 2] from
+        one compiled call per batch bucket."""
+        return _burned_keys_jit(
+            np.asarray(seeds, np.int64),
+            ServingEngine._rng_burn(np.asarray(plens, np.int32)))
 
     # ------------------------------------------------------------------
     # engine loop (single thread)
@@ -3864,10 +3897,9 @@ class ServingEngine:
         toks[B_real:] = toks[0]
         plens_a = np.asarray(plens + [plens[0]] * (B - B_real), np.int32)
         slots_a = np.asarray(slots + [slots[0]] * (B - B_real), np.int32)
-        rng0s = jnp.stack(
-            [self._initial_rng(r.seed, p)
-             for r, p in zip(reqs, plens)]
-            + [self._initial_rng(reqs[0].seed, plens[0])] * (B - B_real))
+        seeds = [r.seed for r in reqs]
+        rng0s = self._initial_rngs(seeds + [seeds[0]] * (B - B_real),
+                                   plens_a)
         lora = aidxs = None
         if self._adapters_on:
             # per-row bank indices (resolved + pinned in _admit):

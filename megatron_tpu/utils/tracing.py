@@ -20,7 +20,7 @@ Serving (`serving/engine.py`, engine thread unless said):
 | `mtpu/serve/iteration` | one pass of the loop's body, `_iteration`; parent of all below but `submit` | `active`, `queued` |
 | `mtpu/serve/reap` | `_maybe_decay_restarts`, `_reap_cancelled`, `_reap_expired`, `_evaluate_degrade` | |
 | `mtpu/serve/admit` | `_preempt_for_priority` + `_admit` (pop, adapter, prefix lookup, grouping) | `popped`+ |
-| `mtpu/serve/prefill` | each `_prefill_group` call (host arrays + the dispatch), child of `admit` | `n`, `padded`, `rid` of the first |
+| `mtpu/serve/prefill` | each `_prefill_group` call (host arrays, the group's sampling keys as one compiled call, `_initial_rngs`, then the dispatch), child of `admit` | `n`, `padded`, `rid` of the first |
 | `mtpu/serve/prefill_chunk` | `_advance_prefill` when it dispatches, `_activate_pending` included | `rid`, `tokens`+ |
 | `mtpu/serve/swap` | `_apply_swap` | |
 | `mtpu/serve/step` | `_step`; parent of the five below | `active`, `K`+ |

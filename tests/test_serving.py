@@ -1302,6 +1302,98 @@ class TestSeeding:
         assert (srv2._seed_for({}), srv2._seed_for({})) != (a, b)
 
 
+def _serial_chain(seed, burn):
+    """The chain admission ran eagerly before it became one device
+    call: the reference the group function is held to."""
+    key = jax.random.PRNGKey(seed)
+    for _ in range(burn):
+        key = jax.random.split(key)[0]
+    return np.asarray(key)
+
+
+class TestAdmissionKeys:
+    """A group's initial sampling keys come from ONE compiled call
+    (`ServingEngine._initial_rngs`), bit-identical to the serial chain
+    `PRNGKey(seed)` -> `split(...)[0]` x burn, with the burn count as
+    data: one compile per batch bucket, none per burn count, and no
+    eager `jax.random` dispatch between admission and prefill."""
+
+    # 3000000019 does not fit 32 signed bits: PRNGKey wraps it, and so
+    # must the group function
+    @pytest.mark.parametrize("seed", [0, 7, 3000000019])
+    @pytest.mark.parametrize("burn", range(16))
+    def test_group_rows_equal_serial_chain(self, seed, burn):
+        plen = 16 + burn  # one full PREFILL_BUCKET, then `burn` steps
+        assert ServingEngine._rng_burn(plen) == burn
+        want = _serial_chain(seed, burn)
+        # a neighbour row with another seed and another burn count
+        o_seed, o_plen = seed + 1, 16 + (burn + 5) % 16
+        o_want = _serial_chain(o_seed, ServingEngine._rng_burn(o_plen))
+        one = np.asarray(ServingEngine._initial_rngs([seed], [plen]))
+        two = np.asarray(ServingEngine._initial_rngs([seed, o_seed],
+                                                     [plen, o_plen]))
+        # the batch-bucket pad row replicates row 0
+        pad = np.asarray(ServingEngine._initial_rngs([seed, seed],
+                                                     [plen, plen]))
+        assert one.shape == (1, 2) and one.dtype == want.dtype
+        np.testing.assert_array_equal(one[0], want)
+        np.testing.assert_array_equal(two, np.stack([want, o_want]))
+        np.testing.assert_array_equal(pad, np.stack([want, want]))
+        np.testing.assert_array_equal(
+            np.asarray(ServingEngine._initial_rng(seed, plen)), want)
+        if 0 < burn < 15:  # under one bucket all but the first token burn
+            np.testing.assert_array_equal(
+                np.asarray(ServingEngine._initial_rng(seed, burn + 1)),
+                want)
+
+    def test_one_compile_per_batch_bucket_and_no_eager_split(
+            self, tiny_model, monkeypatch):
+        from megatron_tpu.serving import engine as engine_mod
+        params, cfg = tiny_model
+        gen = Generator(params, cfg, eos_id=0, pad_id=0)
+        sampling = SamplingOptions(temperature=0.9, top_k=5)
+        # lengths 17..31 share the padded length 32 and differ in burn
+        # count; the waves admit as groups of 2, 1, 2 and 1
+        waves = [(17, 20), (25,), (18, 31), (29,)]
+        rs = np.random.RandomState(11)
+        prompts = {n: rs.randint(1, 96, n).tolist()
+                   for wave in waves for n in wave}
+        real_split = jax.random.split
+
+        def traced_split_only(key, *a, **kw):
+            assert isinstance(key, jax.core.Tracer), (
+                "eager jax.random.split on the engine thread")
+            return real_split(key, *a, **kw)
+
+        engine_mod._burned_keys_jit.clear_cache()
+        outs = {}
+        eng = ServingEngine(gen, ServingConfig(
+            num_slots=2, max_queue=8, max_len=64, prefill_max_batch=2))
+        try:
+            # undone before the serial oracle below splits eagerly
+            with monkeypatch.context() as m:
+                m.setattr(jax.random, "split", traced_split_only)
+                for wave in waves:
+                    with eng._cond:  # the loop sees the wave whole
+                        reqs = [eng.submit(prompts[n], 4, sampling,
+                                           seed=n) for n in wave]
+                    for n, r in zip(wave, reqs):
+                        outs[n] = r.result(timeout=300)[0]
+            snap = eng.metrics.snapshot()
+        finally:
+            eng.close()
+        assert snap["engine_restarts"] == 0
+        assert snap["prefill_calls"] == len(waves)
+        assert snap["prefill_prompts"] == len(prompts)
+        # batch buckets 2 and 1: six burn counts compiled nothing more
+        assert engine_mod._burned_keys_jit._cache_size() == 2
+        for n, toks in outs.items():
+            want_toks, want_lens, _ = gen.generate(
+                [prompts[n]], 4, sampling=SamplingParams(
+                    temperature=0.9, top_k=5), seed=n)
+            assert toks == want_toks[0, :want_lens[0]].tolist(), n
+
+
 class TestSLOAdmission:
     """SLO-aware admission (scheduler units): the queue orders by
     (priority desc, deadline asc, arrival), early shedding fails fast
