@@ -88,7 +88,7 @@ def build_parser(extra_args_provider: Optional[Callable] = None
     g.add_argument("--moe_capacity_factor", type=float, default=1.25)
     g.add_argument("--moe_aux_loss_coeff", type=float, default=1e-2)
     g.add_argument("--moe_dispatch", type=str, default="sort",
-                   choices=["sort", "dense"])
+                   choices=["sort", "dense", "dropless"])
     g.add_argument("--model", type=str, default=None,
                    help="preset name (llama2-7b, falcon-40b, gpt2, ...)")
 

@@ -127,8 +127,18 @@ class ModelConfig:
     moe_aux_loss_coeff: float = 1e-2
     # dispatch implementation: "sort" (stable-sort routing, one
     # scatter/gather — O(s) memory, the long-context-safe default) |
-    # "dense" (GShard [b,s,E,C] one-hot einsums — the semantic oracle)
+    # "dense" (GShard [b,s,E,C] one-hot einsums — the semantic oracle) |
+    # "dropless" (no capacity: the (token, k) rows of the whole batch
+    # sorted by expert and multiplied group by group, ops/grouped_matmul.py;
+    # a token's output depends on no other token, which serving needs)
     moe_dispatch: str = "sort"
+    # renormalise the chosen top-k router probabilities to sum to 1
+    # (Mixtral: yes; OLMoE's config.json says norm_topk_prob false)
+    moe_norm_topk_prob: bool = True
+    # RMSNorm over the WHOLE q projection and the whole k projection,
+    # before the heads are split and before the rotary (OLMoE's
+    # q_norm/k_norm; part of the architecture, not a tuning knob)
+    qk_norm: bool = False
 
     # glu activations double the first MLP projection
     @property
@@ -958,6 +968,13 @@ class ServingConfig:
                 "builds no serving mesh — drop serial_fallback or the "
                 "tp widths")
             if model is not None:
+                assert not model.qk_norm and not (
+                    model.num_experts > 1
+                    and model.moe_dispatch == "dropless"), (
+                    "serving widths > 1 have not been made to work with "
+                    "qk_norm (a norm across sharded heads) or "
+                    "moe_dispatch='dropless' (one unpartitioned grouped "
+                    "product): serve this model at width 1")
                 for phase, tp in (("prefill", eff_pre),
                                   ("decode", eff_dec)):
                     assert model.num_attention_heads % tp == 0 and \
@@ -1269,9 +1286,9 @@ class MegatronConfig:
             assert 1 <= model.moe_top_k <= model.num_experts, (
                 f"moe_top_k={model.moe_top_k} must be in "
                 f"[1, num_experts={model.num_experts}]")
-            assert model.moe_dispatch in ("sort", "dense"), (
+            assert model.moe_dispatch in ("sort", "dense", "dropless"), (
                 f"moe_dispatch={model.moe_dispatch!r} "
-                "(expected 'sort' or 'dense')")
+                "(expected 'sort', 'dense' or 'dropless')")
             assert par.expert_axis in ("tp", "dp"), par.expert_axis
             if par.expert_axis == "tp":
                 ep_size = max(par.tensor_parallel, 1)
@@ -1312,6 +1329,30 @@ class MegatronConfig:
                 "(hard abort; see PERF_NOTES 'MoE under pp'). Use "
                 "pp=1 for expert parallelism, or pp>1 with "
                 "tensor_parallel=1 / expert_axis='tp'-on-tp1")
+        sharded = {"tensor_parallel": par.tensor_parallel,
+                   "pipeline_parallel": par.pipeline_parallel,
+                   "context_parallel": par.context_parallel}
+        if model.num_experts > 1 and model.moe_dispatch == "dropless":
+            # the grouped product is one Pallas call over the whole token
+            # batch: XLA cannot partition it, and no shard_map has been
+            # written round it (ROADMAP R1: expert parallel on four chips)
+            dp = par.data_parallel or (
+                par.derive_dp(n_devices) if n_devices else 1)
+            assert not model.use_bias and model.quantized_gemm == "none", (
+                "moe_dispatch='dropless' has no expert bias and no int8 "
+                "product: use --moe_dispatch sort for either")
+            assert max(*sharded.values(), dp) == 1, (
+                "moe_dispatch='dropless' has been made to work on one "
+                f"device only (got {sharded}, data_parallel={dp}): use "
+                "--moe_dispatch sort with moe_capacity_factor = "
+                "num_experts / moe_top_k for a dropless run on a mesh")
+        if model.qk_norm:
+            # the norm's statistic runs over every head's channels; with
+            # heads sharded over tp (and the flash kernel under shard_map)
+            # it has never been run
+            assert par.tensor_parallel == 1 and par.context_parallel == 1, (
+                "qk_norm (full-width RMSNorm on q and k) has not been "
+                f"made to work with heads sharded (got {sharded})")
         if model.sliding_window is not None:
             assert model.sliding_window >= 1, (
                 f"sliding_window={model.sliding_window} must be >= 1 "
@@ -1500,6 +1541,39 @@ def mixtral_config(size: str = "8x7b", **overrides) -> ModelConfig:
     return ModelConfig(**base).derived()
 
 
+def olmoe_config(size: str = "1b-7b", **overrides) -> ModelConfig:
+    """OLMoE presets: every size of "1b-7b" is a key of
+    allenai/OLMoE-1B-7B-0125-Instruct's config.json (16 layers, hidden
+    2048, 16 heads of 128 over 16 kv heads, 64 experts of width 1024
+    (`intermediate_size`), 8 a token, norm_topk_prob false, SiLU-gated,
+    RMSNorm eps 1e-5, rope_theta 10000, 4096 positions, vocabulary 50304,
+    untied head, no bias). `model_type` "olmoe" also means QK-norm.
+    Dropless: no capacity, no token dropped."""
+    presets = {
+        "tiny": dict(num_layers=2, hidden_size=64, num_attention_heads=4,
+                     ffn_hidden_size=32, vocab_size=512, seq_length=128,
+                     num_experts=8, moe_top_k=2, attention_impl="dot"),
+        "1b-7b": dict(num_layers=16, hidden_size=2048,
+                      num_attention_heads=16, num_kv_heads=16,
+                      ffn_hidden_size=1024, vocab_size=50304,
+                      seq_length=4096, max_position_embeddings=4096,
+                      num_experts=64, moe_top_k=8),
+    }
+    if size not in presets:
+        raise ValueError(f"unknown olmoe size {size!r}; "
+                         f"valid: {sorted(presets)}")
+    base = dict(
+        use_rotary_emb=True, rope_theta=10000.0, norm_type="rmsnorm",
+        norm_epsilon=1e-5, activation="swiglu", use_bias=False,
+        use_post_ln=False, parallel_attn=False, tie_embed_logits=False,
+        qk_norm=True, moe_norm_topk_prob=False, moe_dispatch="dropless",
+        attention_impl="flash",  # see llama2_config
+    )
+    base.update(presets[size])
+    base.update(overrides)
+    return ModelConfig(**base).derived()
+
+
 def gpt_config(**overrides) -> ModelConfig:
     base = dict(
         num_layers=12, hidden_size=768, num_attention_heads=12,
@@ -1521,5 +1595,7 @@ MODEL_PRESETS = {
     "falcon-40b": lambda: falcon_config("40b"),
     "mixtral-tiny": lambda: mixtral_config("tiny"),
     "mixtral-8x7b": lambda: mixtral_config("8x7b"),
+    "olmoe-tiny": lambda: olmoe_config("tiny"),
+    "olmoe-1b-7b": lambda: olmoe_config("1b-7b"),
     "gpt2": gpt_config,
 }

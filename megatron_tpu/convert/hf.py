@@ -333,8 +333,8 @@ def hf_mixtral_to_params(sd: Mapping[str, np.ndarray], cfg: ModelConfig,
     a Llama backbone: GQA + RMSNorm + rotate-half RoPE at theta 1e6), and
     each block_sparse_moe maps onto models/moe.py:
       gate.weight [E, h]            -> router [h, E]
-      experts.{e}.w1 (gate proj)    -> w1[e, :, 0, :]
-      experts.{e}.w3 (up proj)      -> w1[e, :, 1, :]
+      experts.{e}.w1 (gate proj)    -> w1[e, :, :ffn]
+      experts.{e}.w3 (up proj)      -> w1[e, :, ffn:]
       experts.{e}.w2 (down proj)    -> w2[e]
     Routing semantics match by construction: Mixtral's softmax-then-top-k
     renormalization equals our renormalized top-k of the full softmax.
@@ -347,10 +347,10 @@ def hf_mixtral_to_params(sd: Mapping[str, np.ndarray], cfg: ModelConfig,
     def mlp_import(get, p):
         m = p + "block_sparse_moe."
         w1 = np.stack([
-            np.stack([_t(get(m + f"experts.{e}.w1.weight")),   # gate
-                      _t(get(m + f"experts.{e}.w3.weight"))],  # up
-                     axis=1)
-            for e in range(E)])                                # [E, h, 2, ffn]
+            np.concatenate([_t(get(m + f"experts.{e}.w1.weight")),   # gate
+                            _t(get(m + f"experts.{e}.w3.weight"))],  # up
+                           axis=1)
+            for e in range(E)])                                # [E, h, 2 ffn]
         return {"router": _t(get(m + "gate.weight")),
                 "w1": w1,
                 "w2": np.stack([_t(get(m + f"experts.{e}.w2.weight"))
@@ -368,11 +368,12 @@ def params_to_hf_mixtral(params, cfg: ModelConfig, dtype=np.float32) -> dict:
         m = p + "block_sparse_moe."
         out = {m + "gate.weight": _t(np.asarray(t["mlp"]["router"][i],
                                                 dtype))}
-        w1 = np.asarray(t["mlp"]["w1"][i], dtype)   # [E, h, 2, ffn]
+        w1 = np.asarray(t["mlp"]["w1"][i], dtype)   # [E, h, 2 ffn]
         w2 = np.asarray(t["mlp"]["w2"][i], dtype)   # [E, ffn, h]
+        ffn = w2.shape[1]
         for e in range(E):
-            out[m + f"experts.{e}.w1.weight"] = _t(w1[e, :, 0])
-            out[m + f"experts.{e}.w3.weight"] = _t(w1[e, :, 1])
+            out[m + f"experts.{e}.w1.weight"] = _t(w1[e, :, :ffn])
+            out[m + f"experts.{e}.w3.weight"] = _t(w1[e, :, ffn:])
             out[m + f"experts.{e}.w2.weight"] = _t(w2[e])
         return out
 

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from megatron_tpu.config import ModelConfig
+from megatron_tpu.models.norms import rmsnorm, rmsnorm_init
 from megatron_tpu.models.rope import apply_rotary
 from megatron_tpu.ops.dropout import dropout
 from megatron_tpu.ops.quantized import qdense, wcast
@@ -246,6 +247,9 @@ def attention_init(rng, cfg: ModelConfig, dtype=jnp.float32):
         params["bq"] = jnp.zeros((nq * hd,), dtype)
         params["bkv"] = jnp.zeros((2 * nkv * hd,), dtype)
         params["bo"] = jnp.zeros((h,), dtype)
+    if cfg.qk_norm:
+        params["q_norm"] = rmsnorm_init(nq * hd, dtype)
+        params["k_norm"] = rmsnorm_init(nkv * hd, dtype)
     return params
 
 
@@ -257,7 +261,25 @@ def attention_axes(cfg: ModelConfig):
     }
     if cfg.use_bias:
         axes.update({"bq": ("heads",), "bkv": ("kv_heads",), "bo": ("embed",)})
+    if cfg.qk_norm:
+        axes.update({"q_norm": {"scale": ("heads",)},
+                     "k_norm": {"scale": ("kv_heads",)}})
     return axes
+
+
+def qk_norm(params, q, k, eps: float):
+    """OLMoE's q_norm / k_norm: RMSNorm over ALL channels of the q
+    projection and of the k projection (every head together), before the
+    rotary. q: [b, s, nq, hd], k: [b, t, nkv, hd], split into heads
+    already (the fused kv projection and the LoRA deltas are); the
+    statistic runs over the last two axes flattened. Training, prefill,
+    chunked prefill and decode all come through `attention_apply`, so
+    this is the one place."""
+    with jax.named_scope("mtpu/attn/qk_norm"):
+        def whole(p, x):
+            flat = x.reshape(*x.shape[:-2], x.shape[-2] * x.shape[-1])
+            return rmsnorm(p, flat, eps).reshape(x.shape)
+        return whole(params["q_norm"], q), whole(params["k_norm"], k)
 
 
 def _dot_attention(q, k, v, *, causal: bool, softmax_fp32: bool,
@@ -424,6 +446,10 @@ def attention_apply(
             else:
                 position_ids = kv_cache.offset + jnp.arange(s)[None, :]
                 position_ids = jnp.broadcast_to(position_ids, (b, s))
+
+    if cfg.qk_norm:
+        assert not cross, "qk_norm is self-attention's (OLMoE)"
+        q, k = qk_norm(params, q, k, cfg.norm_epsilon)
 
     if cfg.use_rotary_emb and not cross:
         assert rope_cos is not None and rope_sin is not None, (

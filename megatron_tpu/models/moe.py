@@ -38,6 +38,21 @@ Expert parallelism is the 'experts'-axis sharding on the weight bank and
 the [b, E, C, h] blocks in both paths; GSPMD partitions the dense
 einsums directly and the sort path's scatter/gather by resharding the
 (small, [b, sK]) index vectors.
+
+A third dispatch has no capacity at all:
+
+- "dropless" (OLMoE's default): the router's product and softmax run in
+  float32; the (token, k) choices of the WHOLE [b*s] batch are sorted by
+  expert (stable), the sorted rows are multiplied group by group by
+  ops/grouped_matmul.py (rows [b*s*K, h] x bank [E, h, 2f], activation,
+  x bank [E, f, h]), and each token sums its K rows with their weights.
+  No [b, E, C, h] block exists, no token is dropped, and a token's output
+  depends on no other token: what a served model needs, since a prompt
+  prefilled in a padded bucket and decoded beside strangers must give the
+  model's own full forward. Every row given is multiplied, a bucket's
+  padding included: `model_forward` has no mask of real tokens to hand
+  down (PERF.md section 7, PR 27). One device only (config.validate
+  refuses a mesh).
 """
 from __future__ import annotations
 
@@ -63,10 +78,14 @@ def moe_init(rng, cfg: ModelConfig, dtype=jnp.float32):
     std = cfg.init_method_std
     out_std = (std / math.sqrt(2.0 * cfg.num_layers)
                if cfg.use_scaled_init else std)
-    if cfg.is_glu:
-        w1 = jax.random.normal(k1, (E, h, 2, ffn), dtype) * std
-    else:
-        w1 = jax.random.normal(k1, (E, h, ffn), dtype) * std
+    # A GLU bank is [E, h, 2f], gate columns then value columns, whatever
+    # the dispatch: the matrix the grouped product reads as it is. (The
+    # dense MLP's [h, 2, f] with an expert axis in front has a dimension of
+    # 2 next to the minor one, which the device tiles as (2, 128): viewing
+    # that as [E, h, 2f] is a copy of the whole bank, 2.6 ms a layer in
+    # every step at OLMoE's widths; PERF.md section 6, PR 27.)
+    w1 = jax.random.normal(
+        k1, (E, h, 2 * ffn if cfg.is_glu else ffn), dtype) * std
     params = {
         "router": jax.random.normal(kr, (h, E), dtype) * std,
         "w1": w1,
@@ -82,11 +101,9 @@ def moe_init(rng, cfg: ModelConfig, dtype=jnp.float32):
 def moe_axes(cfg: ModelConfig):
     # experts shard over 'tp' (expert parallelism); the ffn dim stays
     # unsharded — one expert's GEMM runs whole on its device
-    w1_axes = (("experts", "embed", None, None) if cfg.is_glu
-               else ("experts", "embed", None))
     axes = {
         "router": ("embed", None),
-        "w1": w1_axes,
+        "w1": ("experts", "embed", None),
         "w2": ("experts", None, "embed"),
     }
     if cfg.use_bias:
@@ -146,6 +163,37 @@ def _sort_route(idx, gates, E: int, C: int):
     return e, tok, g, pos, keep
 
 
+def _dropless_experts(params, x, idx, gates, cfg: ModelConfig):
+    """The dropless expert products and their combination. x [b, s, h],
+    idx / gates [b, s, K] -> y [b, s, h]."""
+    from megatron_tpu.ops.grouped_matmul import grouped_matmul
+    b, s, h = x.shape
+    E, K, f = cfg.num_experts, cfg.moe_top_k, cfg.ffn_hidden_size
+    n, dtype = b * s, x.dtype
+    with jax.named_scope("mtpu/moe/route"):
+        e = idx.reshape(n * K)               # row r: token r // K, choice r % K
+        e_sorted, order = jax.lax.sort_key_val(
+            e, jnp.arange(n * K, dtype=jnp.int32))        # stable
+        starts = jnp.searchsorted(e_sorted, jnp.arange(E + 1, dtype=e.dtype))
+        group_sizes = jnp.diff(starts).astype(jnp.int32)
+        rows = x.reshape(n, h)[order // K]   # [n*K, h], sorted by expert
+    with jax.named_scope("mtpu/moe/experts"):
+        y1 = grouped_matmul(rows, params["w1"].astype(dtype), group_sizes)
+        if cfg.is_glu:                       # bank [E, h, 2f]: gate, value
+            act = activation_fn(cfg.activation, y1[:, :f], y1[:, f:])
+        else:
+            act = activation_fn(cfg.activation, y1)
+        y2 = grouped_matmul(act, params["w2"].astype(dtype), group_sizes)
+    with jax.named_scope("mtpu/moe/combine"):
+        # back to (token, choice) order
+        inv = jnp.zeros((n * K,), jnp.int32).at[order].set(
+            jnp.arange(n * K, dtype=jnp.int32), unique_indices=True)
+        y2 = y2[inv].reshape(n, K, h)
+        y = jnp.einsum("nkh,nk->nh", y2.astype(jnp.float32),
+                       gates.reshape(n, K))
+    return y.astype(dtype).reshape(b, s, h)
+
+
 def moe_apply(params, x, cfg: ModelConfig):
     """x: [b, s, h] -> (y [b, s, h], aux_loss scalar f32)."""
     b, s, h = x.shape
@@ -153,18 +201,34 @@ def moe_apply(params, x, cfg: ModelConfig):
     K = cfg.moe_top_k
     C = moe_capacity(cfg, s)
     dtype = x.dtype
+    dropless = cfg.moe_dispatch == "dropless"
 
-    logits = x @ params["router"].astype(dtype)             # [b, s, E]
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    gates, idx = jax.lax.top_k(probs, K)                    # [b, s, K]
-    gates = gates / jnp.maximum(
-        jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
+    with jax.named_scope("mtpu/moe/route"):
+        if dropless:
+            # float32 throughout: a top-k choice that flips at a near-tie
+            # between this router and the float32 reference's swaps an
+            # expert, which no tolerance on the logits forgives
+            logits = jnp.dot(x.astype(jnp.float32),
+                             params["router"].astype(jnp.float32),
+                             precision=jax.lax.Precision.HIGHEST)
+        else:
+            logits = x @ params["router"].astype(dtype)     # [b, s, E]
+        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        gates, idx = jax.lax.top_k(probs, K)                # [b, s, K]
+        if cfg.moe_norm_topk_prob:
+            gates = gates / jnp.maximum(
+                jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
 
-    # Switch aux loss on the top-1 assignment (before capacity drops)
-    top1 = jax.nn.one_hot(idx[..., 0], E, dtype=jnp.float32)
-    f_e = jnp.mean(top1, axis=(0, 1))                       # [E]
-    p_e = jnp.mean(probs, axis=(0, 1))
-    aux = E * jnp.sum(f_e * p_e)
+        # Switch aux loss on the top-1 assignment (before capacity drops)
+        top1 = jax.nn.one_hot(idx[..., 0], E, dtype=jnp.float32)
+        f_e = jnp.mean(top1, axis=(0, 1))                   # [E]
+        p_e = jnp.mean(probs, axis=(0, 1))
+        aux = E * jnp.sum(f_e * p_e)
+
+    if dropless:
+        assert not cfg.use_bias and cfg.quantized_gemm == "none", (
+            "the dropless path has no expert bias and no int8 product")
+        return _dropless_experts(params, x, idx, gates, cfg), aux
 
     if cfg.moe_dispatch == "dense":
         dispatch, combine = moe_dispatch(idx, gates, E, C)
@@ -179,36 +243,26 @@ def moe_apply(params, x, cfg: ModelConfig):
         xin = jnp.zeros((b, E, C, h), dtype).at[brow, e, pos_c].add(contrib)
     w1 = params["w1"].astype(dtype)
     w2 = params["w2"].astype(dtype)
-    E_, h_ = w1.shape[0], w1.shape[1]
 
     def bank_gemm(xb, wb):
         # expert GEMMs honor --quantized_gemm like the dense MLP does
-        # (wb flattened to [E, K, N]; the GLU split stays a leading
-        # index of the flattened output)
         if cfg.quantized_gemm == "int8":
             from megatron_tpu.ops.quantized import int8_expert_matmul
             return int8_expert_matmul(xb, wb)
         return jnp.einsum("beck,ekn->becn", xb, wb)
 
-    # the float path einsums the weight banks UNRESHAPED: under the 1F1B
+    # the weight banks are multiplied UNRESHAPED: under the 1F1B
     # store-activations stash, reshaped banks would stop being identity-
     # passthrough vjp leaves and a full bank copy would ride every stash
-    # slot (the _assert_dedup_passthrough guard fires). The int8 path
-    # reshapes (its quantization re-materializes weights anyway) — pair
-    # it with the recompute stash mode.
+    # slot (the _assert_dedup_passthrough guard fires)
+    y1 = bank_gemm(xin, w1)
+    if cfg.is_glu:                           # [.., 2f] -> gate, value
+        y1 = y1.reshape(*y1.shape[:-1], 2, cfg.ffn_hidden_size)
+    if cfg.use_bias:
+        y1 = y1 + params["b1"].astype(dtype)[None, :, None]
     if cfg.is_glu:
-        if cfg.quantized_gemm == "int8":
-            y1 = bank_gemm(xin, w1.reshape(E_, h_, -1))
-            y1 = y1.reshape(*y1.shape[:-1], 2, cfg.ffn_hidden_size)
-        else:
-            y1 = jnp.einsum("bech,ehgf->becgf", xin, w1)
-        if cfg.use_bias:
-            y1 = y1 + params["b1"].astype(dtype)[None, :, None]
         act = activation_fn(cfg.activation, y1[..., 0, :], y1[..., 1, :])
     else:
-        y1 = bank_gemm(xin, w1)
-        if cfg.use_bias:
-            y1 = y1 + params["b1"].astype(dtype)[None, :, None]
         act = activation_fn(cfg.activation, y1)
     y2 = bank_gemm(act, w2)
     if cfg.use_bias:

@@ -325,17 +325,33 @@ def stack_apply(
 
     aux0 = jnp.zeros((), jnp.float32)
     # None entries are empty pytrees: scan passes them through untouched
-    # (the no-adapters / no-cache cases scan the same body shape)
-    xs = (stacked_params, drop_rates, dp_rates, layer_ids, kv_caches,
-          lora_stack)
+    # (the no-adapters case scans the same body shape)
+    xs = (stacked_params, drop_rates, dp_rates, layer_ids, lora_stack)
     if kv_caches is None:
         def body_nocache(carry, scanned):
             p, rate, dp_rate, lid, lw = scanned
             c, _ = body(carry, (p, rate, dp_rate, lid, None, lw))
             return c, None
-        (x, aux), _ = jax.lax.scan(body_nocache, (x, aux0),
-                                   (stacked_params, drop_rates, dp_rates,
-                                    layer_ids, lora_stack))
+        (x, aux), _ = jax.lax.scan(body_nocache, (x, aux0), xs)
         return x, None, aux
-    (x, aux), new_caches = jax.lax.scan(body, (x, aux0), xs)
+
+    # The caches ride the loop as its CARRY, each layer reading its slice
+    # and writing it back: a carry is updated in place, where caches scanned
+    # as xs -> ys make XLA allocate a second stacked cache beside the first
+    # and copy it back after the loop (PERF.md section 6, PR 27: two of six
+    # whole-pool copies in a decode step, and the pool's size in temporaries).
+    def body_carried(carry, scanned):
+        h_aux, caches = carry
+        i, (p, rate, dp_rate, lid, lw) = scanned
+        cache = jax.tree.map(
+            lambda c: jax.lax.dynamic_index_in_dim(c, i, 0, keepdims=False),
+            caches)
+        h_aux, new_cache = body(h_aux, (p, rate, dp_rate, lid, cache, lw))
+        caches = jax.tree.map(
+            lambda c, n: jax.lax.dynamic_update_index_in_dim(
+                c, n.astype(c.dtype), i, 0), caches, new_cache)
+        return (h_aux, caches), None
+
+    ((x, aux), new_caches), _ = jax.lax.scan(
+        body_carried, ((x, aux0), kv_caches), (jnp.arange(num_layers), xs))
     return x, new_caches, aux

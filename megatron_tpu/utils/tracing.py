@@ -41,6 +41,21 @@ Training (`training/loop.py`, main thread):
 | `mtpu/train/eval` | `evaluate(...)` | |
 | `mtpu/train/save` | `save_fn(...)` | |
 
+On the device (`jax.named_scope`, so in the `op_name` of every HLO instruction
+traced under it; models/moe.py and models/attention.py):
+
+| scope | round what |
+|---|---|
+| `mtpu/moe/route` | a dropless expert layer's router product, softmax, top-k and aux loss (every dispatch), the sort of the (token, k) rows by expert, the group sizes, the gather of the sorted rows |
+| `mtpu/moe/experts` | the weight casts, the two grouped products (ops/grouped_matmul.py) and the activation between them |
+| `mtpu/moe/combine` | the gather back to (token, k) order and the weighted sum of a token's K rows |
+| `mtpu/attn/qk_norm` | the RMSNorm over the whole q and the whole k projection (`qk_norm`) |
+
+A TPU v5e's trace names an `XLA Ops` event by the HLO instruction's text, which
+does not hold the `op_name` (PERF.md section 7, PR 27): the scopes are in the
+trace file's HLO metadata, for a viewer, and the expert kernels are found by
+their own name, `%_moe_grouped_matmul.N`.
+
 Which benchmark metric reads which span: PERF.md section 3. How an operator
 reads an idle gap off a trace: docs/serving.md "Observability & drills".
 """

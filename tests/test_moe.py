@@ -159,7 +159,10 @@ def test_single_expert_equals_dense_mlp():
     params = moe_init(jax.random.PRNGKey(0), cfg_moe)
     x = jax.random.normal(jax.random.PRNGKey(1), (1, 32, 64))
     y, aux = moe_apply(params, x, cfg_moe)
-    dense_params = {"w1": params["w1"][0], "w2": params["w2"][0]}
+    # an expert's GLU matrix is [h, 2f] (gate, then value columns), the
+    # dense MLP's [h, 2, f]
+    dense_params = {"w1": params["w1"][0].reshape(64, 2, -1),
+                    "w2": params["w2"][0]}
     y_dense = mlp_apply(dense_params, x, cfg)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_dense),
                                rtol=2e-5, atol=2e-5)
@@ -169,7 +172,7 @@ def test_single_expert_equals_dense_mlp():
 def test_glu_expert_shapes():
     cfg = _cfg(activation="swiglu")
     params = moe_init(jax.random.PRNGKey(0), cfg)
-    assert params["w1"].shape == (4, 64, 2, 96)
+    assert params["w1"].shape == (4, 64, 2 * 96)  # gate, then value
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 64))
     y, _ = moe_apply(params, x, cfg)
     assert y.shape == x.shape

@@ -123,3 +123,35 @@ def test_fused_norm_fwd_bwd(one_chip, norm):
         return y.astype(jnp.float32).sum()
     argnums = (0, 1) if norm == "rmsnorm" else (0, 1, 2)
     _compile(jax.value_and_grad(loss, argnums=argnums), x, s, s)
+
+
+@pytest.mark.parametrize("rows,k,n,grad", [
+    (640, 2048, 2048, False),      # a decode step of 80 slots, first product
+    (640, 1024, 2048, False),      # ... and the second
+    (8192, 2048, 2048, True),      # a 1024-token prefill; training's backward
+    (49152, 2048, 2048, False),    # the largest prefill, 2 x 3072 tokens
+    (49152, 1024, 2048, False)])
+def test_grouped_matmul_at_olmoe_widths(one_chip, rows, k, n, grad):
+    """The dropless experts' grouped product (megablox under
+    `ops/grouped_matmul.py`'s tiling) at OLMoE-1B-7B's widths: 64 experts,
+    hidden 2048, expert width 1024. A tile that does not fit the kernel's
+    16 MiB of fast memory is refused here and nowhere on the CPU."""
+    from megatron_tpu.ops.grouped_matmul import grouped_matmul
+
+    def S(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def product(lhs, rhs, sizes):
+        return grouped_matmul(lhs, rhs, sizes, use_kernel=True)
+
+    def loss(lhs, rhs, sizes):
+        return product(lhs, rhs, sizes).astype(jnp.float32).sum()
+    fn = jax.grad(loss, argnums=(0, 1)) if grad else product
+    text = jax.jit(fn).lower(S((rows, k)), S((64, k, n)),
+                             S((64,), jnp.int32)).compile().as_text()
+    # the trace finds the kernels by these names (benchmark/moe_roofline.py)
+    names = ["_moe_grouped_matmul_dlhs", "_moe_grouped_matmul_drhs"] \
+        if grad else ["%_moe_grouped_matmul."]
+    for name in names:
+        assert any(name in line and "tpu_custom_call" in line
+                   for line in text.splitlines()), name
