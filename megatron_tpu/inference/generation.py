@@ -101,25 +101,14 @@ def init_kv_caches(cfg: ModelConfig, batch: int, max_len: int,
     slot-grid layout (serving/kv_pool.py), where every batch row is an
     independent request at its own sequence position."""
     from megatron_tpu.parallel.sharding import constrain
-    L = cfg.num_layers
     # rolling-cap decision single-sourced in kv_region_cap (the serving
     # pool's slot_nbytes sizes from the same helper)
     max_len = kv_region_cap(cfg, max_len, prefill_len)
-    shape = (L, batch, max_len, cfg.num_kv_heads, cfg.kv_channels)
-    # jnp.dtype normalization: "int8" (cfg-style spelling) must behave
-    # exactly like jnp.int8 — see KVCache.create
-    quant = jnp.dtype(dtype) == jnp.dtype(jnp.int8)
-    sshape = shape[:4] + (1,)
-    return KVCache(
-        k=constrain(jnp.zeros(shape, dtype), KV_CACHE_AXES),
-        v=constrain(jnp.zeros(shape, dtype), KV_CACHE_AXES),
-        offset=jnp.zeros((L, batch) if per_slot_offsets else (L,),
-                         jnp.int32),
-        k_scale=(constrain(jnp.ones(sshape, jnp.float32), KV_CACHE_AXES)
-                 if quant else None),
-        v_scale=(constrain(jnp.ones(sshape, jnp.float32), KV_CACHE_AXES)
-                 if quant else None),
-    )
+    caches = KVCache.create(cfg.num_layers, batch, max_len, cfg.num_kv_heads,
+                            cfg.kv_channels, dtype,
+                            per_slot_offsets=per_slot_offsets)
+    return jax.tree.map(
+        lambda a: constrain(a, KV_CACHE_AXES) if a.ndim == 5 else a, caches)
 
 
 def prefill_chunk(params, tokens, caches, cfg: ModelConfig, *, rope,

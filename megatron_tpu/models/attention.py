@@ -20,7 +20,10 @@ design, not omission:
   kernel in megatron_tpu/ops/flash_attention.py.
 - KV-cache (`InferenceParams`, ref: megatron/text_generation/forward_step.py:
   17-42, used at transformer.py:402-409,482-495) becomes an explicit
-  functional cache pytree updated with lax.dynamic_update_slice.
+  functional cache pytree, STACKED over layers: the layer loop carries the
+  whole stack and each layer appends its tokens at (layer, row, position)
+  of that buffer in place, then reads its own layer of it. No layer of the
+  cache is ever cut out, updated and written back.
 """
 from __future__ import annotations
 
@@ -38,7 +41,10 @@ from megatron_tpu.ops.quantized import qdense, wcast
 
 
 class KVCache(NamedTuple):
-    """Functional KV cache (ref: InferenceParams, forward_step.py:17-42).
+    """Functional KV cache (ref: InferenceParams, forward_step.py:17-42),
+    STACKED over layers: `attention_apply` takes the whole stack and the
+    layer's index, writes that layer's new tokens where they live and
+    returns the stack (stack_apply carries it through the layer loop).
 
     dtype=jnp.int8 stores k/v int8 with per-(batch, token, head) fp32
     scales (k_scale/v_scale, amax over head_dim) — decode streams the
@@ -49,19 +55,20 @@ class KVCache(NamedTuple):
     token's own k/v (one round-trip, same ~0.4% error as the rest of
     the cache); only the offset-0 flash-prefill branch bypasses the
     cache entirely (it reads the raw projections)."""
-    k: jax.Array  # [batch, max_seq, n_kv_heads, head_dim]
+    k: jax.Array  # [layers, batch, max_seq, n_kv_heads, head_dim]
     v: jax.Array
-    # tokens already in cache: scalar int32, or PER-ROW [batch] int32 for
-    # the serving engine's slot grid (each row decodes at its own length;
-    # vector offsets support s == 1 steps only — see attention_apply)
+    # tokens already in cache: [layers] int32 (one position for the whole
+    # batch), or PER-ROW [layers, batch] int32 for the serving engine's
+    # slot grid (each row decodes at its own length)
     offset: jax.Array
-    k_scale: Optional[jax.Array] = None  # [batch, max_seq, n_kv, 1] fp32
-    v_scale: Optional[jax.Array] = None
+    k_scale: Optional[jax.Array] = None  # [layers, batch, max_seq, n_kv, 1]
+    v_scale: Optional[jax.Array] = None  # fp32
 
     @staticmethod
-    def create(batch: int, max_seq: int, n_kv: int, head_dim: int,
-               dtype=jnp.bfloat16):
-        shape = (batch, max_seq, n_kv, head_dim)
+    def create(layers: int, batch: int, max_seq: int, n_kv: int,
+               head_dim: int, dtype=jnp.bfloat16,
+               per_slot_offsets: bool = False):
+        shape = (layers, batch, max_seq, n_kv, head_dim)
         # normalize: accept "int8" the way cfg dtypes are spelled — the
         # raw `dtype == jnp.int8` would be False for the string while
         # jnp.zeros still allocated int8, leaving scales None (crash at
@@ -70,9 +77,10 @@ class KVCache(NamedTuple):
         return KVCache(
             k=jnp.zeros(shape, dtype=dtype),
             v=jnp.zeros(shape, dtype=dtype),
-            offset=jnp.zeros((), dtype=jnp.int32),
-            k_scale=jnp.ones(shape[:3] + (1,), jnp.float32) if quant else None,
-            v_scale=jnp.ones(shape[:3] + (1,), jnp.float32) if quant else None,
+            offset=jnp.zeros((layers, batch) if per_slot_offsets
+                             else (layers,), dtype=jnp.int32),
+            k_scale=jnp.ones(shape[:4] + (1,), jnp.float32) if quant else None,
+            v_scale=jnp.ones(shape[:4] + (1,), jnp.float32) if quant else None,
         )
 
 
@@ -85,8 +93,7 @@ class LoraAdapter(NamedTuple):
       - STACKED (what the bank holds and stack_apply scans): every leaf
         carries a leading 'layers' dim — [L, n, h, r] for the A factors,
         [L, n, r, out] for the B factors — so the stack scan slices one
-        layer's [n, ...] bank per step exactly like it slices the KV
-        caches;
+        layer's [n, ...] bank per step with the layer's params;
       - PER-LAYER (what attention_apply consumes inside the scan):
         [n, h, r] / [n, r, out].
 
@@ -115,15 +122,15 @@ class BlockKVCache(NamedTuple):
     resolve_view/scatter_view bracket in serving/kv_pool.py is exactly
     what this type exists to delete from the decode hot path).
 
-    Shapes are per LAYER once inside the stack scan (stack_apply scans
-    the leading layers dim off every leaf, the map included — it is
-    broadcast over layers by serving/kv_pool.block_native_cache):
+    STACKED over layers like KVCache (the map is broadcast over layers
+    by serving/kv_pool.block_native_cache); attention_apply takes the
+    stack and the layer's index:
 
-      k/v:     [total_blocks, B, nkv, hd]   flat arena (int8 for
-                                            quantized pools)
-      offset:  [num_slots] int32            per-slot live lengths
-      map:     [num_slots, cap/B] int32     logical -> physical block
-      k_scale/v_scale: [total_blocks, B, nkv, 1] fp32 (int8 pools)
+      k/v:     [L, total_blocks, B, nkv, hd]  flat arena (int8 for
+                                              quantized pools)
+      offset:  [L, num_slots] int32           per-slot live lengths
+      map:     [L, num_slots, cap/B] int32    logical -> physical block
+      k_scale/v_scale: [L, total_blocks, B, nkv, 1] fp32 (int8 pools)
 
     attention_apply recognizes this type and takes the block-native
     path: the step's k/v scatter ONLY into the touched arena blocks
@@ -140,53 +147,64 @@ class BlockKVCache(NamedTuple):
     v_scale: Optional[jax.Array] = None
 
 
-def _block_native_update_attend(q, k, v, cache: BlockKVCache, *,
+def _layer_of(a, layer):
+    """Layer `layer` (a traced scalar) of an array stacked over layers."""
+    return jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+
+
+def _block_native_update_attend(q, k, v, stacked: BlockKVCache, layer, *,
                                 scale: float, dtype):
-    """Block-native KV append + kernel attention for one layer.
+    """Block-native KV append + kernel attention for layer `layer` of the
+    stacked arena; returns (out, the stacked cache).
 
     Append: row i's s tokens land at positions offset[i]..offset[i]+s-1
     — physical block map[i, pos // B], in-block slot pos % B — as ONE
-    scatter touching only the written blocks (`mode="drop"` vanishes
-    writes past the region for rows parked at the capacity clamp, the
-    same contract as the contiguous per-slot scatter). Idle rows
-    (map parked on the shared TRASH block) write their garbage there,
-    exactly where scatter_view used to land it.
+    scatter into the stacked arena itself, touching only the written
+    blocks of this layer (`mode="drop"` vanishes writes past the region
+    for rows parked at the capacity clamp, the same contract as the
+    contiguous per-slot scatter). Idle rows (map parked on the shared
+    TRASH block) write their garbage there, exactly where scatter_view
+    used to land it.
 
     Read: the Pallas kernel walks the map — q attends each slot's
     block-chained K/V causally from its own offset, dequantizing int8
-    in kernel. Write-before-read holds like the dot path: the kernel
-    consumes the post-append arena."""
+    in kernel. A custom call cannot read a dynamic slice in place, so
+    this layer's arena is cut out of the stack AFTER the append
+    (write-before-read by data dependence) and handed to the kernel;
+    nothing is written back."""
     from megatron_tpu.ops.block_attention_pallas import \
         block_native_attention
     S, s, nq, hd = q.shape
-    T, B, nkv, _ = cache.k.shape
-    nb = cache.map.shape[1]
+    _, T, B, nkv, _ = stacked.k.shape
+    bmap = _layer_of(stacked.map, layer)
+    nb = bmap.shape[1]
     cap = nb * B
-    offset = cache.offset
+    offset = _layer_of(stacked.offset, layer)
     pos = offset[:, None] + jnp.arange(s)[None, :]          # [S, s]
     blk_log = jnp.minimum(pos // B, nb - 1)
-    phys = jnp.take_along_axis(cache.map, blk_log, axis=1)  # [S, s]
+    phys = jnp.take_along_axis(bmap, blk_log, axis=1)       # [S, s]
     # out-of-region writes (idle rows at the clamp with s > 1) index
     # past the arena and are DROPPED — never wrap, never collide
     phys = jnp.where(pos >= cap, jnp.int32(T), phys)
     inblk = pos % B
 
     def wr(arena, val):
-        return arena.at[phys, inblk].set(val.astype(arena.dtype),
-                                         mode="drop")
+        return arena.at[layer, phys, inblk].set(val.astype(arena.dtype),
+                                                mode="drop")
 
-    if cache.k.dtype == jnp.int8:
+    quant = stacked.k.dtype == jnp.int8
+    ks = vs = None
+    if quant:
         from megatron_tpu.ops.quantized import quantize_rows
-        ki, ks = quantize_rows(k)  # per (slot, token, head) scales
-        vi, vs = quantize_rows(v)
-        cache = cache._replace(
-            k=wr(cache.k, ki), v=wr(cache.v, vi),
-            k_scale=wr(cache.k_scale, ks),
-            v_scale=wr(cache.v_scale, vs),
-            offset=offset + s)
-    else:
-        cache = cache._replace(k=wr(cache.k, k), v=wr(cache.v, v),
-                               offset=offset + s)
+        k, ks = quantize_rows(k)  # per (slot, token, head) scales
+        v, vs = quantize_rows(v)
+    stacked = stacked._replace(
+        k=wr(stacked.k, k), v=wr(stacked.v, v),
+        k_scale=wr(stacked.k_scale, ks) if quant else None,
+        v_scale=wr(stacked.v_scale, vs) if quant else None,
+        offset=jax.lax.dynamic_update_index_in_dim(
+            stacked.offset, offset + s, layer, 0))
+    cache = jax.tree.map(lambda a: _layer_of(a, layer), stacked)
     # TP-sharded serving (serving/topology.py): XLA cannot partition a
     # custom call, so with a tp mesh active the kernel runs under an
     # explicit shard_map on the head-sharded arena — each tp shard
@@ -226,7 +244,7 @@ def _block_native_update_attend(q, k, v, cache: BlockKVCache, *,
         out = jax.shard_map(_kern, mesh=mesh,
                             in_specs=tuple(in_specs),
                             out_specs=h_spec, check_vma=False)(*args)
-    return out.astype(dtype), cache
+    return out.astype(dtype), stacked
 
 
 def attention_init(rng, cfg: ModelConfig, dtype=jnp.float32):
@@ -354,6 +372,7 @@ def attention_apply(
     rope_sin=None,
     position_ids=None,
     kv_cache: Optional[KVCache] = None,
+    cache_layer=None,
     layer_number: int = 1,
     dropout_rng=None,
     deterministic: bool = True,
@@ -364,6 +383,12 @@ def attention_apply(
     adapters=None,
 ):
     """Forward pass. x: [b, s, h]. Returns (out [b, s, h], new_kv_cache).
+
+    `kv_cache` is the cache STACKED over layers (KVCache or BlockKVCache)
+    and `cache_layer` this layer's index in it (a traced scalar inside
+    stack_apply's loop). The layer's new k/v are written into the stack
+    where they live and the stack is returned: the loop carries one
+    buffer, updated in place, and no layer of it is copied out or back.
 
     `causal=False` gives a bidirectional encoder (BERT/T5-encoder,
     ref: megatron/model/transformer.py AttnMaskType.padding).
@@ -425,7 +450,7 @@ def attention_apply(
     q_offset = None
     per_slot = False
     if kv_cache is not None:
-        q_offset = kv_cache.offset
+        q_offset = _layer_of(kv_cache.offset, cache_layer)
         # PER-SLOT offsets (vector [b]): every batch row sits at its own
         # sequence position — the continuous-batching engine's slot grid
         # (serving/engine.py). s == 1 is the classic decode step; s > 1
@@ -444,7 +469,7 @@ def attention_apply(
             if per_slot:
                 position_ids = q_offset[:, None] + jnp.arange(s)[None, :]
             else:
-                position_ids = kv_cache.offset + jnp.arange(s)[None, :]
+                position_ids = q_offset + jnp.arange(s)[None, :]
                 position_ids = jnp.broadcast_to(position_ids, (b, s))
 
     if cfg.qk_norm:
@@ -484,7 +509,8 @@ def attention_apply(
             "the resolve/scatter bracket there (ServingConfig.validate)")
         assert not dropout_active, "no dropout on the serving path"
         out, kv_cache = _block_native_update_attend(
-            q, k, v, kv_cache, scale=1.0 / math.sqrt(hd), dtype=dtype)
+            q, k, v, kv_cache, cache_layer, scale=1.0 / math.sqrt(hd),
+            dtype=dtype)
         out = out.reshape(b, s, nq * hd)
         proj = qdense(out, wcast(params["wo"], dtype), cfg.quantized_gemm)
         if lw is not None:
@@ -522,7 +548,7 @@ def attention_apply(
     # algorithm — the exclusions are erased structurally, at the cost
     # of O(s^2) score materialization for int8-flash prefills.
     cache_rolling = (kv_cache is not None and cfg.sliding_window is not None
-                     and kv_cache.k.shape[1] == cfg.sliding_window)
+                     and kv_cache.k.shape[2] == cfg.sliding_window)
     cache_quant = kv_cache is not None and kv_cache.k.dtype == jnp.int8
     prefill_flash = (cfg.attention_impl == "flash" and kv_cache is not None
                      and s > 1 and segment_ids is None and causal
@@ -532,19 +558,16 @@ def attention_apply(
 
     kv_positions = None
     if kv_cache is not None:
-        cap = kv_cache.k.shape[1]
+        cap = kv_cache.k.shape[2]
         # ROLLING mode: the cache holds only the last `sliding_window`
         # positions (capacity == window). Writes land at position % W and
         # reads mask by the slot->position map below — O(W) serving
         # memory for unbounded streams. Created by init_kv_caches when
         # cfg.sliding_window < max_len.
-        rolling = (cfg.sliding_window is not None
-                   and cap == cfg.sliding_window)
-        quant = kv_cache.k.dtype == jnp.int8
-        if quant:
+        if cache_quant:
             from megatron_tpu.ops.quantized import quantize_rows
-            ki, ks = quantize_rows(k)  # per (b, token, head) over head_dim
-            vi, vs = quantize_rows(v)
+            k_new, ks = quantize_rows(k)  # per (b, token, head) over head_dim
+            v_new, vs = quantize_rows(v)
             if prefill_flash:
                 # ROLLING int8 prefill keeps the flash shortcut (a
                 # prompt longer than W cannot take the cached dot
@@ -554,108 +577,91 @@ def attention_apply(
                 # dequantized ring) see the same numbers the prefill
                 # attended, and a retained rolling prefix clone stays
                 # token-consistent with the cache-off path.
-                k_raw = ki.astype(dtype) * ks.astype(dtype)
-                v_raw = vi.astype(dtype) * vs.astype(dtype)
-        if per_slot:
-            # serving slot grid: row i writes its s tokens' k/v at its
-            # own offset[i]..offset[i]+s-1 (one scatter, [b, s] index
-            # grids) — through the ring (position % W) when the buffer
-            # is rolling. s > 1 is the speculative-verify window; its
-            # rewind invariant (rejected-position KV overwritten
-            # write-before-read) cannot hold on a rolling ring, so the
-            # engine excludes that combination (ServingConfig.validate).
-            assert s == 1 or not rolling, (
-                "per-slot multi-token appends (speculative verify) are "
-                "undefined on ROLLING caches: a rejected draft's ring "
-                "write already evicted history — see "
-                "ServingConfig.validate")
-            rows = jnp.arange(b)[:, None]
-            slots = kv_cache.offset[:, None] + jnp.arange(s)[None, :]
-            if rolling:
+                k_raw = k_new.astype(dtype) * ks.astype(dtype)
+                v_raw = v_new.astype(dtype) * vs.astype(dtype)
+        else:
+            k_new, v_new, ks, vs = k, v, None, None
+        # Every write below goes to (cache_layer, row, position) of the
+        # STACKED buffer itself: its operand is the layer loop's carry,
+        # so XLA updates it in place and moves only the new tokens.
+        if per_slot or cache_rolling:
+            if per_slot:
+                # serving slot grid: row i writes its s tokens' k/v at
+                # its own offset[i]..offset[i]+s-1 (one scatter, [b, s]
+                # index grids) — through the ring (position % W) when
+                # the buffer is rolling. s > 1 is the speculative-verify
+                # window; its rewind invariant (rejected-position KV
+                # overwritten write-before-read) cannot hold on a
+                # rolling ring, so the engine excludes that combination
+                # (ServingConfig.validate).
+                assert s == 1 or not cache_rolling, (
+                    "per-slot multi-token appends (speculative verify) are "
+                    "undefined on ROLLING caches: a rejected draft's ring "
+                    "write already evicted history — see "
+                    "ServingConfig.validate")
+                n_keep = s
+                slots = q_offset[:, None] + jnp.arange(s)[None, :]
+            else:
+                # tokens beyond the window never survive a chunked
+                # write: keep only the last min(s, W) and scatter to
+                # their slots (unique by construction). Multi-token
+                # chunks are CORRECT when (a) routed through the
+                # offset-0 flash prefill (outputs come from the raw k/v;
+                # the cache just ends in the right state) or (b) s <= W
+                # at offset 0 on the dot path (nothing is overwritten).
+                # Mid-stream s > 1 chunks would need history this buffer
+                # already dropped — generation.py only prefills at
+                # offset 0, which is the caller contract here.
+                assert s == 1 or prefill_flash or s <= cap, (
+                    "rolling KV cache: multi-token steps need the flash "
+                    "prefill or s <= sliding_window (decode steps are "
+                    "s == 1)")
+                n_keep = min(s, cap)  # static: plain slices, no gather
+                slots = (q_offset + (s - n_keep)
+                         + jnp.arange(n_keep))[None, :]
+            if cache_rolling:
                 slots = slots % cap
+            rows = jnp.arange(b)[:, None]
+
             # mode="drop": a row parked at the capacity clamp
             # (serving/engine.py keeps device lengths <= max_len-1)
             # would index past the region with s > 1 — those writes are
             # garbage for garbage rows and must vanish, not wrap or
             # collide nondeterministically at cap-1
             def wr(buf, val):
-                return buf.at[rows, slots].set(val.astype(buf.dtype),
-                                               mode="drop")
-
-            if quant:
-                kv_cache = KVCache(wr(kv_cache.k, ki), wr(kv_cache.v, vi),
-                                   kv_cache.offset + s,
-                                   wr(kv_cache.k_scale, ks),
-                                   wr(kv_cache.v_scale, vs))
-            else:
-                kv_cache = KVCache(wr(kv_cache.k, k), wr(kv_cache.v, v),
-                                   kv_cache.offset + s)
-            if rolling:
-                # per-row map: slot j holds the largest p <= t_last[row]
-                # with p % W == j (sentinel for never-written slots)
-                t_last = kv_cache.offset[:, None] - 1  # [b, 1]
-                j = jnp.arange(cap)[None, :]
-                p = t_last - ((t_last - j) % cap)
-                kv_positions = jnp.where(p >= 0, p, jnp.int32(2 ** 30))
-        elif rolling:
-            # tokens beyond the window never survive a chunked write:
-            # keep only the last min(s, W) and scatter to their slots
-            # (unique by construction). Multi-token chunks are CORRECT
-            # when (a) routed through the offset-0 flash prefill (outputs
-            # come from the raw k/v; the cache just ends in the right
-            # state) or (b) s <= W at offset 0 on the dot path (nothing
-            # is overwritten). Mid-stream s > 1 chunks would need history
-            # this buffer already dropped — generation.py only prefills
-            # at offset 0, which is the caller contract here.
-            assert s == 1 or prefill_flash or s <= cap, (
-                "rolling KV cache: multi-token steps need the flash "
-                "prefill or s <= sliding_window (decode steps are s == 1)")
-            n_keep = min(s, cap)  # static: plain slices, no gather
-            slots = (kv_cache.offset + (s - n_keep)
-                     + jnp.arange(n_keep)) % cap
-
+                return buf.at[cache_layer, rows, slots].set(
+                    val[:, s - n_keep:].astype(buf.dtype), mode="drop")
+        else:
             def wr(buf, val):
-                return buf.at[:, slots].set(
-                    val[:, s - n_keep:].astype(buf.dtype))
+                return jax.lax.dynamic_update_slice(
+                    buf, val[None].astype(buf.dtype),
+                    (cache_layer, 0, q_offset, 0, 0))
 
-            if quant:
-                kv_cache = KVCache(wr(kv_cache.k, ki), wr(kv_cache.v, vi),
-                                   kv_cache.offset + s,
-                                   wr(kv_cache.k_scale, ks),
-                                   wr(kv_cache.v_scale, vs))
-            else:
-                kv_cache = KVCache(wr(kv_cache.k, k), wr(kv_cache.v, v),
-                                   kv_cache.offset + s)
-            # slot j holds the largest position p <= t_last with
-            # p % W == j; never-written slots (p < 0) map to a sentinel
-            # the causal mask rejects
-            t_last = kv_cache.offset - 1
-            j = jnp.arange(cap)
-            p = t_last - ((t_last - j) % cap)
+        kv_cache = KVCache(
+            wr(kv_cache.k, k_new), wr(kv_cache.v, v_new),
+            jax.lax.dynamic_update_index_in_dim(
+                kv_cache.offset, q_offset + s, cache_layer, 0),
+            wr(kv_cache.k_scale, ks) if cache_quant else None,
+            wr(kv_cache.v_scale, vs) if cache_quant else None)
+        if cache_rolling:
+            # slot j holds the largest position p <= t_last (per row on
+            # the slot grid) with p % W == j; never-written slots (p < 0)
+            # map to a sentinel the causal mask rejects
+            t_last = q_offset + s - 1
+            if per_slot:
+                t_last = t_last[:, None]  # [b, 1]
+            p = t_last - ((t_last - jnp.arange(cap)) % cap)
             kv_positions = jnp.where(p >= 0, p, jnp.int32(2 ** 30))
-        else:
-            dus = jax.lax.dynamic_update_slice_in_dim
-            if quant:
-                kv_cache = KVCache(
-                    dus(kv_cache.k, ki, kv_cache.offset, axis=1),
-                    dus(kv_cache.v, vi, kv_cache.offset, axis=1),
-                    kv_cache.offset + s,
-                    dus(kv_cache.k_scale, ks, kv_cache.offset, axis=1),
-                    dus(kv_cache.v_scale, vs, kv_cache.offset, axis=1))
-            else:
-                kv_cache = KVCache(
-                    dus(kv_cache.k, k.astype(kv_cache.k.dtype),
-                        kv_cache.offset, axis=1),
-                    dus(kv_cache.v, v.astype(kv_cache.v.dtype),
-                        kv_cache.offset, axis=1),
-                    kv_cache.offset + s)
-        if quant:
-            # dequant at read; XLA fuses convert*scale into the attention
-            # dot's operand load, so HBM streams the int8 payload
-            k = kv_cache.k.astype(dtype) * kv_cache.k_scale.astype(dtype)
-            v = kv_cache.v.astype(dtype) * kv_cache.v_scale.astype(dtype)
-        else:
-            k, v = kv_cache.k.astype(dtype), kv_cache.v.astype(dtype)
+        # The read is this layer of the buffer AFTER the write (a step's
+        # own tokens are attended; data dependence keeps the order). It
+        # feeds the products directly, so XLA can fuse the slice (and the
+        # int8 dequant: convert*scale) into the dot's operand load and
+        # stream the pool from HBM once.
+        k = _layer_of(kv_cache.k, cache_layer).astype(dtype)
+        v = _layer_of(kv_cache.v, cache_layer).astype(dtype)
+        if cache_quant:
+            k = k * _layer_of(kv_cache.k_scale, cache_layer).astype(dtype)
+            v = v * _layer_of(kv_cache.v_scale, cache_layer).astype(dtype)
 
     scale = 1.0 / math.sqrt(hd)
     # Note on apply_query_key_layer_scaling: in the reference it divides QK^T

@@ -113,6 +113,7 @@ def layer_apply(
     rope_sin=None,
     position_ids=None,
     kv_cache=None,
+    cache_layer=None,
     layer_number: int = 1,
     hidden_dropout: Optional[float] = None,
     drop_path_rate=None,
@@ -126,6 +127,10 @@ def layer_apply(
 ):
     """One transformer layer. x: [b, s, h]. Returns (x, kv_cache, aux) —
     `aux` is the MoE router's load-balancing loss (0.0 for dense MLPs).
+
+    `kv_cache` is the cache stacked over layers and `cache_layer` this
+    layer's index in it; both pass through to attention_apply, which
+    appends in place and hands the stack back.
 
     `adapters`: (per-layer LoraAdapter bank, adapter_idx [b]) for the
     SELF-attention projections only (multi-tenant LoRA serving —
@@ -177,7 +182,8 @@ def layer_apply(
     attn_out, kv_cache = attention_apply(
         params["attention"], ln_out, cfg,
         rope_cos=rope_cos, rope_sin=rope_sin, position_ids=position_ids,
-        kv_cache=kv_cache, layer_number=layer_number,
+        kv_cache=kv_cache, cache_layer=cache_layer,
+        layer_number=layer_number,
         dropout_rng=r_score, deterministic=deterministic,
         segment_ids=segment_ids, causal=causal,
         cp_pre_zigzag=cp_pre_zigzag, adapters=adapters)
@@ -279,8 +285,8 @@ def stack_apply(
     stages (ref: transformer.py:1014-1044 layer offsets for vpp).
 
     `adapters`: (STACKED LoraAdapter with a leading 'layers' dim,
-    adapter_idx [b]) — the factor bank rides the scan like the KV
-    caches (each step slices one layer's [n, ...] bank), the per-row
+    adapter_idx [b]) — the factor bank rides the scan with the params
+    (each step slices one layer's [n, ...] bank), the per-row
     index is layer-invariant and closes over the body. None compiles to
     exactly today's graph (multi-tenant LoRA serving,
     models/attention.py)."""
@@ -296,15 +302,24 @@ def stack_apply(
     lora_stack, adapter_idx = (adapters if adapters is not None
                                else (None, None))
 
+    # The stacked caches ride the loop as its CARRY and go down whole,
+    # with the layer's index: attention_apply writes the layer's new
+    # tokens into the carried buffer itself (XLA updates a carry in
+    # place) and reads its layer of it for the products. Scanned xs -> ys
+    # they cost a second stacked cache and its copy back; handed down one
+    # layer at a time, a copy of that layer out and in (PERF.md section
+    # 6, PRs 27 and 28). Without caches the carry holds None, an empty
+    # pytree, and the loop is the plain one.
     def body(carry, scanned):
-        h, aux_sum = carry
-        p, rate, dp_rate, lid, cache, lw = scanned
+        h, aux_sum, caches = carry
+        p, rate, dp_rate, lid, lw = scanned
         layer_rng = None
         if rng is not None and not deterministic:
             layer_rng = jax.random.fold_in(rng, lid)
-        h, new_cache, aux = layer_apply(
+        h, caches, aux = layer_apply(
             p, h, cfg, rope_cos=rope_cos, rope_sin=rope_sin,
-            position_ids=position_ids, kv_cache=cache,
+            position_ids=position_ids, kv_cache=caches,
+            cache_layer=None if caches is None else lid - layer_offset,
             layer_number=lid + 1, hidden_dropout=rate,
             drop_path_rate=dp_rate if use_drop_path else None,
             rng=layer_rng,
@@ -312,7 +327,7 @@ def stack_apply(
             causal=causal, encoder_output=encoder_output,
             cp_pre_zigzag=cp_pre_zigzag,
             adapters=(lw, adapter_idx) if lw is not None else None)
-        return (h, aux_sum + aux), new_cache
+        return (h, aux_sum + aux, caches), None
 
     if cfg.recompute_granularity == "full":
         body = jax.checkpoint(body, prevent_cse=False)
@@ -327,31 +342,5 @@ def stack_apply(
     # None entries are empty pytrees: scan passes them through untouched
     # (the no-adapters case scans the same body shape)
     xs = (stacked_params, drop_rates, dp_rates, layer_ids, lora_stack)
-    if kv_caches is None:
-        def body_nocache(carry, scanned):
-            p, rate, dp_rate, lid, lw = scanned
-            c, _ = body(carry, (p, rate, dp_rate, lid, None, lw))
-            return c, None
-        (x, aux), _ = jax.lax.scan(body_nocache, (x, aux0), xs)
-        return x, None, aux
-
-    # The caches ride the loop as its CARRY, each layer reading its slice
-    # and writing it back: a carry is updated in place, where caches scanned
-    # as xs -> ys make XLA allocate a second stacked cache beside the first
-    # and copy it back after the loop (PERF.md section 6, PR 27: two of six
-    # whole-pool copies in a decode step, and the pool's size in temporaries).
-    def body_carried(carry, scanned):
-        h_aux, caches = carry
-        i, (p, rate, dp_rate, lid, lw) = scanned
-        cache = jax.tree.map(
-            lambda c: jax.lax.dynamic_index_in_dim(c, i, 0, keepdims=False),
-            caches)
-        h_aux, new_cache = body(h_aux, (p, rate, dp_rate, lid, cache, lw))
-        caches = jax.tree.map(
-            lambda c, n: jax.lax.dynamic_update_index_in_dim(
-                c, n.astype(c.dtype), i, 0), caches, new_cache)
-        return (h_aux, caches), None
-
-    ((x, aux), new_caches), _ = jax.lax.scan(
-        body_carried, ((x, aux0), kv_caches), (jnp.arange(num_layers), xs))
-    return x, new_caches, aux
+    (x, aux, kv_caches), _ = jax.lax.scan(body, (x, aux0, kv_caches), xs)
+    return x, kv_caches, aux

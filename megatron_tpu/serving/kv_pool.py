@@ -67,8 +67,10 @@ def insert_prefill(pool: KVCache, prefill: KVCache, slot, plen) -> KVCache:
     (full-length or rolling W), dtypes, and scale tensors line up.
     Only the row's offset is set to `plen`, the TRUE prompt length: a
     bucket-padded prefill leaves pad garbage at [plen, padded), which
-    decode overwrites write-before-read (attention_apply writes position
-    `offset` before attending it)."""
+    decode overwrites write-before-read: attention_apply writes position
+    `offset` of its layer into the stacked pool and attends that layer
+    of the buffer it has just written, so the order is a data dependence
+    and not a convention."""
     dus = jax.lax.dynamic_update_slice
     zero = jnp.int32(0)
     slot = jnp.asarray(slot, jnp.int32)
@@ -200,7 +202,7 @@ def scatter_view(bkv: BlockKV, view: KVCache) -> BlockKV:
 def block_native_cache(bkv: BlockKV) -> BlockKVCache:
     """View a BlockKV as the model-facing BlockKVCache WITHOUT moving
     any data: arena leaves pass through, the per-slot map broadcasts
-    over layers so the stack scan can slice it per layer (a few KiB of
+    over layers so attention can index it by layer (a few KiB of
     int32 — the whole point is that block INDICES, not block contents,
     are what dispatch resolves). The engine's block-native decode /
     verify programs (`--block_native_attn`) hand this to

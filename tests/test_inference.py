@@ -375,11 +375,12 @@ class TestRollingKVCache:
         x = jnp.asarray(np.random.RandomState(4).randn(1, 8, 64), jnp.float32)
         for offset, finite in ((0, True), (16, False)):
             cache = KVCache(
-                k=jnp.zeros((1, 32, 2, 16), jnp.bfloat16),
-                v=jnp.zeros((1, 32, 2, 16), jnp.bfloat16),
-                offset=jnp.asarray(offset, jnp.int32))
+                k=jnp.zeros((1, 1, 32, 2, 16), jnp.bfloat16),
+                v=jnp.zeros((1, 1, 32, 2, 16), jnp.bfloat16),
+                offset=jnp.asarray([offset], jnp.int32))
             y, _ = attention_apply(p, x, acfg, rope_cos=rope.cos,
-                                   rope_sin=rope.sin, kv_cache=cache)
+                                   rope_sin=rope.sin, kv_cache=cache,
+                                   cache_layer=0)
             assert bool(np.isfinite(np.asarray(y)).all()) is finite, offset
 
     @pytest.mark.parametrize("delta,dot_cap", [(-1, 32), (0, 32),

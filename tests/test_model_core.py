@@ -149,14 +149,17 @@ class TestAttention:
         rope = make_rope(cfg)
         x = jax.random.normal(jax.random.PRNGKey(1), (1, 12, 64))
         y_full, _ = attention_apply(p, x, cfg, rope_cos=rope.cos, rope_sin=rope.sin)
-        cache = KVCache.create(1, 32, cfg.num_kv_heads, cfg.kv_channels, jnp.float32)
+        cache = KVCache.create(1, 1, 32, cfg.num_kv_heads, cfg.kv_channels,
+                               jnp.float32)
         # prefill 8, then decode 4 one at a time
         y_pre, cache = attention_apply(p, x[:, :8], cfg, rope_cos=rope.cos,
-                                       rope_sin=rope.sin, kv_cache=cache)
+                                       rope_sin=rope.sin, kv_cache=cache,
+                                       cache_layer=0)
         outs = [y_pre]
         for t in range(8, 12):
             y_t, cache = attention_apply(p, x[:, t:t + 1], cfg, rope_cos=rope.cos,
-                                         rope_sin=rope.sin, kv_cache=cache)
+                                         rope_sin=rope.sin, kv_cache=cache,
+                                         cache_layer=0)
             outs.append(y_t)
         y_inc = jnp.concatenate(outs, axis=1)
         np.testing.assert_allclose(y_inc, y_full, atol=1e-4)

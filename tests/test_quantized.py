@@ -299,10 +299,11 @@ class TestWeightQuantizedServing:
         step = jax.random.normal(jax.random.PRNGKey(2), (2, 1, 64))
         outs = {}
         for dt in (jnp.bfloat16, jnp.int8):
-            cache = KVCache.create(2, 16, 2, 16, dtype=dt)
+            cache = KVCache.create(1, 2, 16, 2, 16, dtype=dt)
             _, cache = attention_apply(params, prefix, cfg,
-                                       kv_cache=cache)
-            out, _ = attention_apply(params, step, cfg, kv_cache=cache)
+                                       kv_cache=cache, cache_layer=0)
+            out, _ = attention_apply(params, step, cfg, kv_cache=cache,
+                                     cache_layer=0)
             outs[dt] = np.asarray(out, np.float64)
         err = np.abs(outs[jnp.int8] - outs[jnp.bfloat16]).max()
         ref = np.abs(outs[jnp.bfloat16]).max()
