@@ -1,9 +1,10 @@
 #!/bin/bash
 # Llama-2-70B (GQA) on a v5p-128 slice: TP=8 x PP=4 x DP=4 — BASELINE
-# config 4 and the north-star shape (>=45% MFU, loss-curve-matched).
-# ZeRO-1 (--use_distributed_optimizer) dp-shards the Adam state; the
-# non-stacked-param exclusion under pp costs <0.5% HBM at this shape
-# (PERF_NOTES.md). vpp keeps the reference's interleaved checkpoint
+# config 4. Not run on a chip: no cell of BENCHMARK.json has this shape.
+# ZeRO-1 (--use_distributed_optimizer) dp-shards the Adam state; under
+# pp the embedding's and head's moments stay replicated, 403 MB a device
+# at this shape (docs/parallelism.md "The partitioner's CHECK under
+# pp > 1"). vpp keeps the reference's interleaved checkpoint
 # layout under the 1F1B memory bound if you need layout parity:
 # add --num_layers_per_virtual_pipeline_stage 10 (80 layers / pp4 / 2).
 # Prereqs: converted weights (tools/convert_hf_checkpoint.py --model

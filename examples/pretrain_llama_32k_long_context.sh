@@ -1,6 +1,7 @@
 #!/bin/bash
 # 32k long-context training: flash kernel + RoPE scaling + full remat +
-# context parallelism (BASELINE config 5; PERF_NOTES has on-chip numbers).
+# context parallelism (BASELINE config 5; not measured on the chip:
+# ROADMAP R7).
 DATA=${DATA:-data/corpus}
 
 python finetune.py \

@@ -6,8 +6,6 @@
 # (megatron_tpu/serving): NUM_SLOTS concurrent decode slots over a
 # pooled KV cache, bounded admission queue, 429 backpressure.
 # SERIAL=1 restores the reference's one-lock serial path.
-# LOAD=1 runs the concurrent-load micro-bench against the live server
-# instead of the interactive CLI (tools/serving_bench.py --url).
 set -e
 CKPT=${CKPT:-ckpts/llama2-7b-ft}
 TOK=${TOK:-meta-llama/Llama-2-7b-hf}
@@ -35,12 +33,4 @@ for _ in $(seq 1 120); do
     sleep 5
 done
 
-if [ -n "$LOAD" ]; then
-    # concurrent-load mode: offered load vs latency/throughput record
-    python tools/serving_bench.py --url "localhost:$PORT" \
-        --requests "${REQUESTS:-32}" --rps "${RPS:-0}" \
-        --new "${NEW_TOKENS:-32}" --out /tmp/serving_bench.log
-    curl -s "http://localhost:$PORT/metrics"; echo
-else
-    python tools/text_generation_cli.py "localhost:$PORT"
-fi
+python tools/text_generation_cli.py "localhost:$PORT"

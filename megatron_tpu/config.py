@@ -1314,8 +1314,9 @@ class MegatronConfig:
             # expert bank's sharded 'experts' dim meets the pipeline's
             # partial-manual shard_map region; verified on current jax
             # for BOTH expert_axis choices and both dispatch impls
-            # (PERF_NOTES "MoE under pp"). Same CHECK family as the
-            # ZeRO-1 pp exclusion. MoE+pp therefore requires the expert
+            # (docs/parallelism.md "The partitioner's CHECK under
+            # pp > 1"). Same CHECK family as the ZeRO-1 pp
+            # exclusion. MoE+pp therefore requires the expert
             # axis be UNSPLIT (size-1); expert sharding composes freely
             # at pp=1, and pp MoE composes with dp/sp.
             # (ep_size is None only when pipeline_parallel == 1 — the
@@ -1326,7 +1327,8 @@ class MegatronConfig:
                 f"requires the expert mesh axis be unsplit (got "
                 f"'{par.expert_axis}' size {ep_size}): sharded experts "
                 "inside the pp shard_map trip an XLA partitioner CHECK "
-                "(hard abort; see PERF_NOTES 'MoE under pp'). Use "
+                "(hard abort; see docs/parallelism.md, \"The "
+                "partitioner's CHECK under pp > 1\"). Use "
                 "pp=1 for expert parallelism, or pp>1 with "
                 "tensor_parallel=1 / expert_axis='tp'-on-tp1")
         sharded = {"tensor_parallel": par.tensor_parallel,

@@ -50,8 +50,8 @@ sidesteps exactly that objection:
 Like flash_attention_pallas.py, the kernel body uses only ops the
 interpret path supports (no pltpu-only primitives), so the SAME kernel
 runs under `interpret=True` on CPU — that is the tier-1 test path and
-the serving engine's CPU fallback; on-chip shapes/timings live in the
-`slow` tier and tools/bench_block_attn.py.
+the serving engine's CPU fallback; tests/test_tpu_compile.py compiles it
+for the chip, where it has not been timed.
 
 Layout: q [S, w, nq, hd] at the API boundary; arena k/v
 [total_blocks, B, nkv, hd] (the serving pool's per-layer arena slice),

@@ -16,9 +16,8 @@ outside — the Pallas formulation of the CUDA kernel's two-stage
 gamma/beta reduction (ref: layer_norm_cuda_kernel.cu cuComputePartGradGammaBeta).
 
 `megatron_tpu/models/norms.py` is the canonical jnp implementation; these
-kernels exist for explicit fusion control. On-chip A/B numbers live in
-PERF_NOTES.md — XLA already fuses the jnp chain well, so the model default
-stays jnp unless a profile says otherwise.
+kernels exist for explicit fusion control. Not timed on the chip (ROADMAP
+D6): the model default stays jnp unless a profile says otherwise.
 """
 from __future__ import annotations
 

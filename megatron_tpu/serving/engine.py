@@ -4079,7 +4079,7 @@ class ServingEngine:
     def _fetch(tree):
         """ONE device→host transfer for the window's sampled tokens —
         the engine's sync seam (counted as `host_syncs`; wrapped by the
-        cadence tests and tools/bench_sync.py)."""
+        cadence tests)."""
         return jax.device_get(tree)
 
     def _set_slot_mask(self, slot: int, req: GenRequest):

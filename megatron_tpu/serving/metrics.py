@@ -229,8 +229,7 @@ class ServingMetrics:
         # never a cap region); prefill_group_busy / decode_group_busy =
         # instantaneous occupancy of each chip group at the last sync
         # window (pending prefills > 0 -> 1.0; active slots /
-        # num_slots), the phase-interference A/B seam bench_disagg
-        # reads
+        # num_slots), the phase-interference A/B seam
         self.handoff_bytes_per_req = 0
         self.prefill_group_busy = 0.0
         self.decode_group_busy = 0.0

@@ -58,8 +58,7 @@ def _device_fetch(tree):
     """ONE device→host transfer for a pytree of device values — THE
     sync seam of the training path. Every metrics/eval fetch funnels
     through here so sync-cadence tests (tests/test_async_dispatch.py)
-    and tools/bench_sync.py can count host syncs by wrapping this one
-    function."""
+    can count host syncs by wrapping this one function."""
     return jax.device_get(tree)
 
 

@@ -1,0 +1,14 @@
+"""The yardstick's own unit tests, collected by tier-1: the statistics,
+the FLOP count, the load generator, the trace reductions and the float32
+references of `benchmark/`. The bodies live in `benchmark/tests/`; the cell
+rehearsals there spawn child runs and stay by hand."""
+import pytest
+
+pytest.register_assert_rewrite(
+    "benchmark.tests.test_yardstick", "benchmark.tests.test_program_spans",
+    "benchmark.tests.test_moe_roofline", "benchmark.tests.test_reference")
+
+from benchmark.tests.test_moe_roofline import *  # noqa: E402,F401,F403
+from benchmark.tests.test_program_spans import *  # noqa: E402,F401,F403
+from benchmark.tests.test_reference import *  # noqa: E402,F401,F403
+from benchmark.tests.test_yardstick import *  # noqa: E402,F401,F403

@@ -6,8 +6,7 @@ read the engine uses to drop the resolve_view/scatter_view bracket.
 On CPU it runs in pallas interpret mode (the dropout-RNG precedent
 from flash_attention_pallas: the kernel body uses only interpret-able
 ops), so the full numerics suite runs hermetically in tier-1 under
-JAX_PLATFORMS=cpu; on-chip shapes live in the `slow` tier and
-tools/bench_block_attn.py.
+JAX_PLATFORMS=cpu; tests/test_tpu_compile.py compiles it for the chip.
 """
 import jax
 import jax.numpy as jnp

@@ -247,7 +247,7 @@ class TestMetaLlamaConversion:
 def test_golden_logit_fixture():
     """The pinned-logit stand-in for the reference's real-weight CI gate
     (ref: tests/test_llama_weights.py:106; real Llama-2 weights are
-    unreachable from this environment — blocked command in COVERAGE.md).
+    unreachable from this environment).
     The numpy-seeded synthetic model regenerates bit-identically, so any
     drift in the HF conversion or the forward numerics shows up against
     the committed fixture at the reference's <=1e-3 avg-max-abs."""

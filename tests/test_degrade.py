@@ -191,9 +191,18 @@ class TestEngineDegrade:
     def test_level1_spec_off_token_exact_and_reversible(self, tiny_model):
         params, cfg = tiny_model
         gen = Generator(params, cfg, eos_id=0, pad_id=0)
+
+        class AlwaysDraft:
+            """Proposes at every step, whatever the history: with it, no
+            speculative round at level 0 can only mean that the ladder
+            left drafting switched off."""
+
+            def propose(self, tokens, n):
+                return [tokens[-1]] * n
+
         with ServingEngine(gen, ServingConfig(
                 num_slots=2, max_queue=8, max_len=64,
-                speculative_k=3, **HOLD)) as eng:
+                speculative_k=3, **HOLD), drafter=AlwaysDraft()) as eng:
             eng.degrade.level = LEVEL_NO_SPEC
             reqs = [eng.submit(p, 12, GREEDY, seed=0)
                     for p in ([5, 17, 3, 42], [7, 8, 9])]

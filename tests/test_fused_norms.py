@@ -3,8 +3,8 @@
 Contract port of the reference's fused-kernel tests
 (ref: megatron/fused_kernels/tests/test_fused_kernels.py — fused LN
 compared against module outputs): fwd and full vjp equality, fp32 stats
-under bf16 inputs, odd row counts. Interpret mode (CPU-hermetic); the
-compiled path is exercised on-chip by the PERF_NOTES microbench.
+under bf16 inputs, odd row counts. Interpret mode (CPU-hermetic);
+tests/test_tpu_compile.py compiles both kernels for the chip.
 """
 import jax
 import jax.numpy as jnp

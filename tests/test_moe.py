@@ -269,7 +269,7 @@ def test_moe_pp2_validates():
 def test_moe_pp_with_split_expert_axis_rejected():
     """pp>1 + a SPLIT expert axis must fail in validate() (a python
     error), never reach the XLA partitioner CHECK (a hard SIGABRT —
-    PERF_NOTES 'MoE under pp'). Covers tp-split, dp-split, and the
+    docs/parallelism.md). Covers tp-split, dp-split, and the
     underivable-dp bypass."""
     from megatron_tpu.config import (MegatronConfig, ParallelConfig,
                                      TrainingConfig)

@@ -10,6 +10,7 @@ moves them by 1e-2 and more (the `differ` tests below show by how much).
 """
 import dataclasses
 import json
+import os
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +28,8 @@ from megatron_tpu.serving import SamplingOptions, ServingEngine
 
 # float32 on both sides: only the order of summation differs
 TOL = dict(rtol=2e-4, atol=2e-4)
-CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+PUBLISHED = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark", "configs", "olmoe-1b-7b-4l.json")
 
 
 def tiny(**overrides):
@@ -233,10 +235,12 @@ def test_engine_prefill_and_decode_match_reference(model, how):
     np.testing.assert_allclose(got, want, rtol=0, atol=5e-4)
 
 
-def test_preset_fields_equal_the_catalog():
-    rows = [json.loads(line) for line in open(CATALOG)]
-    hf = next(r for r in rows
-              if r["name"] == "OLMoE-1B-7B-0125-Instruct")["config"]
+def test_preset_fields_equal_the_published_config():
+    """The preset against the published `config.json` as the repo holds it
+    (the benchmark's configuration file, whose only cut is the depth)."""
+    with open(PUBLISHED) as f:
+        hf = json.load(f)
+    hf.update(hf["published"])
     cfg = MODEL_PRESETS["olmoe-1b-7b"]()
     assert (cfg.num_layers, cfg.hidden_size, cfg.ffn_hidden_size) == (
         hf["num_hidden_layers"], hf["hidden_size"], hf["intermediate_size"])

@@ -210,9 +210,8 @@ def test_int8_convergence_tracks_bf16():
 
 class TestWeightQuantizedServing:
     """W8 int8-resident weights (ops/quantized.quantize_weights) — the
-    serving-side half of the int8 path. Decode is HBM-bandwidth-bound;
-    int8 storage halves the weight stream (bench_decode --int8_weights
-    measures it on-chip)."""
+    serving-side half of the int8 path: int8 storage halves the bytes
+    of weights a decode step reads."""
 
     def _model(self):
         from megatron_tpu.models.language_model import model_init

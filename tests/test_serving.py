@@ -2152,7 +2152,10 @@ class TestSpeculativeDecode:
         """A request's sampled stream depends only on its own seed,
         drafts, and accepts — never on what OTHER slots proposed: a
         1-slot engine (serial verify) and a 4-slot engine (grid-batched
-        verify) emit identical tokens and logprobs."""
+        verify) emit identical tokens. The two verifies are different
+        XLA programs (batch 1 against batch 4) and may round a float32
+        log-probability differently in its last place, so the logprobs
+        are compared to 1e-5 and not bit for bit."""
         params, cfg = tiny_model
         gen = Generator(params, cfg, eos_id=0, pad_id=0)
         sampling = SamplingOptions(temperature=0.9, top_k=5)
@@ -2175,7 +2178,10 @@ class TestSpeculativeDecode:
 
         one = run(1, True)
         grid = run(4, False)
-        assert one == grid
+        assert [toks for toks, _ in one] == [toks for toks, _ in grid]
+        for (_, lps_one), (_, lps_grid) in zip(one, grid):
+            assert len(lps_one) == len(lps_grid)
+            np.testing.assert_allclose(lps_one, lps_grid, rtol=0, atol=1e-5)
 
     def test_accepted_prefix_bitexact_vs_serial_verify_replay(
             self, tiny_model):
@@ -2673,11 +2679,13 @@ class TestBlockPoolEngine:
         retention LRU-thrashes (a retained sequence costs a full
         96-token region, at most num_slots survive, and every turn-2
         miss evicts another session) while the block pool keeps all
-        five 16-token prefixes resident — every turn 2 hits."""
+        five 16-token prefixes resident — every turn 2 hits. A prompt
+        is a whole block by itself, so a session retains one whatever
+        the drawn model emits (an early EOS included)."""
         params, cfg = block_model
         gen = Generator(params, cfg, eos_id=0, pad_id=0)
         greedy = SamplingOptions(temperature=0.0)
-        prompts = [[10 + i] * 12 for i in range(5)]
+        prompts = [[10 + i] * 16 for i in range(5)]
 
         def run(block):
             turn2 = []
@@ -3487,7 +3495,11 @@ class TestSSEStreaming:
         assert ids == list(range(len(toks)))  # monotonic token index
         status2, body2 = sse_server.handle(payload)
         ref = body2["segments"][0]
-        assert toks == ref[len(ref) - 8:]
+        # EOS may end the stream before the 8 tokens asked for; the stream
+        # is all of what the completed future holds past the prompt
+        assert 1 <= len(toks) <= 8
+        assert toks == ref[len(ref) - len(toks):]
+        assert len(ref) - len(toks) == len(FakeTokenizer().tokenize("hello"))
 
     def test_reconnect_resumes_exactly(self, sse_server):
         status, body = sse_server.handle(

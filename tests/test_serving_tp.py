@@ -48,9 +48,9 @@ def tiny_model():
     return params, cfg
 
 
-def _gen(tiny_model, kv_dtype=None):
+def _gen(tiny_model, kv_dtype=None, eos_id=0):
     params, cfg = tiny_model
-    return Generator(params, cfg, eos_id=0, pad_id=0,
+    return Generator(params, cfg, eos_id=eos_id, pad_id=0,
                      kv_cache_dtype=(jnp.int8 if kv_dtype == "int8"
                                      else jnp.bfloat16))
 
@@ -67,10 +67,30 @@ def _sink_cfg(tp, **overrides):
     return ServingConfig(**base)
 
 
+def _iterate(eng, until):
+    """Run the engine loop's body on this thread until `until()` holds."""
+    for _ in range(2000):
+        if until():
+            return
+        eng._iteration()
+    raise AssertionError("not reached in 2000 iterations")
+
+
+def _drive(eng, reqs):
+    """`_iterate` until `reqs` are done; returns their token lists."""
+    _iterate(eng, lambda: all(r.done() for r in reqs))
+    return [r.result(timeout=0)[0] for r in reqs]
+
+
 def _drive_sink(gen, serving, cfg):
     """One workload exercising every named scenario; returns the
-    ordered token lists plus the engine's compile/metric evidence."""
-    eng = ServingEngine(gen, serving.validate(cfg))
+    ordered token lists plus the engine's compile/metric evidence.
+    The engine has no thread of its own (`start=False`): the test runs
+    its iterations, so which requests share a step, and that three
+    low-priority rows are live when the high-priority one lands, does
+    not depend on timing. `gen` must not be able to emit EOS, or the
+    drawn model can end a low-priority row at its first token."""
+    eng = ServingEngine(gen, serving.validate(cfg), start=False)
     try:
         for aid in ("tenant-a", "tenant-b"):
             eng.register_adapter(
@@ -87,32 +107,30 @@ def _drive_sink(gen, serving, cfg):
                               seed=0),
                    eng.submit([7, 8, 7, 8, 7, 8, 7], 10, greedy, seed=1),
                    eng.submit([11, 12, 13], 6, sampled, seed=2)]
-        outs += [r.result(timeout=300)[0] for r in r_plain]
+        outs += _drive(eng, r_plain)
         # (2) prefix hit: a new prompt sharing the served one's first
         # (block-aligned) 16 tokens clones the retained KV
-        outs.append(eng.submit(shared + [71, 72], 8, greedy,
-                               seed=5).result(timeout=300)[0])
+        outs += _drive(eng, [eng.submit(shared + [71, 72], 8, greedy,
+                                        seed=5)])
         # (3) chunked prefill: prompt longer than prefill_chunk=8
-        outs.append(eng.submit(list(range(2, 25)), 6, greedy,
-                               seed=3).result(timeout=300)[0])
+        outs += _drive(eng, [eng.submit(list(range(2, 25)), 6, greedy,
+                                        seed=3)])
         # (4) mixed-adapter rows decoding concurrently
         r_mix = [eng.submit([21, 22, 23], 6, greedy, seed=4,
                             adapter_id="tenant-a"),
                  eng.submit([21, 22, 23], 6, greedy, seed=4,
                             adapter_id="tenant-b"),
                  eng.submit([21, 22, 23], 6, greedy, seed=4)]
-        outs += [r.result(timeout=300)[0] for r in r_mix]
+        outs += _drive(eng, r_mix)
         # (5) preemption-resume: fill every slot with low-priority
         # work, then land a high-priority request (lossless park)
         lows = [eng.submit([31 + i, 32, 33], 24, sampled, seed=10 + i,
                            priority=0) for i in range(3)]
-        t0 = time.monotonic()
-        while any(len(r.generated) < 1 for r in lows):
-            time.sleep(0.002)
-            assert time.monotonic() - t0 < 120
+        _iterate(eng, lambda: all(r.generated for r in lows))
+        assert not any(r.done() for r in lows)  # three live rows
         hi = eng.submit([41, 42], 4, greedy, seed=20, priority=1)
-        outs.append(hi.result(timeout=300)[0])
-        outs += [r.result(timeout=300)[0] for r in lows]
+        outs += _drive(eng, [hi])
+        outs += _drive(eng, lows)
         snap = eng.metrics.snapshot()
         evidence = dict(
             decode_traces=eng._decode_traces,
@@ -134,7 +152,7 @@ class TestTPShardedEngine:
     @pytest.mark.parametrize("kv_dtype", [None, "int8"])
     def test_tp2_token_exact_all_scenarios(self, tiny_model, kv_dtype):
         params, cfg = tiny_model
-        gen = _gen(tiny_model, kv_dtype)
+        gen = _gen(tiny_model, kv_dtype, eos_id=-1)
         base, ev1 = _drive_sink(gen, _sink_cfg(1, kv_dtype=kv_dtype),
                                 cfg)
         tp2, ev2 = _drive_sink(gen, _sink_cfg(2, kv_dtype=kv_dtype),

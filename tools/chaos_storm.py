@@ -43,7 +43,7 @@ the one-line seed repro — the checker-not-vacuous pin for the perf
 laws, same contract as chaos_mesh's `--inject_violation`.
 
 Every record carries the seed + full repro line; `--smoke` runs the
-fixed seed set wired into bench.py extras and the slow test tier.
+fixed seed set of the slow test tier.
 """
 from __future__ import annotations
 

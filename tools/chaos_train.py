@@ -7,9 +7,7 @@ on a preemptible cluster would — transient checkpoint-write failures,
 a NaN streak mid-run, a corrupted checkpoint on disk — and reports
 whether training still completed, how many rollbacks it took, and the
 recovery latency (wall-clock cost of a rollback: detect → restore →
-resume). Emits ONE BENCH-style JSON record on stdout (and to --out),
-like bench.py, so recovery-latency regressions surface in the
-`BENCH_*.json` extras.
+resume). Emits ONE JSON record on stdout (and to --out).
 
 Modes:
 - `--smoke` (bench extras / CI): tiny model, short schedule, fixed

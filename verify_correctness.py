@@ -152,8 +152,8 @@ def main(argv=None):
     p.add_argument("--seq", type=int, default=64)
     p.add_argument("--tolerance", type=float, default=1e-3)
     # Golden-logit fixture mode (VERDICT r3 item 5): real Llama weights
-    # are unreachable from this environment (zero egress — the blocked
-    # command is documented in COVERAGE.md), so the numerics gate is
+    # are unreachable from this environment (zero egress), so the
+    # numerics gate is
     # pinned instead: --save_golden writes the numpy-seeded synthetic
     # model's fp32 logits; --golden replays conversion+forward and
     # compares against the pinned values at the same <=1e-3 avg-max-abs
