@@ -140,10 +140,64 @@ class ModelConfig:
     # q_norm/k_norm; part of the architecture, not a tuning knob)
     qk_norm: bool = False
 
+    # Multi-head latent attention (models/mla.py), each field the published
+    # key of the same name. `kv_lora_rank` set is what says the model's
+    # attention is MLA: the cache then holds one row of kv_lora_rank +
+    # qk_rope_head_dim values a token a layer (models/mla.py::LatentKVCache)
+    # and `kv_channels` (the published `head_dim`) is the rotary width.
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: Optional[int] = None
+    qk_rope_head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
+    # the first k layers keep a dense MLP of this width (the published
+    # `intermediate_size`) where the others have experts: a stack of its
+    # own, run ahead of the experts' (models/transformer.py)
+    first_k_dense_replace: int = 0
+    dense_ffn_hidden_size: Optional[int] = None
+    # experts every token takes with weight 1, beside the routed sum
+    # (as one MLP of n_shared_experts x ffn_hidden_size)
+    n_shared_experts: int = 0
+    # the router's `scoring_func` ("softmax" | "sigmoid"), its
+    # `routed_scaling_factor` on the chosen gates, and whether a per-expert
+    # bias joins the scores to CHOOSE the top k and stays out of their
+    # value (`topk_method` noaux_tc's e_score_correction_bias)
+    moe_scoring_func: str = "softmax"
+    moe_routed_scaling_factor: float = 1.0
+    moe_score_correction_bias: bool = False
+    # multi-token-prediction modules (`num_nextn_predict_layers`): one more
+    # block each behind the trunk, in the training loss at mtp_loss_coeff;
+    # nothing of it is in the model's own logits (models/language_model.py)
+    mtp_num_layers: int = 0
+    mtp_loss_coeff: float = 0.3
+
     # glu activations double the first MLP projection
     @property
     def is_glu(self) -> bool:
         return self.activation in ("swiglu", "geglu", "reglu", "liglu")
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank is not None
+
+    @property
+    def kv_row_width(self) -> int:
+        """Values one token costs one layer of the KV cache."""
+        if self.mla:
+            return self.kv_lora_rank + self.qk_rope_head_dim
+        return 2 * self.num_kv_heads * self.kv_channels
+
+    def dense_layers(self) -> "ModelConfig":
+        """The configuration of the `first_k_dense_replace` leading layers:
+        this one with a dense MLP of `dense_ffn_hidden_size`."""
+        return dataclasses.replace(
+            self, num_experts=1, ffn_hidden_size=self.dense_ffn_hidden_size,
+            n_shared_experts=0, first_k_dense_replace=0)
+
+    def expert_layers(self) -> "ModelConfig":
+        """The configuration of the layers behind them (and of an MTP
+        module's block): this one as a model of one kind of layer."""
+        return dataclasses.replace(self, first_k_dense_replace=0)
 
     def derived(self) -> "ModelConfig":
         """Fill derived fields (ffn size, kv heads, head dim, max positions)."""
@@ -779,6 +833,36 @@ class ServingConfig:
             self.retained_slots)
         assert self.kv_block_size is None or self.kv_block_size >= 1, (
             self.kv_block_size)
+        if model is not None and model.mla:
+            # the latent pool is ONE array [layers, slots, positions, row]
+            # (models/mla.py::LatentKVCache): no head axis, no k beside v
+            widths = (self.serving_tp, self.prefill_tp or 1,
+                      self.decode_tp or 1, self.serving_pp)
+            assert max(widths) == 1, (
+                "MLA (kv_lora_rank set): serving_tp / prefill_tp / "
+                f"decode_tp / serving_pp > 1 are refused (got {widths}): "
+                "the latent row has no head axis to shard over a serving "
+                "mesh and the two stacks have no stage cut (ROADMAP R5)")
+            assert self.kv_block_size is None \
+                and not self.block_native_attn, (
+                "MLA (kv_lora_rank set): kv_block_size / block_native_attn "
+                "are refused: the block arena and its kernel are built "
+                "round k and v of [kv_heads, head_dim] (ROADMAP R5)")
+            assert (self.kv_dtype or "bfloat16") != "int8", (
+                "MLA (kv_lora_rank set): an int8 KV pool is refused: the "
+                "per-(token, head) scales have no head to belong to, and "
+                "a scale a latent row has not been tried against the "
+                "reference (ROADMAP R5)")
+            assert not self.disaggregate_prefill \
+                and not self.host_kv_bytes, (
+                "MLA (kv_lora_rank set): disaggregate_prefill and the "
+                "host tier (host_kv_bytes) are refused: both move "
+                "physical KV blocks, which the latent pool does not have "
+                "(ROADMAP R5)")
+            assert not self.adapter_slots, (
+                "MLA (kv_lora_rank set): adapter_slots is refused: the "
+                "LoRA bank holds factors for wq / wkv / wo, which this "
+                "attention does not have")
         if self.kv_block_size is not None:
             if self.enable_prefix_cache:
                 # prefix hits must stay aligned to BOTH the jit-bucket
@@ -1355,6 +1439,64 @@ class MegatronConfig:
             assert par.tensor_parallel == 1 and par.context_parallel == 1, (
                 "qk_norm (full-width RMSNorm on q and k) has not been "
                 f"made to work with heads sharded (got {sharded})")
+        if model.mla:
+            # models/mla.py: one latent row a token, shared by every head
+            for name in ("q_lora_rank", "qk_nope_head_dim",
+                         "qk_rope_head_dim", "v_head_dim"):
+                assert getattr(model, name), (
+                    f"kv_lora_rank is set (MLA): {name} must be too")
+            assert model.kv_channels == model.qk_rope_head_dim, (
+                f"MLA: kv_channels={model.kv_channels} is the rotary "
+                f"width and must equal qk_rope_head_dim="
+                f"{model.qk_rope_head_dim}")
+            assert max(sharded.values()) == 1, (
+                "MLA (kv_lora_rank set) has been made to work on one "
+                f"device only (got {sharded}): the latent row has no "
+                "head axis to shard and the up-projections have not been "
+                "split by head (ROADMAP R5)")
+            assert (model.use_rotary_emb and model.sliding_window is None
+                    and not model.qk_norm and not model.use_bias
+                    and model.quantized_gemm == "none"
+                    and model.attention_dropout == 0.0
+                    and model.attention_impl in ("dot", "flash")), (
+                "MLA (kv_lora_rank set) is causal rotary attention over the "
+                "whole context: no sliding_window, qk_norm, use_bias, "
+                "quantized_gemm, attention_dropout or context-parallel "
+                "attention_impl")
+        if model.first_k_dense_replace or model.n_shared_experts:
+            assert model.num_experts > 1, (
+                "first_k_dense_replace / n_shared_experts describe a model "
+                "with experts (num_experts > 1)")
+        if model.first_k_dense_replace:
+            assert 0 < model.first_k_dense_replace < model.num_layers \
+                and model.dense_ffn_hidden_size, (
+                f"first_k_dense_replace={model.first_k_dense_replace} "
+                f"needs k < num_layers={model.num_layers} and "
+                "dense_ffn_hidden_size")
+        if model.first_k_dense_replace or model.n_shared_experts \
+                or model.mtp_num_layers:
+            assert model.mtp_num_layers in (0, 1), (
+                f"mtp_num_layers={model.mtp_num_layers}: one "
+                "multi-token-prediction module (depth 1) is what the loss "
+                "has")
+            assert par.pipeline_parallel == 1, (
+                "first_k_dense_replace / n_shared_experts / mtp_num_layers "
+                "have not been made to work with pipeline_parallel > 1: "
+                "the stages cut ONE stack of identical layers, and these "
+                "models have two stacks and a module behind the trunk")
+        if model.num_experts > 1:
+            assert model.moe_scoring_func in ("softmax", "sigmoid"), (
+                f"moe_scoring_func={model.moe_scoring_func!r} "
+                "(expected 'softmax' or 'sigmoid')")
+            plain = (model.moe_scoring_func == "softmax"
+                     and model.moe_routed_scaling_factor == 1.0
+                     and not model.moe_score_correction_bias
+                     and not model.n_shared_experts)
+            assert plain or model.moe_dispatch == "dropless", (
+                "sigmoid scoring, routed_scaling_factor, the choosing bias "
+                "and shared experts are the dropless router's "
+                "(--moe_dispatch dropless): the capacity dispatches keep "
+                "the Switch router")
         if model.sliding_window is not None:
             assert model.sliding_window >= 1, (
                 f"sliding_window={model.sliding_window} must be >= 1 "
@@ -1576,6 +1718,56 @@ def olmoe_config(size: str = "1b-7b", **overrides) -> ModelConfig:
     return ModelConfig(**base).derived()
 
 
+def joyai_config(size: str = "llm-flash", **overrides) -> ModelConfig:
+    """JoyAI-LLM-Flash presets: every size of "llm-flash" is a key of
+    jdopensource/JoyAI-LLM-Flash's config.json (48B-A2.7B: 40 layers,
+    hidden 2048, 32 heads, MLA with q_lora_rank 1536, kv_lora_rank 512,
+    qk_nope_head_dim 128, qk_rope_head_dim 64 = `head_dim`, v_head_dim 128;
+    layer 0 dense of width 7168 (`intermediate_size`,
+    `first_k_dense_replace` 1), the others 256 experts of width 768
+    (`moe_intermediate_size`), 8 a token, beside 1 shared expert; sigmoid
+    scoring, `topk_method` noaux_tc (a choosing bias), `norm_topk_prob`
+    true, `routed_scaling_factor` 2.5; `n_group` and `topk_group` 1 (no
+    group limit); RMSNorm eps 1e-6, SiLU-gated, no bias, rope_theta 32e6
+    over interleaved pairs, no rope scaling, 131,072 positions, vocabulary
+    129,280, untied head; one multi-token-prediction module). Published
+    and held in bfloat16. Dropless; no auxiliary loss is in the config."""
+    presets = {
+        "tiny": dict(num_layers=4, hidden_size=64, num_attention_heads=4,
+                     q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16,
+                     qk_rope_head_dim=8, v_head_dim=16, kv_channels=8,
+                     ffn_hidden_size=32, dense_ffn_hidden_size=128,
+                     vocab_size=512, seq_length=128, num_experts=8,
+                     moe_top_k=2, attention_impl="dot"),
+        "llm-flash": dict(num_layers=40, hidden_size=2048,
+                          num_attention_heads=32, num_kv_heads=32,
+                          q_lora_rank=1536, kv_lora_rank=512,
+                          qk_nope_head_dim=128, qk_rope_head_dim=64,
+                          v_head_dim=128, kv_channels=64,
+                          ffn_hidden_size=768, dense_ffn_hidden_size=7168,
+                          vocab_size=129280, seq_length=4096,
+                          max_position_embeddings=131072,
+                          num_experts=256, moe_top_k=8,
+                          params_dtype="bfloat16"),
+    }
+    if size not in presets:
+        raise ValueError(f"unknown joyai size {size!r}; "
+                         f"valid: {sorted(presets)}")
+    base = dict(
+        use_rotary_emb=True, rope_theta=32e6, norm_type="rmsnorm",
+        norm_epsilon=1e-6, activation="swiglu", use_bias=False,
+        use_post_ln=False, parallel_attn=False, tie_embed_logits=False,
+        first_k_dense_replace=1, n_shared_experts=1,
+        moe_scoring_func="sigmoid", moe_routed_scaling_factor=2.5,
+        moe_score_correction_bias=True, moe_norm_topk_prob=True,
+        moe_dispatch="dropless", moe_aux_loss_coeff=0.0, mtp_num_layers=1,
+        attention_impl="flash",  # see llama2_config
+    )
+    base.update(presets[size])
+    base.update(overrides)
+    return ModelConfig(**base).derived()
+
+
 def gpt_config(**overrides) -> ModelConfig:
     base = dict(
         num_layers=12, hidden_size=768, num_attention_heads=12,
@@ -1599,5 +1791,7 @@ MODEL_PRESETS = {
     "mixtral-8x7b": lambda: mixtral_config("8x7b"),
     "olmoe-tiny": lambda: olmoe_config("tiny"),
     "olmoe-1b-7b": lambda: olmoe_config("1b-7b"),
+    "joyai-llm-flash-tiny": lambda: joyai_config("tiny"),
+    "joyai-llm-flash": lambda: joyai_config("llm-flash"),
     "gpt2": gpt_config,
 }

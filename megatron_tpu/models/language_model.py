@@ -53,6 +53,8 @@ def model_init(rng, cfg: ModelConfig, dtype=None):
             * cfg.init_method_std)
     if not cfg.tie_embed_logits:
         params["lm_head"] = jax.random.normal(k_head, (h, v), dtype) * cfg.init_method_std
+    if cfg.mtp_num_layers:
+        params["mtp"] = mtp_init(jax.random.fold_in(rng, 7), cfg, dtype)
     return params
 
 
@@ -66,7 +68,58 @@ def model_axes(cfg: ModelConfig):
         axes["embedding"]["position_embeddings"] = (None, "embed")
     if not cfg.tie_embed_logits:
         axes["lm_head"] = ("embed", "vocab")
+    if cfg.mtp_num_layers:
+        axes["mtp"] = {
+            "enorm": norm_axes(cfg.norm_type),
+            "hnorm": norm_axes(cfg.norm_type),
+            "eh_proj": (None, "embed"),
+            "layer": tfm.layer_axes(cfg.expert_layers()),
+            "final_norm": norm_axes(cfg.norm_type),
+        }
     return axes
+
+
+def mtp_init(rng, cfg: ModelConfig, dtype):
+    """The multi-token-prediction module (depth 1, DeepSeek-V3's form): two
+    norms, the projection `eh_proj` [2h, h] over [norm(embedding of the next
+    token) ; norm(trunk's state)] (the embedding's half first, as the
+    published checkpoints lay it out), one block, its own final norm. The
+    embedding and the head are the model's own. It is in the training loss
+    (`loss_fn`) and in nothing else: `model_forward` does not read it, so a
+    server that drops the key serves the same model."""
+    h = cfg.hidden_size
+    k_proj, k_layer = jax.random.split(rng)
+    return {
+        "enorm": norm_init(cfg.norm_type, h, dtype),
+        "hnorm": norm_init(cfg.norm_type, h, dtype),
+        "eh_proj": jax.random.normal(k_proj, (2 * h, h), dtype)
+        * cfg.init_method_std,
+        "layer": tfm.layer_init(k_layer, cfg.expert_layers(), dtype),
+        "final_norm": norm_init(cfg.norm_type, h, dtype),
+    }
+
+
+def mtp_logits(params, hidden, next_tokens, cfg: ModelConfig, *, rope,
+               position_ids=None, segment_ids=None,
+               logits_dtype=jnp.float32):
+    """(logits [b, s, vocab], router aux). Position i holds the trunk's
+    state `hidden[:, i]` (before the final norm) and the token after it,
+    `next_tokens[:, i]`; its logits predict the token after that."""
+    from megatron_tpu.config import as_dtype
+    mtp, eps = params["mtp"], cfg.norm_epsilon
+    e = params["embedding"]["word_embeddings"][next_tokens].astype(
+        as_dtype(cfg.compute_dtype))
+    x = jnp.concatenate(
+        [apply_norm(cfg.norm_type, mtp["enorm"], e, eps),
+         apply_norm(cfg.norm_type, mtp["hnorm"], hidden, eps)], axis=-1)
+    x = x @ mtp["eh_proj"].astype(x.dtype)
+    x, _, aux = tfm.layer_apply(
+        mtp["layer"], x, cfg.expert_layers(), rope_cos=rope.cos,
+        rope_sin=rope.sin, position_ids=position_ids,
+        segment_ids=segment_ids, layer_number=cfg.num_layers + 1)
+    logits = head_logits({**params, "final_norm": mtp["final_norm"]}, x, cfg,
+                         logits_dtype=logits_dtype)
+    return logits, aux
 
 
 class RopeTables(NamedTuple):
@@ -98,11 +151,15 @@ def model_forward(
     segment_ids=None,
     cp_pre_zigzag: bool = False,
     return_aux: bool = False,
+    return_hidden: bool = False,
     adapters=None,
+    logits_rows=None,
 ):
     """Forward to logits [b, s, padded_vocab]. Returns (logits, kv_caches),
     or (logits, kv_caches, moe_aux) with `return_aux=True` (loss_fn uses
-    it to add the MoE router's load-balancing loss).
+    it to add the MoE router's load-balancing loss), and with
+    `return_hidden` one more: the last layer's output before the final
+    norm, which the MTP module takes.
 
     `cp_pre_zigzag`: the caller pre-permuted tokens/positions into the
     ring-cp zigzag order (see loss_fn / parallel/ring_attention.py
@@ -110,7 +167,11 @@ def model_forward(
 
     `adapters`: (stacked LoraAdapter bank, adapter_idx [b]) — per-row
     low-rank deltas on the attention projections (multi-tenant LoRA
-    serving / LoRA finetuning; models/attention.py)."""
+    serving / LoRA finetuning; models/attention.py).
+
+    `logits_rows` [b] int: the head runs on that one position of each
+    sequence alone and the logits are [b, 1, padded_vocab] (a prefill
+    wants its last real position's; generation.whole_logits_fit)."""
     from megatron_tpu.config import as_dtype
     compute_dtype = as_dtype(cfg.compute_dtype)
     emb = params["embedding"]["word_embeddings"]
@@ -147,7 +208,11 @@ def model_forward(
 
     # final norm + SP gather + vocab-parallel head: ONE implementation
     # shared with both pp schedules (head_logits below)
+    if logits_rows is not None:
+        x = jnp.take_along_axis(x, logits_rows[:, None, None], axis=1)
     logits = head_logits(params, x, cfg, logits_dtype=logits_dtype)
+    if return_hidden:
+        return logits, kv_caches, aux, x
     if return_aux:
         return logits, kv_caches, aux
     return logits, kv_caches
@@ -174,7 +239,16 @@ def head_logits(params, x, cfg: ModelConfig, *, mb_axis: bool = False,
         w_out = params["embedding"]["word_embeddings"].T
     else:
         w_out = params["lm_head"]
-    logits = (x @ w_out.astype(compute_dtype)).astype(logits_dtype)
+    if w_out.dtype == compute_dtype and logits_dtype != compute_dtype:
+        # a head held in the compute dtype (a model served in bf16): the
+        # product's float32 accumulator comes out as it is. Rounded to bf16
+        # and widened again, the top logit of a 129,280-word vocabulary
+        # (about 4, where bf16 steps by 0.031) alone costs 0.008 of mean
+        # log-probability error, more than int8 weights do (PERF.md section
+        # 6, PR 31). A head held in float32 keeps the program it had.
+        logits = jnp.dot(x, w_out, preferred_element_type=logits_dtype)
+    else:
+        logits = (x @ w_out.astype(compute_dtype)).astype(logits_dtype)
     return constrain(logits, pre + ("batch", "seq", "vocab"))
 
 
@@ -225,6 +299,13 @@ def loss_fn(
         if loss_mask is not None:
             loss_mask = loss_mask[:, perm]
 
+    if cfg.mtp_num_layers:
+        return _loss_with_mtp(params, inputs, labels, loss_mask, cfg,
+                              rope=rope, rng=rng,
+                              deterministic=deterministic,
+                              position_ids=position_ids,
+                              segment_ids=segment_ids,
+                              pre_zigzag=pre_zigzag, adapters=adapters)
     logits, _, aux = model_forward(params, inputs, cfg, rope=rope, rng=rng,
                                    deterministic=deterministic,
                                    position_ids=position_ids,
@@ -239,3 +320,48 @@ def loss_fn(
     loss_mask = loss_mask.astype(losses.dtype)
     return (jnp.sum(losses * loss_mask)
             / jnp.maximum(jnp.sum(loss_mask), 1.0)) + aux_term
+
+
+def _loss_with_mtp(params, inputs, labels, loss_mask, cfg: ModelConfig, *,
+                   rope, rng, deterministic, position_ids, segment_ids,
+                   pre_zigzag, adapters):
+    """`loss_fn` for a model with an MTP module: L_main + mtp_loss_coeff x
+    L_mtp. At position i the module sees the trunk's state and the NEXT
+    token, `labels[:, i]`, and is scored on the one after, `labels[:, i +
+    1]`; the last position has no such token and is masked out, as is every
+    position whose target the loss mask leaves out. Both terms are masked
+    means."""
+    assert cfg.mtp_num_layers == 1 and not pre_zigzag and adapters is None
+    if rope is None:
+        rope = make_rope(cfg)
+    logits, _, aux, hidden = model_forward(
+        params, inputs, cfg, rope=rope, rng=rng, deterministic=deterministic,
+        position_ids=position_ids, segment_ids=segment_ids,
+        return_hidden=True)
+    if loss_mask is None:
+        loss_mask = jnp.ones(labels.shape, jnp.float32)
+    loss_mask = loss_mask.astype(jnp.float32)
+
+    def masked_mean(losses, mask):
+        return jnp.sum(losses * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+    main = masked_mean(
+        cross_entropy_loss(logits, labels, vocab_size=cfg.vocab_size),
+        loss_mask)
+    logits2, aux2 = mtp_logits(params, hidden, labels, cfg, rope=rope,
+                               position_ids=position_ids,
+                               segment_ids=segment_ids)
+    # the target of position i is labels[:, i + 1]; the last has none
+    targets = jnp.concatenate([labels[:, 1:], labels[:, -1:]], axis=1)
+    mask2 = jnp.concatenate(
+        [loss_mask[:, 1:], jnp.zeros_like(loss_mask[:, :1])], axis=1)
+    if segment_ids is not None:
+        # a target in the next document is not this position's to predict
+        mask2 = mask2 * jnp.concatenate(
+            [segment_ids[:, 1:] == segment_ids[:, :-1],
+             jnp.zeros_like(segment_ids[:, :1], bool)], axis=1)
+    mtp = masked_mean(
+        cross_entropy_loss(logits2, targets, vocab_size=cfg.vocab_size),
+        mask2)
+    aux_term = (cfg.moe_aux_loss_coeff * (aux + aux2)
+                if cfg.num_experts > 1 else 0.0)
+    return main + cfg.mtp_loss_coeff * mtp + aux_term

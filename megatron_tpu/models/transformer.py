@@ -59,8 +59,13 @@ def layer_init(rng, cfg: ModelConfig, dtype=jnp.float32,
         mlp_params = moe_init(k_mlp, cfg, dtype)
     else:
         mlp_params = mlp_init(k_mlp, cfg, dtype)
+    if cfg.mla:
+        from megatron_tpu.models.mla import mla_init
+        attn_params = mla_init(k_attn, cfg, dtype)
+    else:
+        attn_params = attention_init(k_attn, cfg, dtype)
     params = {
-        "attention": attention_init(k_attn, cfg, dtype),
+        "attention": attn_params,
         "mlp": mlp_params,
     }
     if cross_attn:
@@ -86,8 +91,13 @@ def layer_axes(cfg: ModelConfig, cross_attn: bool = False):
         mlp_ax = moe_axes(cfg)
     else:
         mlp_ax = mlp_axes(cfg)
+    if cfg.mla:
+        from megatron_tpu.models.mla import mla_axes
+        attn_ax = mla_axes(cfg)
+    else:
+        attn_ax = attention_axes(cfg)
     axes = {
-        "attention": attention_axes(cfg),
+        "attention": attn_ax,
         "mlp": mlp_ax,
     }
     if cross_attn:
@@ -115,6 +125,7 @@ def layer_apply(
     kv_cache=None,
     cache_layer=None,
     expert_banks=None,
+    bank_layer=None,
     layer_number: int = 1,
     hidden_dropout: Optional[float] = None,
     drop_path_rate=None,
@@ -133,8 +144,9 @@ def layer_apply(
     layer's index in it; both pass through to attention_apply, which
     appends in place and hands the stack back. `expert_banks`, where the
     loop gives any, are the MoE parameters it did not scan, stacked over
-    layers too and read at the same index (models/moe.py::
-    split_stacked_banks).
+    the layers of their own stack and read at `bank_layer` (models/moe.py::
+    split_stacked_banks), which is `cache_layer` where the model has one
+    stack.
 
     `adapters`: (per-layer LoraAdapter bank, adapter_idx [b]) for the
     SELF-attention projections only (multi-tenant LoRA serving —
@@ -176,7 +188,7 @@ def layer_apply(
             from megatron_tpu.models.moe import moe_apply
             if expert_banks:
                 return moe_apply({**params["mlp"], **expert_banks}, inp, cfg,
-                                 bank_layer=cache_layer)
+                                 bank_layer=bank_layer)
             return moe_apply(params["mlp"], inp, cfg)
         return mlp_apply(params["mlp"], inp, cfg), jnp.zeros((), jnp.float32)
 
@@ -186,14 +198,24 @@ def layer_apply(
     else:
         ln_out = apply_norm(cfg.norm_type, params["input_norm"], x, eps)
 
-    attn_out, kv_cache = attention_apply(
-        params["attention"], ln_out, cfg,
-        rope_cos=rope_cos, rope_sin=rope_sin, position_ids=position_ids,
-        kv_cache=kv_cache, cache_layer=cache_layer,
-        layer_number=layer_number,
-        dropout_rng=r_score, deterministic=deterministic,
-        segment_ids=segment_ids, causal=causal,
-        cp_pre_zigzag=cp_pre_zigzag, adapters=adapters)
+    if cfg.mla:
+        from megatron_tpu.models.mla import mla_apply
+        assert causal and encoder_output is None and adapters is None \
+            and not cp_pre_zigzag, "MLA is causal self-attention, unsharded"
+        attn_out, kv_cache = mla_apply(
+            params["attention"], ln_out, cfg,
+            rope_cos=rope_cos, rope_sin=rope_sin, position_ids=position_ids,
+            kv_cache=kv_cache, cache_layer=cache_layer,
+            segment_ids=segment_ids)
+    else:
+        attn_out, kv_cache = attention_apply(
+            params["attention"], ln_out, cfg,
+            rope_cos=rope_cos, rope_sin=rope_sin, position_ids=position_ids,
+            kv_cache=kv_cache, cache_layer=cache_layer,
+            layer_number=layer_number,
+            dropout_rng=r_score, deterministic=deterministic,
+            segment_ids=segment_ids, causal=causal,
+            cp_pre_zigzag=cp_pre_zigzag, adapters=adapters)
 
     if cfg.parallel_attn:
         # Falcon block: no dropout-add after attention
@@ -235,8 +257,16 @@ def layer_apply(
 
 def stack_init(rng, cfg: ModelConfig, num_layers: Optional[int] = None,
                dtype=jnp.float32, cross_attn: bool = False):
-    """Stacked params with leading 'layers' dim via vmap over per-layer init."""
+    """Stacked params with leading 'layers' dim via vmap over per-layer init.
+    A model whose first layers are dense and whose others have experts
+    (`cfg.first_k_dense_replace`) has two stacks, {"dense", "moe"}."""
     n = num_layers if num_layers is not None else cfg.num_layers
+    k = cfg.first_k_dense_replace
+    if k:
+        assert num_layers is None and not cross_attn
+        r_dense, r_moe = jax.random.split(rng)
+        return {"dense": stack_init(r_dense, cfg.dense_layers(), k, dtype),
+                "moe": stack_init(r_moe, cfg.expert_layers(), n - k, dtype)}
     keys = jax.random.split(rng, n)
     return jax.vmap(lambda k: layer_init(k, cfg, dtype,
                                          cross_attn=cross_attn))(keys)
@@ -244,6 +274,9 @@ def stack_init(rng, cfg: ModelConfig, num_layers: Optional[int] = None,
 
 def stack_axes(cfg: ModelConfig, cross_attn: bool = False):
     """Logical axes for stacked params: prepend 'layers'."""
+    if cfg.first_k_dense_replace:
+        return {"dense": stack_axes(cfg.dense_layers()),
+                "moe": stack_axes(cfg.expert_layers())}
     per_layer = layer_axes(cfg, cross_attn=cross_attn)
     return jax.tree.map(lambda ax: ("layers",) + ax, per_layer,
                         is_leaf=lambda x: isinstance(x, tuple))
@@ -281,8 +314,15 @@ def stack_apply(
     encoder_output=None,
     cp_pre_zigzag: bool = False,
     adapters=None,
+    cache_offset: int = 0,
 ):
     """Apply all (or a pipeline stage's worth of) layers via lax.scan.
+
+    Two stacks ({"dense", "moe"}: `cfg.first_k_dense_replace` dense layers
+    ahead of the expert layers) run one after the other, each its own scan
+    over its own kind of layer, with ONE cache and one running layer number
+    through both: `cache_offset` is where a stack's first layer sits in the
+    cache.
 
     Returns (x, kv_caches, aux) — `aux` sums the layers' MoE router
     load-balancing losses (0.0 for dense stacks; loss_fn weighs it by
@@ -297,6 +337,23 @@ def stack_apply(
     index is layer-invariant and closes over the body. None compiles to
     exactly today's graph (multi-tenant LoRA serving,
     models/attention.py)."""
+    k_dense = cfg.first_k_dense_replace
+    if k_dense:
+        assert layer_offset == 0 and adapters is None, (
+            "two stacks have no pipeline stage and no adapter bank")
+        common = dict(
+            rope_cos=rope_cos, rope_sin=rope_sin, position_ids=position_ids,
+            rng=rng, deterministic=deterministic, segment_ids=segment_ids,
+            causal=causal, encoder_output=encoder_output,
+            cp_pre_zigzag=cp_pre_zigzag)
+        x, kv_caches, aux_dense = stack_apply(
+            stacked_params["dense"], x, cfg.dense_layers(),
+            kv_caches=kv_caches, **common)
+        x, kv_caches, aux_moe = stack_apply(
+            stacked_params["moe"], x, cfg.expert_layers(),
+            kv_caches=kv_caches, layer_offset=k_dense, cache_offset=k_dense,
+            **common)
+        return x, kv_caches, aux_dense + aux_moe
     num_layers = jax.tree.leaves(stacked_params)[0].shape[0]
     drop_rates = lima_dropout_rates(cfg, cfg.num_layers)
     drop_rates = jax.lax.dynamic_slice_in_dim(drop_rates, layer_offset, num_layers)
@@ -332,11 +389,15 @@ def stack_apply(
         layer_rng = None
         if rng is not None and not deterministic:
             layer_rng = jax.random.fold_in(rng, lid)
+        # the layer's index in its own stack (its banks) and in the cache,
+        # which a stack behind another enters at `cache_offset`
+        li = None if caches is None else lid - layer_offset
         h, caches, aux = layer_apply(
             p, h, cfg, rope_cos=rope_cos, rope_sin=rope_sin,
             position_ids=position_ids, kv_cache=caches,
-            cache_layer=None if caches is None else lid - layer_offset,
-            expert_banks=expert_banks,
+            cache_layer=(li + cache_offset
+                         if cache_offset and caches is not None else li),
+            expert_banks=expert_banks, bank_layer=li,
             layer_number=lid + 1, hidden_dropout=rate,
             drop_path_rate=dp_rate if use_drop_path else None,
             rng=layer_rng,

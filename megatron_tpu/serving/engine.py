@@ -123,13 +123,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from megatron_tpu.inference.generation import (PREFILL_BUCKET, Generator,
-                                               prefill_chunk, verify_tokens)
+                                               prefill_chunk, verify_tokens,
+                                               whole_logits_fit)
 from megatron_tpu.inference.sampling import (sample_batched,
                                              verify_draft_probs)
 from megatron_tpu.models import language_model as lm
 from megatron_tpu.resilience.faults import get_fault_injector
 from megatron_tpu.serving.kv_pool import (SlotKVPool, block_native_cache,
-                                          insert_blocks, insert_prefill,
+                                          batch_row, insert_blocks,
+                                          insert_prefill,
                                           pack_block_native, resolve_view,
                                           scatter_view, slice_blocks,
                                           slice_slot)
@@ -536,6 +538,8 @@ class ServingEngine:
         self.scheduler.active_fn = (
             lambda: int(self._active.sum()) + len(self._prefilling))
         self.metrics = metrics if metrics is not None else ServingMetrics()
+        self.metrics.set_pool_gauges(self.pool.bytes_per_token(),
+                                     self.pool.nbytes())
         # graceful degradation (serving/degrade.py): None when the
         # brownout ladder is disabled — the None path is the
         # bit-identical pre-ladder engine (test-pinned). The
@@ -2615,19 +2619,16 @@ class ServingEngine:
             bkv, pool = pool, resolve_view(pool)
         B = tokens.shape[0]
         caches = self.pool.make_prefill_caches(B)
+        # the head on each row's last real position alone where the whole
+        # bucket's logits would not fit (generation.whole_logits_fit)
+        whole = whole_logits_fit(*tokens.shape, self.cfg)
         logits, caches = lm.model_forward(
             params, tokens, self.cfg, kv_caches=caches,
             rope=self.gen.rope, logits_dtype=jnp.float32,
-            adapters=adapters)
+            adapters=adapters,
+            logits_rows=None if whole else plens - 1)
         for i in range(B):  # static unroll: B is a trace-time shape
-            def row(x):
-                return jax.lax.dynamic_slice_in_dim(x, i, 1, axis=1)
-            sub = caches._replace(
-                k=row(caches.k), v=row(caches.v),
-                k_scale=(None if caches.k_scale is None
-                         else row(caches.k_scale)),
-                v_scale=(None if caches.v_scale is None
-                         else row(caches.v_scale)))
+            sub = batch_row(caches, i)
             if self._kernel_on:
                 pool = insert_blocks(pool, sub, slots[i], plens[i],
                                      jnp.int32(0))
@@ -2635,8 +2636,9 @@ class ServingEngine:
                 pool = insert_prefill(pool, sub, slots[i], plens[i])
             # logits at the LAST REAL prompt position (bucket pads sit
             # after it and are causally invisible to it)
-            last = jax.lax.dynamic_slice_in_dim(
-                logits[i], plens[i] - 1, 1, axis=0)[0]
+            last = (jax.lax.dynamic_slice_in_dim(
+                logits[i], plens[i] - 1, 1, axis=0)[0] if whole
+                else logits[i, 0])
             last_logits = last_logits.at[slots[i]].set(last)
             rngs = rngs.at[slots[i]].set(rng0s[i])
         if bkv is not None:

@@ -55,6 +55,7 @@ from megatron_tpu.config import ModelConfig
 from megatron_tpu.inference.generation import (KV_CACHE_AXES, init_kv_caches,
                                                kv_region_cap)
 from megatron_tpu.models.attention import BlockKVCache, KVCache
+from megatron_tpu.models.mla import LatentKVCache
 from megatron_tpu.utils.logging import print_rank_0
 
 
@@ -74,6 +75,13 @@ def insert_prefill(pool: KVCache, prefill: KVCache, slot, plen) -> KVCache:
     dus = jax.lax.dynamic_update_slice
     zero = jnp.int32(0)
     slot = jnp.asarray(slot, jnp.int32)
+    if isinstance(pool, LatentKVCache):
+        return LatentKVCache(
+            c=dus(pool.c, prefill.c.astype(pool.c.dtype),
+                  (zero, slot, zero, zero)),
+            offset=dus(pool.offset,
+                       jnp.full((pool.offset.shape[0], 1), plen, jnp.int32),
+                       (zero, slot)))
     start5 = (zero, slot, zero, zero, zero)
     new = KVCache(
         k=dus(pool.k, prefill.k.astype(pool.k.dtype), start5),
@@ -100,9 +108,14 @@ def slice_slot(pool: KVCache, slot, offset) -> KVCache:
     write-before-read, the same invariant bucket-padded prefill relies
     on. int8 pools copy quantized blocks + scales verbatim."""
     ds = jax.lax.dynamic_slice
-    L, _, cap, nkv, hd = pool.k.shape
     zero = jnp.int32(0)
     slot = jnp.asarray(slot, jnp.int32)
+    if isinstance(pool, LatentKVCache):
+        L, _, row, cap = pool.c.shape
+        return LatentKVCache(
+            c=ds(pool.c, (zero, slot, zero, zero), (L, 1, row, cap)),
+            offset=jnp.full((L,), offset, jnp.int32))
+    L, _, cap, nkv, hd = pool.k.shape
     start5 = (zero, slot, zero, zero, zero)
     return KVCache(
         k=ds(pool.k, start5, (L, 1, cap, nkv, hd)),
@@ -113,6 +126,16 @@ def slice_slot(pool: KVCache, slot, offset) -> KVCache:
         v_scale=(None if pool.v_scale is None
                  else ds(pool.v_scale, start5, (L, 1, cap, nkv, 1))),
     )
+
+
+def batch_row(caches, i: int):
+    """Row `i` of a batch-B prefill cache as a batch-1 cache (every array
+    but the offsets cut on the batch axis), for `insert_prefill`."""
+    def row(x):
+        return None if x is None else jax.lax.dynamic_slice_in_dim(
+            x, i, 1, axis=1)
+    return caches._replace(**{f: row(getattr(caches, f))
+                              for f in caches._fields if f != "offset"})
 
 
 def clone_prefix(pool: KVCache, src_slot, dst_slot, plen) -> KVCache:
@@ -368,7 +391,9 @@ class SlotKVPool:
             self.caches = init_kv_caches(cfg, num_slots, max_len,
                                          dtype=dtype,
                                          per_slot_offsets=True)
-            assert self.cap == self.caches.k.shape[2], (
+            # positions: axis 2 of k and v, the minor axis of a latent pool
+            assert self.cap == (self.caches.c.shape[3] if cfg.mla
+                                else self.caches.k.shape[2]), (
                 "kv_region_cap drifted from init_kv_caches")
             return
         # ---- block mode ----------------------------------------------
@@ -864,6 +889,8 @@ class SlotKVPool:
                 return n
             return sum(_one(b.arena) for b in self.caches)
         c = self.caches.arena if self.blocks_enabled else self.caches
+        if isinstance(c, LatentKVCache):
+            return c.c.nbytes
         n = c.k.nbytes + c.v.nbytes
         if c.k_scale is not None:
             n += c.k_scale.nbytes + c.v_scale.nbytes
@@ -876,18 +903,20 @@ class SlotKVPool:
         the engine's kv_gather_bytes_per_step gauge. Defined for every
         layout (whole-region pools never bracket, but the unit is
         still what a bracket WOULD move)."""
-        elems = (self.cfg.num_layers * self.num_slots * self.cap
-                 * self.cfg.num_kv_heads * self.cfg.kv_channels)
-        n = 2 * elems * self.dtype.itemsize
+        n = (self.cfg.num_layers * self.num_slots * self.cap
+             * self.cfg.kv_row_width * self.dtype.itemsize)
         if self.dtype == jnp.dtype(jnp.int8):
-            n += 2 * (elems // self.cfg.kv_channels) * 4  # fp32 scales
+            n += 2 * (self.cfg.num_layers * self.num_slots * self.cap
+                      * self.cfg.num_kv_heads) * 4  # fp32 scales
         return n
 
     def bytes_per_token(self) -> int:
         """k+v (and int8 scale) bytes one cached token costs across
-        layers — the unit behind kv_bytes_wasted."""
-        n = 2 * self.cfg.num_layers * self.cfg.num_kv_heads \
-            * self.cfg.kv_channels * self.dtype.itemsize
+        layers — the unit behind kv_bytes_wasted. From the cache's own row
+        width (`ModelConfig.kv_row_width`: 2 x kv heads x head dim, or a
+        latent row)."""
+        n = self.cfg.num_layers * self.cfg.kv_row_width \
+            * self.dtype.itemsize
         if self.dtype == jnp.dtype(jnp.int8):
             n += 2 * self.cfg.num_layers * self.cfg.num_kv_heads * 4
         return n
@@ -949,10 +978,9 @@ def slot_nbytes(cfg: ModelConfig, max_len: int,
     cap = kv_region_cap(cfg, max_len)
     if block_size is not None and block_size < cap:
         cap = -(-cap // block_size) * block_size
-    elems = cfg.num_layers * cap * cfg.num_kv_heads * cfg.kv_channels
-    n = 2 * elems * jnp.dtype(dtype).itemsize
+    n = cfg.num_layers * cap * cfg.kv_row_width * jnp.dtype(dtype).itemsize
     if jnp.dtype(dtype) == jnp.dtype(jnp.int8):
-        n += 2 * (elems // cfg.kv_channels) * 4  # fp32 scales
+        n += 2 * (cfg.num_layers * cap * cfg.num_kv_heads) * 4  # fp32 scales
     return n
 
 

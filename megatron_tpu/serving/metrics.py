@@ -166,6 +166,7 @@ _BASE_GAUGES = (
     "queue_depth", "active_slots", "num_slots",
     "kv_blocks_used", "kv_blocks_retained", "kv_bytes_wasted",
     "kv_gather_bytes_per_step", "kv_attn_path",
+    "kv_bytes_per_token", "kv_pool_bytes",
     "active_adapters", "handoff_bytes_per_req",
     "prefill_group_busy", "decode_group_busy",
     "prefill_tp", "decode_tp", "prefill_devices", "decode_devices",
@@ -218,6 +219,11 @@ class ServingMetrics:
         # resolve/scatter bracket, 2 = block-native Pallas kernel.
         self.kv_gather_bytes_per_step = 0
         self.kv_attn_path = 0
+        # the pool's own count of what a cached token costs across layers
+        # (2 x kv heads x head dim x itemsize a layer, or a latent row) and
+        # of the bytes it holds, pushed once when the engine builds it
+        self.kv_bytes_per_token = 0
+        self.kv_pool_bytes = 0
         # multi-tenant LoRA serving: device-resident (non-identity)
         # adapters right now — 0 on adapterless engines, pushed by the
         # engine on pool churn like the KV gauges
@@ -369,6 +375,13 @@ class ServingMetrics:
         at build): the current degradation level."""
         with self._lock:
             self.degrade_level = float(level)
+
+    def set_pool_gauges(self, bytes_per_token: int, pool_bytes: int):
+        """`SlotKVPool.bytes_per_token()` and `.nbytes()`, as the pool
+        counts them."""
+        with self._lock:
+            self.kv_bytes_per_token = int(bytes_per_token)
+            self.kv_pool_bytes = int(pool_bytes)
 
     def set_attn_gauges(self, gather_bytes_per_step: int, path: int):
         """Engine-pushed attention-path gauges (per sync window):

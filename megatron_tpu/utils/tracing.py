@@ -42,7 +42,7 @@ Training (`training/loop.py`, main thread):
 | `mtpu/train/save` | `save_fn(...)` | |
 
 On the device (`jax.named_scope`, so in the `op_name` of every HLO instruction
-traced under it; models/moe.py and models/attention.py):
+traced under it; models/moe.py, models/attention.py and models/mla.py):
 
 | scope | round what |
 |---|---|
@@ -50,6 +50,16 @@ traced under it; models/moe.py and models/attention.py):
 | `mtpu/moe/experts` | the weight casts, the two grouped products (ops/grouped_matmul.py) and the activation between them |
 | `mtpu/moe/combine` | the gather back to (token, k) order and the weighted sum of a token's K rows |
 | `mtpu/attn/qk_norm` | the RMSNorm over the whole q and the whole k projection (`qk_norm`) |
+| `mtpu/moe/shared` | the shared experts' MLP, added beside the routed sum (`n_shared_experts`) |
+| `mtpu/mla/q` | latent attention's query: down-projection, norm, up-projection, the rotary on its rope part |
+| `mtpu/mla/latent` | the latent row: down-projection, norm over kv_lora_rank, the rotary on the shared key, the write into the cache |
+| `mtpu/mla/attend_expanded` | the expanded form: keys and values of every head from the rows, causal attention from position 0 (training; a prefill at offset 0) |
+| `mtpu/mla/attend_absorbed` | the absorbed form: the layer of the cache read by the scores and by the weighted sum, each head's query and output through W_uk and W_uv (decode, verify, continuation chunks) |
+
+Counters of the serving metrics' snapshot that a benchmark reader takes:
+`kv_bytes_per_token` and `kv_pool_bytes`, `SlotKVPool.bytes_per_token()` and
+`.nbytes()` as the pool counts them, pushed once when the engine builds it
+(`serve_kv_bytes_per_token`).
 
 A TPU v5e's trace names an `XLA Ops` event by the HLO instruction's text, which
 does not hold the `op_name` (PERF.md section 7, PR 27): the scopes are in the

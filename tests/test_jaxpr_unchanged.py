@@ -1,0 +1,86 @@
+"""The programs of the one-kind models are the ones they were.
+
+PR 31 put a second attention (MLA over a latent cache), a second stack (dense
+layers ahead of expert layers), a second router form and an MTP loss term
+through `stack_apply`, `layer_apply`, `moe_apply`, `kv_pool` and the engine.
+None of it may reach a model that has none of it: the traced decode and
+prefill programs of the engine and the plain loop (the training loss, forward
+and backward) of `falcon-tiny` and `olmoe-tiny` are held, character for
+character, to digests taken at the parent commit (280f7aa) with this file's
+own `digests()`: `python tests/test_jaxpr_unchanged.py` prints them. A later
+PR that changes one of these programs on purpose prints them again and says
+so.
+"""
+import hashlib
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from megatron_tpu.config import MODEL_PRESETS, ServingConfig
+from megatron_tpu.inference import Generator
+from megatron_tpu.models import language_model as lm
+from megatron_tpu.serving import ServingEngine
+
+SLOTS, CAP, B_PRE, BUCKET = 3, 64, 2, 16
+
+AT_PARENT = {
+    "falcon-tiny": {"decode": "3fd8de871acd6b59",
+                    "prefill": "56d397c890fa45a5",
+                    "plain_loop": "5ec7bf8d21be92cf"},
+    "olmoe-tiny": {"decode": "97a15a8d0a346943",
+                   "prefill": "ca0fa2aa71a1872f",
+                   "plain_loop": "c88e314638cb0a8f"},
+}
+
+
+def _programs(model):
+    import dataclasses
+    # the presets' own dtypes (float32 weights, bf16 compute), as the
+    # benchmark's cells run them
+    cfg = dataclasses.replace(MODEL_PRESETS[model](), vocab_size=512)
+    params = lm.model_init(jax.random.PRNGKey(0), cfg)
+    gen = Generator(params, cfg, eos_id=0, pad_id=0)
+    serving = ServingConfig(num_slots=SLOTS, max_len=CAP,
+                            prefill_bucket=BUCKET,
+                            prefill_max_batch=B_PRE).validate(cfg)
+    eng = ServingEngine(gen, serving, start=False)
+    try:
+        state = (eng._p_dec, eng.pool.caches, eng._last_logits, eng._rngs)
+        grid = (eng._d_lengths, eng._d_temps, eng._d_top_ks, eng._d_top_ps)
+        yield "decode", eng._decode_fn, (
+            *state, *grid, eng._d_reject, eng._d_masks, None, None)
+        yield "prefill", eng._prefill_fn, (
+            *state, jnp.zeros((B_PRE, BUCKET), jnp.int32),
+            jnp.full((B_PRE,), 7, jnp.int32), jnp.arange(B_PRE),
+            jnp.zeros((B_PRE, 2), jnp.uint32), None, None)
+        tokens = jnp.zeros((2, 33), jnp.int32)
+        rope = lm.make_rope(cfg)
+        yield "plain_loop", jax.value_and_grad(
+            lambda p, t: lm.loss_fn(p, t, cfg, rope=rope)), (params, tokens)
+    finally:
+        eng.close()
+
+
+def digests(model):
+    out = {}
+    # the precision tests/conftest.py sets, so that the script and the test
+    # print the same
+    with jax.default_matmul_precision("highest"):
+        for name, fn, args in _programs(model):
+            text = re.sub(r"0x[0-9a-f]+", "0x",
+                          str(jax.make_jaxpr(fn)(*args)))
+            out[name] = hashlib.sha256(text.encode()).hexdigest()[:16]
+    return out
+
+
+@pytest.mark.parametrize("model", sorted(AT_PARENT))
+def test_programs_are_the_parents(model):
+    assert digests(model) == AT_PARENT[model]
+
+
+if __name__ == "__main__":
+    import json
+    print(json.dumps({m: digests(m) for m in ("falcon-tiny", "olmoe-tiny")},
+                     indent=4))

@@ -1,0 +1,146 @@
+"""JoyAI-LLM-Flash through `ServingEngine` (PR 31): the latent pool, a
+bucket-padded prefill in the expanded form, decode steps in the absorbed form
+beside other live slots, and prefix cache, chunked prefill and speculation
+over the latent cache, each against the float32 reference's full forward
+(`benchmark/reference/joyai.py`). Log-probabilities, never tokens."""
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.reference import joyai as reference
+from megatron_tpu.config import MODEL_PRESETS, ServingConfig
+from megatron_tpu.inference import Generator
+from megatron_tpu.models import language_model as lm
+from megatron_tpu.models.mla import LatentKVCache
+from megatron_tpu.serving import SamplingOptions, ServingEngine
+from megatron_tpu.serving.kv_pool import SlotKVPool, slot_nbytes
+
+
+@pytest.fixture(scope="module")
+def model():
+    cfg = dataclasses.replace(MODEL_PRESETS["joyai-llm-flash-tiny"](),
+                              compute_dtype="float32")
+    params = lm.model_init(jax.random.PRNGKey(0), cfg)
+    mlp = params["transformer"]["moe"]["mlp"]
+    mlp["e_score_correction_bias"] = 0.1 * jax.random.normal(
+        jax.random.PRNGKey(5), mlp["e_score_correction_bias"].shape)
+    params["embedding"]["word_embeddings"] *= 50.0
+    params.pop("mtp")            # a server does not load the module
+    return cfg, params
+
+
+def _logprobs(eng, prompt, n_new):
+    req = eng.submit(prompt, n_new, SamplingOptions(temperature=0.0), seed=11)
+    tokens, _ = req.result(timeout=600)
+    return req, tokens, np.asarray(req.gen_logprobs, np.float64)
+
+
+@pytest.mark.parametrize("how", ["plain", "chunked_prefill", "prefix_hit",
+                                 "speculative", "head_on_last_rows",
+                                 "chunked_head_on_last_rows"])
+def test_engine_prefill_and_decode_match_reference(model, how, monkeypatch):
+    """A prompt prefilled in a padded bucket (37 tokens in 48), then decoded
+    through the latent cache one token at a time beside an unrelated
+    request. `head_on_last_rows`: the same where a bucket's whole logits
+    are counted too large to make (generation.whole_logits_fit)."""
+    cfg, params = model
+    if "head_on_last_rows" in how:
+        from megatron_tpu.inference import generation
+        monkeypatch.setattr(generation, "WHOLE_LOGITS_BYTES_MAX", 0)
+    gen = Generator(params, cfg, eos_id=-1, pad_id=0,
+                    kv_cache_dtype=jnp.float32)
+    serving = dict(num_slots=3, max_queue=8, max_len=96, prefill_bucket=16)
+    serving.update({"chunked_prefill": dict(prefill_chunk=16),
+                    "chunked_head_on_last_rows": dict(prefill_chunk=16),
+                    "prefix_hit": dict(enable_prefix_cache=True),
+                    "speculative": dict(speculative_k=2)}.get(how, {}))
+    rng = np.random.default_rng(5)
+    prompt = rng.integers(1, cfg.vocab_size, size=37).tolist()
+    if how == "speculative":     # a prompt the n-gram drafter can draft from
+        prompt = (prompt[:6] * 7)[:37]
+    other = rng.integers(1, cfg.vocab_size, size=21).tolist()
+    with ServingEngine(gen, ServingConfig(**serving).validate(cfg)) as eng:
+        assert isinstance(eng.pool.caches, LatentKVCache)
+        noise = eng.submit(other, 20, SamplingOptions(temperature=1.0),
+                           seed=3)
+        if how == "prefix_hit":
+            first = prompt[:32] + rng.integers(1, 512, size=4).tolist()
+            _logprobs(eng, first, 2)
+        req, tokens, got = _logprobs(eng, prompt, 12)
+        noise.result(timeout=600)
+        snap = eng.metrics.snapshot()
+    if how == "prefix_hit":
+        assert snap["prefix_hits"] >= 1 and req.prefix_len >= 16
+    if "chunked" in how:
+        assert snap["prefill_chunks"] >= 3
+    if how == "speculative":
+        assert snap["spec_rounds"] > 0 and snap["draft_tokens"] > 0
+    assert len(got) == 12 and tokens[:37] == prompt
+    want = np.asarray(reference.token_logprobs(
+        params, jnp.asarray(tokens, jnp.int32), cfg), np.float64)[36:]
+    np.testing.assert_allclose(got, want, rtol=0, atol=5e-4)
+    # the pool's own count, in the metrics' snapshot
+    assert snap["kv_bytes_per_token"] == 4 * 40 * 4
+    assert snap["kv_pool_bytes"] == 4 * 3 * 96 * 40 * 4
+
+
+@pytest.mark.parametrize("name, want", [
+    ("joyai-llm-flash-tiny", 4 * (32 + 8) * 2),       # layers x row x bf16
+    ("joyai-llm-flash", 40 * 576 * 2),
+    ("falcon-tiny", 2 * 2 * 1 * 64 * 2),              # layers x k,v x nkv x hd
+    ("falcon-7b", 32 * 2 * 1 * 64 * 2),
+    ("olmoe-tiny", 2 * 2 * 4 * 16 * 2),
+    ("olmoe-1b-7b", 16 * 2 * 16 * 128 * 2),
+])
+def test_bytes_per_token_reads_the_caches_own_row(name, want):
+    """576 values a token a layer for the latent pool; 2 x kv heads x head
+    dim for the others, as before."""
+    cfg = MODEL_PRESETS[name]()
+    pool = SlotKVPool.__new__(SlotKVPool)
+    pool.cfg, pool.dtype = cfg, jnp.dtype(jnp.bfloat16)
+    pool.num_slots, pool.cap = 4, 64
+    assert pool.bytes_per_token() == want
+    assert slot_nbytes(cfg, 64) == 64 * want
+    assert pool.view_nbytes() == 4 * 64 * want
+    pool.dtype = jnp.dtype(jnp.int8)
+    if not cfg.mla:
+        scales = 2 * cfg.num_layers * cfg.num_kv_heads * 4
+        assert pool.bytes_per_token() == want // 2 + scales
+        assert slot_nbytes(cfg, 64, jnp.int8) == 64 * (want // 2 + scales)
+
+
+def test_latent_pool_is_one_array_written_in_place(model):
+    """The decode and prefill programs carry the latent pool through the
+    layer loops of both stacks and write it where it lies: no operation
+    makes, cuts out or writes back a whole layer of it."""
+    cfg, params = model
+    gen = Generator(params, cfg, eos_id=-1, pad_id=0)
+    serving = ServingConfig(num_slots=5, max_len=48, prefill_bucket=16,
+                            prefill_max_batch=2).validate(cfg)
+    eng = ServingEngine(gen, serving, start=False)
+    try:
+        pool = eng.pool.caches
+        assert pool.c.shape == (4, 5, 40, 48) and pool.c.dtype == jnp.bfloat16
+        assert eng.pool.nbytes() == pool.c.nbytes
+        state = (eng._p_dec, pool, eng._last_logits, eng._rngs)
+        grid = (eng._d_lengths, eng._d_temps, eng._d_top_ks, eng._d_top_ps)
+        programs = {
+            "decode": (eng._decode_fn, (*state, *grid, eng._d_reject,
+                                        eng._d_masks, None, None)),
+            "prefill": (eng._prefill_fn, (
+                *state, jnp.zeros((2, 16), jnp.int32),
+                jnp.full((2,), 7, jnp.int32), jnp.arange(2),
+                jnp.zeros((2, 2), jnp.uint32), None, None))}
+        from tests.test_kv_inplace import check_in_place
+        for name, (fn, args) in programs.items():
+            batches = (5,) if name == "decode" else (5, 2)
+            seen = check_in_place(
+                jax.make_jaxpr(fn)(*args),
+                {(4, b, 40, 48) for b in batches})
+            # both stacks' loops carry it, and every layer writes once
+            assert seen["carried"] >= 2 and seen["writes"] >= 2, (name, seen)
+    finally:
+        eng.close()
