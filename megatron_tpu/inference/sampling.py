@@ -75,6 +75,28 @@ def _top_p_filter_rows(logits, p):
     return jnp.where(((p > 0.0) & (p < 1.0))[:, None], filtered, logits)
 
 
+def rows_need_filter(temperature, top_k, top_p):
+    """Per row [b]: does top-k or top-p change what the row samples? Not
+    for a greedy row (its token is the argmax whatever is filtered) and not
+    with both knobs at their disabled values. Plain operators only, so the
+    engine asks the same question of its host (numpy) mirrors."""
+    greedy = (temperature == 0.0) | (top_k == 1)
+    return ~greedy & ((top_k > 1) | ((top_p > 0.0) & (top_p < 1.0)))
+
+
+def _filter_rows(x, temperature, top_k, top_p):
+    """top-k then top-p over the [b, vocab] grid, under ONE `lax.cond`:
+    both full-vocabulary sorts run only in a step where some row
+    `rows_need_filter`. With none, each filter's closing
+    `jnp.where(False, filtered, x)` would hand `x` back row for row, which
+    is what the false branch returns: bit-identical, without the sorts.
+    The predicate is data (the per-row knobs), so it stays one program."""
+    return jax.lax.cond(
+        jnp.any(rows_need_filter(temperature, top_k, top_p)),
+        lambda x: _top_p_filter_rows(_top_k_filter_rows(x, top_k), top_p),
+        lambda x: x, x)
+
+
 def sample_batched(rngs, logits, *, temperature, top_k, top_p,
                    vocab_size: int | None = None, banned=None,
                    mask=None):
@@ -87,9 +109,10 @@ def sample_batched(rngs, logits, *, temperature, top_k, top_p,
 
     Row-for-row it reproduces `sample(rngs[i], logits[i:i+1], ...)`
     bit-exactly: the filters are the same row-wise math with traced
-    instead of static knobs, and a vmapped `categorical` over a [V] row
-    draws the same threefry bits as the serial [1, V] call (the counter
-    stream depends only on the key and the element count).
+    instead of static knobs (and run only in a step where some row's
+    knobs ask for one: `_filter_rows`), and a vmapped `categorical` over
+    a [V] row draws the same threefry bits as the serial [1, V] call (the
+    counter stream depends only on the key and the element count).
 
     `banned` (int32 [b], < 0 disables a row): mask ONE token per row
     out of the PROCESSED distribution — i.e. AFTER temperature/top-k/
@@ -123,8 +146,7 @@ def sample_batched(rngs, logits, *, temperature, top_k, top_p,
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     greedy_rows = (temperature == 0.0) | (top_k == 1)
     x = logits / jnp.maximum(temperature, 1e-6)[:, None]
-    x = _top_k_filter_rows(x, top_k)
-    x = _top_p_filter_rows(x, top_p)
+    x = _filter_rows(x, temperature, top_k, top_p)
     if banned is not None:
         iota = jnp.arange(x.shape[-1])
         x = jnp.where((banned >= 0)[:, None]
@@ -159,7 +181,10 @@ def verify_draft_probs(logits, drafts, *, temperature, top_k, top_p,
     `greedy_targets` is the plain argmax (greedy rows accept by exact
     match). The [b, w] grid folds to [b*w] rows with each row's knobs
     repeated, so the filters are bit-identical to a serial
-    one-position-at-a-time verify of the same logits.
+    one-position-at-a-time verify of the same logits. A window in which
+    no row filters skips the sorts (`_filter_rows`); a GREEDY row's
+    `probs` then come unfiltered even where it names a top_p, and nobody
+    reads them: greedy rows accept on `greedy_targets` alone.
 
     `mask` (bool [b, w, vocab], True = allowed): grammar-constrained
     rows' per-POSITION legal-token masks (the host steps the FSM along
@@ -185,8 +210,7 @@ def verify_draft_probs(logits, drafts, *, temperature, top_k, top_p,
         greedy_targets = jnp.argmax(x, axis=-1).astype(jnp.int32)
     temp = jnp.repeat(temperature, w)
     x = x / jnp.maximum(temp, 1e-6)[:, None]
-    x = _top_k_filter_rows(x, jnp.repeat(top_k, w))
-    x = _top_p_filter_rows(x, jnp.repeat(top_p, w))
+    x = _filter_rows(x, temp, jnp.repeat(top_k, w), jnp.repeat(top_p, w))
     if mask is not None:
         x = jnp.where(mask.reshape(b * w, V), x, -jnp.inf)
     p = jax.nn.softmax(x, axis=-1)

@@ -125,7 +125,8 @@ import numpy as np
 from megatron_tpu.inference.generation import (PREFILL_BUCKET, Generator,
                                                prefill_chunk, verify_tokens,
                                                whole_logits_fit)
-from megatron_tpu.inference.sampling import (sample_batched,
+from megatron_tpu.inference.sampling import (rows_need_filter,
+                                             sample_batched,
                                              verify_draft_probs)
 from megatron_tpu.models import language_model as lm
 from megatron_tpu.resilience.faults import get_fault_injector
@@ -619,6 +620,10 @@ class ServingEngine:
         self._d_temps = jnp.asarray(self._temps)
         self._d_top_ks = jnp.asarray(self._top_ks)
         self._d_top_ps = jnp.asarray(self._top_ps)
+        # does some slot's knobs ask for top-k / top-p? Recomputed with
+        # each upload of the three arrays above: it is the predicate of
+        # sampling._filter_rows' cond, kept for `sample_filter_steps`
+        self._filter_live = False
         # speculative-decode residual carry: per-slot token a stochastic
         # rejection banned from the NEXT first sample (-1 = none); the
         # host mirror is exact at sync boundaries (it rides the window
@@ -3114,6 +3119,7 @@ class ServingEngine:
         self._mask_state = np.full(S, -1, np.int64)
         self._masks_dirty = False
         self._slot_req = [None] * S
+        self._park_knobs(slice(None))
         self._sampling_dirty = True
         self._lengths_dirty = True
         self._kv_dirty = True
@@ -3208,6 +3214,7 @@ class ServingEngine:
             self._masks[slot, :] = True
             self._mask_state[slot] = -1
             self._masks_dirty = True
+        self._park_knobs(slot)
         self._sampling_dirty = True
         self._kv_dirty = True
         self._lengths_dirty = True
@@ -3996,6 +4003,14 @@ class ServingEngine:
         # terminal hook counts requests_expired per request
         self.scheduler.drop_expired(self._deadline_s, now)
 
+    def _park_knobs(self, slot):
+        """A freed row samples unfiltered: its last tenant's top_k /
+        top_p would keep sampling._filter_rows' two vocabulary sorts on
+        for every step the row stays empty. The disabled values ride the
+        `_sampling_dirty` upload every freeing site sets anyway."""
+        self._top_ks[slot] = 0
+        self._top_ps[slot] = 0.0
+
     def _evict(self, slot: int, failed: Optional[str] = None,
                kind: str = "error"):
         slot = int(slot)  # callers iterate np.nonzero -> np.int64;
@@ -4018,6 +4033,7 @@ class ServingEngine:
             self._masks_dirty = True
         self._kv_dirty = True
         self._lengths_dirty = True  # device copy re-parks at next step
+        self._park_knobs(slot)
         self._sampling_dirty = True
         if failed is None and self._prefix_on and self._blocks_on:
             # block-granular retention: the finished row converts into
@@ -4226,8 +4242,14 @@ class ServingEngine:
                 self._d_temps = jnp.asarray(self._temps)
                 self._d_top_ks = jnp.asarray(self._top_ks)
                 self._d_top_ps = jnp.asarray(self._top_ps)
+                self._filter_live = bool(rows_need_filter(
+                    self._temps, self._top_ks, self._top_ps).any())
                 self._sampling_dirty = False
                 self.metrics.count("sampling_uploads")
+            if self._filter_live:
+                # the K dispatches below each pay the two vocabulary
+                # sorts (a freed slot's knobs were parked: _park_knobs)
+                self.metrics.count("sample_filter_steps", K)
             if self._masks_dirty:
                 # grammar masks upload ONLY when some slot's FSM state
                 # actually changed since the last window (_set_slot_mask /

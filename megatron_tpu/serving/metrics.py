@@ -48,6 +48,10 @@ _BASE_COUNTERS = (
     "requests_cancelled", "requests_expired",
     "tokens_generated", "decode_steps", "host_syncs",
     "wasted_decode_steps", "sampling_uploads",
+    # decode steps dispatched while some live row's knobs asked for a
+    # top-k / top-p filter: over decode_steps, the share of steps that
+    # still pay the two vocabulary sorts (inference/sampling.py)
+    "sample_filter_steps",
     "prefill_calls", "prefill_prompts",
     # prefix cache / chunked prefill (docs/serving.md):
     # prefix_hit_tokens counts tokens MATCHED at lookup (including

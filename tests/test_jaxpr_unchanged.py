@@ -7,9 +7,15 @@ None of it may reach a model that has none of it: the traced decode and
 prefill programs of the engine and the plain loop (the training loss, forward
 and backward) of `falcon-tiny` and `olmoe-tiny` are held, character for
 character, to digests taken at the parent commit (280f7aa) with this file's
-own `digests()`: `python tests/test_jaxpr_unchanged.py` prints them. A later
-PR that changes one of these programs on purpose prints them again and says
-so.
+own `digests()`: `PYTHONPATH=. python tests/test_jaxpr_unchanged.py` prints
+them. A later PR that changes one of these programs on purpose prints them
+again and says so.
+
+PR 32 changed the two `decode` programs on purpose (the sampler's top-k and
+top-p filters moved under one `lax.cond`, `inference/sampling.py::
+_filter_rows`) and printed them again at its parent's tree (4cf2126) plus
+that change. The four `prefill` and `plain_loop` digests came out as they
+were at 280f7aa: no prefill and no training program holds the sampler.
 """
 import hashlib
 import re
@@ -26,10 +32,10 @@ from megatron_tpu.serving import ServingEngine
 SLOTS, CAP, B_PRE, BUCKET = 3, 64, 2, 16
 
 AT_PARENT = {
-    "falcon-tiny": {"decode": "3fd8de871acd6b59",
+    "falcon-tiny": {"decode": "be486d462f3e2d4e",
                     "prefill": "56d397c890fa45a5",
                     "plain_loop": "5ec7bf8d21be92cf"},
-    "olmoe-tiny": {"decode": "97a15a8d0a346943",
+    "olmoe-tiny": {"decode": "0309990a12d28acb",
                    "prefill": "ca0fa2aa71a1872f",
                    "plain_loop": "c88e314638cb0a8f"},
 }
