@@ -70,7 +70,7 @@ class ModelConfig:
     use_position_embedding: bool = False
 
     # norms / activations / structure
-    norm_type: str = "rmsnorm"  # "rmsnorm" | "layernorm"
+    norm_type: str = "rmsnorm"  # "rmsnorm" | "layernorm" | "layernorm_nobias"
     norm_epsilon: float = 1e-5
     activation: str = "swiglu"  # swiglu|geglu|reglu|liglu|gelu|relu|squared_relu
     use_bias: bool = False  # bias on linear layers (ref: --use_bias)
@@ -171,6 +171,28 @@ class ModelConfig:
     mtp_num_layers: int = 0
     mtp_loss_coeff: float = 0.3
 
+    # Window and full attention in one stack (the published `layer_types`
+    # with `order_of_interleaved_layers` local_attn_first): layer l is FULL
+    # where (l + 1) % window_layer_period == 0 and attends the last
+    # `sliding_window` positions otherwise. 0: every layer is of the one
+    # kind `sliding_window` says. The stack is scanned a PERIOD at a time
+    # (models/transformer.py) and the cache holds the two kinds side by
+    # side (models/attention.py::HybridKVCache): rings of `sliding_window`
+    # rows for the window layers, whole regions for the full ones. The
+    # full layers take no rotation (no positional signal at all: "global
+    # NoPE"); the window layers keep theirs.
+    window_layer_period: int = 0
+    # The chip's share of an expert layer (models/moe.py): the router is
+    # `moe_router_experts` wide (the published `num_experts`; None: as wide
+    # as `num_experts`), the banks hold the `num_experts` experts from
+    # `moe_first_expert` on, and a token's choices outside them add
+    # nothing here. Gates are normalised over all the chosen, held or not.
+    moe_router_experts: Optional[int] = None
+    moe_first_expert: int = 0
+    # how the shared experts combine: "sum" (one MLP of their widths
+    # together) or "average" (the mean of their outputs: the same MLP / n)
+    moe_shared_combination: str = "sum"
+
     # glu activations double the first MLP projection
     @property
     def is_glu(self) -> bool:
@@ -186,6 +208,26 @@ class ModelConfig:
         if self.mla:
             return self.kv_lora_rank + self.qk_rope_head_dim
         return 2 * self.num_kv_heads * self.kv_channels
+
+    @property
+    def router_experts(self) -> int:
+        """The router's width: every expert of the layer, held here or not."""
+        return self.moe_router_experts or self.num_experts
+
+    @property
+    def window_layers_per_period(self) -> int:
+        return self.window_layer_period - 1 if self.window_layer_period else 0
+
+    def window_layers(self) -> "ModelConfig":
+        """The configuration of a window layer of a stack of two kinds:
+        this one as a model of window layers alone."""
+        return dataclasses.replace(self, window_layer_period=0)
+
+    def full_layers(self) -> "ModelConfig":
+        """The configuration of its full layers: no window, no rotation."""
+        return dataclasses.replace(
+            self, window_layer_period=0, sliding_window=None,
+            use_rotary_emb=False)
 
     def dense_layers(self) -> "ModelConfig":
         """The configuration of the `first_k_dense_replace` leading layers:
@@ -256,7 +298,6 @@ class ParallelConfig:
     pipeline_parallel: int = 1
     data_parallel: Optional[int] = None  # derived from world size
     context_parallel: int = 1
-    expert_parallel: int = 1  # unused; kept for config compatibility
     # which mesh axis the MoE expert bank's 'experts' dim shards over:
     # "tp" (default — each tp rank holds E/tp whole experts, router
     # all-to-alls ride the tp ICI) or "dp" (GShard-style expert
@@ -833,6 +874,36 @@ class ServingConfig:
             self.retained_slots)
         assert self.kv_block_size is None or self.kv_block_size >= 1, (
             self.kv_block_size)
+        if model is not None and model.window_layer_period:
+            # the pool holds rings and whole regions side by side
+            # (models/attention.py::HybridKVCache); what a ring cannot do
+            # yet is refused by name, not served wrong (ROADMAP R3)
+            refused = {
+                "enable_prefix_cache": self.enable_prefix_cache,
+                "retained_slots": self.retained_slots,
+                "preemption": self.preemption,
+                "speculative_k": self.speculative_k,
+                "kv_block_size": self.kv_block_size is not None,
+                "block_native_attn": self.block_native_attn,
+                "serving_pp": self.serving_pp > 1,
+                "serving_tp": self.serving_tp > 1,
+                "prefill_tp": (self.prefill_tp or 1) > 1,
+                "decode_tp": (self.decode_tp or 1) > 1,
+                "disaggregate_prefill": self.disaggregate_prefill,
+                "host_kv_bytes": self.host_kv_bytes,
+                "adapter_slots": self.adapter_slots,
+                "kv_dtype int8": (self.kv_dtype or "bfloat16") == "int8",
+            }
+            for name, on in refused.items():
+                assert not on, (
+                    f"window_layer_period={model.window_layer_period} "
+                    f"(window and full attention in one stack): {name} is "
+                    "refused on the pool of rings and whole regions: a "
+                    "ring keeps the last sliding_window rows only, so a "
+                    "retained, parked, cloned or rewound slot has lost the "
+                    "rows it would need, the block arena and its kernel "
+                    "know one region shape, and the two stacks have no "
+                    "stage cut, head shard or adapter bank (ROADMAP R3)")
         if model is not None and model.mla:
             # the latent pool is ONE array [layers, slots, positions, row]
             # (models/mla.py::LatentKVCache): no head axis, no k beside v
@@ -956,7 +1027,8 @@ class ServingConfig:
             assert max_len is None or self.speculative_k < max_len, (
                 f"speculative_k={self.speculative_k} must be smaller "
                 f"than the slot capacity (max_len={max_len})")
-        if model is not None and model.sliding_window is not None:
+        if model is not None and model.sliding_window is not None \
+                and not model.window_layer_period:
             # ROLLING pools (flash impl caps the region to W < max_len)
             # hold the last W positions ring-ordered by position % W.
             # WHOLE-REGION rolling pools cannot retain, clone, or park:
@@ -1463,6 +1535,47 @@ class MegatronConfig:
                 "whole context: no sliding_window, qk_norm, use_bias, "
                 "quantized_gemm, attention_dropout or context-parallel "
                 "attention_impl")
+        if model.window_layer_period:
+            # models/transformer.py scans a period at a time; the cache is
+            # models/attention.py::HybridKVCache
+            assert model.window_layer_period >= 2 \
+                and model.sliding_window is not None \
+                and model.num_layers % model.window_layer_period == 0, (
+                f"window_layer_period={model.window_layer_period} needs "
+                "sliding_window set and num_layers="
+                f"{model.num_layers} a whole number of periods (each: "
+                "period - 1 window layers, then one full layer)")
+            assert not model.mla and not model.first_k_dense_replace \
+                and not model.mtp_num_layers, (
+                "window_layer_period is refused with MLA (kv_lora_rank), "
+                "first_k_dense_replace and mtp_num_layers: the period "
+                "scan carries k/v rings and regions and ONE stack "
+                "(ROADMAP R3, R4)")
+            assert max(sharded.values()) == 1, (
+                "window_layer_period (window and full attention in one "
+                f"stack) has been made to work on one device only (got "
+                f"{sharded}): the period scan has no stage cut and the "
+                "two cache stacks no head shard (ROADMAP R3)")
+            assert model.attention_impl in ("dot", "flash") \
+                and model.attention_dropout == 0.0, (
+                "window_layer_period is refused with context-parallel "
+                "attention_impl (ring / ulysses) and attention_dropout")
+        assert model.moe_shared_combination in ("sum", "average"), (
+            f"moe_shared_combination={model.moe_shared_combination!r} "
+            "(expected 'sum' or 'average')")
+        if model.moe_router_experts is not None or model.moe_first_expert:
+            # models/moe.py: one chip's share of an expert layer
+            assert model.num_experts > 1 \
+                and model.moe_dispatch == "dropless", (
+                "moe_router_experts / moe_first_expert (the chip's share "
+                "of an expert layer) are the dropless path's "
+                "(--moe_dispatch dropless)")
+            assert 0 <= model.moe_first_expert and model.moe_first_expert \
+                + model.num_experts <= model.router_experts, (
+                f"the experts held, {model.moe_first_expert} to "
+                f"{model.moe_first_expert + model.num_experts - 1}, are "
+                f"not among the router's {model.router_experts}")
+            assert model.moe_top_k <= model.router_experts
         if model.first_k_dense_replace or model.n_shared_experts:
             assert model.num_experts > 1, (
                 "first_k_dense_replace / n_shared_experts describe a model "
@@ -1768,6 +1881,55 @@ def joyai_config(size: str = "llm-flash", **overrides) -> ModelConfig:
     return ModelConfig(**base).derived()
 
 
+def command_a_config(size: str = "plus", **overrides) -> ModelConfig:
+    """Command A+ presets: every size of "plus" is a key of
+    CohereLabs/command-a-plus-05-2026's config.json (`cohere2_moe`,
+    218B-A25B: 32 layers, hidden 4096, 128 heads of 128 over 8 kv heads;
+    `layer_types` of period 4, three `sliding_attention` layers of window
+    4096 with rotary positions (theta 50000, adjacent pairs, all 128
+    channels) and then one `full_attention` layer with none; in every
+    layer 128 sigmoid-scored experts, 8 a token, gates normalised, beside
+    4 shared experts whose outputs are averaged; one LayerNorm a layer with
+    a scale and no bias, eps 1e-5, attention and experts in parallel on it
+    (`use_parallel_block`); SiLU-gated; no bias, no QK-norm; tied head,
+    `logit_scale` 1 (nothing to multiply); 200,000 positions; vocabulary 262,144). One expert's
+    width is `intermediate_size` 4096 (benchmark/configs/
+    command-a-plus-4l.json, `assumed`). Dropless. `moe_router_experts` is
+    the published expert count, so that `--num_experts 16` gives one
+    chip's share of 8 (experts 0 to 15) under a router of 128."""
+    presets = {
+        "tiny": dict(num_layers=4, hidden_size=64, num_attention_heads=8,
+                     num_kv_heads=2, kv_channels=16, ffn_hidden_size=32,
+                     vocab_size=512, seq_length=128, sliding_window=16,
+                     num_experts=8, moe_top_k=2, n_shared_experts=2,
+                     attention_impl="dot"),
+        "plus": dict(num_layers=32, hidden_size=4096,
+                     num_attention_heads=128, num_kv_heads=8,
+                     kv_channels=128, ffn_hidden_size=4096,
+                     vocab_size=262144, seq_length=4096,
+                     max_position_embeddings=200000, sliding_window=4096,
+                     num_experts=128, moe_top_k=8, n_shared_experts=4,
+                     params_dtype="bfloat16"),
+    }
+    if size not in presets:
+        raise ValueError(f"unknown command-a size {size!r}; "
+                         f"valid: {sorted(presets)}")
+    base = dict(
+        use_rotary_emb=True, rope_theta=50000.0,
+        norm_type="layernorm_nobias", norm_epsilon=1e-5,
+        activation="swiglu", use_bias=False, use_post_ln=False,
+        parallel_attn=True, tie_embed_logits=True, window_layer_period=4,
+        moe_scoring_func="sigmoid", moe_norm_topk_prob=True,
+        moe_shared_combination="average", moe_dispatch="dropless",
+        moe_aux_loss_coeff=0.0,
+        moe_router_experts=presets[size]["num_experts"],
+        attention_impl="flash",  # see llama2_config
+    )
+    base.update(presets[size])
+    base.update(overrides)
+    return ModelConfig(**base).derived()
+
+
 def gpt_config(**overrides) -> ModelConfig:
     base = dict(
         num_layers=12, hidden_size=768, num_attention_heads=12,
@@ -1793,5 +1955,7 @@ MODEL_PRESETS = {
     "olmoe-1b-7b": lambda: olmoe_config("1b-7b"),
     "joyai-llm-flash-tiny": lambda: joyai_config("tiny"),
     "joyai-llm-flash": lambda: joyai_config("llm-flash"),
+    "command-a-plus-tiny": lambda: command_a_config("tiny"),
+    "command-a-plus": lambda: command_a_config("plus"),
     "gpt2": gpt_config,
 }

@@ -32,7 +32,7 @@ import numpy as np
 from megatron_tpu.config import ModelConfig
 from megatron_tpu.inference.sampling import sample
 from megatron_tpu.models import language_model as lm
-from megatron_tpu.models.attention import KVCache
+from megatron_tpu.models.attention import HybridKVCache, KVCache
 
 
 class SamplingParams(NamedTuple):
@@ -116,6 +116,11 @@ def init_kv_caches(cfg: ModelConfig, batch: int, max_len: int,
     slot-grid layout (serving/kv_pool.py), where every batch row is an
     independent request at its own sequence position."""
     from megatron_tpu.parallel.sharding import constrain
+    if cfg.window_layer_period:
+        # window and full layers in one stack: rings beside whole regions
+        # (models/attention.py::HybridKVCache)
+        return HybridKVCache.create(cfg, batch, max_len, dtype,
+                                    per_slot_offsets=per_slot_offsets)
     # rolling-cap decision single-sourced in kv_region_cap (the serving
     # pool's slot_nbytes sizes from the same helper)
     max_len = kv_region_cap(cfg, max_len, prefill_len)
@@ -154,6 +159,10 @@ def prefill_chunk(params, tokens, caches, cfg: ModelConfig, *, rope,
     write-before-read — the same invariant bucketed prefill +
     insert_prefill already rely on for the final pads."""
     whole = whole_logits_fit(*tokens.shape, cfg)
+    if isinstance(caches, HybridKVCache):
+        # a ring takes no padding row
+        caches = caches._replace(
+            live_end=jnp.asarray(next_offset, jnp.int32))
     logits, caches = lm.model_forward(
         params, tokens, cfg, kv_caches=caches, rope=rope,
         logits_dtype=jnp.float32, adapters=adapters,
@@ -406,6 +415,9 @@ def beam_search(generator: Generator, prompt: list[int], beam_width: int,
     beam_width by cumulative logprob (length-penalized at finalization,
     matching the reference's scoring)."""
     cfg = generator.cfg
+    assert not cfg.window_layer_period, (
+        "beam_search reorders one k/v cache by beam: a stack of window and "
+        "full layers (window_layer_period) is refused")
     eos = generator.eos_id
     params = generator.params
     rope = generator.rope

@@ -147,6 +147,69 @@ class BlockKVCache(NamedTuple):
     v_scale: Optional[jax.Array] = None
 
 
+class HybridKVCache(NamedTuple):
+    """The cache of a stack that mixes window and full attention
+    (`cfg.window_layer_period`): two stacks side by side, each carried
+    through the layer loop and written in place as `KVCache` is.
+
+    - RINGS for the window layers, [window layers, batch, n_kv, ring, hd]
+      with ring = min(sliding_window, max_seq): position p of a sequence
+      lives in row p % ring, so a ring holds the last `ring` positions and
+      nothing else, whatever the sequence's length.
+    - WHOLE REGIONS for the full layers, [full layers, batch, n_kv, max_seq,
+      hd]: position p in row p.
+
+    HEADS before rows, where `KVCache` has rows before heads: the products
+    contract the head's channels and batch over (sequence, kv head), so this
+    is the order they read, and the flash kernel's too. Held the other way,
+    with 8 kv heads, the chip's compiler copied each layer of the pool into
+    this order in every decode step (1 GiB a full layer of 16 slots x
+    32,768, twice; compile, PR 33), as it copied the latent pool in PR 31.
+
+    Nothing is read from the order rows are stored in: every mask is made
+    from positions, and those from `offset`. A multi-token step at a scalar
+    offset (a prefill, a chunk of one) reads a window layer's ring as it
+    stood BEFORE its own rows (turned into time order, `jnp.roll`) beside
+    its own fresh k and v, and only then writes its rows over the oldest;
+    it writes a full layer's region first and reads it back, as `KVCache`
+    does. A decode step (one token a row, at each row's own offset) writes
+    and then reads, both kinds.
+
+    `live_end` (scalar or [batch]): positions at and past it are a bucket's
+    padding. A padded row may lie in a REGION, beyond the offset, until it
+    is overwritten; written into a RING it would land on a row the next
+    real token still reads, so ring writes stop at `live_end`
+    (generation.prefill_chunk and the engine's prefill set it; it is
+    `NO_PADDING` wherever all the rows given are real)."""
+    ring_k: jax.Array
+    ring_v: jax.Array
+    full_k: jax.Array
+    full_v: jax.Array
+    # tokens already in the cache, one entry a layer of the MODEL (window
+    # and full layers in their own order): [layers] or [layers, batch], as
+    # KVCache.offset
+    offset: jax.Array
+    live_end: jax.Array
+
+    NO_PADDING = 2 ** 30
+
+    @staticmethod
+    def create(cfg: ModelConfig, batch: int, max_seq: int,
+               dtype=jnp.bfloat16, per_slot_offsets: bool = False):
+        periods = cfg.num_layers // cfg.window_layer_period
+        n_win = periods * cfg.window_layers_per_period
+        ring = min(cfg.sliding_window, max_seq)
+        nkv, hd = cfg.num_kv_heads, cfg.kv_channels
+        return HybridKVCache(
+            ring_k=jnp.zeros((n_win, batch, nkv, ring, hd), dtype),
+            ring_v=jnp.zeros((n_win, batch, nkv, ring, hd), dtype),
+            full_k=jnp.zeros((periods, batch, nkv, max_seq, hd), dtype),
+            full_v=jnp.zeros((periods, batch, nkv, max_seq, hd), dtype),
+            offset=jnp.zeros((cfg.num_layers, batch) if per_slot_offsets
+                             else (cfg.num_layers,), jnp.int32),
+            live_end=jnp.int32(HybridKVCache.NO_PADDING))
+
+
 def _layer_of(a, layer):
     """Layer `layer` (a traced scalar) of an array stacked over layers."""
     return jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
@@ -245,6 +308,151 @@ def _block_native_update_attend(q, k, v, stacked: BlockKVCache, layer, *,
                             in_specs=tuple(in_specs),
                             out_specs=h_spec, check_vma=False)(*args)
     return out.astype(dtype), stacked
+
+
+def _attend_heads_major(q, k, v, q_pos, kv_pos, *, scale, window,
+                        softmax_fp32: bool):
+    """Unfused causal attention over keys held HEADS-MAJOR, as
+    `HybridKVCache` holds them: q [b, s, nq, hd], k/v [b, nkv, t, hd], q_pos
+    [b|1, s], kv_pos [b|1, t] (a row that holds nothing: a position no
+    query reaches) -> [b, s, nq, hd]. `_dot_attention` with the keys' two
+    middle axes the other way round, so that a layer of the cache is read
+    where it lies."""
+    b, s, nq, hd = q.shape
+    nkv = k.shape[1]
+    qg = q.reshape(b, s, nkv, nq // nkv, hd)
+    scores = jnp.einsum("bsngd,bntd->bngst", qg, k) * scale
+    if softmax_fp32:
+        scores = scores.astype(jnp.float32)
+    mask = q_pos[:, :, None] >= kv_pos[:, None, :]          # [b|1, s, t]
+    if window is not None:
+        mask = mask & (q_pos[:, :, None] - kv_pos[:, None, :] < window)
+    scores = jnp.where(mask[:, None, None], scores,
+                       jnp.finfo(scores.dtype).min)
+    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    out = jnp.einsum("bngst,bntd->bsngd", probs, v)
+    return out.reshape(b, s, nq, hd)
+
+
+def _hybrid_update_attend(q, k, v, cache: HybridKVCache, layer, kind_layer,
+                          cfg: ModelConfig, *, scale: float):
+    """Append this layer's k and v to its stack of `cache` and attend; `cfg`
+    is the layer's own kind (`ModelConfig.window_layers()` with its
+    `sliding_window`, or `.full_layers()` with none), `layer` the layer's
+    index in the model (its offset) and `kind_layer` in its kind's stack.
+    q [b, s, nq, hd], k/v [b, s, nkv, hd] -> (out [b, s, nq, hd], cache).
+    The three cases are the class docstring's."""
+    b, s = q.shape[:2]
+    dtype = q.dtype
+    window = cfg.sliding_window
+    is_window = window is not None
+    buf_k, buf_v = ((cache.ring_k, cache.ring_v) if is_window
+                    else (cache.full_k, cache.full_v))
+    cap = buf_k.shape[3]
+    offset = _layer_of(cache.offset, layer)
+    per_slot = jnp.ndim(offset) == 1
+    # what the cache will hold of the new rows: the step attends the values
+    # a later step will read back
+    k = k.astype(buf_k.dtype)
+    v = v.astype(buf_v.dtype)
+    rows = jnp.arange(b)[:, None]
+    flash = cfg.attention_impl == "flash"
+    fp32 = cfg.attention_softmax_in_fp32
+    NOWHERE = jnp.int32(2 ** 30)       # a position no query reaches
+
+    def scatter(at):
+        # at [b|1, s]: the row of the buffer each new token goes to; `cap`
+        # and beyond is nowhere (mode="drop"). The heads' axis lies between
+        # the indexed ones, so the value is [b, s, nkv, hd], as k and v are
+        def wr(buf, val):
+            return buf.at[kind_layer, rows, :, at].set(val, mode="drop")
+        return wr(buf_k, k), wr(buf_v, v)
+
+    def heads_major(x):                 # [b, t, nkv, hd] -> [b, nkv, t, hd]
+        return x.transpose(0, 2, 1, 3)
+
+    if per_slot:
+        assert s == 1, (
+            "a pool of rings and regions takes one token a row a step: a "
+            "verify window's rejected rows would have overwritten ring "
+            "rows that the rewind needs (ServingConfig.validate refuses "
+            "speculative_k)")
+        pos = offset[:, None]                                   # [b, 1]
+        at = offset % cap if is_window else offset
+
+        # one `dynamic_update_slice` a row, as models/mla.py writes its
+        # pool: a scatter wants the rows' axis major and the products want
+        # the heads' axis major, and the chip's compiler then copies the
+        # whole pool into the scatter's order and back in every step (2.7
+        # GiB of temporaries; compile, PR 33). The engine keeps a parked
+        # row's length inside the region, so no start is clamped onto a
+        # live row.
+        def write(buf, val):
+            val = val.transpose(0, 2, 1, 3)[None]        # [1, b, nkv, 1, hd]
+            for i in range(b):      # unrolled: b is the grid's static size
+                buf = jax.lax.dynamic_update_slice(
+                    buf, val[:, i:i + 1], (kind_layer, i, 0, at[i], 0))
+            return buf
+        new_k, new_v = write(buf_k, k), write(buf_v, v)
+        if is_window:
+            # row j holds the latest position p <= offset with p % cap == j
+            p = pos - ((pos - jnp.arange(cap)[None, :]) % cap)  # [b, cap]
+            kv_pos = jnp.where(p >= 0, p, NOWHERE)
+        else:
+            kv_pos = jnp.arange(cap)[None, :]
+        out = _attend_heads_major(
+            q, _layer_of(new_k, kind_layer).astype(dtype),
+            _layer_of(new_v, kind_layer).astype(dtype), pos, kv_pos,
+            scale=scale, window=window, softmax_fp32=fp32)
+    elif is_window:
+        # the ring BEFORE this step's rows, in time order: index i holds
+        # position offset - cap + i (nothing where that is negative)
+        shift = offset % cap
+        keys = jnp.concatenate(
+            [jnp.roll(_layer_of(buf_k, kind_layer), -shift, axis=2),
+             heads_major(k)], axis=2).astype(dtype)
+        vals = jnp.concatenate(
+            [jnp.roll(_layer_of(buf_v, kind_layer), -shift, axis=2),
+             heads_major(v)], axis=2).astype(dtype)
+        pos = offset + jnp.arange(s)[None, :]                   # [1, s]
+        if flash:
+            from megatron_tpu.ops.flash_attention import flash_attention
+            out = flash_attention(q, keys, vals, causal=True, scale=scale,
+                                  sliding_window=window, q_offset=cap,
+                                  kv_start=jnp.maximum(cap - offset, 0),
+                                  kv_heads_major=True)
+        else:
+            kv_pos = jnp.arange(cap + s) + (offset - cap)
+            out = _attend_heads_major(
+                q, keys, vals, pos,
+                jnp.where(kv_pos >= 0, kv_pos, NOWHERE)[None, :],
+                scale=scale, window=window, softmax_fp32=fp32)
+        # then the new rows go over the oldest: the last `cap` real ones
+        end = jnp.minimum(jnp.reshape(cache.live_end, (-1, 1)), offset + s)
+        new_k, new_v = scatter(jnp.where((pos < end) & (pos >= end - cap),
+                                         pos % cap, cap))
+    else:
+        def wr(buf, val):
+            return jax.lax.dynamic_update_slice(
+                buf, heads_major(val)[None], (kind_layer, 0, 0, offset, 0))
+        new_k, new_v = wr(buf_k, k), wr(buf_v, v)
+        keys = _layer_of(new_k, kind_layer).astype(dtype)
+        vals = _layer_of(new_v, kind_layer).astype(dtype)
+        if flash:
+            from megatron_tpu.ops.flash_attention import flash_attention
+            out = flash_attention(q, keys, vals, causal=True, scale=scale,
+                                  q_offset=offset, kv_heads_major=True)
+        else:
+            out = _attend_heads_major(
+                q, keys, vals, offset + jnp.arange(s)[None, :],
+                jnp.arange(cap)[None, :], scale=scale, window=None,
+                softmax_fp32=fp32)
+    cache = cache._replace(
+        offset=jax.lax.dynamic_update_index_in_dim(
+            cache.offset, offset + s, layer, 0),
+        **({"ring_k": new_k, "ring_v": new_v} if is_window
+           else {"full_k": new_k, "full_v": new_v}))
+    return out.astype(dtype), cache
 
 
 def attention_init(rng, cfg: ModelConfig, dtype=jnp.float32):
@@ -381,8 +589,13 @@ def attention_apply(
     kv_input=None,
     cp_pre_zigzag: bool = False,
     adapters=None,
+    kind_layer=None,
 ):
     """Forward pass. x: [b, s, h]. Returns (out [b, s, h], new_kv_cache).
+
+    `kind_layer`: with a `HybridKVCache` (a stack of window and full
+    layers; `cfg` is then the layer's own kind), the layer's index in its
+    kind's stack, beside `cache_layer`, its index in the model.
 
     `kv_cache` is the cache STACKED over layers (KVCache or BlockKVCache)
     and `cache_layer` this layer's index in it (a traced scalar inside
@@ -476,6 +689,13 @@ def attention_apply(
         assert not cross, "qk_norm is self-attention's (OLMoE)"
         q, k = qk_norm(params, q, k, cfg.norm_epsilon)
 
+    if isinstance(kv_cache, HybridKVCache) and s == 1:
+        # a decode step's q and k stay the projections' outputs: left free,
+        # the chip's compiler carries the rotary's view of adjacent pairs
+        # ([.., hd / 2, 2]) back through the product into the weight and
+        # copies wq (128 MiB at 128 heads of 128) into that order in every
+        # step, 1.1 ms a window layer (my chip run, PR 33)
+        q, k = jax.lax.optimization_barrier((q, k))
     if cfg.use_rotary_emb and not cross:
         assert rope_cos is not None and rope_sin is not None, (
             "cfg.use_rotary_emb=True requires rope_cos/rope_sin tables "
@@ -492,6 +712,19 @@ def attention_apply(
     assert cfg.sliding_window is None or (causal and not cross), (
         "sliding_window requires causal self-attention")
     dropout_active = not deterministic and cfg.attention_dropout > 0.0
+    if isinstance(kv_cache, HybridKVCache):
+        assert causal and not cross and segment_ids is None \
+            and lw is None and not cfg.use_bias and not dropout_active, (
+            "a stack of window and full layers serves causal "
+            "self-attention without bias, adapters or segments")
+        with jax.named_scope("mtpu/attn/window" if cfg.sliding_window
+                             else "mtpu/attn/full"):
+            out, kv_cache = _hybrid_update_attend(
+                q, k, v, kv_cache, cache_layer, kind_layer, cfg,
+                scale=1.0 / math.sqrt(hd))
+        out = out.reshape(b, s, nq * hd)
+        return qdense(out, wcast(params["wo"], dtype),
+                      cfg.quantized_gemm), kv_cache
     if isinstance(kv_cache, BlockKVCache):
         # block-NATIVE serving path (--block_native_attn): append this
         # step's k/v into the touched arena blocks only and read the

@@ -53,6 +53,20 @@ A third dispatch has no capacity at all:
   padding included: `model_forward` has no mask of real tokens to hand
   down (PERF.md section 7, PR 27). One device only (config.validate
   refuses a mesh).
+
+The dropless path also runs as ONE CHIP'S SHARE of an expert layer
+(`cfg.moe_router_experts` wider than `cfg.num_experts`): the router scores
+every expert of the layer in float32 and each token keeps its top k among
+all of them, gates normalised over the chosen whether they are held here or
+not; the banks hold the `num_experts` experts from `cfg.moe_first_expert`
+on; the (token, choice) rows of those are sorted to the front and multiplied
+group by group, and the other choices' rows lie behind the last group, buy no
+product (ops/grouped_matmul.py skips them) and add nothing to the token's
+sum. Dropless within the share: every row of a held expert is multiplied,
+whatever the skew. What the absent experts would have added is left out:
+that is another chip's part, and no code here stands in for that chip or
+for the exchange with it (ROADMAP R1). The shared experts are computed on
+every chip alike.
 """
 from __future__ import annotations
 
@@ -74,7 +88,10 @@ def moe_capacity(cfg: ModelConfig, seq: int) -> int:
 
 
 def _shared_cfg(cfg: ModelConfig) -> ModelConfig:
-    """The shared experts as ONE dense MLP of their widths together."""
+    """The shared experts as ONE dense MLP of their widths together: the
+    second product sums over all their columns, which IS the sum of their
+    outputs (`moe_shared_combination` "average" divides it by their
+    number)."""
     return dataclasses.replace(
         cfg, num_experts=1,
         ffn_hidden_size=cfg.n_shared_experts * cfg.ffn_hidden_size)
@@ -97,7 +114,7 @@ def moe_init(rng, cfg: ModelConfig, dtype=jnp.float32):
     w1 = jax.random.normal(
         k1, (E, h, 2 * ffn if cfg.is_glu else ffn), dtype) * std
     params = {
-        "router": jax.random.normal(kr, (h, E), dtype) * std,
+        "router": jax.random.normal(kr, (h, cfg.router_experts), dtype) * std,
         "w1": w1,
         "w2": jax.random.normal(k2, (E, ffn, h), dtype) * out_std,
     }
@@ -108,7 +125,8 @@ def moe_init(rng, cfg: ModelConfig, dtype=jnp.float32):
     if cfg.moe_score_correction_bias:
         # chooses and is not valued: no gradient reaches it, and how a
         # training run moves it (DeepSeek-V3's balance update) is not here
-        params["e_score_correction_bias"] = jnp.zeros((E,), dtype)
+        params["e_score_correction_bias"] = jnp.zeros(
+            (cfg.router_experts,), dtype)
     if cfg.n_shared_experts:
         params["shared"] = mlp_init(jax.random.fold_in(rng, 3),
                                     _shared_cfg(cfg), dtype)
@@ -230,20 +248,30 @@ def _dropless_experts(params, x, idx, gates, cfg: ModelConfig, layer=None):
             return grouped_matmul(rows, bank, group_sizes, layer=layer)
         return grouped_matmul(rows, round_bank(bank, layer, dtype),
                               group_sizes)
+    share = cfg.router_experts != E
     with jax.named_scope("mtpu/moe/route"):
         e = idx.reshape(n * K)               # row r: token r // K, choice r % K
+        if share:
+            # the experts held here are 0 .. E-1 of the banks; a choice of
+            # any other sorts behind them all, into no group
+            e = e - cfg.moe_first_expert
+            e = jnp.where((e >= 0) & (e < E), e, E)
         e_sorted, order = jax.lax.sort_key_val(
             e, jnp.arange(n * K, dtype=jnp.int32))        # stable
         starts = jnp.searchsorted(e_sorted, jnp.arange(E + 1, dtype=e.dtype))
         group_sizes = jnp.diff(starts).astype(jnp.int32)
         rows = x.reshape(n, h)[order // K]   # [n*K, h], sorted by expert
-    with jax.named_scope("mtpu/moe/experts"):
+    with jax.named_scope("mtpu/moe/share" if share else "mtpu/moe/experts"):
         y1 = product(rows, "w1")
         if cfg.is_glu:                       # bank [E, h, 2f]: gate, value
             act = activation_fn(cfg.activation, y1[:, :f], y1[:, f:])
         else:
             act = activation_fn(cfg.activation, y1)
         y2 = product(act, "w2")
+        if share:
+            # rows behind the last group were multiplied by nothing and
+            # hold whatever the buffer held: they read as zero
+            y2 = jnp.where(jnp.arange(n * K)[:, None] < starts[E], y2, 0)
     with jax.named_scope("mtpu/moe/combine"):
         # back to (token, choice) order
         inv = jnp.zeros((n * K,), jnp.int32).at[order].set(
@@ -260,7 +288,7 @@ def moe_apply(params, x, cfg: ModelConfig, *, bank_layer=None):
     `bank_layer`: the dropless path's banks in `params` are the stack's
     (`split_stacked_banks`) and this is the layer's index."""
     b, s, h = x.shape
-    E = cfg.num_experts
+    E = cfg.router_experts          # num_experts, but for a chip's share
     K = cfg.moe_top_k
     C = moe_capacity(cfg, s)
     dtype = x.dtype
@@ -306,7 +334,10 @@ def moe_apply(params, x, cfg: ModelConfig, *, bank_layer=None):
         y = _dropless_experts(params, x, idx, gates, cfg, bank_layer)
         if cfg.n_shared_experts:
             with jax.named_scope("mtpu/moe/shared"):
-                y = y + mlp_apply(params["shared"], x, _shared_cfg(cfg))
+                shared = mlp_apply(params["shared"], x, _shared_cfg(cfg))
+                if cfg.moe_shared_combination == "average":
+                    shared = shared / cfg.n_shared_experts
+                y = y + shared
         return y, aux
 
     if cfg.moe_dispatch == "dense":

@@ -57,16 +57,27 @@ def layernorm(params, x, eps: float = 1e-5):
     return xf.astype(dtype) * params["scale"].astype(dtype) + params["bias"].astype(dtype)
 
 
+def layernorm_nobias(params, x, eps: float = 1e-5):
+    """LayerNorm with a scale and no bias (Cohere's): fp32 stats."""
+    dtype = x.dtype
+    xf = x.astype(jnp.float32)
+    mean = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xf - mean), axis=-1, keepdims=True)
+    xf = (xf - mean) * lax.rsqrt(var + eps)
+    return xf.astype(dtype) * params["scale"].astype(dtype)
+
+
 def norm_init(norm_type: str, hidden_size: int, dtype=jnp.float32):
-    if norm_type == "rmsnorm":
-        return rmsnorm_init(hidden_size, dtype)
+    if norm_type in ("rmsnorm", "layernorm_nobias"):
+        return rmsnorm_init(hidden_size, dtype)  # a scale alone
     elif norm_type == "layernorm":
         return layernorm_init(hidden_size, dtype)
     raise ValueError(norm_type)
 
 
 def norm_axes(norm_type: str):
-    return rmsnorm_axes() if norm_type == "rmsnorm" else layernorm_axes()
+    return (layernorm_axes() if norm_type == "layernorm"
+            else rmsnorm_axes())
 
 
 def apply_norm(norm_type: str, params, x, eps: float = 1e-5):
@@ -74,4 +85,6 @@ def apply_norm(norm_type: str, params, x, eps: float = 1e-5):
         return rmsnorm(params, x, eps)
     elif norm_type == "layernorm":
         return layernorm(params, x, eps)
+    elif norm_type == "layernorm_nobias":
+        return layernorm_nobias(params, x, eps)
     raise ValueError(norm_type)

@@ -124,6 +124,7 @@ def layer_apply(
     position_ids=None,
     kv_cache=None,
     cache_layer=None,
+    kind_layer=None,
     expert_banks=None,
     bank_layer=None,
     layer_number: int = 1,
@@ -146,7 +147,8 @@ def layer_apply(
     loop gives any, are the MoE parameters it did not scan, stacked over
     the layers of their own stack and read at `bank_layer` (models/moe.py::
     split_stacked_banks), which is `cache_layer` where the model has one
-    stack.
+    stack. `kind_layer`: in a stack of window and full layers
+    (`_period_stack_apply`), the layer's index in its own kind's cache stack.
 
     `adapters`: (per-layer LoraAdapter bank, adapter_idx [b]) for the
     SELF-attention projections only (multi-tenant LoRA serving —
@@ -215,7 +217,8 @@ def layer_apply(
             layer_number=layer_number,
             dropout_rng=r_score, deterministic=deterministic,
             segment_ids=segment_ids, causal=causal,
-            cp_pre_zigzag=cp_pre_zigzag, adapters=adapters)
+            cp_pre_zigzag=cp_pre_zigzag, adapters=adapters,
+            kind_layer=kind_layer)
 
     if cfg.parallel_attn:
         # Falcon block: no dropout-add after attention
@@ -337,6 +340,15 @@ def stack_apply(
     index is layer-invariant and closes over the body. None compiles to
     exactly today's graph (multi-tenant LoRA serving,
     models/attention.py)."""
+    if cfg.window_layer_period:
+        assert layer_offset == 0 and adapters is None \
+            and encoder_output is None and not cp_pre_zigzag and causal, (
+            "a stack of window and full layers has no pipeline stage, no "
+            "adapter bank and no encoder")
+        return _period_stack_apply(
+            stacked_params, x, cfg, rope_cos=rope_cos, rope_sin=rope_sin,
+            position_ids=position_ids, kv_caches=kv_caches, rng=rng,
+            deterministic=deterministic, segment_ids=segment_ids)
     k_dense = cfg.first_k_dense_replace
     if k_dense:
         assert layer_offset == 0 and adapters is None, (
@@ -421,4 +433,71 @@ def stack_apply(
     # (the no-adapters case scans the same body shape)
     xs = (stacked_params, drop_rates, dp_rates, layer_ids, lora_stack)
     (x, aux, kv_caches), _ = jax.lax.scan(body, (x, aux0, kv_caches), xs)
+    return x, kv_caches, aux
+
+
+def _period_stack_apply(stacked_params, x, cfg: ModelConfig, *, rope_cos,
+                        rope_sin, position_ids, kv_caches, rng,
+                        deterministic, segment_ids):
+    """`stack_apply` for a stack that mixes two kinds of attention layer
+    (`cfg.window_layer_period` = P: P - 1 window layers, then one full
+    layer, and so on): ONE `lax.scan` over PERIODS. The parameters stay
+    stacked over layers (a checkpoint does not know the period) and are
+    viewed `[periods, P, ...]`, which moves nothing; the body applies the
+    period's layers in order, each under its own kind's configuration
+    (`ModelConfig.window_layers()` / `.full_layers()`: the window and the
+    rotation are the kind's), so every layer of a kind shares one traced
+    body per place in the period. The cache (`attention.HybridKVCache`: rings
+    for the window layers, whole regions for the full ones) is the loop's
+    carry and goes down whole with three indices: the layer's place in the
+    model (its offset), in its kind's stack (its rows) and in the expert
+    banks' stack, all written in place, as the one-kind loop does it."""
+    P = cfg.window_layer_period
+    n_win = cfg.window_layers_per_period
+    num_layers = jax.tree.leaves(stacked_params)[0].shape[0]
+    periods = num_layers // P
+    kinds = [cfg.window_layers()] * n_win + [cfg.full_layers()]
+    drop_rates = lima_dropout_rates(cfg, cfg.num_layers).reshape(periods, P)
+    expert_banks = None
+    if kv_caches is not None and cfg.num_experts > 1:
+        from megatron_tpu.models.moe import split_stacked_banks
+        expert_banks, scanned_mlp = split_stacked_banks(
+            stacked_params["mlp"], cfg)
+        stacked_params = {**stacked_params, "mlp": scanned_mlp}
+    by_period = jax.tree.map(
+        lambda a: a.reshape(periods, P, *a.shape[1:]), stacked_params)
+
+    def body(carry, scanned):
+        h, aux_sum, caches = carry
+        params, rates, period = scanned
+        for j, kind in enumerate(kinds):
+            lid = period * P + j
+            layer_rng = None
+            if rng is not None and not deterministic:
+                layer_rng = jax.random.fold_in(rng, lid)
+            cached = caches is not None
+            h, caches, aux = layer_apply(
+                jax.tree.map(lambda a: a[j], params), h, kind,
+                rope_cos=rope_cos, rope_sin=rope_sin,
+                position_ids=position_ids, kv_cache=caches,
+                cache_layer=lid if cached else None,
+                kind_layer=((period * n_win + j if j < n_win else period)
+                            if cached else None),
+                expert_banks=expert_banks,
+                bank_layer=lid if cached else None,
+                layer_number=lid + 1, hidden_dropout=rates[j],
+                rng=layer_rng, deterministic=deterministic,
+                segment_ids=segment_ids)
+            aux_sum = aux_sum + aux
+        return (h, aux_sum, caches), None
+
+    if cfg.recompute_granularity == "full":
+        body = jax.checkpoint(body, prevent_cse=False)
+    elif cfg.recompute_granularity == "selective":
+        body = jax.checkpoint(
+            body, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+            prevent_cse=False)
+    (x, aux, kv_caches), _ = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.float32), kv_caches),
+        (by_period, drop_rates, jnp.arange(periods)))
     return x, kv_caches, aux

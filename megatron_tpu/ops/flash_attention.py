@@ -33,8 +33,17 @@ _warned_shapes = set()
 def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
                     block_kv: int = DEFAULT_BLOCK_KV, use_pallas: bool | None = None,
                     segment_ids=None, sliding_window: int | None = None,
-                    dropout_rate: float = 0.0, dropout_rng=None):
+                    dropout_rate: float = 0.0, dropout_rng=None,
+                    q_offset=None, kv_start=None,
+                    kv_heads_major: bool = False):
     """Blockwise attention with online softmax. Returns [b, sq, nq, d].
+
+    `q_offset` (a traced scalar; None: 0) puts query row i at position
+    q_offset + i of the keys' numbering, and keys before `kv_start` hold
+    nothing: a chunk of a serving prefill against the cache it continues
+    (models/attention.py::HybridKVCache). Causal, forward only, no
+    segments, no dropout, no mesh. `kv_heads_major`: k and v come [b, nkv,
+    skv, d], as that cache holds them and as the kernel reads them.
 
     `segment_ids` [b, s] (shared q/k length) masks attention across
     EOD-separated documents (ref: --reset_attention_mask) — the flash
@@ -58,7 +67,9 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
     independent per (batch row, head), so no collective is needed."""
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
-    if use_pallas and (q.shape[1] % 128 != 0 or k.shape[1] % 128 != 0):
+    assert not kv_heads_major or q_offset is not None
+    if use_pallas and (q.shape[1] % 128 != 0
+                       or k.shape[2 if kv_heads_major else 1] % 128 != 0):
         # kernel blocks need 128-divisible sequence lengths; odd shapes take
         # the XLA blockwise path. Warn once per shape — this is a perf cliff,
         # not a correctness issue.
@@ -73,6 +84,13 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
     if dropout_rate > 0.0:
         assert dropout_rng is not None, (
             "flash_attention: dropout_rate > 0 needs dropout_rng")
+    if q_offset is not None:
+        assert causal and segment_ids is None and dropout_rate == 0.0
+        return _flash_attention_offset(
+            q, k, v, jnp.asarray(q_offset, jnp.int32),
+            jnp.asarray(0 if kv_start is None else kv_start, jnp.int32),
+            scale=scale, block_kv=block_kv, use_pallas=use_pallas,
+            sliding_window=sliding_window, kv_heads_major=kv_heads_major)
     static = dict(causal=causal, scale=scale, block_kv=block_kv,
                   use_pallas=use_pallas, sliding_window=sliding_window,
                   dropout_rate=dropout_rate)
@@ -164,9 +182,30 @@ def _flash_attention(q, k, v, segment_ids, dropout_rng, *, causal, scale,
                                 dropout_rng=dropout_rng)
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "block_kv", "use_pallas", "sliding_window", "kv_heads_major"))
+def _flash_attention_offset(q, k, v, q_offset, kv_start, *, scale, block_kv,
+                            use_pallas, sliding_window, kv_heads_major):
+    """The chunk form (`flash_attention(q_offset=...)`); jitted under its
+    own name so that the device trace names the kernel after it."""
+    if use_pallas:
+        from megatron_tpu.ops.flash_attention_pallas import \
+            pallas_flash_attention_offset
+        return pallas_flash_attention_offset(
+            q, k, v, q_offset, kv_start, scale=scale,
+            sliding_window=sliding_window, kv_heads_major=kv_heads_major)
+    if kv_heads_major:
+        k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+    return _blockwise_attention(q, k, v, causal=True, scale=scale,
+                                block_kv=block_kv,
+                                sliding_window=sliding_window,
+                                q_offset=q_offset, kv_start=kv_start)
+
+
 def _blockwise_attention(q, k, v, *, causal, scale, block_kv,
                          segment_ids=None, sliding_window=None,
-                         dropout_rate=0.0, dropout_rng=None):
+                         dropout_rate=0.0, dropout_rng=None,
+                         q_offset=None, kv_start=None):
     b, sq, nq, d = q.shape
     skv, nkv = k.shape[1], k.shape[2]
     if scale is None:
@@ -190,6 +229,8 @@ def _blockwise_attention(q, k, v, *, causal, scale, block_kv,
     kb = k.astype(jnp.float32).reshape(b, n_blocks, block_kv, nkv, d)
     vb = v.astype(jnp.float32).reshape(b, n_blocks, block_kv, nkv, d)
     q_pos = jnp.arange(sq)
+    if q_offset is not None:
+        q_pos = q_pos + q_offset
 
     def body(carry, blk):
         acc, m, l = carry  # acc [b,sq,nkv,g,d], m/l [b,sq,nkv,g]
@@ -197,6 +238,8 @@ def _blockwise_attention(q, k, v, *, causal, scale, block_kv,
         s = jnp.einsum("bsngd,btnd->bsngt", qg, kj)  # [b,sq,nkv,g,block_kv]
         kv_pos = j * block_kv + jnp.arange(block_kv)
         valid = kv_pos < skv
+        if kv_start is not None:
+            valid = valid & (kv_pos >= kv_start)
         if causal:
             win = q_pos[:, None] >= kv_pos[None, :]
             if sliding_window is not None:

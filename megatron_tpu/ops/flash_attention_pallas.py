@@ -97,7 +97,10 @@ def _dropout_keep(seed_i32, bh, qi, ki, block_q, block_kv, rate):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_q,
                 block_kv, num_kv, has_segs=False, window=None,
-                dropout_rate=0.0):
+                dropout_rate=0.0, q_off=None, kv_start=None):
+    # q_off / kv_start (traced scalars, `_fwd_kernel_offset` alone): query
+    # row i stands at position q_off + i of the keys' own numbering, and
+    # keys before kv_start hold nothing
     # refs: [qs_ref, ks_ref]? [seed_ref]? o_ref, lse_ref, acc_ref, m_ref,
     # l_ref — segment-id blocks / the dropout seed are inputs only when
     # the feature is on, so the plain path pays zero extra DMA
@@ -131,11 +134,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_q,
     # whole block beyond the diagonal -> skip (causal); with a sliding
     # window also skip blocks entirely BEHIND the band
     run = True
+    q_first = qi * block_q                # the block's first query position
+    if q_off is not None:
+        q_first = q_first + q_off
     if causal:
-        run = ki * block_kv <= qi * block_q + block_q - 1
+        run = ki * block_kv <= q_first + block_q - 1
         if window is not None:
             run = run & (ki * block_kv + block_kv - 1
-                         > qi * block_q - window)
+                         > q_first - window)
+        if kv_start is not None:
+            run = run & (ki * block_kv + block_kv - 1 >= kv_start)
 
     @pl.when(run)
     def _body():
@@ -145,13 +153,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_q,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
+            q_pos = q_first + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_kv), 0)
             kv_pos = ki * block_kv + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_kv), 1)
             keep = q_pos >= kv_pos
             if window is not None:
                 keep = keep & (q_pos - kv_pos < window)
+            if kv_start is not None:
+                keep = keep & (kv_pos >= kv_start)
             s = jnp.where(keep, s, NEG_INF)
         if has_segs:
             # block-diagonal across documents (ref: --reset_attention_mask,
@@ -477,6 +487,81 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_kv, interpret,
     )(qT, kT, vT, *seg_inputs, *drop_inputs)
     out = out.transpose(0, 2, 1, 3)
     return out, (q, k, v, out, lse, q_seg, k_seg, dropout_seed)
+
+
+def _fwd_kernel_offset(off_ref, q_ref, k_ref, v_ref, *refs, **static):
+    """`_fwd_kernel` with the two prefetched scalars of
+    `pallas_flash_attention_offset`."""
+    _fwd_kernel(q_ref, k_ref, v_ref, *refs, q_off=off_ref[0],
+                kv_start=off_ref[1], **static)
+
+
+def pallas_flash_attention_offset(q, k, v, q_offset, kv_start=0, *,
+                                  scale=None, sliding_window=None,
+                                  block_q=DEFAULT_BLOCK_Q,
+                                  block_kv=DEFAULT_BLOCK_KV,
+                                  interpret=False,
+                                  kv_heads_major: bool = False):
+    """Causal attention of a CHUNK of queries against keys that begin before
+    it: q [b, sq, nq, d] (row i at position `q_offset` + i of the keys'
+    numbering), k/v [b, sk, nkv, d] (or, `kv_heads_major`, [b, nkv, sk, d]:
+    the order the kernel reads, so a cache held that way is not transposed)
+    with sk >= q_offset + sq -> [b, sq, nq, d]. `q_offset` and `kv_start` are traced scalars (one compiled program
+    serves every offset); keys before `kv_start` hold nothing and are masked.
+    What a serving prefill that continues a cache needs (models/attention.py:
+    a chunk against its ring's earlier rows and itself, or against the
+    region it has just been written into). Forward only.
+
+    The kernel is `_fwd_kernel` with its positions shifted. Key blocks
+    wholly past a query block's diagonal, behind its window or before
+    `kv_start` are skipped as the aligned kernel skips them, and their DMA
+    too: the block index is clamped into the needed range, and a block
+    index that does not change is not fetched again."""
+    b, sq, nq, d = q.shape
+    if not kv_heads_major:
+        k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+    nkv, sk = k.shape[1], k.shape[2]
+    g = nq // nkv
+    if scale is None:
+        scale = d ** -0.5
+    bq, bkv = _pick_blocks(sq, sk, block_q, block_kv)
+    num_q, num_kv = sq // bq, sk // bkv
+    offs = jnp.stack([jnp.asarray(q_offset, jnp.int32),
+                      jnp.asarray(kv_start, jnp.int32)])
+
+    def kv_block(qi, ki, off):
+        last = (off[0] + qi * bq + bq - 1) // bkv
+        first = off[1]
+        if sliding_window is not None:
+            first = jnp.maximum(first,
+                                off[0] + qi * bq - sliding_window + 1)
+        first = jnp.maximum(first, 0) // bkv
+        return jnp.clip(ki, first, jnp.minimum(last, num_kv - 1))
+
+    q_spec = pl.BlockSpec((1, 1, bq, d),
+                          lambda bi, h, qi, ki, off: (bi, h, qi, 0))
+    kv_spec = pl.BlockSpec(
+        (1, 1, bkv, d),
+        lambda bi, h, qi, ki, off: (bi, h // g, kv_block(qi, ki, off), 0))
+    lse_spec = pl.BlockSpec((1, 1, bq, STAT_LANES),
+                            lambda bi, h, qi, ki, off: (bi, h, qi, 0))
+    out, _ = pl.pallas_call(
+        functools.partial(_fwd_kernel_offset, scale=scale, causal=True,
+                          block_q=bq, block_kv=bkv, num_kv=num_kv,
+                          window=sliding_window),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, nq, num_q, num_kv),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=[q_spec, lse_spec],
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
+                            pltpu.VMEM((bq, STAT_LANES), jnp.float32),
+                            pltpu.VMEM((bq, STAT_LANES), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((b, nq, sq, d), q.dtype),
+                   jax.ShapeDtypeStruct((b, nq, sq, STAT_LANES),
+                                        jnp.float32)],
+        interpret=interpret,
+    )(offs, q.transpose(0, 2, 1, 3), k, v)
+    return out.transpose(0, 2, 1, 3)
 
 
 def _flash_bwd_core(causal, scale, block_q, block_kv, interpret, res, dout,

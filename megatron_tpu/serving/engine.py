@@ -361,6 +361,17 @@ class ServingEngine:
                                dtype=kv_dtype,
                                retained_limit=self.serving.retained_slots,
                                block_size=self.serving.kv_block_size)
+        # every program closes over the rotary tables as constants. On a
+        # pool of rings and regions they are cut to this engine's
+        # positions: a published context of 200,000 rows made each
+        # serialised program 283 MB where 32,768 are served, more than the
+        # chip's compile cache takes (PERF.md section 6, PR 33). Every
+        # other pool keeps the generator's, and so the programs it had
+        # (tests/test_jaxpr_unchanged.py; ROADMAP S22)
+        self._rope = generator.rope
+        if self.pool.hybrid and self._rope is not None:
+            self._rope = type(self._rope)(
+                *(t[:self.max_len] for t in self._rope))
         if self._pp > 1:
             # fail BEFORE the staged pool placement tries to slice a
             # block-less arena (pinned reasons below)
@@ -539,8 +550,7 @@ class ServingEngine:
         self.scheduler.active_fn = (
             lambda: int(self._active.sum()) + len(self._prefilling))
         self.metrics = metrics if metrics is not None else ServingMetrics()
-        self.metrics.set_pool_gauges(self.pool.bytes_per_token(),
-                                     self.pool.nbytes())
+        self.metrics.set_pool_gauges(self.pool)
         # graceful degradation (serving/degrade.py): None when the
         # brownout ladder is disabled — the None path is the
         # bit-identical pre-ladder engine (test-pinned). The
@@ -1783,7 +1793,7 @@ class ServingEngine:
         Ls = cfg.num_layers // S_pp
         max_len = self.max_len
         adapters_on = self._adapters_on
-        rope = self.gen.rope
+        rope = self._rope
 
         def _stage_jit(i, fn, n_array_args, donate_argnums=()):
             return topo._jit(topo.stage_meshes[i], self._psh_dec[i],
@@ -2436,7 +2446,7 @@ class ServingEngine:
             lengths[None, :], (L, lengths.shape[0])).astype(jnp.int32))
         logits, pool = lm.model_forward(
             params, toks[:, None], cfg, kv_caches=pool,
-            position_ids=lengths[:, None], rope=self.gen.rope,
+            position_ids=lengths[:, None], rope=self._rope,
             logits_dtype=jnp.float32, adapters=adapters)
         new_lengths = jnp.minimum(lengths + 1,
                                   jnp.int32(self.max_len - 1))
@@ -2531,7 +2541,7 @@ class ServingEngine:
         lp0 = jnp.take_along_axis(lp0, toks0[:, None], axis=-1)[:, 0]
         window = jnp.concatenate([toks0[:, None], drafts], axis=1)
         logits, pool = verify_tokens(params, window, pool, cfg,
-                                     rope=self.gen.rope,
+                                     rope=self._rope,
                                      lengths=lengths,
                                      max_len=self.max_len,
                                      adapters=adapters)
@@ -2624,12 +2634,15 @@ class ServingEngine:
             bkv, pool = pool, resolve_view(pool)
         B = tokens.shape[0]
         caches = self.pool.make_prefill_caches(B)
+        if self.pool.hybrid:
+            # a ring takes no padding row (attention.HybridKVCache)
+            caches = caches._replace(live_end=plens)
         # the head on each row's last real position alone where the whole
         # bucket's logits would not fit (generation.whole_logits_fit)
         whole = whole_logits_fit(*tokens.shape, self.cfg)
         logits, caches = lm.model_forward(
             params, tokens, self.cfg, kv_caches=caches,
-            rope=self.gen.rope, logits_dtype=jnp.float32,
+            rope=self._rope, logits_dtype=jnp.float32,
             adapters=adapters,
             logits_rows=None if whole else plens - 1)
         for i in range(B):  # static unroll: B is a trace-time shape
@@ -2676,7 +2689,7 @@ class ServingEngine:
         self._chunk_traces += 1
         adapters = (lora, aidx1) if self._adapters_on else None
         return prefill_chunk(params, tokens, sub, self.cfg,
-                             rope=self.gen.rope, last_idx=last_idx,
+                             rope=self._rope, last_idx=last_idx,
                              next_offset=next_offset, adapters=adapters)
 
     def _insert_fn(self, params, pool, last_logits, rngs, sub, slot,

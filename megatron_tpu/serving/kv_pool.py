@@ -54,7 +54,8 @@ import numpy as np
 from megatron_tpu.config import ModelConfig
 from megatron_tpu.inference.generation import (KV_CACHE_AXES, init_kv_caches,
                                                kv_region_cap)
-from megatron_tpu.models.attention import BlockKVCache, KVCache
+from megatron_tpu.models.attention import (BlockKVCache, HybridKVCache,
+                                            KVCache)
 from megatron_tpu.models.mla import LatentKVCache
 from megatron_tpu.utils.logging import print_rank_0
 
@@ -75,6 +76,19 @@ def insert_prefill(pool: KVCache, prefill: KVCache, slot, plen) -> KVCache:
     dus = jax.lax.dynamic_update_slice
     zero = jnp.int32(0)
     slot = jnp.asarray(slot, jnp.int32)
+    if isinstance(pool, HybridKVCache):
+        # both stacks of the slot whole: a ring's rows are where the
+        # prefill's own ring put them (position % ring), so the copy keeps
+        # them valid; the pool's `live_end` stays "no padding"
+        start5 = (zero, slot, zero, zero, zero)
+        return pool._replace(
+            offset=dus(pool.offset,
+                       jnp.full((pool.offset.shape[0], 1), plen, jnp.int32),
+                       (zero, slot)),
+            **{f: dus(getattr(pool, f),
+                      getattr(prefill, f).astype(getattr(pool, f).dtype),
+                      start5)
+               for f in ("ring_k", "ring_v", "full_k", "full_v")})
     if isinstance(pool, LatentKVCache):
         return LatentKVCache(
             c=dus(pool.c, prefill.c.astype(pool.c.dtype),
@@ -110,6 +124,10 @@ def slice_slot(pool: KVCache, slot, offset) -> KVCache:
     ds = jax.lax.dynamic_slice
     zero = jnp.int32(0)
     slot = jnp.asarray(slot, jnp.int32)
+    assert not isinstance(pool, HybridKVCache), (
+        "a slot of rings cannot be cut out at a shorter length: the rows "
+        "of its earlier positions are gone (ServingConfig.validate refuses "
+        "prefix cache and preemption on window_layer_period)")
     if isinstance(pool, LatentKVCache):
         L, _, row, cap = pool.c.shape
         return LatentKVCache(
@@ -135,7 +153,8 @@ def batch_row(caches, i: int):
         return None if x is None else jax.lax.dynamic_slice_in_dim(
             x, i, 1, axis=1)
     return caches._replace(**{f: row(getattr(caches, f))
-                              for f in caches._fields if f != "offset"})
+                              for f in caches._fields
+                              if f not in ("offset", "live_end")})
 
 
 def clone_prefix(pool: KVCache, src_slot, dst_slot, plen) -> KVCache:
@@ -364,6 +383,15 @@ class SlotKVPool:
         self.rolling = (cfg.sliding_window is not None
                         and self.cap == cfg.sliding_window
                         and self.cap < max_len)
+        # window and full layers in one stack: rings and whole regions
+        # side by side (attention.HybridKVCache). The slot's capacity in
+        # POSITIONS is the regions' (max_len); `rolling`, which means "the
+        # whole slot forgets", stays False: chunks and buckets are taken
+        if self.hybrid:
+            assert block_size is None, (
+                "kv_block_size is refused on a pool of rings and regions "
+                "(ServingConfig.validate)")
+            self.cap, self.rolling = max_len, False
         if block_size is not None and block_size >= self.cap:
             # whole-region blocks ARE the regions — EXCEPT on rolling
             # pools, where block mode is what makes retention possible
@@ -393,6 +421,8 @@ class SlotKVPool:
                                          per_slot_offsets=True)
             # positions: axis 2 of k and v, the minor axis of a latent pool
             assert self.cap == (self.caches.c.shape[3] if cfg.mla
+                                else self.caches.full_k.shape[3]
+                                if self.hybrid
                                 else self.caches.k.shape[2]), (
                 "kv_region_cap drifted from init_kv_caches")
             return
@@ -444,6 +474,11 @@ class SlotKVPool:
     @property
     def blocks_enabled(self) -> bool:
         return self.block_size is not None
+
+    @property
+    def hybrid(self) -> bool:
+        """Rings and whole regions side by side (`cfg.window_layer_period`)."""
+        return bool(self.cfg.window_layer_period)
 
     def make_prefill_caches(self, batch: int = 1) -> KVCache:
         """A fresh request-local cache in the POOL's layout (same cap /
@@ -889,12 +924,32 @@ class SlotKVPool:
                 return n
             return sum(_one(b.arena) for b in self.caches)
         c = self.caches.arena if self.blocks_enabled else self.caches
+        if isinstance(c, HybridKVCache):
+            return self.ring_nbytes() + self.full_nbytes()
         if isinstance(c, LatentKVCache):
             return c.c.nbytes
         n = c.k.nbytes + c.v.nbytes
         if c.k_scale is not None:
             n += c.k_scale.nbytes + c.v_scale.nbytes
         return n
+
+    def ring_nbytes(self) -> int:
+        """Bytes of the window layers' rings (0 where the pool has none)."""
+        if not self.hybrid:
+            return 0
+        return self.caches.ring_k.nbytes + self.caches.ring_v.nbytes
+
+    def full_nbytes(self) -> int:
+        """Bytes of the whole regions: the full layers' of a pool of two
+        kinds, else the whole pool."""
+        if not self.hybrid:
+            return self.nbytes()
+        return self.caches.full_k.nbytes + self.caches.full_v.nbytes
+
+    def bytes_per_slot(self) -> int:
+        """What one slot reserves, whatever it holds: its regions and,
+        where the pool has them, its rings."""
+        return self.nbytes() // self.num_slots
 
     def view_nbytes(self) -> int:
         """Bytes of ONE materialized contiguous [L, S, cap, ...] view
@@ -903,6 +958,8 @@ class SlotKVPool:
         the engine's kv_gather_bytes_per_step gauge. Defined for every
         layout (whole-region pools never bracket, but the unit is
         still what a bracket WOULD move)."""
+        if self.hybrid:
+            return self.nbytes()
         n = (self.cfg.num_layers * self.num_slots * self.cap
              * self.cfg.kv_row_width * self.dtype.itemsize)
         if self.dtype == jnp.dtype(jnp.int8):
@@ -914,9 +971,15 @@ class SlotKVPool:
         """k+v (and int8 scale) bytes one cached token costs across
         layers — the unit behind kv_bytes_wasted. From the cache's own row
         width (`ModelConfig.kv_row_width`: 2 x kv heads x head dim, or a
-        latent row)."""
-        n = self.cfg.num_layers * self.cfg.kv_row_width \
-            * self.dtype.itemsize
+        latent row). In a pool of rings and regions a token costs a row in
+        every FULL layer's region for as long as its sequence lives, and
+        that is what this counts: its rows in the rings are reserved with
+        the slot (`bytes_per_slot`, `ring_nbytes`) and cost the same
+        whatever the sequence's length, so they are no part of what a
+        shorter sequence leaves unused (`kv_bytes_wasted`)."""
+        layers = (self.cfg.num_layers // self.cfg.window_layer_period
+                  if self.hybrid else self.cfg.num_layers)
+        n = layers * self.cfg.kv_row_width * self.dtype.itemsize
         if self.dtype == jnp.dtype(jnp.int8):
             n += 2 * self.cfg.num_layers * self.cfg.num_kv_heads * 4
         return n
@@ -975,6 +1038,12 @@ def slot_nbytes(cfg: ModelConfig, max_len: int,
     engine actually builds. `block_size` rounds the region up to
     whole blocks (a no-op when it divides the cap, which
     ServingConfig.validate enforces)."""
+    if cfg.window_layer_period:
+        # rings for the window layers, whole regions for the full ones
+        periods = cfg.num_layers // cfg.window_layer_period
+        rows = periods * (cfg.window_layers_per_period
+                          * min(cfg.sliding_window, max_len) + max_len)
+        return rows * cfg.kv_row_width * jnp.dtype(dtype).itemsize
     cap = kv_region_cap(cfg, max_len)
     if block_size is not None and block_size < cap:
         cap = -(-cap // block_size) * block_size

@@ -171,6 +171,7 @@ _BASE_GAUGES = (
     "kv_blocks_used", "kv_blocks_retained", "kv_bytes_wasted",
     "kv_gather_bytes_per_step", "kv_attn_path",
     "kv_bytes_per_token", "kv_pool_bytes",
+    "kv_bytes_per_slot", "kv_ring_bytes", "kv_full_bytes",
     "active_adapters", "handoff_bytes_per_req",
     "prefill_group_busy", "decode_group_busy",
     "prefill_tp", "decode_tp", "prefill_devices", "decode_devices",
@@ -228,6 +229,11 @@ class ServingMetrics:
         # of the bytes it holds, pushed once when the engine builds it
         self.kv_bytes_per_token = 0
         self.kv_pool_bytes = 0
+        # what one slot reserves, and the pool's bytes by kind: the window
+        # layers' rings (0 in a pool of one kind) and the whole regions
+        self.kv_bytes_per_slot = 0
+        self.kv_ring_bytes = 0
+        self.kv_full_bytes = 0
         # multi-tenant LoRA serving: device-resident (non-identity)
         # adapters right now — 0 on adapterless engines, pushed by the
         # engine on pool churn like the KV gauges
@@ -380,12 +386,15 @@ class ServingMetrics:
         with self._lock:
             self.degrade_level = float(level)
 
-    def set_pool_gauges(self, bytes_per_token: int, pool_bytes: int):
-        """`SlotKVPool.bytes_per_token()` and `.nbytes()`, as the pool
-        counts them."""
+    def set_pool_gauges(self, pool):
+        """`SlotKVPool.bytes_per_token()`, `.nbytes()`, `.bytes_per_slot()`,
+        `.ring_nbytes()` and `.full_nbytes()`, as the pool counts them."""
         with self._lock:
-            self.kv_bytes_per_token = int(bytes_per_token)
-            self.kv_pool_bytes = int(pool_bytes)
+            self.kv_bytes_per_token = int(pool.bytes_per_token())
+            self.kv_pool_bytes = int(pool.nbytes())
+            self.kv_bytes_per_slot = int(pool.bytes_per_slot())
+            self.kv_ring_bytes = int(pool.ring_nbytes())
+            self.kv_full_bytes = int(pool.full_nbytes())
 
     def set_attn_gauges(self, gather_bytes_per_step: int, path: int):
         """Engine-pushed attention-path gauges (per sync window):

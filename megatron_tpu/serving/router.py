@@ -61,7 +61,8 @@ UP, DOWN, PROBING = "up", "down", "probing"
 # footprint — the placement plan's aggregate-visible shape)
 _SUM_GAUGES = ("queue_depth", "active_slots", "num_slots",
                "kv_blocks_used", "kv_blocks_retained", "kv_bytes_wasted",
-               "kv_pool_bytes", "active_adapters", "prefill_devices", "decode_devices")
+               "kv_pool_bytes", "kv_ring_bytes", "kv_full_bytes",
+               "active_adapters", "prefill_devices", "decode_devices")
 # gauges reported as the WORST replica (max) — per-request /
 # per-group readings where summing fractions would be meaningless
 # (same treatment as the *_ms latency keys below). The per-phase tp
@@ -76,7 +77,7 @@ _SUM_GAUGES = ("queue_depth", "active_slots", "num_slots",
 _MAX_GAUGES = ("handoff_bytes_per_req", "prefill_group_busy",
                "decode_group_busy", "prefill_tp", "decode_tp",
                "kv_gather_bytes_per_step", "kv_attn_path",
-               "kv_bytes_per_token", "degrade_level",
+               "kv_bytes_per_token", "kv_bytes_per_slot", "degrade_level",
                # pipeline-sharded decode: stage depth / wave count are
                # per-replica mesh shapes (summing would invent a
                # pipeline no engine runs), the bubble is an idle

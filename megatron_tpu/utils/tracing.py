@@ -48,8 +48,11 @@ traced under it; models/moe.py, models/attention.py and models/mla.py):
 |---|---|
 | `mtpu/moe/route` | a dropless expert layer's router product, softmax, top-k and aux loss (every dispatch), the sort of the (token, k) rows by expert, the group sizes, the gather of the sorted rows |
 | `mtpu/moe/experts` | the weight casts, the two grouped products (ops/grouped_matmul.py) and the activation between them |
+| `mtpu/moe/share` | the same where the chip holds a share of the layer's experts (`moe_router_experts` wider than `num_experts`): the products of the held experts' rows alone and the zeroing of the rows behind the last group |
 | `mtpu/moe/combine` | the gather back to (token, k) order and the weighted sum of a token's K rows |
 | `mtpu/attn/qk_norm` | the RMSNorm over the whole q and the whole k projection (`qk_norm`) |
+| `mtpu/attn/window` | a window layer of a stack of two kinds over its ring (`attention.HybridKVCache`): the ring turned into time order, the flash kernel or the scores over ring + chunk, the rows' write over the oldest; a decode step's write and read of a layer of rings |
+| `mtpu/attn/full` | a full layer of such a stack over its whole region: the write at the offset, the flash kernel or the scores over the region up to the chunk's end; a decode step's write and read of a layer of regions |
 | `mtpu/moe/shared` | the shared experts' MLP, added beside the routed sum (`n_shared_experts`) |
 | `mtpu/mla/q` | latent attention's query: down-projection, norm, up-projection, the rotary on its rope part |
 | `mtpu/mla/latent` | the latent row: down-projection, norm over kv_lora_rank, the rotary on the shared key, the write into the cache |
@@ -59,7 +62,13 @@ traced under it; models/moe.py, models/attention.py and models/mla.py):
 Counters of the serving metrics' snapshot that a benchmark reader takes:
 `kv_bytes_per_token` and `kv_pool_bytes`, `SlotKVPool.bytes_per_token()` and
 `.nbytes()` as the pool counts them, pushed once when the engine builds it
-(`serve_kv_bytes_per_token`).
+(`serve_kv_bytes_per_token`); beside them `kv_bytes_per_slot`, `kv_ring_bytes`
+and `kv_full_bytes` (`.bytes_per_slot()`, `.ring_nbytes()`, `.full_nbytes()`:
+what a slot reserves, and the pool's bytes by kind; `serve_kv_bytes_per_slot`).
+`prefill_chunks` counts the chunk programs dispatched. The rows a share's held
+experts took (`moe_rows_held` of ISSUE 33) are NOT counted by the program: no
+serving program hands a scalar out of the layer loop, and the benchmark counts
+them with the reference's router on the window's own tokens (PERF.md section 7).
 
 A TPU v5e's trace names an `XLA Ops` event by the HLO instruction's text, which
 does not hold the `op_name` (PERF.md section 7, PR 27): the scopes are in the

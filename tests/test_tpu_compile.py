@@ -67,6 +67,26 @@ def test_flash_attention_fwd_bwd(one_chip):
     _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, kv, kv)
 
 
+@pytest.mark.parametrize("keys,window", [(8192, 4096), (32768, None)])
+def test_flash_attention_at_an_offset(one_chip, keys, window):
+    """A serving chunk that continues a cache (PR 33): 4,096 queries of 128
+    heads over 8 kv heads of 128 against a window layer's ring + chunk
+    (8,192 keys, the band) and a full layer's region (32,768 keys), keys
+    heads-major, the offset and the first live key prefetched scalars."""
+    from megatron_tpu.ops.flash_attention import flash_attention
+
+    def S(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(q, k, v, off, start):
+        return flash_attention(q, k, v, causal=True, use_pallas=True,
+                               sliding_window=window, q_offset=off,
+                               kv_start=start, kv_heads_major=True)
+    kv = S((1, 8, keys, 128))
+    _compile(fn, S((1, 4096, 128, 128)), kv, kv, S((), jnp.int32),
+             S((), jnp.int32))
+
+
 def test_flash_attention_under_tp_mesh(topo):
     """XLA cannot partition a Mosaic call: under a tensor-parallel mesh
     the kernel has to sit in flash_attention's shard_map (Falcon-40B's
