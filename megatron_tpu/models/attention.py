@@ -37,7 +37,7 @@ from megatron_tpu.config import ModelConfig
 from megatron_tpu.models.norms import rmsnorm, rmsnorm_init
 from megatron_tpu.models.rope import apply_rotary
 from megatron_tpu.ops.dropout import dropout
-from megatron_tpu.ops.quantized import qdense, wcast
+from megatron_tpu.ops.quantized import W8, qdense, wcast
 
 
 class KVCache(NamedTuple):
@@ -571,6 +571,31 @@ def _dot_attention(q, k, v, *, causal: bool, softmax_fp32: bool,
     return out.reshape(b, s, nq, hd)
 
 
+def _project(x, w, cfg: ModelConfig, *, read_once: bool):
+    """x [b, t, d] through a projection's weight, cast as `wcast` casts it.
+
+    Where the program reads the weight once (`read_once`: it carries a KV
+    cache) and the weight is held wider than x, the product is made a
+    product of its own: rows flattened to [b * t, d], a barrier after it.
+    `wcast` then rounds the layer's slice on the way into the product, and
+    that holds only while the compiler sees a plain matrix product. Left
+    free it carries what comes after back into the weight: the rotary's
+    view of adjacent pairs (a decode step copied wq into [heads, hd / 2, 2,
+    h] order, three passes over it) and, for a prefill of two prompts, the
+    residual's rows-minor layout of [b, t, h], which a float32 matrix of
+    4,544 columns cannot be read in without a transposed copy of the slice
+    (20 bytes a weight where the lifted cast cost 8 and this form 4;
+    compile for v5e, PR 34: benchmark/fit.py). A weight already in x's
+    dtype, or int8, takes the plain product: its program is unchanged."""
+    wn = wcast(w, x.dtype, read_once=read_once)
+    if not read_once or isinstance(w, W8) or w.dtype == x.dtype:
+        return qdense(x, wn, cfg.quantized_gemm)
+    b, t, d = x.shape
+    y = jax.lax.optimization_barrier(
+        qdense(x.reshape(b * t, d), wn, cfg.quantized_gemm))
+    return y.reshape(b, t, *y.shape[1:])
+
+
 def attention_apply(
     params,
     x,
@@ -641,9 +666,11 @@ def attention_apply(
         t = jnp.einsum("bsd,bdr->bsr", inp.astype(dtype), at)
         return jnp.einsum("bsr,brd->bsd", t, bt)
 
-    q = qdense(x, wcast(params["wq"], dtype), cfg.quantized_gemm)
-    kv = qdense(kv_input if cross else x, wcast(params["wkv"], dtype),
-                cfg.quantized_gemm)
+    # a program with a cache multiplies by each weight once a call
+    read_once = kv_cache is not None
+    q = _project(x, params["wq"], cfg, read_once=read_once)
+    kv = _project(kv_input if cross else x, params["wkv"], cfg,
+                  read_once=read_once)
     if cfg.use_bias:
         q = q + params["bq"].astype(dtype)
         kv = kv + params["bkv"].astype(dtype)
@@ -723,8 +750,8 @@ def attention_apply(
                 q, k, v, kv_cache, cache_layer, kind_layer, cfg,
                 scale=1.0 / math.sqrt(hd))
         out = out.reshape(b, s, nq * hd)
-        return qdense(out, wcast(params["wo"], dtype),
-                      cfg.quantized_gemm), kv_cache
+        return _project(out, params["wo"], cfg,
+                        read_once=read_once), kv_cache
     if isinstance(kv_cache, BlockKVCache):
         # block-NATIVE serving path (--block_native_attn): append this
         # step's k/v into the touched arena blocks only and read the
@@ -745,7 +772,7 @@ def attention_apply(
             q, k, v, kv_cache, cache_layer, scale=1.0 / math.sqrt(hd),
             dtype=dtype)
         out = out.reshape(b, s, nq * hd)
-        proj = qdense(out, wcast(params["wo"], dtype), cfg.quantized_gemm)
+        proj = _project(out, params["wo"], cfg, read_once=read_once)
         if lw is not None:
             proj = proj + _lora(out, lw.ao, lw.bo)
         out = proj
@@ -1005,7 +1032,7 @@ def attention_apply(
             kv_positions=kv_positions)
 
     out = out.reshape(b, s, nq * hd)
-    proj = qdense(out, wcast(params["wo"], dtype), cfg.quantized_gemm)
+    proj = _project(out, params["wo"], cfg, read_once=read_once)
     if lw is not None:
         proj = proj + _lora(out, lw.ao, lw.bo)
     out = proj

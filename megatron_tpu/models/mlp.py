@@ -82,18 +82,22 @@ def mlp_axes(cfg: ModelConfig):
     return axes
 
 
-def mlp_apply(params, x, cfg: ModelConfig):
-    """x: [b, s, h] -> [b, s, h]."""
+def mlp_apply(params, x, cfg: ModelConfig, *, read_once: bool = False):
+    """x: [b, s, h] -> [b, s, h]. `read_once`: the program multiplies by
+    each weight once a call (it carries a KV cache), which chooses the
+    form of the weights' cast (`ops/quantized.py::wcast`)."""
     dtype = x.dtype
     # GLU: single h -> 2*ffn GEMM, gate/value as leading index of the output
-    y = qdense(x, wcast(params["w1"], dtype), cfg.quantized_gemm)
+    y = qdense(x, wcast(params["w1"], dtype, read_once=read_once),
+               cfg.quantized_gemm)
     if cfg.use_bias:
         y = y + params["b1"].astype(dtype)
     if cfg.is_glu:
         y = activation_fn(cfg.activation, y[:, :, 0], y[:, :, 1])
     else:
         y = activation_fn(cfg.activation, y)
-    y = qdense(y, wcast(params["w2"], dtype), cfg.quantized_gemm)
+    y = qdense(y, wcast(params["w2"], dtype, read_once=read_once),
+               cfg.quantized_gemm)
     if cfg.use_bias:
         y = y + params["b2"].astype(dtype)
     return y

@@ -334,7 +334,9 @@ def moe_apply(params, x, cfg: ModelConfig, *, bank_layer=None):
         y = _dropless_experts(params, x, idx, gates, cfg, bank_layer)
         if cfg.n_shared_experts:
             with jax.named_scope("mtpu/moe/shared"):
-                shared = mlp_apply(params["shared"], x, _shared_cfg(cfg))
+                # only a program with a cache hands down `bank_layer`
+                shared = mlp_apply(params["shared"], x, _shared_cfg(cfg),
+                                   read_once=bank_layer is not None)
                 if cfg.moe_shared_combination == "average":
                     shared = shared / cfg.n_shared_experts
                 y = y + shared

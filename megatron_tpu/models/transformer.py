@@ -192,7 +192,9 @@ def layer_apply(
                 return moe_apply({**params["mlp"], **expert_banks}, inp, cfg,
                                  bank_layer=bank_layer)
             return moe_apply(params["mlp"], inp, cfg)
-        return mlp_apply(params["mlp"], inp, cfg), jnp.zeros((), jnp.float32)
+        return (mlp_apply(params["mlp"], inp, cfg,
+                          read_once=kv_cache is not None),
+                jnp.zeros((), jnp.float32))
 
     residual = x
     if cfg.use_post_ln:

@@ -167,12 +167,58 @@ def has_quantized_weights(params) -> bool:
         params, is_leaf=lambda x: isinstance(x, W8)))
 
 
-def wcast(w, dtype):
+def wcast(w, dtype, *, read_once: bool = False):
     """The call-site weight cast: fp weights cast to the compute dtype;
     W8 weights pass through untouched (dequantization is fused into the
-    int8 GEMM inside qdense)."""
+    int8 GEMM inside qdense).
+
+    `read_once` is what the caller knows of its program: a program that
+    carries a KV cache (decode, prefill, chunk, verify) multiplies by each
+    weight once a call, a training step 3 to 24 times (forward, backward,
+    recompute, micro-batches). It chooses the cast's FORM for a weight held
+    wider than `dtype`, never its value:
+
+    - not `read_once`: a bare `astype`. Inside the layer loop the TPU
+      compiler moves a narrowing convert above the layer's slice and out of
+      the loop, so the whole stack is cast once a call and every use reads
+      the narrow copy: right where there are many uses.
+    - `read_once`: the slice is first rounded to `dtype`'s grid in its OWN
+      dtype (`reduce_precision`, round to nearest even), then narrowed, which
+      is then exact: bit for bit `w.astype(dtype)`, NaN and infinities
+      included. The compiler does not lift that pair; it fuses slice,
+      rounding and narrowing into the product's operand, which reads the
+      wide layer where it lies. A lifted cast costs such a program 8 bytes a
+      weight (read 4, write 2, read 2) and a narrow copy of every stack among
+      its temporaries (4.3 GiB at Falcon-7B widths and depth 11) where 4
+      bytes do (PERF.md section 6, PR 34).
+
+    Every matrix that comes through here takes the in-place form in a
+    cached program; none was left on the lifted path (compile for v5e and
+    my chip runs, PR 34, Falcon-7B widths at depth 11): both MLP products
+    fuse it as written and run near the float32 bytes' rate (~0.42 ms a
+    call where a layer's 330 MB take 0.40 at 819 GB/s); wq, wkv
+    and wo do once each projection is a product of its own
+    (`models/attention.py::_project`: left free, a decode step copied wq
+    three times over and a two-prompt prefill copied wq's and wo's slice
+    into another order, 20 bytes a weight). The embedding's lookup is not
+    a cast of this kind and does not come through here: a tied table's
+    bf16 copy is read by the token gather alone (the head's product reads
+    the float32 table in place), and rounding after the gather made it a
+    float32 copy (compile, ISSUE 34).
+
+    The rule covers a narrowing that keeps the exponent's width (float32
+    to bfloat16, the served case). `reduce_precision` flushes what would be
+    a subnormal of a format with a narrower exponent (float16) to zero where
+    `astype` rounds to it, so such a cast keeps the bare form and its exact
+    value. A weight already in `dtype`, or narrower, emits nothing."""
     if isinstance(w, W8):
         return w
+    if read_once and jnp.issubdtype(w.dtype, jnp.floating) \
+            and jnp.issubdtype(dtype, jnp.floating):
+        wide, narrow = jnp.finfo(w.dtype), jnp.finfo(dtype)
+        if wide.nexp == narrow.nexp and wide.nmant > narrow.nmant:
+            w = jax.lax.reduce_precision(w, exponent_bits=narrow.nexp,
+                                         mantissa_bits=narrow.nmant)
     return w.astype(dtype)
 
 

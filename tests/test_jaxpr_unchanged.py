@@ -16,6 +16,14 @@ top-p filters moved under one `lax.cond`, `inference/sampling.py::
 _filter_rows`) and printed them again at its parent's tree (4cf2126) plus
 that change. The four `prefill` and `plain_loop` digests came out as they
 were at 280f7aa: no prefill and no training program holds the sampler.
+
+PR 34 changed the four serving programs (`decode` and `prefill` of both
+models) on purpose and printed them again at its parent's tree (7011b80)
+plus that change: a program that carries a KV cache rounds each float32
+weight to bf16's grid in float32 before it narrows it, and makes each
+attention projection a product of its own (`ops/quantized.py::wcast`,
+`models/attention.py::_project`). The two `plain_loop` digests came out as
+they were: the proof that no training program changed.
 """
 import hashlib
 import re
@@ -32,11 +40,11 @@ from megatron_tpu.serving import ServingEngine
 SLOTS, CAP, B_PRE, BUCKET = 3, 64, 2, 16
 
 AT_PARENT = {
-    "falcon-tiny": {"decode": "be486d462f3e2d4e",
-                    "prefill": "56d397c890fa45a5",
+    "falcon-tiny": {"decode": "fef49c4b476ecdfa",
+                    "prefill": "f04c8d250b4f22c4",
                     "plain_loop": "5ec7bf8d21be92cf"},
-    "olmoe-tiny": {"decode": "0309990a12d28acb",
-                   "prefill": "ca0fa2aa71a1872f",
+    "olmoe-tiny": {"decode": "338d2003810367f2",
+                   "prefill": "75c16c126a97e557",
                    "plain_loop": "c88e314638cb0a8f"},
 }
 
