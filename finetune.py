@@ -18,7 +18,10 @@ import jax
 from megatron_tpu.utils.compile_cache import ensure_compile_cache
 ensure_compile_cache()
 
+from megatron_tpu.utils.tracing import phase  # noqa: E402
 
+
+@phase("data")
 def build_data(cfg, tokenizer, consumed_samples: int, mesh=None):
     """(ref: megatron/training.py:855-939 build_train_valid_test_data_iterators
     + finetune.py:107 dataset provider)"""
@@ -77,6 +80,7 @@ def build_data(cfg, tokenizer, consumed_samples: int, mesh=None):
             make_iter(test_ds, 0))
 
 
+@phase("init_state")
 def init_state(cfg, mesh, rng):
     """The fresh TrainState, born where it will live. With a mesh every
     leaf is created already sharded as the train step wants it: a model

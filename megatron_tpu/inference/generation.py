@@ -33,6 +33,7 @@ from megatron_tpu.config import ModelConfig
 from megatron_tpu.inference.sampling import sample
 from megatron_tpu.models import language_model as lm
 from megatron_tpu.models.attention import HybridKVCache, KVCache
+from megatron_tpu.utils.tracing import phase
 
 
 class SamplingParams(NamedTuple):
@@ -275,6 +276,7 @@ class Generator:
     The reference's equivalent is the 8-GPU TP text_generation_server with
     broadcast tokens (ref: megatron/text_generation_server.py)."""
 
+    @phase("generator")
     def __init__(self, params, cfg: ModelConfig, eos_id: int,
                  pad_id: Optional[int] = None, mesh=None,
                  kv_cache_dtype=jnp.bfloat16, expert_axis: str = "tp"):

@@ -60,6 +60,7 @@ from typing import Optional, Tuple
 from megatron_tpu.inference.api import (beam_search_and_post_process,
                                         generate_and_post_process)
 from megatron_tpu.inference.generation import Generator
+from megatron_tpu.utils import tracing
 from megatron_tpu.utils.logging import print_rank_0
 
 MAX_PROMPTS = 128
@@ -1440,6 +1441,7 @@ class MegatronServer:
 
         print_rank_0(f"serving (http.server) on {host}:{port}/api")
         httpd = ThreadingHTTPServer((host, port), Handler)
+        tracing.ready()          # bound and listening: start-up is over
         # SIGTERM drains in-flight work, then shutdown() unblocks
         # serve_forever for a clean exit (rolling-restart contract)
         self.install_sigterm_drain(shutdown_cb=httpd.shutdown)

@@ -25,6 +25,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from megatron_tpu.config import ParallelConfig
+from megatron_tpu.utils.tracing import phase
 
 # Canonical mesh axis names, outermost (slowest-varying) first.
 DATA_AXIS = "dp"
@@ -34,6 +35,7 @@ TENSOR_AXIS = "tp"
 MESH_AXES = (DATA_AXIS, PIPELINE_AXIS, CONTEXT_AXIS, TENSOR_AXIS)
 
 
+@phase("mesh")
 def build_mesh(
     parallel: ParallelConfig,
     devices: Optional[Sequence[jax.Device]] = None,

@@ -149,8 +149,9 @@ from megatron_tpu.serving.spec_decode import (NGramDrafter,
                                               build_draft_rounds)
 from megatron_tpu.serving.structured import (GrammarCompileError,
                                              compile_response_format)
+from megatron_tpu.utils import compile_cache
 from megatron_tpu.utils.logging import print_rank_0
-from megatron_tpu.utils.tracing import span
+from megatron_tpu.utils.tracing import phase, span
 
 from megatron_tpu.config import SERVING_KV_DTYPES as _KV_DTYPES
 
@@ -269,6 +270,7 @@ class ServingEngine:
     # into permanent 503
     RESTART_DECAY_S = 300.0
 
+    @phase("engine")
     def __init__(self, generator: Generator, serving=None,
                  metrics: Optional[ServingMetrics] = None,
                  writer=None, report_interval: int = 100,
@@ -357,10 +359,11 @@ class ServingEngine:
                 src = jax.device_put(src)
             self._p_dec = self._p_pre = src
             _jit_dec = _jit_pre = self.gen._jit
-        self.pool = SlotKVPool(cfg, self.num_slots, self.max_len,
-                               dtype=kv_dtype,
-                               retained_limit=self.serving.retained_slots,
-                               block_size=self.serving.kv_block_size)
+        with phase("engine.pool"):
+            self.pool = SlotKVPool(
+                cfg, self.num_slots, self.max_len, dtype=kv_dtype,
+                retained_limit=self.serving.retained_slots,
+                block_size=self.serving.kv_block_size)
         # every program closes over the rotary tables as constants. On a
         # pool of rings and regions they are cut to this engine's
         # positions: a published context of 200,000 rows made each
@@ -1280,12 +1283,22 @@ class ServingEngine:
         if drained:
             if self._watchdog is not None:
                 self._watchdog.stop()
+            # this engine's own count of Python traces, then the compile
+            # ledger's rows for the same programs: what the process
+            # compiled, and what it loaded from the persistent cache
+            by = compile_cache.ledger()["by_program"]
+            rows = ", ".join(
+                f"{name} {by[name]['programs'] - by[name]['hits']}"
+                f"/{by[name]['hits']}"
+                for name in ("_decode_fn", "_prefill_fn", "_chunk_fwd_fn",
+                             "_verify_fn") if name in by)
             print_rank_0(
                 "serving engine drained: all in-flight requests "
                 f"completed (program traces: decode={self._decode_traces}"
                 f" prefill={self._prefill_traces}"
                 f" chunk={self._chunk_traces}"
-                f" verify={self._verify_traces})")
+                f" verify={self._verify_traces}; this process "
+                f"compiled/loaded from the cache: {rows or 'none'})")
         return drained
 
     def __enter__(self):
@@ -1639,6 +1652,7 @@ class ServingEngine:
                                    donate_argnums))
         return _jit_dec, _jit_pre
 
+    @phase("engine.programs")
     def _compile_programs(self, _jit_dec, _jit_pre):
         """Build every compiled program against the current topology.
         Called once at construction and again only at an applied

@@ -13,6 +13,8 @@ import threading
 import time
 from typing import Deque, Dict, Optional, Tuple
 
+from megatron_tpu.utils.tracing import startup_scalars
+
 
 def _percentile(sorted_vals, q: float) -> float:
     """Nearest-rank percentile over an already-sorted sequence. Total
@@ -467,6 +469,10 @@ class ServingMetrics:
         calls = counters.get("prefill_calls", 0)
         out["prompts_per_prefill"] = (
             counters.get("prefill_prompts", 0) / calls if calls else 0.0)
+        # the PROCESS's start-up and compile ledger (utils/tracing.py):
+        # the same six keys on every engine of the process, from the
+        # first scrape. compiles_after_ready is the recompile alarm.
+        out.update(startup_scalars())
         return out
 
     def report(self, writer, step: Optional[int] = None):

@@ -49,6 +49,7 @@ import numpy as np
 
 from megatron_tpu.resilience import integrity
 from megatron_tpu.utils.logging import print_rank_0
+from megatron_tpu.utils.tracing import phase
 
 
 class WeightSwapError(RuntimeError):
@@ -120,6 +121,7 @@ def manifest_digest(ckpt_dir: str) -> str:
         return hashlib.sha256(f.read()).hexdigest()[:12]
 
 
+@phase("load")
 def load_staged(ckpt_dir: str, example_params, *,
                 require_manifest: bool = True) -> StagedWeights:
     """Verify + stage one checkpoint HOST-side. The order is the

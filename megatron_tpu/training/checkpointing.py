@@ -45,6 +45,7 @@ from megatron_tpu.resilience.faults import fault_point
 from megatron_tpu.resilience.retry import RetryPolicy, policy_from, retry
 from megatron_tpu.training.train_step import TrainState
 from megatron_tpu.utils.logging import print_rank_0
+from megatron_tpu.utils.tracing import phase
 
 TRACKER = "latest_checkpointed_iteration.txt"
 STATE_DIR = "state"  # orbax pytree directory inside an iteration dir
@@ -325,6 +326,7 @@ def _dir_for_tag(root: str, tag: Optional[str]) -> Optional[str]:
         return None
 
 
+@phase("load")
 def load_checkpoint(
     root: str,
     example_state: TrainState,

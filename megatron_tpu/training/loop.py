@@ -51,7 +51,8 @@ from megatron_tpu.data.samplers import PrefetchIterator
 from megatron_tpu.training.microbatches import MicrobatchCalculator
 from megatron_tpu.utils.logging import make_writer, print_rank_0
 from megatron_tpu.utils.timers import Timers
-from megatron_tpu.utils.tracing import span, start_trace, step_span
+from megatron_tpu.utils.tracing import (phase, ready, span, start_trace,
+                                        startup_scalars, step_span)
 
 
 def _device_fetch(tree):
@@ -301,6 +302,10 @@ def train(
     distinct code when a step wedges. An active FaultInjector
     (resilience/faults.py) can poison batches / stall steps here — the
     chaos-test entry points."""
+    # the start-up record's last phase: from here to the return of the
+    # first step's flush, closed at the once-only site below
+    first_step = phase("first_step")
+    first_step.__enter__()
     # async by default: the loop blocks once per log window (the
     # metrics flush), not per step; sync_metrics restores the
     # step-exact barriers (docstring "Host/device overlap")
@@ -617,6 +622,12 @@ def train(
                     memory_reported = True
                     from megatron_tpu.utils.logging import report_memory
                     report_memory("after first step")
+                    # ... and start-up is over: the step has compiled
+                    # (or loaded) and run
+                    first_step.__exit__(None, None, None)
+                    ready()
+                    for k, v in startup_scalars().items():
+                        writer.add_scalar(f"startup/{k}", v, iteration)
 
             if rollback_at is not None:
                 exhausted = guard.note_rollback()
@@ -806,6 +817,7 @@ def train(
             if exiting:
                 break
     finally:
+        first_step.__exit__(None, None, None)  # a run that never flushed
         if watchdog is not None:
             watchdog.stop()
         # flush an in-flight profiler trace so early exits still produce it

@@ -4,7 +4,40 @@ A span exists only while a profiler session is on (`--profile` on a training
 job, `PUT /admin {"op": "trace"}` on a server, or whoever calls
 `jax.profiler.start_trace` round the code). It then lands in the profiler's
 trace beside the device's events, on the same clock. With no session it is a
-C++ "is anyone tracing" check. There is no recorder, flag or option here.
+C++ "is anyone tracing" check. The loops have no recorder, flag or option: a
+span there runs thousands of times a second and a list of them would grow for
+the life of the process.
+
+Start-up is the one exception, because no profiler session is ever on round it
+and it happens once. `phase(name)` is a span `mtpu/setup/<name>` AND a row
+`(name, start, end)` on `time.monotonic()` in the process's start-up record,
+which `startup_record()` returns. It is for code that runs once a process: no
+loop body calls it. `ready()` stamps the moment the process can do its work (a
+server as it starts to listen, a training job as its first step's flush
+returns), prints one line, and closes the record: from then on, and past
+`MAX_PHASES` rows in any case, `phase` is the span alone (a hot swap's `load`
+or a re-plan's `engine.programs` shows in a profile and adds no row;
+`startup_record()["dropped"]` counts them). What compiling cost is not here but
+in `utils/compile_cache.py`'s ledger, which JAX's own events fill: an engine's
+programs compile at their first dispatch, inside the loop, and no phase goes
+there.
+
+| phase | round what |
+|---|---|
+| `mtpu/setup/mesh` | `parallel.mesh.build_mesh` |
+| `mtpu/setup/init_state` | `finetune.init_state`: the train state born sharded (its jit, its run, the wait for it) |
+| `mtpu/setup/load` | a checkpoint read and placed (`checkpointing.load_checkpoint`, `serving/weights.py::load_staged`) |
+| `mtpu/setup/data` | `finetune.build_data`: index maps built or mapped, the iterators made |
+| `mtpu/setup/first_step` | `loop.train` from entry to the return of the first step's flush (trace, lower, compile or load, run), closed at the once-only site that reports memory "after first step" |
+| `mtpu/setup/generator` | `Generator.__init__` (the rotary tables: ROADMAP S22) |
+| `mtpu/setup/engine` | `ServingEngine.__init__`; children `engine.pool` (the KV pool's allocation) and `engine.programs` (`_compile_programs`, the resident uploads) |
+
+`startup_scalars()` is what `/metrics` and the training writer show of both:
+`startup_seconds` (process start to `ready()`, 0 before), `compile_programs`,
+`compile_seconds`, `compile_cache_hits`, `compile_cache_misses` (the ledger's
+totals now) and `compiles_after_ready`, the programs compiled or loaded since
+`ready()`: in a steady state it stays where it is, and on a server that warms
+nothing it counts what the first users of a new build waited for.
 
 A span is a `with` block on the thread that does the work; nesting gives the
 parent. Names are constant strings, stats are integers: keyword arguments for
@@ -80,11 +113,164 @@ reads an idle gap off a trace: docs/serving.md "Observability & drills".
 """
 from __future__ import annotations
 
+import functools
+import os
+import threading
+import time
+from typing import Dict, List, Optional
+
 import jax
+
+from megatron_tpu.utils import compile_cache
+from megatron_tpu.utils.logging import print_rank_0
 
 
 def span(name: str, **stats):
     return jax.profiler.TraceAnnotation("mtpu/" + name, **stats)
+
+
+MAX_PHASES = 64
+
+
+def _process_start() -> float:
+    """The process's start on `time.monotonic()`'s clock: its age by the
+    kernel's count (`/proc/self/stat` field 22 against `/proc/uptime`, to
+    a hundredth of a second) taken off the clock's reading now, so that
+    imports are inside. Where that cannot be read, now: this module's
+    import."""
+    now = time.monotonic()
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            age = float(f.read().split()[0]) - ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return now
+    return now - age if 0.0 <= age < 86400.0 else now
+
+
+class _StartupRecord:
+    """The process's one record of its start (the table above)."""
+
+    def __init__(self):
+        self.lock = threading.Lock()
+        self.t0 = _process_start()
+        self.rows: List[list] = []         # [name, start, end or None]
+        self.dropped = 0
+        self.ready: Optional[float] = None
+        self.programs_at_ready = 0
+
+    def open(self, name: str) -> Optional[list]:
+        with self.lock:
+            if self.ready is not None or len(self.rows) >= MAX_PHASES:
+                self.dropped += 1
+                return None
+            row = [name, time.monotonic(), None]
+            self.rows.append(row)
+            return row
+
+
+_record = _StartupRecord()
+
+
+class phase:
+    """`with phase("engine"):` or `@phase("engine")` on a function. For
+    code that runs once a process (module docstring)."""
+
+    def __init__(self, name: str):
+        self.name = name
+        self._span = self._row = None
+
+    def __enter__(self):
+        self._span = span("setup/" + self.name)
+        self._span.__enter__()
+        self._row = _record.open(self.name)
+        return self
+
+    def __exit__(self, *exc):
+        if self._row is not None:
+            self._row[2] = time.monotonic()
+        self._row = None
+        if self._span is not None:
+            self._span.__exit__(*exc)
+        self._span = None
+        return False
+
+    def __call__(self, fn):
+        @functools.wraps(fn)
+        def inside(*args, **kwargs):
+            with phase(self.name):
+                return fn(*args, **kwargs)
+        return inside
+
+
+def startup_record() -> Dict[str, object]:
+    """`t0` the process's start, `rows` the phases as (name, start, end)
+    in the order they began (`end` None while one is open), `ready` the
+    stamp or None, `dropped` the phases that added no row; all clock
+    readings are `time.monotonic()`'s."""
+    with _record.lock:
+        return {"t0": _record.t0, "ready": _record.ready,
+                "rows": [tuple(r) for r in _record.rows],
+                "dropped": _record.dropped}
+
+
+def ready() -> float:
+    """Stamp the moment the process can do its work, once: a second call
+    returns the first stamp and prints nothing."""
+    with _record.lock:
+        if _record.ready is not None:
+            return _record.ready
+        _record.ready = time.monotonic()
+        _record.programs_at_ready = compile_cache.totals()["programs"]
+    print_rank_0(ready_line())
+    return _record.ready
+
+
+def ready_line() -> str:
+    """`ready in 44.7 s: engine 3.1 (pool 0.4), 14 programs: traced
+    6.2 s, lowered 2.9 s, backend 19.8 s, 14 of 14 from the cache (saved
+    96 s)`: the outermost phases with their children in brackets, then
+    the compile ledger up to the stamp."""
+    rec = startup_record()
+    t1 = rec["ready"] if rec["ready"] is not None else time.monotonic()
+    tops: List[list] = []                  # [name, start, end, children]
+    for name, a, b in rec["rows"]:         # in the order they began
+        b = t1 if b is None else b
+        if tops and tops[-1][1] <= a and b <= tops[-1][2]:
+            tops[-1][3].append(
+                f"{name.removeprefix(tops[-1][0] + '.')} {b - a:.1f}")
+        else:
+            tops.append([name, a, b, []])
+    parts = [f"{name} {b - a:.1f}" + (f" ({', '.join(kids)})" if kids else "")
+             for name, a, b, kids in tops]
+    led = compile_cache.until(t1)
+    kept = led["hits"] + led["misses"]
+    return (f"ready in {t1 - rec['t0']:.1f} s: "
+            + (", ".join(parts) + ", " if parts else "")
+            + f"{led['programs']} programs: traced {led['trace_s']:.1f} s, "
+            f"lowered {led['lower_s']:.1f} s, backend "
+            f"{led['backend_s']:.1f} s, {led['hits']} of {kept} from the "
+            f"cache (saved {led['saved_s']:.0f} s)")
+
+
+def startup_scalars() -> Dict[str, float]:
+    """The six keys of the module docstring, as `/metrics` shows them."""
+    led = compile_cache.totals()
+    with _record.lock:
+        ready_at, at_ready = _record.ready, _record.programs_at_ready
+    return {
+        "startup_seconds":
+            float(ready_at - _record.t0) if ready_at is not None else 0.0,
+        "compile_programs": float(led["programs"]),
+        "compile_seconds": float(led["trace_s"] + led["lower_s"]
+                                 + led["backend_s"]),
+        "compile_cache_hits": float(led["hits"]),
+        "compile_cache_misses": float(led["misses"]),
+        "compiles_after_ready":
+            float(led["programs"] - at_ready) if ready_at is not None
+            else 0.0,
+    }
 
 
 def step_span(name: str, step: int):
