@@ -319,7 +319,7 @@ def phase_kernels(args) -> int:
                 # interpret=None: the op's own dispatch (compiled on TPU)
                 return block_native_attention(
                     qq, ka, va, bmap, lengths, scale=hd ** -0.5,
-                    block_size=B, k_scale=ks_, v_scale=vs_,
+                    k_scale=ks_, v_scale=vs_,
                     interpret=True if interp else None)
             got = kern(qq, ka, va, lengths, ks_, vs_)
             want = jax.jit(gathered_dot)(qq, ka, va, lengths, ks_, vs_)

@@ -23,7 +23,10 @@ design, not omission:
   functional cache pytree, STACKED over layers: the layer loop carries the
   whole stack and each layer appends its tokens at (layer, row, position)
   of that buffer in place, then reads its own layer of it. No layer of the
-  cache is ever cut out, updated and written back.
+  cache is ever cut out, updated and written back. Where every row sits at
+  its own length (a decode step of the serving grid) and the pool's shape
+  allows, the read is the block kernel's, which stops at each row's length
+  (ops/block_attention_pallas.py::pool_block_rows says where).
 """
 from __future__ import annotations
 
@@ -229,12 +232,13 @@ def _block_native_update_attend(q, k, v, stacked: BlockKVCache, layer, *,
     TRASH block) write their garbage there, exactly where scatter_view
     used to land it.
 
-    Read: the Pallas kernel walks the map — q attends each slot's
-    block-chained K/V causally from its own offset, dequantizing int8
-    in kernel. A custom call cannot read a dynamic slice in place, so
-    this layer's arena is cut out of the stack AFTER the append
-    (write-before-read by data dependence) and handed to the kernel;
-    nothing is written back."""
+    Read: the Pallas kernel walks the map: q attends each slot's
+    block-chained K/V causally from its own offset, dequantizing int8 in
+    kernel. It takes the arena STACKED over layers and the layer's index,
+    AFTER the append (write-before-read by data dependence): no layer of
+    the arena is cut out of the stack (the scales, a thirty-second of the
+    payload, are: ops/block_attention_pallas.py says why), and nothing is
+    written back."""
     from megatron_tpu.ops.block_attention_pallas import \
         block_native_attention
     S, s, nq, hd = q.shape
@@ -267,20 +271,27 @@ def _block_native_update_attend(q, k, v, stacked: BlockKVCache, layer, *,
         v_scale=wr(stacked.v_scale, vs) if quant else None,
         offset=jax.lax.dynamic_update_index_in_dim(
             stacked.offset, offset + s, layer, 0))
-    cache = jax.tree.map(lambda a: _layer_of(a, layer), stacked)
+    args = [q, stacked.k, stacked.v, bmap, offset,
+            jnp.asarray(layer, jnp.int32)]
+    if quant:
+        args += [_layer_of(stacked.k_scale, layer),
+                 _layer_of(stacked.v_scale, layer)]
+
+    def _kern(q_, k_, v_, m_, off_, layer_, ks_=None, vs_=None):
+        return block_native_attention(
+            q_, k_, v_, m_, off_, scale=scale, layer=layer_,
+            k_scale=ks_, v_scale=vs_)
     # TP-sharded serving (serving/topology.py): XLA cannot partition a
     # custom call, so with a tp mesh active the kernel runs under an
     # explicit shard_map on the head-sharded arena — each tp shard
-    # walks its OWN nkv/tp kv heads' block chains (the GQA head loop
+    # walks its OWN nkv/tp kv heads' block chains (the head loop
     # shrinks per shard; attention is per-head independent, so no
     # collective inside). Single-device traces (mesh None) lower the
-    # bare call, bit-identical to before.
+    # bare call.
     from megatron_tpu.parallel.sharding import active_tp_mesh
     mesh = active_tp_mesh()
     if mesh is None:
-        out = block_native_attention(
-            q, cache.k, cache.v, cache.map, offset, scale=scale,
-            block_size=B, k_scale=cache.k_scale, v_scale=cache.v_scale)
+        out = _kern(*args)
     else:
         from jax.sharding import PartitionSpec as P
         from megatron_tpu.parallel.mesh import TENSOR_AXIS
@@ -291,22 +302,12 @@ def _block_native_update_attend(q, k, v, stacked: BlockKVCache, layer, *,
             "serve with the resolve/scatter bracket instead "
             "(ServingConfig.validate rejects this combination)")
         h_spec = P(None, None, TENSOR_AXIS, None)
-        quant = cache.k_scale is not None
-
-        def _kern(q_, k_, v_, m_, off_, *sc):
-            ks_, vs_ = sc if quant else (None, None)
-            return block_native_attention(
-                q_, k_, v_, m_, off_, scale=scale, block_size=B,
-                k_scale=ks_, v_scale=vs_)
-
-        args = [q, cache.k, cache.v, cache.map, offset]
-        in_specs = [h_spec, h_spec, h_spec, P(), P()]
-        if quant:
-            args += [cache.k_scale, cache.v_scale]
-            in_specs += [h_spec, h_spec]
-        out = jax.shard_map(_kern, mesh=mesh,
-                            in_specs=tuple(in_specs),
-                            out_specs=h_spec, check_vma=False)(*args)
+        stack_spec = P(None, *h_spec)
+        out = jax.shard_map(
+            _kern, mesh=mesh,
+            in_specs=(h_spec, stack_spec, stack_spec, P(), P(), P())
+            + (h_spec, h_spec) * quant,
+            out_specs=h_spec, check_vma=False)(*args)
     return out.astype(dtype), stacked
 
 
@@ -816,7 +817,7 @@ def attention_apply(
                      and (not cache_quant or cache_rolling))
     k_raw, v_raw = k, v
 
-    kv_positions = None
+    kv_positions = pool_rows = None
     if kv_cache is not None:
         cap = kv_cache.k.shape[2]
         # ROLLING mode: the cache holds only the last `sliding_window`
@@ -913,15 +914,25 @@ def attention_apply(
             p = t_last - ((t_last - jnp.arange(cap)) % cap)
             kv_positions = jnp.where(p >= 0, p, jnp.int32(2 ** 30))
         # The read is this layer of the buffer AFTER the write (a step's
-        # own tokens are attended; data dependence keeps the order). It
-        # feeds the products directly, so XLA can fuse the slice (and the
-        # int8 dequant: convert*scale) into the dot's operand load and
-        # stream the pool from HBM once.
-        k = _layer_of(kv_cache.k, cache_layer).astype(dtype)
-        v = _layer_of(kv_cache.v, cache_layer).astype(dtype)
-        if cache_quant:
-            k = k * _layer_of(kv_cache.k_scale, cache_layer).astype(dtype)
-            v = v * _layer_of(kv_cache.v_scale, cache_layer).astype(dtype)
+        # own tokens are attended; data dependence keeps the order).
+        from megatron_tpu.ops.block_attention_pallas import pool_block_rows
+        from megatron_tpu.parallel.sharding import active_kernel_mesh
+        pool_rows = pool_block_rows(
+            kv_cache.k.shape, kv_cache.k.dtype, queries=q.shape[:3],
+            per_slot=per_slot,
+            window=cfg.sliding_window is not None,
+            mesh=active_kernel_mesh() is not None)
+        if pool_rows is None:
+            # The whole layer feeds the products directly, so XLA can fuse
+            # the slice (and the int8 dequant: convert*scale) into the
+            # dot's operand load and stream the pool from HBM once.
+            k = _layer_of(kv_cache.k, cache_layer).astype(dtype)
+            v = _layer_of(kv_cache.v, cache_layer).astype(dtype)
+            if cache_quant:
+                k = k * _layer_of(kv_cache.k_scale,
+                                  cache_layer).astype(dtype)
+                v = v * _layer_of(kv_cache.v_scale,
+                                  cache_layer).astype(dtype)
 
     scale = 1.0 / math.sqrt(hd)
     # Note on apply_query_key_layer_scaling: in the reference it divides QK^T
@@ -990,6 +1001,19 @@ def attention_apply(
                           if dropout_active and dropout_rng is not None
                           else 0.0),
             dropout_rng=dropout_rng if dropout_active else None)
+    elif pool_rows is not None:
+        # each slot at its own length (a decode step, a verify window):
+        # the kernel reads every slot's blocks up to its length out of the
+        # stacked pool where they lie, and no layer of it is cut out
+        # (ops/block_attention_pallas.py::pool_block_rows says which pools)
+        assert causal and segment_ids is None and not dropout_active, (
+            "per-slot offsets serve causal self-attention, no dropout")
+        from megatron_tpu.ops.block_attention_pallas import \
+            contiguous_pool_attention
+        out = contiguous_pool_attention(
+            q, kv_cache.k, kv_cache.v, q_offset, layer=cache_layer,
+            rows=pool_rows, scale=scale, k_scale=kv_cache.k_scale,
+            v_scale=kv_cache.v_scale).astype(dtype)
     elif prefill_flash:
         from megatron_tpu.ops.flash_attention import flash_attention
 

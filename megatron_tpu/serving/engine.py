@@ -129,6 +129,7 @@ from megatron_tpu.inference.sampling import (rows_need_filter,
                                              sample_batched,
                                              verify_draft_probs)
 from megatron_tpu.models import language_model as lm
+from megatron_tpu.models.attention import KVCache
 from megatron_tpu.resilience.faults import get_fault_injector
 from megatron_tpu.serving.kv_pool import (SlotKVPool, block_native_cache,
                                           batch_row, insert_blocks,
@@ -467,6 +468,10 @@ class ServingEngine:
         self._bracket_bytes = 0
         self._attn_path = (2 if self._kernel_on
                            else 1 if self._blocks_on else 0)
+        # rows of the blocks a decode step's attention reads the pool in,
+        # up to each slot's length (`kv_blocks_read` / `kv_blocks_held`);
+        # 0 where it reads every slot's region whole
+        self._attend_rows = self._attend_block_rows()
         self._prefix_on = bool(self.serving.enable_prefix_cache)
         self._chunk = self.serving.prefill_chunk
         self._preempt_on = bool(self.serving.preemption)
@@ -1567,6 +1572,44 @@ class ServingEngine:
             ticket, self._pending_swap = self._pending_swap, None
         if ticket is not None and not ticket.done.is_set():
             ticket.done.set()  # version stays None -> typed abort
+
+    def _attend_block_rows(self) -> int:
+        """The block the Pallas kernel of ops/block_attention_pallas.py
+        reads this engine's pool in: the arena's own under
+        `block_native_attn`, what `pool_block_rows` gives a contiguous
+        `KVCache` pool (the question `attention_apply` asks as it traces a
+        decode step), else 0: a latent or hybrid pool, a pool of pipeline
+        stages, a bracketed block view and every pool on the dot path."""
+        if self._kernel_on:
+            return int(self.serving.kv_block_size)
+        caches = self.pool.caches
+        if not isinstance(caches, KVCache):
+            return 0
+        from megatron_tpu.ops.block_attention_pallas import pool_block_rows
+        return pool_block_rows(
+            caches.k.shape, caches.k.dtype, per_slot=True,
+            queries=(self.num_slots, 1, self.cfg.num_attention_heads),
+            window=self.cfg.sliding_window is not None,
+            mesh=self.topo is not None
+            or self.gen.mesh is not None) or 0
+
+    def _count_kv_blocks(self, spec_round, spec_k: int):
+        """`kv_blocks_read`: over the window's dispatches and the grid's
+        rows, the blocks up to the row's last query (a parked row reads its
+        first), from the lengths the device holds: the host's, one further a
+        chained step (a verify round's accepted tokens are not known yet:
+        counted as one). `kv_blocks_held`: every row's whole region."""
+        rows = self._attend_rows
+        nb = self.pool.cap // rows
+        lengths = self._lengths.astype(np.int64)
+        read = 0
+        for r, spec in enumerate(spec_round):
+            last = np.minimum(lengths + r, self.max_len - 1) \
+                + (spec_k if spec else 0)
+            read += int((np.minimum(last // rows, nb - 1) + 1).sum())
+        self.metrics.count("kv_blocks_read", read)
+        self.metrics.count("kv_blocks_held",
+                           len(spec_round) * self.num_slots * nb)
 
     # ------------------------------------------------------------------
     # per-phase placement (serving/placement.py + serving/topology.py;
@@ -4340,6 +4383,8 @@ class ServingEngine:
                         histories[slot] = req.prompt + req.generated
                 grids, spec_round, guesses = build_draft_rounds(
                     histories, self.drafter, spec_k, K)
+        if self._attend_rows:
+            self._count_kv_blocks(spec_round, spec_k)
         with span("serve/step.dispatch"):
             # adapter bank args: the stacked factor pytree + per-slot rows
             # (None/None with adapters off — the empty-pytree args lower to

@@ -54,6 +54,11 @@ _BASE_COUNTERS = (
     # top-k / top-p filter: over decode_steps, the share of steps that
     # still pay the two vocabulary sorts (inference/sampling.py)
     "sample_filter_steps",
+    # blocks of the KV pool a decode step's attention read, up to each
+    # row's length (ops/block_attention_pallas.py), and the blocks of the
+    # regions it would have read whole: read / held is the share of the
+    # pool's bytes a step moves. 0 / 0 where every region is read whole
+    "kv_blocks_read", "kv_blocks_held",
     "prefill_calls", "prefill_prompts",
     # prefix cache / chunked prefill (docs/serving.md):
     # prefix_hit_tokens counts tokens MATCHED at lookup (including

@@ -13,7 +13,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from megatron_tpu.ops.block_attention_pallas import block_native_attention
+from megatron_tpu.models.attention import _dot_attention
+from megatron_tpu.ops.block_attention_pallas import (
+    block_native_attention, contiguous_pool_attention)
 
 
 def _gather_view(arena, bmap, s):
@@ -65,7 +67,6 @@ def _run(q, ka, va, bmap, lengths, scale, B, ks=None, vs=None):
     return np.asarray(block_native_attention(
         jnp.asarray(q), jnp.asarray(ka), jnp.asarray(va),
         jnp.asarray(bmap), jnp.asarray(lengths), scale=scale,
-        block_size=B,
         k_scale=None if ks is None else jnp.asarray(ks),
         v_scale=None if vs is None else jnp.asarray(vs),
         interpret=True))
@@ -206,9 +207,81 @@ def test_bf16_payload_dequantizes_like_dot():
                hd ** -0.5, B)
     got_bf = np.asarray(block_native_attention(
         jnp.asarray(q), ka, va, jnp.asarray(bmap),
-        jnp.asarray(lengths), scale=hd ** -0.5, block_size=B,
+        jnp.asarray(lengths), scale=hd ** -0.5,
         interpret=True))
     np.testing.assert_allclose(got_bf, got, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_stacked_arena_reads_the_indexed_layer(layer):
+    """The arena stacked over L = 3 layers with the layer's index (a traced
+    scalar, as the layer loop hands it down): the answer is that layer's
+    under its scattered map, whatever the other two hold."""
+    L, S, w, B, nb, nq, nkv, hd = 3, 3, 2, 8, 4, 4, 2, 16
+    T = S * nb + 1
+    rs = np.random.RandomState(8)
+    ka, va, _, _ = _arena(rs, L * T, B, nkv, hd, np.float32)
+    ka, va = ka.reshape(L, T, B, nkv, hd), va.reshape(L, T, B, nkv, hd)
+    q = rs.randn(S, w, nq, hd).astype(np.float32)
+    bmap = np.stack([rs.permutation(T - 1)[:nb]
+                     for _ in range(S)]).astype(np.int32)
+    lengths = np.array([0, B + 3, 3 * B - 1], np.int32)
+    got = np.asarray(jax.jit(
+        lambda i: block_native_attention(
+            jnp.asarray(q), jnp.asarray(ka), jnp.asarray(va),
+            jnp.asarray(bmap), jnp.asarray(lengths), scale=hd ** -0.5,
+            layer=i, interpret=True))(jnp.int32(layer)))
+    want = ref_block_attention(q, ka[layer], va[layer], bmap, lengths,
+                               hd ** -0.5)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+@pytest.mark.parametrize("w", [1, 3])
+@pytest.mark.parametrize("nq,nkv", [(4, 4), (4, 2)], ids=["mha", "gqa"])
+def test_contiguous_pool_is_the_identity_chain(nq, nkv, w, kind):
+    """The slot pool as `KVCache` holds it, [L, slots, max_len, nkv, hd],
+    read as blocks of B rows where it lies: every layer of L = 3 against
+    `_dot_attention` over that layer read whole, at lengths on and either
+    side of a block's edge. Everything past what a row's last query may see
+    holds large finite values: none may reach the result."""
+    L, B, nb, hd = 3, 8, 4, 16
+    max_len = B * nb
+    lens = [0, 1, B - 1, B, B + 1, max_len - 1]
+    S = len(lens)
+    rs = np.random.RandomState(9)
+    past = np.arange(max_len)[None, :] > np.asarray(lens)[:, None] + w - 1
+    ks = vs = None
+    if kind == "int8":
+        k = rs.randint(-127, 127, (L, S, max_len, nkv, hd)).astype(np.int8)
+        v = rs.randint(-127, 127, (L, S, max_len, nkv, hd)).astype(np.int8)
+        ks = rs.rand(L, S, max_len, nkv, 1).astype(np.float32) * 0.02
+        vs = rs.rand(L, S, max_len, nkv, 1).astype(np.float32) * 0.02
+        k[:, past], v[:, past] = 127, 127
+        ks[:, past], vs[:, past] = 1e3, 1e3
+        k_ref, v_ref = k.astype(np.float32) * ks, v.astype(np.float32) * vs
+    else:
+        k = rs.randn(L, S, max_len, nkv, hd).astype(np.float32)
+        v = rs.randn(L, S, max_len, nkv, hd).astype(np.float32)
+        k[:, past], v[:, past] = 1e4, 1e4
+        k, v = jnp.asarray(k, jnp.bfloat16), jnp.asarray(v, jnp.bfloat16)
+        k_ref, v_ref = (np.asarray(k.astype(jnp.float32)),
+                        np.asarray(v.astype(jnp.float32)))
+    q = rs.randn(S, w, nq, hd).astype(np.float32)
+    lengths = jnp.asarray(lens, jnp.int32)
+    read = jax.jit(lambda i: contiguous_pool_attention(
+        jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), lengths, layer=i,
+        rows=B, scale=hd ** -0.5,
+        k_scale=None if ks is None else jnp.asarray(ks),
+        v_scale=None if vs is None else jnp.asarray(vs)))
+    for layer in range(L):
+        got = np.asarray(read(jnp.int32(layer)))
+        want = np.asarray(_dot_attention(
+            jnp.asarray(q), jnp.asarray(k_ref[layer]),
+            jnp.asarray(v_ref[layer]), causal=True, softmax_fp32=True,
+            scale=hd ** -0.5, q_offset=lengths))
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+        assert np.all(np.abs(got) < 1e2), "what lies past a length leaked"
 
 
 @pytest.mark.slow
@@ -228,6 +301,6 @@ def test_onchip_shapes_compile_and_match():
     got = np.asarray(block_native_attention(
         jnp.asarray(q), jnp.asarray(ka), jnp.asarray(va),
         jnp.asarray(bmap), jnp.asarray(lengths), scale=hd ** -0.5,
-        block_size=B, interpret=interp))
+        interpret=interp))
     want = ref_block_attention(q, ka, va, bmap, lengths, hd ** -0.5)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)

@@ -125,7 +125,7 @@ def test_block_native_attention(one_chip, w, quant):
     def fn(q, k, v, bmap, lengths, *sc):
         ks, vs = sc if sc else (None, None)
         return block_native_attention(
-            q, k, v, bmap, lengths, scale=HD ** -0.5, block_size=B,
+            q, k, v, bmap, lengths, scale=HD ** -0.5,
             k_scale=ks, v_scale=vs, interpret=False)
     _compile(fn, S((slots, w, NQ, HD), jnp.bfloat16), kv, kv,
              S((slots, nb), jnp.int32), S((slots,), jnp.int32), *scales)
@@ -247,17 +247,17 @@ def test_the_check_sees_a_cast_and_a_slice_of_a_bank(one_chip):
     assert _bank_shaped(text, {(4, 8, 256, 128)})
 
 
-def _olmoe_program(one_chip, monkeypatch, positions):
-    """The HLO of a cached forward of OLMoE-1B-7B's widths at depth 4, 24
-    slots of `positions` tokens, compiled for the chip; and its
-    configuration."""
+def _olmoe_compiled(one_chip, monkeypatch, positions, cap=256, vocab=4096):
+    """A cached forward of OLMoE-1B-7B's widths at depth 4, 24 slots of
+    `positions` tokens over a pool of `cap` positions a slot (donated, as
+    the engine donates it), compiled for the chip; and its configuration."""
     from megatron_tpu.config import olmoe_config
     from megatron_tpu.models import language_model as lm
     from megatron_tpu.inference.generation import init_kv_caches
 
     cfg = olmoe_config("1b-7b", num_layers=4, compute_dtype="bfloat16",
-                       vocab_size=4096, make_vocab_size_divisible_by=128)
-    slots, cap = 24, 256
+                       vocab_size=vocab, make_vocab_size_divisible_by=128)
+    slots = 24
     # the program asks the backend which product to take; this test
     # compiles for the chip from a CPU process
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -274,7 +274,13 @@ def _olmoe_program(one_chip, monkeypatch, positions):
 
     def forward(params, tokens, caches):
         return lm.model_forward(params, tokens, cfg, kv_caches=caches)
-    return jax.jit(forward).lower(params, tokens, caches).compile().as_text(), cfg
+    return jax.jit(forward, donate_argnums=2).lower(
+        params, tokens, caches).compile(), cfg
+
+
+def _olmoe_program(one_chip, monkeypatch, positions):
+    compiled, cfg = _olmoe_compiled(one_chip, monkeypatch, positions)
+    return compiled.as_text(), cfg
 
 
 def _kernel_calls(text, name):
@@ -340,3 +346,45 @@ def test_prefill_program_rounds_a_layers_banks_in_one_pass(one_chip,
     made = _bank_shaped(text, {(4, E, h, 2 * f), (4, E, f, h)})
     assert len(made) == 2 and all("%_moe_round_bank." in line
                                   for line in made), made
+
+
+def test_decode_program_reads_the_kv_pool_where_it_lies(one_chip,
+                                                        monkeypatch):
+    """A decode step at OLMoE's widths over the cell's pool, 24 slots of
+    4,096 positions (16 kv heads of 128 in bf16, 3 GiB over 4 layers): the
+    attention of every layer is ONE call of the block kernel, handed the
+    stacked pool as `[4 * 24 * 32 blocks, 128 rows * 16 heads, 128]` (the
+    carry of the layer loop under another shape: a bitcast) after the
+    in-place write of the step's rows. Nothing else the program makes has a
+    slots axis beside a positions axis: no layer cut out of the pool, no
+    copy of it into the order a kernel wants, none of `_dot_attention`'s
+    scores or products over `[24, 4096, 16, ..]` (PR 36; the parent read a
+    layer whole, twice, 3.2 GB a step). Its temporaries are a few MiB: less
+    than a layer of k by two orders."""
+    compiled, cfg = _olmoe_compiled(one_chip, monkeypatch, positions=1,
+                                    cap=4096, vocab=8192)
+    text = compiled.as_text()
+    L, S, P, nkv, hd = pool = (4, 24, 4096, cfg.num_kv_heads,
+                               cfg.kv_channels)
+    calls = _kernel_calls(text, "block_native_attention")
+    assert len(calls) == 1, [c[:400] for c in calls]
+    view = f"bf16[{L * S * 32},{128 * nkv},{hd}]"
+    assert _as_traced(calls[0]).count(view) == 2, calls[0][:600]
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if not m:
+            continue
+        dims = tuple(int(d) for d in m.group(2).split(",") if d)
+        if dims == pool:
+            # the pool's own shape: handed on, or written in place (a
+            # scatter of the step's rows, fused or not); never a copy
+            assert m.group(3) in ("parameter", "get-tuple-element", "bitcast",
+                                  "scatter") or (
+                m.group(3) == "fusion" and "scatter" in line), line[:300]
+        else:
+            # ([24, 4096] is a row of projections: k and v, 2 x 2048 wide)
+            assert not (S in dims and P in dims and len(dims) > 2), line[:200]
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < S * P * nkv * hd * 2 // 100, memory
+    # arguments + outputs - aliased: the pool is updated where it lies
+    assert memory.alias_size_in_bytes >= 2 * L * S * P * nkv * hd * 2
