@@ -89,6 +89,16 @@ def build_parser(extra_args_provider: Optional[Callable] = None
     g.add_argument("--moe_aux_loss_coeff", type=float, default=1e-2)
     g.add_argument("--moe_dispatch", type=str, default="sort",
                    choices=["sort", "dense", "dropless"])
+    # a pattern of mixers (ModelConfig.layer_types: the published key, as a
+    # comma-separated list) and how many of its leading layers are dense
+    # (the published `num_dense_layers`): what a cut of a preset's depth
+    # has to say with `--num_layers`
+    g.add_argument("--layer_types", default=None,
+                   type=lambda s: tuple(s.split(",")),
+                   help="the mixer of each layer, e.g. "
+                        "conv,full_attention,conv,conv,conv")
+    g.add_argument("--num_dense_layers", type=int, default=0,
+                   dest="first_k_dense_replace")
     g.add_argument("--model", type=str, default=None,
                    help="preset name (llama2-7b, falcon-40b, gpt2, ...)")
 

@@ -15,7 +15,7 @@ from __future__ import annotations
 import dataclasses
 import json
 from dataclasses import dataclass, field
-from typing import Any, Optional, Sequence
+from typing import Any, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 
@@ -193,6 +193,25 @@ class ModelConfig:
     # together) or "average" (the mean of their outputs: the same MLP / n)
     moe_shared_combination: str = "sum"
 
+    # The mixer of each layer (the published `layer_types`): "conv", a gated
+    # short convolution that keeps its last `conv_L_cache` - 1 inputs a
+    # sequence and no keys or values (models/short_conv.py), or
+    # "full_attention". None: every layer is attention. The
+    # `first_k_dense_replace` leading layers (LFM2's `num_dense_layers`)
+    # follow the same pattern. Each group of layers is scanned a period of
+    # the pattern at a time with the parameters stacked by kind, and what
+    # lies past the last whole period runs behind the scan
+    # (models/transformer.py); the cache holds keys and values for the
+    # attention layers alone and the convolutions' state beside them
+    # (models/attention.py::ConvKVCache).
+    layer_types: Optional[Tuple[str, ...]] = None
+    conv_L_cache: int = 3
+    # RMSNorm over each head's channels of q and of k, one scale
+    # [kv_channels] shared by the heads, before the rotary (LFM2's
+    # q_layernorm / k_layernorm). `qk_norm` above is OLMoE's, over all the
+    # heads' channels together.
+    qk_head_norm: bool = False
+
     # glu activations double the first MLP projection
     @property
     def is_glu(self) -> bool:
@@ -229,6 +248,28 @@ class ModelConfig:
             self, window_layer_period=0, sliding_window=None,
             use_rotary_emb=False)
 
+    def layers_of(self, kind: str) -> int:
+        """How many of the model's layers have the mixer `kind` (with no
+        `layer_types`, every layer is "full_attention")."""
+        if self.layer_types is None:
+            return self.num_layers if kind == "full_attention" else 0
+        return self.layer_types.count(kind)
+
+    @property
+    def kv_layers(self) -> int:
+        """The layers that hold a row of keys and values a token for as long
+        as its sequence lives: the full layers of a stack of window and full
+        layers, the attention layers of a pattern of mixers, else all."""
+        if self.window_layer_period:
+            return self.num_layers // self.window_layer_period
+        return self.layers_of("full_attention")
+
+    @property
+    def conv_state_width(self) -> int:
+        """Values one sequence costs one convolution layer, whatever its
+        length: the last `conv_L_cache` - 1 inputs of the depthwise kernel."""
+        return (self.conv_L_cache - 1) * self.hidden_size
+
     def dense_layers(self) -> "ModelConfig":
         """The configuration of the `first_k_dense_replace` leading layers:
         this one with a dense MLP of `dense_ffn_hidden_size`."""
@@ -251,6 +292,9 @@ class ModelConfig:
             f"quantized_gemm must be 'none' or 'int8', "
             f"got {self.quantized_gemm!r}")
         d: dict[str, Any] = {}
+        if self.layer_types is not None \
+                and not isinstance(self.layer_types, tuple):
+            d["layer_types"] = tuple(self.layer_types)  # a JSON list
         if self.num_kv_heads is None:
             d["num_kv_heads"] = self.num_attention_heads
         else:
@@ -904,6 +948,45 @@ class ServingConfig:
                     "rows it would need, the block arena and its kernel "
                     "know one region shape, and the two stacks have no "
                     "stage cut, head shard or adapter bank (ROADMAP R3)")
+        if model is not None and model.layers_of("conv"):
+            # the pool holds a convolution state a slot beside the keys and
+            # values (models/attention.py::ConvKVCache); what a state cannot
+            # do yet is refused by name, not served wrong (ROADMAP R6)
+            cut = ("a state is the last two inputs at the slot's CURRENT "
+                   "length: cutting, cloning or rewinding a slot to a "
+                   "shorter one needs a snapshot of the state taken at the "
+                   "cut")
+            arena = ("the block arena, which the host tier and the handoff "
+                     "move by blocks, has no row for a state")
+            shard = "the state and the depthwise kernel need a channel shard"
+            refused = {
+                "enable_prefix_cache": (self.enable_prefix_cache, cut),
+                "retained_slots": (self.retained_slots, cut),
+                "speculative_k": (self.speculative_k, cut),
+                "preemption": (self.preemption, (
+                    "a parked slot's state has to be read out and put back "
+                    "with its rows: kv_pool.slice_slot cuts keys and values "
+                    "alone")),
+                "kv_block_size": (self.kv_block_size is not None, arena),
+                "block_native_attn": (self.block_native_attn, arena),
+                "host_kv_bytes": (self.host_kv_bytes, arena),
+                "disaggregate_prefill": (self.disaggregate_prefill, arena),
+                "serving_pp": (self.serving_pp > 1, (
+                    "the stages cut ONE stack of identical layers and the "
+                    "arena by layer; the kinds are stacked apart")),
+                "serving_tp": (self.serving_tp > 1, shard),
+                "prefill_tp": ((self.prefill_tp or 1) > 1, shard),
+                "decode_tp": ((self.decode_tp or 1) > 1, shard),
+                "adapter_slots": (self.adapter_slots, (
+                    "the adapter bank is stacked over one kind of layer")),
+                "kv_dtype int8": ((self.kv_dtype or "bfloat16") == "int8", (
+                    "the cache of two kinds of state has no scales")),
+            }
+            for name, (on, why) in refused.items():
+                assert not on, (
+                    f"layer_types with conv layers: {name} is refused on the "
+                    f"pool of keys, values and convolution state: {why} "
+                    "(ROADMAP R6)")
         if model is not None and model.mla:
             # the latent pool is ONE array [layers, slots, positions, row]
             # (models/mla.py::LatentKVCache): no head axis, no k beside v
@@ -1546,11 +1629,13 @@ class MegatronConfig:
                 f"{model.num_layers} a whole number of periods (each: "
                 "period - 1 window layers, then one full layer)")
             assert not model.mla and not model.first_k_dense_replace \
-                and not model.mtp_num_layers, (
+                and not model.mtp_num_layers \
+                and model.layer_types is None, (
                 "window_layer_period is refused with MLA (kv_lora_rank), "
-                "first_k_dense_replace and mtp_num_layers: the period "
-                "scan carries k/v rings and regions and ONE stack "
-                "(ROADMAP R3, R4)")
+                "first_k_dense_replace, mtp_num_layers and layer_types: "
+                "its period scan carries k/v rings and regions and ONE "
+                "stack; a pattern of mixers with a leading dense stack is "
+                "layer_types' scan (ROADMAP R3, R4)")
             assert max(sharded.values()) == 1, (
                 "window_layer_period (window and full attention in one "
                 f"stack) has been made to work on one device only (got "
@@ -1560,6 +1645,43 @@ class MegatronConfig:
                 and model.attention_dropout == 0.0, (
                 "window_layer_period is refused with context-parallel "
                 "attention_impl (ring / ulysses) and attention_dropout")
+        if model.layer_types is not None:
+            # models/transformer.py scans each group a period of the pattern
+            # at a time, the kinds' parameters stacked apart
+            kinds = set(model.layer_types)
+            assert len(model.layer_types) == model.num_layers \
+                and kinds <= {"conv", "full_attention"}, (
+                f"layer_types has {len(model.layer_types)} entries "
+                f"{sorted(kinds)} for num_layers={model.num_layers}: one of "
+                "'conv' | 'full_attention' a layer")
+            assert model.conv_L_cache >= 2, (
+                f"conv_L_cache={model.conv_L_cache}: the kernel's length, "
+                "of which the state keeps all but the newest input")
+            assert not model.mla and not model.mtp_num_layers \
+                and model.sliding_window is None \
+                and not model.parallel_attn and not model.use_post_ln \
+                and not model.use_bias, (
+                "layer_types is refused with MLA (kv_lora_rank), "
+                "mtp_num_layers, sliding_window, parallel_attn, use_post_ln "
+                "and use_bias: the pattern's layers are pre-norm, one mixer "
+                "then one feed-forward, over whole regions of keys and "
+                "values (ROADMAP R6)")
+            assert max(sharded.values()) == 1, (
+                "layer_types (convolution and attention layers in one "
+                f"model) has been made to work on one device only (got "
+                f"{sharded}): the kinds are stacked apart with no stage "
+                "cut, and the convolution has no channel shard "
+                "(ROADMAP R6)")
+            assert model.attention_impl in ("dot", "flash") \
+                and model.attention_dropout == 0.0 \
+                and model.drop_path_rate == 0.0, (
+                "layer_types is refused with context-parallel "
+                "attention_impl (ring / ulysses), attention_dropout and "
+                "drop_path_rate")
+        if model.qk_head_norm:
+            assert not model.qk_norm and not model.mla, (
+                "qk_head_norm (a norm a head) and qk_norm (one over all "
+                "heads) / MLA are different models' norms")
         assert model.moe_shared_combination in ("sum", "average"), (
             f"moe_shared_combination={model.moe_shared_combination!r} "
             "(expected 'sum' or 'average')")
@@ -1930,6 +2052,58 @@ def command_a_config(size: str = "plus", **overrides) -> ModelConfig:
     return ModelConfig(**base).derived()
 
 
+LFM2_LAYER_TYPES = tuple(
+    "full_attention" if l in (2, 6, 10, 14, 18, 21) else "conv"
+    for l in range(24))
+
+
+def lfm2_config(size: str = "8b-a1b", **overrides) -> ModelConfig:
+    """LFM2 presets: every size of "8b-a1b" is a key of
+    LiquidAI/LFM2-8B-A1B's config.json (`lfm2_moe`, 8.3B-A1.5B: 24 layers,
+    hidden 2048; `layer_types` 18 "conv" (a gated short convolution,
+    `conv_L_cache` 3, no bias) and 6 "full_attention" (32 heads of 64 over 8
+    kv heads, RMSNorm a head on q and k, rope_theta 1e6) at layers 2, 6, 10,
+    14, 18 and 21; `num_dense_layers` 2 leading layers with a dense MLP of
+    width 7168 (`intermediate_size`), the others 32 experts of width 1792
+    (`moe_intermediate_size`), 4 a token; sigmoid scoring, `use_expert_bias`
+    (a choosing bias), `norm_topk_prob` true, `routed_scaling_factor` 1, no
+    shared expert; RMSNorm eps 1e-5, SiLU-gated, 128,000 positions,
+    vocabulary 65,536; tied head). Held in bfloat16. Dropless; no auxiliary
+    loss is in the config. A cut of the depth gives its own `layer_types`
+    (`--layer_types`) and `--num_dense_layers`."""
+    presets = {
+        "tiny": dict(num_layers=24, hidden_size=64, num_attention_heads=8,
+                     num_kv_heads=2, kv_channels=8, ffn_hidden_size=32,
+                     dense_ffn_hidden_size=96, vocab_size=512,
+                     seq_length=128, num_experts=8, moe_top_k=2,
+                     attention_impl="dot"),
+        "8b-a1b": dict(num_layers=24, hidden_size=2048,
+                       num_attention_heads=32, num_kv_heads=8,
+                       kv_channels=64, ffn_hidden_size=1792,
+                       dense_ffn_hidden_size=7168, vocab_size=65536,
+                       seq_length=4096, max_position_embeddings=128000,
+                       num_experts=32, moe_top_k=4,
+                       params_dtype="bfloat16"),
+    }
+    if size not in presets:
+        raise ValueError(f"unknown lfm2 size {size!r}; "
+                         f"valid: {sorted(presets)}")
+    base = dict(
+        use_rotary_emb=True, rope_theta=1e6, norm_type="rmsnorm",
+        norm_epsilon=1e-5, activation="swiglu", use_bias=False,
+        use_post_ln=False, parallel_attn=False, tie_embed_logits=True,
+        layer_types=LFM2_LAYER_TYPES, conv_L_cache=3, qk_head_norm=True,
+        first_k_dense_replace=2, moe_scoring_func="sigmoid",
+        moe_routed_scaling_factor=1.0, moe_score_correction_bias=True,
+        moe_norm_topk_prob=True, moe_dispatch="dropless",
+        moe_aux_loss_coeff=0.0,
+        attention_impl="flash",  # see llama2_config
+    )
+    base.update(presets[size])
+    base.update(overrides)
+    return ModelConfig(**base).derived()
+
+
 def gpt_config(**overrides) -> ModelConfig:
     base = dict(
         num_layers=12, hidden_size=768, num_attention_heads=12,
@@ -1957,5 +2131,7 @@ MODEL_PRESETS = {
     "joyai-llm-flash": lambda: joyai_config("llm-flash"),
     "command-a-plus-tiny": lambda: command_a_config("tiny"),
     "command-a-plus": lambda: command_a_config("plus"),
+    "lfm2-8b-a1b-tiny": lambda: lfm2_config("tiny"),
+    "lfm2-8b-a1b": lambda: lfm2_config("8b-a1b"),
     "gpt2": gpt_config,
 }

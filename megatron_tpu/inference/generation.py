@@ -32,7 +32,8 @@ import numpy as np
 from megatron_tpu.config import ModelConfig
 from megatron_tpu.inference.sampling import sample
 from megatron_tpu.models import language_model as lm
-from megatron_tpu.models.attention import HybridKVCache, KVCache
+from megatron_tpu.models.attention import (ConvKVCache, HybridKVCache,
+                                            KVCache)
 from megatron_tpu.utils.tracing import phase
 
 
@@ -122,6 +123,11 @@ def init_kv_caches(cfg: ModelConfig, batch: int, max_len: int,
         # (models/attention.py::HybridKVCache)
         return HybridKVCache.create(cfg, batch, max_len, dtype,
                                     per_slot_offsets=per_slot_offsets)
+    if cfg.layers_of("conv"):
+        # keys and values for the attention layers alone, the convolutions'
+        # state beside them (models/attention.py::ConvKVCache)
+        return ConvKVCache.create(cfg, batch, max_len, dtype,
+                                  per_slot_offsets=per_slot_offsets)
     # rolling-cap decision single-sourced in kv_region_cap (the serving
     # pool's slot_nbytes sizes from the same helper)
     max_len = kv_region_cap(cfg, max_len, prefill_len)
@@ -164,6 +170,11 @@ def prefill_chunk(params, tokens, caches, cfg: ModelConfig, *, rope,
         # a ring takes no padding row
         caches = caches._replace(
             live_end=jnp.asarray(next_offset, jnp.int32))
+    if isinstance(caches, ConvKVCache):
+        # a state is left as it stood after the chunk's last real row; the
+        # chunk starts where every attention layer's offset stands
+        caches = caches._replace(live_rows=jnp.asarray(
+            next_offset, jnp.int32) - caches.offset[0])
     logits, caches = lm.model_forward(
         params, tokens, cfg, kv_caches=caches, rope=rope,
         logits_dtype=jnp.float32, adapters=adapters,
@@ -417,9 +428,10 @@ def beam_search(generator: Generator, prompt: list[int], beam_width: int,
     beam_width by cumulative logprob (length-penalized at finalization,
     matching the reference's scoring)."""
     cfg = generator.cfg
-    assert not cfg.window_layer_period, (
+    assert not cfg.window_layer_period and not cfg.layers_of("conv"), (
         "beam_search reorders one k/v cache by beam: a stack of window and "
-        "full layers (window_layer_period) is refused")
+        "full layers (window_layer_period) and a pattern with convolution "
+        "layers (layer_types) are refused")
     eos = generator.eos_id
     params = generator.params
     rope = generator.rope

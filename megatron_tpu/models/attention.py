@@ -213,6 +213,53 @@ class HybridKVCache(NamedTuple):
             live_end=jnp.int32(HybridKVCache.NO_PADDING))
 
 
+class ConvKVCache(NamedTuple):
+    """The cache of a model whose layers are convolutions and attention
+    (`cfg.layer_types`): two kinds of state side by side, each carried
+    through the layer loop and written in place at its own kind's index.
+
+    - KEYS AND VALUES for the attention layers alone, [attention layers,
+      batch, max_seq, n_kv * hd]: a position's row holds every kv head's
+      channels side by side (`_folded_update_attend` says why the heads'
+      axis is folded away: with heads of 64 channels the device keeps
+      neither `KVCache`'s order nor `HybridKVCache`'s in place).
+    - THE CONVOLUTIONS' STATE, [conv layers, batch, conv_L_cache - 1,
+      hidden]: the last inputs of the depthwise kernel (`a = B * z`,
+      models/short_conv.py), the older first. It costs the same whatever
+      the sequence's length, and no mask hides it: whoever takes a slot
+      writes the whole of its state.
+
+    `live_rows` (scalar or [batch]): how many of the rows given to THIS call
+    are real; those behind them are a bucket's padding. Keys and values of
+    padding rows lie beyond the offset until they are overwritten; a state
+    taken at the end of the rows would be the state after the padding, so a
+    convolution layer leaves the state as it stood after row `live_rows` -
+    1 (the engine's prefill and generation.prefill_chunk set it; it is
+    `NO_PADDING` wherever all the rows given are real)."""
+    k: jax.Array       # [attention layers, batch, max_seq, n_kv * head_dim]
+    v: jax.Array
+    conv: jax.Array    # [conv layers, batch, conv_L_cache - 1, hidden]
+    # tokens already in the cache, one entry an ATTENTION layer: [layers]
+    # or [layers, batch], as KVCache.offset
+    offset: jax.Array
+    live_rows: jax.Array
+
+    NO_PADDING = 2 ** 30
+
+    @staticmethod
+    def create(cfg: ModelConfig, batch: int, max_seq: int,
+               dtype=jnp.bfloat16, per_slot_offsets: bool = False):
+        n_attn = cfg.layers_of("full_attention")
+        kv = (n_attn, batch, max_seq, cfg.num_kv_heads * cfg.kv_channels)
+        return ConvKVCache(
+            k=jnp.zeros(kv, dtype), v=jnp.zeros(kv, dtype),
+            conv=jnp.zeros((cfg.layers_of("conv"), batch,
+                            cfg.conv_L_cache - 1, cfg.hidden_size), dtype),
+            offset=jnp.zeros((n_attn, batch) if per_slot_offsets
+                             else (n_attn,), jnp.int32),
+            live_rows=jnp.int32(ConvKVCache.NO_PADDING))
+
+
 def _layer_of(a, layer):
     """Layer `layer` (a traced scalar) of an array stacked over layers."""
     return jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
@@ -456,6 +503,92 @@ def _hybrid_update_attend(q, k, v, cache: HybridKVCache, layer, kind_layer,
     return out.astype(dtype), cache
 
 
+def _folded_update_attend(q, k, v, cache: ConvKVCache, layer,
+                          cfg: ModelConfig, *, scale: float):
+    """Append this layer's k and v to the keys and values of a `ConvKVCache`
+    and attend. q [b, s, nq, hd], k/v [b, s, nkv, hd] -> (out [b, s, nq, hd],
+    cache); `layer` is the layer's index among the attention layers.
+
+    The pool holds a position's row as [n_kv * hd] values, the kv heads'
+    channels side by side, and the products are taken over that whole row:
+    head h's query is laid into its own kv head's channels of a row of
+    zeros, the scores are one product of [heads, n_kv * hd] with the rows
+    (every other head's channels meet zeros), the weighted sum one product
+    of [heads, positions] with the rows, of which each head keeps its own
+    kv head's channels. That is n_kv times the operations (8 here, tens of
+    GFLOP a decode step of 128 slots: a fraction of a millisecond on the
+    matrix unit) for rows the device holds in the order they are written and
+    read. Why not a heads' axis: with heads of 64 channels the device lays
+    [.., max_seq, hd] out with max_seq minor (64 values would pad a tile of
+    128 lanes), in `KVCache`'s order and `HybridKVCache`'s alike; the chip's
+    compiler then kept the pool in a third order through the layer loop
+    (slots minor, for the writes) and copied all of it in and out of that
+    order in every decode step, and a layer of it into the products' order
+    in every layer: 13.8 GiB counted for the decode program, 3.7 of it
+    temporaries, where this form counts none (compile, PR 37).
+
+    A step writes first and reads the layer back, as `KVCache` does: every
+    slot at its own offset through one scatter, or the rows of a prefill or a
+    chunk at the scalar offset. A prefill at offset 0 under
+    `attention_impl="flash"` attends its own fresh k and v through the flash
+    kernel, as `KVCache`'s does (`lax.cond` on the offset: a continuation
+    chunk takes the products over the region)."""
+    b, s, nq, hd = q.shape
+    nkv = k.shape[2]
+    width, group = nkv * hd, nq // nkv
+    dtype = q.dtype
+    offset = _layer_of(cache.offset, layer)
+    per_slot = jnp.ndim(offset) == 1
+    # what the cache will hold of the new rows: the step attends the values
+    # a later step will read back
+    k_new = k.reshape(b, s, width).astype(cache.k.dtype)
+    v_new = v.reshape(b, s, width).astype(cache.v.dtype)
+    if per_slot:
+        pos = offset[:, None] + jnp.arange(s)[None, :]            # [b, s]
+        rows = jnp.arange(b)[:, None]
+
+        def wr(buf, val):       # past the region: nowhere (a parked row)
+            return buf.at[layer, rows, pos].set(val, mode="drop")
+    else:
+        pos = (offset + jnp.arange(s))[None, :]                   # [1, s]
+
+        def wr(buf, val):
+            return jax.lax.dynamic_update_slice(buf, val[None],
+                                                (layer, 0, offset, 0))
+    new_k, new_v = wr(cache.k, k_new), wr(cache.v, v_new)
+    cache = cache._replace(
+        k=new_k, v=new_v, offset=jax.lax.dynamic_update_index_in_dim(
+            cache.offset, offset + s, layer, 0))
+
+    def over_the_region():
+        keys = _layer_of(new_k, layer).astype(dtype)          # [b, t, width]
+        vals = _layer_of(new_v, layer).astype(dtype)
+        own = (jnp.arange(nq)[:, None] // group
+               == jnp.arange(nkv)[None, :])                   # [nq, nkv]
+        q_wide = (q[..., None, :] * own[:, :, None].astype(dtype)).reshape(
+            b, s, nq, width)
+        scores = jnp.einsum("bshc,btc->bhst", q_wide, keys) * scale
+        if cfg.attention_softmax_in_fp32:
+            scores = scores.astype(jnp.float32)
+        mask = pos[:, :, None] >= jnp.arange(keys.shape[1])[None, None, :]
+        scores = jnp.where(mask[:, None], scores,
+                           jnp.finfo(scores.dtype).min)
+        probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
+        wide = jnp.einsum("bhst,btc->bshc", probs, vals).reshape(
+            b, s, nq, nkv, hd)
+        return jnp.sum(jnp.where(own[:, :, None], wide, 0), axis=3)
+
+    if cfg.attention_impl == "flash" and s > 1 and not per_slot:
+        from megatron_tpu.ops.flash_attention import flash_attention
+        out = jax.lax.cond(
+            offset == 0,
+            lambda: flash_attention(q, k, v, causal=True, scale=scale),
+            over_the_region)
+    else:
+        out = over_the_region()
+    return out.astype(dtype), cache
+
+
 def attention_init(rng, cfg: ModelConfig, dtype=jnp.float32):
     """Params: wq [h, nq*hd], wkv [h, 2*nkv*hd], wo [nq*hd, h]."""
     h = cfg.hidden_size
@@ -477,6 +610,9 @@ def attention_init(rng, cfg: ModelConfig, dtype=jnp.float32):
     if cfg.qk_norm:
         params["q_norm"] = rmsnorm_init(nq * hd, dtype)
         params["k_norm"] = rmsnorm_init(nkv * hd, dtype)
+    if cfg.qk_head_norm:
+        params["q_norm"] = rmsnorm_init(hd, dtype)
+        params["k_norm"] = rmsnorm_init(hd, dtype)
     return params
 
 
@@ -491,6 +627,9 @@ def attention_axes(cfg: ModelConfig):
     if cfg.qk_norm:
         axes.update({"q_norm": {"scale": ("heads",)},
                      "k_norm": {"scale": ("kv_heads",)}})
+    if cfg.qk_head_norm:
+        axes.update({"q_norm": {"scale": (None,)},
+                     "k_norm": {"scale": (None,)}})
     return axes
 
 
@@ -507,6 +646,15 @@ def qk_norm(params, q, k, eps: float):
             flat = x.reshape(*x.shape[:-2], x.shape[-2] * x.shape[-1])
             return rmsnorm(p, flat, eps).reshape(x.shape)
         return whole(params["q_norm"], q), whole(params["k_norm"], k)
+
+
+def qk_head_norm(params, q, k, eps: float):
+    """LFM2's q_layernorm / k_layernorm: RMSNorm over EACH head's channels,
+    one scale [head_dim] shared by the heads, before the rotary. q: [b, s,
+    nq, hd], k: [b, t, nkv, hd]."""
+    with jax.named_scope("mtpu/attn/head_norm"):
+        return (rmsnorm(params["q_norm"], q, eps),
+                rmsnorm(params["k_norm"], k, eps))
 
 
 def _dot_attention(q, k, v, *, causal: bool, softmax_fp32: bool,
@@ -620,8 +768,9 @@ def attention_apply(
     """Forward pass. x: [b, s, h]. Returns (out [b, s, h], new_kv_cache).
 
     `kind_layer`: with a `HybridKVCache` (a stack of window and full
-    layers; `cfg` is then the layer's own kind), the layer's index in its
-    kind's stack, beside `cache_layer`, its index in the model.
+    layers; `cfg` is then the layer's own kind) or a `ConvKVCache`
+    (convolution and attention layers), the layer's index in its kind's
+    stack, beside `cache_layer`, its index in the model.
 
     `kv_cache` is the cache STACKED over layers (KVCache or BlockKVCache)
     and `cache_layer` this layer's index in it (a traced scalar inside
@@ -643,6 +792,10 @@ def attention_apply(
     shape, with row 0 the identity (zero) adapter so base rows ride the
     same trace. Indices are data: adapters on keeps one compile per
     program; adapters=None compiles to exactly today's graph."""
+    if isinstance(kv_cache, ConvKVCache):
+        # keys, values and offsets lie at the layer's index among the
+        # attention layers
+        cache_layer = kind_layer
     b, s, h = x.shape
     hd = cfg.kv_channels
     nq = cfg.num_attention_heads
@@ -716,6 +869,9 @@ def attention_apply(
     if cfg.qk_norm:
         assert not cross, "qk_norm is self-attention's (OLMoE)"
         q, k = qk_norm(params, q, k, cfg.norm_epsilon)
+    if cfg.qk_head_norm:
+        assert not cross, "qk_head_norm is self-attention's (LFM2)"
+        q, k = qk_head_norm(params, q, k, cfg.norm_epsilon)
 
     if isinstance(kv_cache, HybridKVCache) and s == 1:
         # a decode step's q and k stay the projections' outputs: left free,
@@ -749,6 +905,20 @@ def attention_apply(
                              else "mtpu/attn/full"):
             out, kv_cache = _hybrid_update_attend(
                 q, k, v, kv_cache, cache_layer, kind_layer, cfg,
+                scale=1.0 / math.sqrt(hd))
+        out = out.reshape(b, s, nq * hd)
+        return _project(out, params["wo"], cfg,
+                        read_once=read_once), kv_cache
+    if isinstance(kv_cache, ConvKVCache):
+        assert causal and not cross and segment_ids is None \
+            and lw is None and not cfg.use_bias and not dropout_active \
+            and cfg.sliding_window is None, (
+            "a pattern of convolution and attention layers serves causal "
+            "self-attention over whole regions, without bias, adapters or "
+            "segments")
+        with jax.named_scope("mtpu/attn/folded"):
+            out, kv_cache = _folded_update_attend(
+                q, k, v, kv_cache, cache_layer, cfg,
                 scale=1.0 / math.sqrt(hd))
         out = out.reshape(b, s, nq * hd)
         return _project(out, params["wo"], cfg,

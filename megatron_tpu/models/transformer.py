@@ -47,8 +47,11 @@ RESIDUAL_AXES = ("batch", "seq_sp", "act_embed")
 # ---------------------------------------------------------------------------
 
 def layer_init(rng, cfg: ModelConfig, dtype=jnp.float32,
-               cross_attn: bool = False):
-    """Norm layout mirrors ref: transformer.py:606-633 —
+               cross_attn: bool = False, mixer: str = "full_attention"):
+    """`mixer` "conv": a gated short convolution (`params["conv"]`,
+    models/short_conv.py) where the others have `params["attention"]`.
+
+    Norm layout mirrors ref: transformer.py:606-633 —
     pre-LN: input_layernorm + post_attention_layernorm (output_layernorm=Id);
     post-LN: input_layernorm=Id, post_attention_layernorm + output_layernorm;
     parallel_attn drops post_attention_layernorm; parallel_layernorm adds a
@@ -59,15 +62,15 @@ def layer_init(rng, cfg: ModelConfig, dtype=jnp.float32,
         mlp_params = moe_init(k_mlp, cfg, dtype)
     else:
         mlp_params = mlp_init(k_mlp, cfg, dtype)
-    if cfg.mla:
+    if mixer == "conv":
+        from megatron_tpu.models.short_conv import short_conv_init
+        params = {"conv": short_conv_init(k_attn, cfg, dtype)}
+    elif cfg.mla:
         from megatron_tpu.models.mla import mla_init
-        attn_params = mla_init(k_attn, cfg, dtype)
+        params = {"attention": mla_init(k_attn, cfg, dtype)}
     else:
-        attn_params = attention_init(k_attn, cfg, dtype)
-    params = {
-        "attention": attn_params,
-        "mlp": mlp_params,
-    }
+        params = {"attention": attention_init(k_attn, cfg, dtype)}
+    params["mlp"] = mlp_params
     if cross_attn:
         # decoder cross-attention + its input norm
         # (ref: transformer.py:664-683,782-794)
@@ -85,21 +88,22 @@ def layer_init(rng, cfg: ModelConfig, dtype=jnp.float32,
     return params
 
 
-def layer_axes(cfg: ModelConfig, cross_attn: bool = False):
+def layer_axes(cfg: ModelConfig, cross_attn: bool = False,
+               mixer: str = "full_attention"):
     if cfg.num_experts > 1:
         from megatron_tpu.models.moe import moe_axes
         mlp_ax = moe_axes(cfg)
     else:
         mlp_ax = mlp_axes(cfg)
-    if cfg.mla:
+    if mixer == "conv":
+        from megatron_tpu.models.short_conv import short_conv_axes
+        axes = {"conv": short_conv_axes(cfg)}
+    elif cfg.mla:
         from megatron_tpu.models.mla import mla_axes
-        attn_ax = mla_axes(cfg)
+        axes = {"attention": mla_axes(cfg)}
     else:
-        attn_ax = attention_axes(cfg)
-    axes = {
-        "attention": attn_ax,
-        "mlp": mlp_ax,
-    }
+        axes = {"attention": attention_axes(cfg)}
+    axes["mlp"] = mlp_ax
     if cross_attn:
         axes["inter_attention"] = attention_axes(cfg)
         axes["post_inter_norm"] = norm_axes(cfg.norm_type)
@@ -137,6 +141,7 @@ def layer_apply(
     encoder_output=None,
     cp_pre_zigzag: bool = False,
     adapters=None,
+    mixer: str = "full_attention",
 ):
     """One transformer layer. x: [b, s, h]. Returns (x, kv_cache, aux) —
     `aux` is the MoE router's load-balancing loss (0.0 for dense MLPs).
@@ -148,7 +153,9 @@ def layer_apply(
     the layers of their own stack and read at `bank_layer` (models/moe.py::
     split_stacked_banks), which is `cache_layer` where the model has one
     stack. `kind_layer`: in a stack of window and full layers
-    (`_period_stack_apply`), the layer's index in its own kind's cache stack.
+    (`_period_stack_apply`) or of convolution and attention layers
+    (`_pattern_stack_apply`; `mixer` says which this one is), the layer's
+    index in its own kind's cache stack.
 
     `adapters`: (per-layer LoraAdapter bank, adapter_idx [b]) for the
     SELF-attention projections only (multi-tenant LoRA serving —
@@ -202,7 +209,15 @@ def layer_apply(
     else:
         ln_out = apply_norm(cfg.norm_type, params["input_norm"], x, eps)
 
-    if cfg.mla:
+    if mixer == "conv":
+        from megatron_tpu.models.short_conv import short_conv_apply
+        assert causal and encoder_output is None and adapters is None \
+            and segment_ids is None and not cp_pre_zigzag, (
+            "a convolution layer is causal, unsharded, over one document")
+        attn_out, kv_cache = short_conv_apply(
+            params["conv"], ln_out, cfg, kv_cache=kv_cache,
+            kind_layer=kind_layer)
+    elif cfg.mla:
         from megatron_tpu.models.mla import mla_apply
         assert causal and encoder_output is None and adapters is None \
             and not cp_pre_zigzag, "MLA is causal self-attention, unsharded"
@@ -267,6 +282,14 @@ def stack_init(rng, cfg: ModelConfig, num_layers: Optional[int] = None,
     (`cfg.first_k_dense_replace`) has two stacks, {"dense", "moe"}."""
     n = num_layers if num_layers is not None else cfg.num_layers
     k = cfg.first_k_dense_replace
+    if cfg.layer_types is not None:
+        assert num_layers is None and not cross_attn
+        return {name: {
+            kind: jax.vmap(lambda key, kind=kind: layer_init(
+                key, group_cfg, dtype, mixer=kind))(jax.random.split(
+                    jax.random.fold_in(rng, i), kinds.count(kind)))
+            for i, kind in enumerate(sorted(set(kinds)))}
+            for name, group_cfg, kinds, rng in _pattern_groups(cfg, rng)}
     if k:
         assert num_layers is None and not cross_attn
         r_dense, r_moe = jax.random.split(rng)
@@ -279,12 +302,47 @@ def stack_init(rng, cfg: ModelConfig, num_layers: Optional[int] = None,
 
 def stack_axes(cfg: ModelConfig, cross_attn: bool = False):
     """Logical axes for stacked params: prepend 'layers'."""
+    stacked = lambda per_layer: jax.tree.map(  # noqa: E731
+        lambda ax: ("layers",) + ax, per_layer,
+        is_leaf=lambda x: isinstance(x, tuple))
+    if cfg.layer_types is not None:
+        return {name: {kind: stacked(layer_axes(group_cfg, mixer=kind))
+                       for kind in sorted(set(kinds))}
+                for name, group_cfg, kinds, _ in _pattern_groups(cfg)}
     if cfg.first_k_dense_replace:
         return {"dense": stack_axes(cfg.dense_layers()),
                 "moe": stack_axes(cfg.expert_layers())}
-    per_layer = layer_axes(cfg, cross_attn=cross_attn)
-    return jax.tree.map(lambda ax: ("layers",) + ax, per_layer,
-                        is_leaf=lambda x: isinstance(x, tuple))
+    return stacked(layer_axes(cfg, cross_attn=cross_attn))
+
+
+def _pattern_groups(cfg: ModelConfig, rng=None):
+    """The groups of a model with `cfg.layer_types`, in order: (name, the
+    group's configuration, its layers' mixers, its share of `rng`). One
+    group "layers" where every layer has the same feed-forward, else "dense"
+    (the `first_k_dense_replace` leading layers) and "moe". A group's
+    parameters are stacked BY KIND, {"conv": ..., "full_attention": ...},
+    each in the model's order: the kinds' trees differ."""
+    k, types = cfg.first_k_dense_replace, cfg.layer_types
+    if not k:
+        return [("layers", cfg, types, rng)]
+    rngs = (None, None) if rng is None else jax.random.split(rng)
+    return [("dense", cfg.dense_layers(), types[:k], rngs[0]),
+            ("moe", cfg.expert_layers(), types[k:], rngs[1])]
+
+
+def _pattern_period(kinds):
+    """(P, n): the first n * P of `kinds` are n >= 1 repeats of its first P
+    entries, chosen to cover the most layers with n >= 2 (the shortest such
+    P), else one period of them all."""
+    best = (len(kinds), 1)
+    covered = 0
+    for P in range(1, len(kinds) // 2 + 1):
+        n = 1
+        while kinds[n * P:(n + 1) * P] == kinds[:P]:
+            n += 1
+        if n >= 2 and n * P > covered:
+            best, covered = (P, n), n * P
+    return best
 
 
 def lima_dropout_rates(cfg: ModelConfig, num_layers: int):
@@ -342,6 +400,16 @@ def stack_apply(
     index is layer-invariant and closes over the body. None compiles to
     exactly today's graph (multi-tenant LoRA serving,
     models/attention.py)."""
+    if cfg.layer_types is not None:
+        assert layer_offset == 0 and adapters is None \
+            and encoder_output is None and not cp_pre_zigzag and causal \
+            and segment_ids is None, (
+            "a pattern of convolution and attention layers has no pipeline "
+            "stage, no adapter bank, no encoder and one document a row")
+        return _pattern_stack_apply(
+            stacked_params, x, cfg, rope_cos=rope_cos, rope_sin=rope_sin,
+            position_ids=position_ids, kv_caches=kv_caches, rng=rng,
+            deterministic=deterministic)
     if cfg.window_layer_period:
         assert layer_offset == 0 and adapters is None \
             and encoder_output is None and not cp_pre_zigzag and causal, (
@@ -502,4 +570,90 @@ def _period_stack_apply(stacked_params, x, cfg: ModelConfig, *, rope_cos,
     (x, aux, kv_caches), _ = jax.lax.scan(
         body, (x, jnp.zeros((), jnp.float32), kv_caches),
         (by_period, drop_rates, jnp.arange(periods)))
+    return x, kv_caches, aux
+
+
+def _pattern_stack_apply(stacked_params, x, cfg: ModelConfig, *, rope_cos,
+                         rope_sin, position_ids, kv_caches, rng,
+                         deterministic):
+    """`stack_apply` for a model whose layers' mixers follow a pattern
+    (`cfg.layer_types`: convolutions and attention). Group by group
+    (`_pattern_groups`: the leading dense layers, then the expert layers),
+    ONE `lax.scan` over the whole PERIODS of the group's pattern
+    (`_pattern_period`); what lies past the last whole period (a published
+    tail off the period) runs behind the scan, layer by layer: right, not
+    fast. The kinds' parameters are stacked apart, so the scan takes a
+    period's worth of each kind, [periods, layers of the kind a period,
+    ...], and the body applies the period's layers in order. The cache
+    (`attention.ConvKVCache`) is the loop's carry and goes down whole with
+    the layer's index among its own kind in the MODEL; the dropless experts'
+    banks go down whole beside it, each kind's own, with the layer's index
+    among its kind in the GROUP."""
+    rates = lima_dropout_rates(cfg, cfg.num_layers)
+    cached = kv_caches is not None
+    aux = jnp.zeros((), jnp.float32)
+    first = 0                       # the group's first layer in the model
+    for name, group_cfg, kinds, _ in _pattern_groups(cfg):
+        params = stacked_params[name]
+        banks = {kind: None for kind in params}
+        if cached and group_cfg.num_experts > 1:
+            from megatron_tpu.models.moe import split_stacked_banks
+            for kind in list(params):
+                banks[kind], rest = split_stacked_banks(
+                    params[kind]["mlp"], group_cfg)
+                params = {**params, kind: {**params[kind], "mlp": rest}}
+        # layers of each kind ahead of this group: where its cache rows start
+        ahead = {kind: cfg.layer_types[:first].count(kind)
+                 for kind in params}
+
+        def apply_one(h, caches, layer_params, kind, lid, at):
+            """Layer `lid` of the model, the `at`-th of its kind in the
+            group."""
+            layer_rng = None
+            if rng is not None and not deterministic:
+                layer_rng = jax.random.fold_in(rng, lid)
+            return layer_apply(
+                layer_params, h, group_cfg, mixer=kind, rope_cos=rope_cos,
+                rope_sin=rope_sin, position_ids=position_ids,
+                kv_cache=caches, cache_layer=lid if cached else None,
+                kind_layer=ahead[kind] + at if cached else None,
+                expert_banks=banks[kind],
+                bank_layer=at if cached else None, layer_number=lid + 1,
+                hidden_dropout=rates[lid], rng=layer_rng,
+                deterministic=deterministic)
+
+        P, periods = _pattern_period(kinds)
+        per = {kind: kinds[:P].count(kind) for kind in params}
+
+        def body(carry, scanned):
+            h, aux_sum, caches = carry
+            by_kind, period = scanned
+            for j, kind in enumerate(kinds[:P]):
+                jk = kinds[:j].count(kind)
+                h, caches, a = apply_one(
+                    h, caches, jax.tree.map(lambda t: t[jk], by_kind[kind]),
+                    kind, first + period * P + j, period * per[kind] + jk)
+                aux_sum = aux_sum + a
+            return (h, aux_sum, caches), None
+
+        if cfg.recompute_granularity == "full":
+            body = jax.checkpoint(body, prevent_cse=False)
+        elif cfg.recompute_granularity == "selective":
+            body = jax.checkpoint(
+                body, prevent_cse=False,
+                policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+        by_period = {
+            kind: jax.tree.map(
+                lambda t: t[:periods * per[kind]].reshape(
+                    periods, per[kind], *t.shape[1:]), params[kind])
+            for kind in params if per[kind]}
+        (x, aux, kv_caches), _ = jax.lax.scan(
+            body, (x, aux, kv_caches), (by_period, jnp.arange(periods)))
+        for i in range(periods * P, len(kinds)):        # the tail
+            kind, at = kinds[i], kinds[:i].count(kinds[i])
+            x, kv_caches, a = apply_one(
+                x, kv_caches, jax.tree.map(lambda t: t[at], params[kind]),
+                kind, first + i, at)
+            aux = aux + a
+        first += len(kinds)
     return x, kv_caches, aux

@@ -106,9 +106,12 @@ def _tiling(m: int, k: int, n: int, itemsize: int = 2):
     Columns fill what is left of the kernel's 16 MiB with two buffers of the
     bank's tile AS THE BANK IS HELD (`itemsize`): 4 MiB each, 1024 columns
     of a bf16 bank at k = 2048 and 512 of a float32 one, whose rounded copy
-    takes 2 MiB more."""
+    takes 2 MiB more. Fewer columns than the bank has are a whole number of
+    lanes (128), which the chip's compiler asks of a block: at k = 1792
+    (LFM2's second product) 4 MiB are 1,170 columns, and the tile is 1,152."""
     tk = min(k, 2048)
-    return (128 if m <= 4096 else 256, tk, min(n, (4 << 20) // (tk * itemsize)))
+    return (128 if m <= 4096 else 256, tk,
+            min(n, (4 << 20) // (tk * itemsize) // 128 * 128))
 
 
 def _backward_tiling(m: int, k: int, n: int):

@@ -366,14 +366,17 @@ class ServingEngine:
                 retained_limit=self.serving.retained_slots,
                 block_size=self.serving.kv_block_size)
         # every program closes over the rotary tables as constants. On a
-        # pool of rings and regions they are cut to this engine's
-        # positions: a published context of 200,000 rows made each
-        # serialised program 283 MB where 32,768 are served, more than the
-        # chip's compile cache takes (PERF.md section 6, PR 33). Every
-        # other pool keeps the generator's, and so the programs it had
+        # pool of rings and regions, and on one with a convolution state,
+        # they are cut to this engine's positions: a published context of
+        # 200,000 rows made each serialised program 283 MB where 32,768 are
+        # served, more than the chip's compile cache takes (PERF.md section
+        # 6, PR 33; 128,000 rows, 33 MB in each of thirteen programs, kept
+        # every run of PR 37's cell cold). Every other pool keeps the
+        # generator's, and so the programs it had
         # (tests/test_jaxpr_unchanged.py; ROADMAP S22)
         self._rope = generator.rope
-        if self.pool.hybrid and self._rope is not None:
+        if (self.pool.hybrid or self.pool.conv_layers) \
+                and self._rope is not None:
             self._rope = type(self._rope)(
                 *(t[:self.max_len] for t in self._rope))
         if self._pp > 1:
@@ -2694,6 +2697,10 @@ class ServingEngine:
         if self.pool.hybrid:
             # a ring takes no padding row (attention.HybridKVCache)
             caches = caches._replace(live_end=plens)
+        elif self.pool.conv_layers:
+            # a state is left as it stood after each row's own last real
+            # token, not after the bucket's padding (attention.ConvKVCache)
+            caches = caches._replace(live_rows=plens)
         # the head on each row's last real position alone where the whole
         # bucket's logits would not fit (generation.whole_logits_fit)
         whole = whole_logits_fit(*tokens.shape, self.cfg)

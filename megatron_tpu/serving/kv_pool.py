@@ -54,8 +54,8 @@ import numpy as np
 from megatron_tpu.config import ModelConfig
 from megatron_tpu.inference.generation import (KV_CACHE_AXES, init_kv_caches,
                                                kv_region_cap)
-from megatron_tpu.models.attention import (BlockKVCache, HybridKVCache,
-                                            KVCache)
+from megatron_tpu.models.attention import (BlockKVCache, ConvKVCache,
+                                            HybridKVCache, KVCache)
 from megatron_tpu.models.mla import LatentKVCache
 from megatron_tpu.utils.logging import print_rank_0
 
@@ -89,6 +89,18 @@ def insert_prefill(pool: KVCache, prefill: KVCache, slot, plen) -> KVCache:
                       getattr(prefill, f).astype(getattr(pool, f).dtype),
                       start5)
                for f in ("ring_k", "ring_v", "full_k", "full_v")})
+    if isinstance(pool, ConvKVCache):
+        # the slot's convolution state WHOLE, beside its keys and values: no
+        # mask hides a state, so what the slot's last tenant left goes here
+        start4 = (zero, slot, zero, zero)
+        return pool._replace(
+            k=dus(pool.k, prefill.k.astype(pool.k.dtype), start4),
+            v=dus(pool.v, prefill.v.astype(pool.v.dtype), start4),
+            conv=dus(pool.conv, prefill.conv.astype(pool.conv.dtype),
+                     start4),
+            offset=dus(pool.offset,
+                       jnp.full((pool.offset.shape[0], 1), plen, jnp.int32),
+                       (zero, slot)))
     if isinstance(pool, LatentKVCache):
         return LatentKVCache(
             c=dus(pool.c, prefill.c.astype(pool.c.dtype),
@@ -128,6 +140,11 @@ def slice_slot(pool: KVCache, slot, offset) -> KVCache:
         "a slot of rings cannot be cut out at a shorter length: the rows "
         "of its earlier positions are gone (ServingConfig.validate refuses "
         "prefix cache and preemption on window_layer_period)")
+    assert not isinstance(pool, ConvKVCache), (
+        "a slot with a convolution state cannot be cut out at a shorter "
+        "length: the state is the one at the slot's current length "
+        "(ServingConfig.validate refuses prefix cache, retained slots and "
+        "preemption on layer_types with conv layers)")
     if isinstance(pool, LatentKVCache):
         L, _, row, cap = pool.c.shape
         return LatentKVCache(
@@ -154,7 +171,8 @@ def batch_row(caches, i: int):
             x, i, 1, axis=1)
     return caches._replace(**{f: row(getattr(caches, f))
                               for f in caches._fields
-                              if f not in ("offset", "live_end")})
+                              if f not in ("offset", "live_end",
+                                           "live_rows")})
 
 
 def clone_prefix(pool: KVCache, src_slot, dst_slot, plen) -> KVCache:
@@ -392,6 +410,9 @@ class SlotKVPool:
                 "kv_block_size is refused on a pool of rings and regions "
                 "(ServingConfig.validate)")
             self.cap, self.rolling = max_len, False
+        assert not (self.conv_layers and block_size is not None), (
+            "kv_block_size is refused on a pool with a convolution state "
+            "(ServingConfig.validate)")
         if block_size is not None and block_size >= self.cap:
             # whole-region blocks ARE the regions — EXCEPT on rolling
             # pools, where block mode is what makes retention possible
@@ -479,6 +500,19 @@ class SlotKVPool:
     def hybrid(self) -> bool:
         """Rings and whole regions side by side (`cfg.window_layer_period`)."""
         return bool(self.cfg.window_layer_period)
+
+    @property
+    def conv_layers(self) -> int:
+        """Layers that keep a convolution state a slot and no keys or values
+        (`cfg.layer_types`; models/attention.py::ConvKVCache)."""
+        return self.cfg.layers_of("conv")
+
+    @property
+    def kv_layers(self) -> int:
+        """Layers that hold a row a token for as long as its sequence lives
+        (`ModelConfig.kv_layers`): every byte count a token below is over
+        these."""
+        return self.cfg.kv_layers
 
     def make_prefill_caches(self, batch: int = 1) -> KVCache:
         """A fresh request-local cache in the POOL's layout (same cap /
@@ -928,10 +962,16 @@ class SlotKVPool:
             return self.ring_nbytes() + self.full_nbytes()
         if isinstance(c, LatentKVCache):
             return c.c.nbytes
+        if isinstance(c, ConvKVCache):
+            return c.k.nbytes + c.v.nbytes + c.conv.nbytes
         n = c.k.nbytes + c.v.nbytes
         if c.k_scale is not None:
             n += c.k_scale.nbytes + c.v_scale.nbytes
         return n
+
+    def conv_state_nbytes(self) -> int:
+        """Bytes of the convolutions' state (0 where the pool has none)."""
+        return self.caches.conv.nbytes if self.conv_layers else 0
 
     def ring_nbytes(self) -> int:
         """Bytes of the window layers' rings (0 where the pool has none)."""
@@ -943,12 +983,12 @@ class SlotKVPool:
         """Bytes of the whole regions: the full layers' of a pool of two
         kinds, else the whole pool."""
         if not self.hybrid:
-            return self.nbytes()
+            return self.nbytes() - self.conv_state_nbytes()
         return self.caches.full_k.nbytes + self.caches.full_v.nbytes
 
     def bytes_per_slot(self) -> int:
         """What one slot reserves, whatever it holds: its regions and,
-        where the pool has them, its rings."""
+        where the pool has them, its rings or its convolution state."""
         return self.nbytes() // self.num_slots
 
     def view_nbytes(self) -> int:
@@ -960,10 +1000,10 @@ class SlotKVPool:
         still what a bracket WOULD move)."""
         if self.hybrid:
             return self.nbytes()
-        n = (self.cfg.num_layers * self.num_slots * self.cap
+        n = (self.kv_layers * self.num_slots * self.cap
              * self.cfg.kv_row_width * self.dtype.itemsize)
         if self.dtype == jnp.dtype(jnp.int8):
-            n += 2 * (self.cfg.num_layers * self.num_slots * self.cap
+            n += 2 * (self.kv_layers * self.num_slots * self.cap
                       * self.cfg.num_kv_heads) * 4  # fp32 scales
         return n
 
@@ -976,12 +1016,11 @@ class SlotKVPool:
         that is what this counts: its rows in the rings are reserved with
         the slot (`bytes_per_slot`, `ring_nbytes`) and cost the same
         whatever the sequence's length, so they are no part of what a
-        shorter sequence leaves unused (`kv_bytes_wasted`)."""
-        layers = (self.cfg.num_layers // self.cfg.window_layer_period
-                  if self.hybrid else self.cfg.num_layers)
-        n = layers * self.cfg.kv_row_width * self.dtype.itemsize
+        shorter sequence leaves unused (`kv_bytes_wasted`). A convolution
+        layer holds no row at all: its state, too, is the slot's."""
+        n = self.kv_layers * self.cfg.kv_row_width * self.dtype.itemsize
         if self.dtype == jnp.dtype(jnp.int8):
-            n += 2 * self.cfg.num_layers * self.cfg.num_kv_heads * 4
+            n += 2 * self.kv_layers * self.cfg.num_kv_heads * 4
         return n
 
     def kv_gauges(self, lengths) -> Tuple[int, int, int]:
@@ -1047,9 +1086,12 @@ def slot_nbytes(cfg: ModelConfig, max_len: int,
     cap = kv_region_cap(cfg, max_len)
     if block_size is not None and block_size < cap:
         cap = -(-cap // block_size) * block_size
-    n = cfg.num_layers * cap * cfg.kv_row_width * jnp.dtype(dtype).itemsize
+    n = cfg.kv_layers * cap * cfg.kv_row_width * jnp.dtype(dtype).itemsize
     if jnp.dtype(dtype) == jnp.dtype(jnp.int8):
-        n += 2 * (cfg.num_layers * cap * cfg.num_kv_heads) * 4  # fp32 scales
+        n += 2 * (cfg.kv_layers * cap * cfg.num_kv_heads) * 4  # fp32 scales
+    # a convolution layer's state, whatever the length
+    n += (cfg.layers_of("conv") * cfg.conv_state_width
+          * jnp.dtype(dtype).itemsize)
     return n
 
 

@@ -86,6 +86,11 @@ traced under it; models/moe.py, models/attention.py and models/mla.py):
 | `mtpu/attn/qk_norm` | the RMSNorm over the whole q and the whole k projection (`qk_norm`) |
 | `mtpu/attn/window` | a window layer of a stack of two kinds over its ring (`attention.HybridKVCache`): the ring turned into time order, the flash kernel or the scores over ring + chunk, the rows' write over the oldest; a decode step's write and read of a layer of rings |
 | `mtpu/attn/full` | a full layer of such a stack over its whole region: the write at the offset, the flash kernel or the scores over the region up to the chunk's end; a decode step's write and read of a layer of regions |
+| `mtpu/attn/head_norm` | the RMSNorm over each head's channels of q and of k (`qk_head_norm`) |
+| `mtpu/conv/in_proj` | a convolution layer's first product, rows x [h, 3h]: the gates B and C and the input z (`models/short_conv.py`) |
+| `mtpu/conv/state` | the read of the layer's state (the kernel's last inputs, a row each slot) ahead of the rows, and the write of the state after the call's last real row, one update in place a layer |
+| `mtpu/conv/mix` | B * z, the depthwise taps over [state ; rows] accumulated in float32, times C |
+| `mtpu/conv/out_proj` | the layer's second product, rows x [h, h] |
 | `mtpu/moe/shared` | the shared experts' MLP, added beside the routed sum (`n_shared_experts`) |
 | `mtpu/mla/q` | latent attention's query: down-projection, norm, up-projection, the rotary on its rope part |
 | `mtpu/mla/latent` | the latent row: down-projection, norm over kv_lora_rank, the rotary on the shared key, the write into the cache |
@@ -97,7 +102,9 @@ Counters of the serving metrics' snapshot that a benchmark reader takes:
 `.nbytes()` as the pool counts them, pushed once when the engine builds it
 (`serve_kv_bytes_per_token`); beside them `kv_bytes_per_slot`, `kv_ring_bytes`
 and `kv_full_bytes` (`.bytes_per_slot()`, `.ring_nbytes()`, `.full_nbytes()`:
-what a slot reserves, and the pool's bytes by kind; `serve_kv_bytes_per_slot`).
+what a slot reserves, and the pool's bytes by kind; `serve_kv_bytes_per_slot`)
+and `conv_state_bytes` (`.conv_state_nbytes()`: the convolution layers' state
+of every slot; a slot's share of it is `serve_state_bytes_per_slot`).
 `prefill_chunks` counts the chunk programs dispatched. The rows a share's held
 experts took (`moe_rows_held` of ISSUE 33) are NOT counted by the program: no
 serving program hands a scalar out of the layer loop, and the benchmark counts

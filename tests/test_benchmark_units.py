@@ -7,8 +7,10 @@ import pytest
 pytest.register_assert_rewrite(
     "benchmark.tests.test_yardstick", "benchmark.tests.test_program_spans",
     "benchmark.tests.test_moe_roofline", "benchmark.tests.test_reference",
-    "benchmark.tests.test_moe_share_roofline")
+    "benchmark.tests.test_moe_share_roofline",
+    "benchmark.tests.test_conv_kinds")
 
+from benchmark.tests.test_conv_kinds import *  # noqa: E402,F401,F403
 from benchmark.tests.test_moe_roofline import *  # noqa: E402,F401,F403
 from benchmark.tests.test_moe_share_roofline import *  # noqa: E402,F401,F403
 from benchmark.tests.test_program_spans import *  # noqa: E402,F401,F403
