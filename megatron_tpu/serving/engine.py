@@ -30,6 +30,22 @@ TPU-native:
   lengths keep cached device copies re-uploaded only on slot churn,
   and queued same-length-bucket admissions coalesce into one batched
   prefill call (`prefill_max_batch`).
+- The first token leaves with its prefill. A prefill writes the
+  prompt's last logits and the request's key into its slot's row; the
+  head of the next decode step draws the token. `_step` draws it ahead
+  of that step with one small program on the same arrays
+  (`_draw_ahead`: it reads and returns, no key advances), queues the
+  step behind it, fetches the draw and hands each token to its request
+  (`_deliver_first`: `first_token_time`, `wait_token(0)`) while the
+  step runs. The step draws the same token from the same key and
+  `_commit` checks it, appends nothing twice and runs everything else
+  on it. It engages in every window whose first round is a plain
+  decode round, by what the engine sees and by no option; a window
+  that opens with a speculative verify round, a row whose drawn
+  log-probability is not finite or whose grammar is at a dead end, a
+  session the watchdog flagged and a preemption resume (it has its
+  tokens) are left to the commit whole. Counters:
+  `first_tokens_early`, `first_token_mismatches` (stays 0).
 - Prefix-cache KV reuse (`enable_prefix_cache`, SGLang's
   RadixAttention made slot-grid native): finished slots RETAIN their
   KV on an LRU list (serving/kv_pool.py) and a host-side radix index
@@ -173,6 +189,26 @@ def _burned_key(seed, burn):
 # the device, uncommitted, and feeds _insert / _prefill as it is
 _burned_key_jit = jax.jit(_burned_key)
 _burned_keys_jit = jax.jit(jax.vmap(_burned_key))
+
+
+def _draw_ahead(last_logits, rngs, temps, top_ks, top_ps, rejects, masks,
+                *, vocab_size):
+    """What the head of the next plain decode step will draw for every
+    row of the grid, and the chosen token's log-probability under the raw
+    logits: `_decode_fn`'s own first lines on the same arrays. It reads
+    and returns; the keys advance and the state moves in the decode step
+    alone, which draws the same tokens again (`sample_batched` is
+    row-for-row bit-identical, so a grid cut into waves draws them too)."""
+    step_keys = jax.vmap(jax.random.split)(rngs)[:, 1]
+    toks = sample_batched(step_keys, last_logits, temperature=temps,
+                          top_k=top_ks, top_p=top_ps, vocab_size=vocab_size,
+                          banned=rejects, mask=masks)
+    lp = jax.nn.log_softmax(last_logits, axis=-1)
+    return toks, jnp.take_along_axis(lp, toks[:, None], axis=-1)[:, 0]
+
+
+# one shape an engine (the whole grid), so its first prefill compiles it
+_draw_ahead_jit = jax.jit(_draw_ahead, static_argnames=("vocab_size",))
 
 
 class EngineHungError(RuntimeError):
@@ -4392,7 +4428,20 @@ class ServingEngine:
                     histories, self.drafter, spec_k, K)
         if self._attend_rows:
             self._count_kv_blocks(spec_round, spec_k)
+        # rows prefilled since the last window hold no token yet. Where
+        # round 0 is a plain decode round their first token is drawn
+        # ahead of it and handed over while it runs; a verify round draws
+        # its window sample its own way and keeps them for the commit
+        fresh = [] if spec_round[0] else [
+            int(s) for s in np.nonzero(self._active)[0]
+            if not self._slot_req[s].generated]
         with span("serve/step.dispatch"):
+            if fresh:
+                # ahead of the decode dispatch, which donates what it reads
+                drawn = _draw_ahead_jit(
+                    self._last_logits, self._rngs, self._d_temps,
+                    self._d_top_ks, self._d_top_ps, self._d_reject,
+                    self._d_masks, vocab_size=self.cfg.vocab_size)
             # adapter bank args: the stacked factor pytree + per-slot rows
             # (None/None with adapters off — the empty-pytree args lower to
             # exactly the pre-adapter graph)
@@ -4433,19 +4482,56 @@ class ServingEngine:
                 self._d_reject = out[-1]
                 tok_steps.append(out[3])
                 lp_steps.append(out[4])
+        early = {}
+        if fresh:
+            with span("serve/step.first"):
+                # returns when the prefill and the draw are done: the
+                # window is queued behind them and the device goes on
+                early = self._deliver_first(fresh, *jax.device_get(drawn))
         with span("serve/step.fetch"):
             fetched = self._fetch(
                 (tok_steps, lp_steps,
                  [x for x in acc_steps if x is not None], self._d_reject))
         with span("serve/step.commit") as sp:
             sp.set_metadata(
-                tokens=self._commit(fetched, K, spec_round, grids))
+                tokens=self._commit(fetched, K, spec_round, grids, early))
         return K
 
-    def _commit(self, fetched, K: int, spec_round, grids) -> int:
+    def _append_token(self, req: GenRequest, tok: int, lp: float):
+        """Append one token; a request's first also records its TTFT
+        and counts it against the TTFT SLO."""
+        first = not req.generated
+        req.append_token(tok, lp)
+        if first:
+            self.metrics.record_first_token(req.ttft)
+            if self._slo_ttft_s is not None \
+                    and req.ttft > self._slo_ttft_s:
+                self.metrics.count("slo_ttft_violations")
+
+    def _deliver_first(self, fresh, toks, lps) -> dict:
+        """Hand each fresh row's first token to its request ahead of the
+        window that commits it. Only what `_commit` would append: a row
+        whose log-probability is not finite or whose grammar is at a dead
+        end, and every row of a session the watchdog flagged, is left to
+        `_commit` whole. Returns {slot: token} of the rows delivered."""
+        early = {}
+        if self._wedged:
+            return early
+        for slot in fresh:
+            tok, lp = int(toks[slot]), float(lps[slot])
+            if tok >= 0 and math.isfinite(lp):
+                self._append_token(self._slot_req[slot], tok, lp)
+                early[slot] = tok
+        self.metrics.count("first_tokens_early", len(early))
+        return early
+
+    def _commit(self, fetched, K: int, spec_round, grids, early) -> int:
         """The host's half of a decode window, after the fetch: append
         each slot's tokens in order, step its FSM, evict what finished,
-        set the gauges. Returns the tokens delivered."""
+        set the gauges. A row in `early` has its first token already
+        (`_deliver_first`): round 0's token is that token, checked and
+        not appended again, and everything else runs on it as on any
+        other. Returns the tokens delivered."""
         self.metrics.count("host_syncs")
         if self._wedged:
             # the watchdog flagged THIS iteration while it was in
@@ -4478,7 +4564,7 @@ class ServingEngine:
         for slot in active_slots:
             req = self._slot_req[slot]
             done = False
-            had_tokens = len(req.generated)
+            had_tokens = len(req.generated) - (slot in early)
             for r in range(K):
                 if done:
                     break
@@ -4542,13 +4628,25 @@ class ServingEngine:
                             kind="grammar")
                         done = True
                         break
-                    first = not req.generated
-                    req.append_token(tok, lp)
-                    if first:
-                        self.metrics.record_first_token(req.ttft)
-                        if self._slo_ttft_s is not None \
-                                and req.ttft > self._slo_ttft_s:
-                            self.metrics.count("slo_ttft_violations")
+                    if r == 0 and j == 0 and slot in early:
+                        if tok != early[slot]:
+                            # the step drew another token than the one
+                            # already handed over: the stream cannot be
+                            # both, so the request fails
+                            self.metrics.count("first_token_mismatches")
+                            if K - 1:
+                                self.metrics.count("wasted_decode_steps",
+                                                   K - 1)
+                            self._evict(
+                                slot,
+                                failed=("first token mismatch: "
+                                        f"{early[slot]} was delivered "
+                                        f"ahead of the step that drew "
+                                        f"{tok}"))
+                            done = True
+                            break
+                    else:
+                        self._append_token(req, tok, lp)
                     self._lengths[slot] += 1
                     consumed[r] += 1
                     if j > 0:

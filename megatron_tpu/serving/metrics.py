@@ -59,6 +59,13 @@ _BASE_COUNTERS = (
     # regions it would have read whole: read / held is the share of the
     # pool's bytes a step moves. 0 / 0 where every region is read whole
     "kv_blocks_read", "kv_blocks_held",
+    # first tokens handed to their requests ahead of the decode window
+    # that commits them (engine._deliver_first): over the requests
+    # admitted less the resumes, the share of first tokens that waited
+    # for no decode step. first_token_mismatches = windows that then
+    # drew another token than the one handed over (the request fails;
+    # stays 0)
+    "first_tokens_early", "first_token_mismatches",
     "prefill_calls", "prefill_prompts",
     # prefix cache / chunked prefill (docs/serving.md):
     # prefix_hit_tokens counts tokens MATCHED at lookup (including

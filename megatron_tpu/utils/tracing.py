@@ -56,10 +56,11 @@ Serving (`serving/engine.py`, engine thread unless said):
 | `mtpu/serve/prefill` | each `_prefill_group` call (host arrays, the group's sampling keys as one compiled call, `_initial_rngs`, then the dispatch), child of `admit` | `n`, `padded`, `rid` of the first |
 | `mtpu/serve/prefill_chunk` | `_advance_prefill` when it dispatches, `_activate_pending` included | `rid`, `tokens`+ |
 | `mtpu/serve/swap` | `_apply_swap` | |
-| `mtpu/serve/step` | `_step`; parent of the five below | `active`, `K`+ |
+| `mtpu/serve/step` | `_step`; parent of the six below | `active`, `K`+ |
 | `mtpu/serve/step.upload` | the dirty sampling / mask / lengths / adapter-row uploads | |
 | `mtpu/serve/step.draft` | `build_draft_rounds` (only entered with `speculative_k`) | |
-| `mtpu/serve/step.dispatch` | the chain of K `_decode` / `_verify` calls | |
+| `mtpu/serve/step.dispatch` | the chain of K `_decode` / `_verify` calls, behind the draw of the fresh rows' first tokens (`_draw_ahead`) in a window that has any | |
+| `mtpu/serve/step.first` | only in a window with fresh rows whose round 0 is a plain decode round: the fetch of that draw (it returns when the prefill and the draw are done, the window queued behind them) and `_deliver_first`, which hands each token to its request | |
 | `mtpu/serve/step.fetch` | `self._fetch(...)`: the host waits, the device works | |
 | `mtpu/serve/step.commit` | `_commit`, everything after the fetch: per-slot token append, FSM, evictions, gauges, writer | `tokens`+ |
 | `mtpu/serve/submit` | `submit()`, on the caller's thread | `rid`+ |

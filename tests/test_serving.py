@@ -3717,3 +3717,235 @@ class TestHostKVTier:
             finally:
                 eng.close()
         assert outs[0] == outs[1 << 22]
+
+
+class TestFirstTokenAhead:
+    """A freshly prefilled request's first token is drawn ahead of the
+    decode window that commits it and handed over before that window's
+    fetch. It is the token the window draws again: streams and
+    log-probabilities are those of an engine that hands nothing over
+    ahead (the commit appends every token, as before), and of the serial
+    path. Engines here are driven by hand, one `_iteration` a call, so
+    that both arms batch alike."""
+
+    GREEDY = SamplingOptions(temperature=0.0)
+    DRAWN = SamplingOptions(temperature=0.9, top_k=5)
+    DIGITS = {"type": "regex", "pattern": "[0-9]{2,6}"}
+    P4, Q4 = [5, 17, 3, 42], [7, 8, 9, 10]
+
+    @staticmethod
+    def _drive(eng, reqs, until=lambda r: r.done()):
+        for _ in range(400):
+            if all(until(r) for r in reqs):
+                return
+            eng._iteration()
+        raise AssertionError("the engine made no end of it")
+
+    def _together(self, jobs):
+        def run(eng):
+            reqs = [eng.submit(p, n, s, seed=seed, **kw)
+                    for p, n, s, seed, kw in jobs]
+            self._drive(eng, reqs)
+            return reqs
+        return run
+
+    def _in_turn(self, jobs):
+        def run(eng):
+            reqs = []
+            for p, n, s, seed, kw in jobs:
+                reqs.append(eng.submit(p, n, s, seed=seed, **kw))
+                self._drive(eng, reqs)
+            return reqs
+        return run
+
+    def _poisoned(self, jobs):
+        def run(eng):
+            from megatron_tpu.resilience import (FaultInjector,
+                                                 use_fault_injector)
+            # the first step's second active row carries NaN logits
+            with use_fault_injector(FaultInjector(serve_nan_calls={1: 1})):
+                return self._together(jobs)(eng)
+        return run
+
+    def _dead_ended(self, eng):
+        """Two grammar rows in one prefill; the second one's mask is
+        emptied under it before the first window: the sentinel row."""
+        reqs = [eng.submit(self.P4, 6, SamplingOptions(temperature=1.0),
+                           seed=seed, response_format=self.DIGITS)
+                for seed in (3, 4)]
+        eng._admit()
+        eng._masks[eng._slot_req.index(reqs[1]), :] = False
+        eng._masks_dirty = True
+        self._drive(eng, reqs)
+        return reqs
+
+    def _preempted(self, eng):
+        victim = eng.submit(self.P4, 10, self.DRAWN, seed=9, priority=0)
+        self._drive(eng, [victim], until=lambda r: len(r.generated) >= 2)
+        hp = eng.submit(self.Q4[:3], 4, self.DRAWN, seed=11, priority=1)
+        self._drive(eng, [victim, hp])
+        assert victim.preemptions == 1
+        return [victim, hp]
+
+    def _cases(self, gen):
+        """name -> (gen, ServingConfig fields, scenario, first tokens
+        expected ahead, counters expected, {request index: error})."""
+        G, D = self.GREEDY, self.DRAWN
+        long = np.random.RandomState(3)
+        p20, p33 = (long.randint(1, 96, n).tolist() for n in (20, 33))
+        shared = list(range(5, 21))
+        first = int(gen.generate([self.P4], 1, sampling=SamplingParams(
+            temperature=0.0))[0][0, len(self.P4)])
+        ends_at_once = Generator(gen.params, gen.cfg, eos_id=first,
+                                 pad_id=0)
+        two = [(self.P4, 6, G, 0, {}), (self.Q4, 6, D, 11, {})]
+        return {
+            "batch_of_two": (gen, {}, self._together(two), 2,
+                             {"prefill_calls": 1}, {}),
+            "chunked_last_chunk": (
+                gen, dict(prefill_chunk=8),
+                self._together([(p20, 6, D, 50, {}), (p33, 6, G, 0, {})]),
+                2, {"prefill_chunks": 3 + 5}, {}),
+            "prefix_hit": (
+                gen, dict(enable_prefix_cache=True),
+                self._in_turn([(shared + [70, 80], 6, D, 300, {}),
+                               (shared + [71, 81], 6, D, 301, {})]),
+                2, {"prefix_hits": 1}, {}),
+            "sync_interval_2": (gen, dict(decode_sync_interval=2),
+                                self._together(two), 2, {}, {}),
+            "one_token": (
+                gen, {}, self._together([(self.P4, 1, G, 0, {}),
+                                         (self.Q4, 1, D, 11, {})]),
+                2, {"requests_completed": 2}, {}),
+            "eos_first": (ends_at_once, {},
+                          self._together([(self.P4, 6, G, 0, {})]), 1,
+                          {"requests_completed": 1}, {}),
+            "grammar_and_dead_end": (
+                gen, {}, self._dead_ended, 1,
+                {"grammar_dead_ends": 1, "structured_requests": 2},
+                {1: "dead end"}),
+            "nonfinite_row": (gen, {}, self._poisoned(two), 1,
+                              {"nonfinite_logit_fails": 1},
+                              {1: "non-finite"}),
+            # three activations, two of them fresh
+            "preemption_resume": (
+                gen, dict(num_slots=1, priority_levels=2,
+                          preemption=True),
+                self._preempted, 2, {"preemptions": 1}, {}),
+            # every row drafts from its repeats: the first window is a
+            # verify round, which draws its own way
+            "speculative_verify_first": (
+                gen, dict(speculative_k=2),
+                self._together([([5, 6, 7, 5, 6, 7, 5, 6], 8, G, 0, {}),
+                                ([9, 2, 9, 2, 9, 2], 8, G, 0, {})]),
+                0, {"spec_rounds": 2}, {}),
+            # nothing to draft from: the window falls back to a plain
+            # decode round
+            "speculative_fallback": (
+                gen, dict(speculative_k=2),
+                self._together([(self.P4, 1, D, 11, {})]), 1,
+                {"spec_rounds": 0, "spec_fallback_steps": 1}, {}),
+        }
+
+    @pytest.mark.parametrize("case", [
+        "batch_of_two", "chunked_last_chunk", "prefix_hit",
+        "sync_interval_2", "one_token", "eos_first",
+        "grammar_and_dead_end", "nonfinite_row", "preemption_resume",
+        "speculative_verify_first", "speculative_fallback"])
+    def test_first_token_is_the_windows_own_and_leaves_before_it(
+            self, tiny_model, case):
+        params, cfg = tiny_model
+        gen, serving, scenario, n_ahead, counters, errors = self._cases(
+            Generator(params, cfg, eos_id=-1, pad_id=0))[case]
+        serving = dict(dict(num_slots=3, max_queue=16, max_len=64),
+                       **serving)
+        arms = {}
+        for ahead in (True, False):
+            eng = ServingEngine(gen, ServingConfig(**serving), start=False)
+            handed = []       # (request, its tokens, clock) at each commit
+            try:
+                if ahead:
+                    commit = eng._commit
+
+                    def spy(*args, eng=eng, commit=commit):
+                        handed.extend(
+                            (eng._slot_req[s], list(
+                                eng._slot_req[s].generated),
+                             time.monotonic()) for s in args[-1])
+                        return commit(*args)
+                    eng._commit = spy
+                else:
+                    eng._deliver_first = lambda fresh, toks, lps: {}
+                reqs = scenario(eng)
+                arms[ahead] = (reqs, eng.metrics.snapshot())
+            finally:
+                eng.close()
+            if not ahead:
+                continue
+            # in the requests' hands before their window's commit began
+            assert len(handed) == n_ahead
+            for req, tokens, t_commit in handed:
+                assert tokens == req.generated[:1] and len(tokens) == 1
+                assert req.first_token_time <= t_commit
+        (reqs, snap), (ref_reqs, ref_snap) = arms[True], arms[False]
+        assert snap["first_tokens_early"] == n_ahead
+        assert ref_snap["first_tokens_early"] == 0
+        assert snap["first_token_mismatches"] == 0
+        for name, want in counters.items():
+            assert snap[name] == want, name
+        for name in ("tokens_generated", "decode_steps", "host_syncs",
+                     "requests_completed", "requests_failed",
+                     "spec_rounds", "spec_fallback_steps",
+                     "accepted_tokens", *counters):
+            assert snap[name] == ref_snap[name], name
+        for i, (req, ref) in enumerate(zip(reqs, ref_reqs)):
+            assert req.generated == ref.generated, i
+            assert req.gen_logprobs == ref.gen_logprobs, i
+            assert len(req.generated) <= req.max_new_tokens
+            if i in errors:
+                assert errors[i] in str(req.error), req.error
+                assert not req.generated     # left to the commit, whole
+                continue
+            assert req.error is None and req.generated
+            if req.fsm is not None:
+                continue                     # the serial path has no grammar
+            s = req.sampling
+            want, lens, _ = gen.generate(
+                [req.prompt], req.max_new_tokens,
+                sampling=SamplingParams(temperature=s.temperature,
+                                        top_k=s.top_k, top_p=s.top_p),
+                seed=req.seed)
+            assert req.prompt + req.generated == \
+                want[0, :lens[0]].tolist(), i
+
+    def test_a_window_that_draws_another_token_fails_that_request_alone(
+            self, tiny_model):
+        """The guard behind the hand-over: were the window ever to draw
+        another token than the one already in the request's hands, the
+        stream could not be both, so that request fails and is counted;
+        its neighbour goes on."""
+        params, cfg = tiny_model
+        gen = Generator(params, cfg, eos_id=-1, pad_id=0)
+        eng = ServingEngine(gen, ServingConfig(num_slots=3, max_queue=16,
+                                               max_len=64), start=False)
+        try:
+            deliver = eng._deliver_first
+
+            def other_token(fresh, toks, lps):
+                early = deliver(fresh, toks, lps)
+                first = min(early)
+                early[first] = (early[first] + 1) % cfg.vocab_size
+                return early
+            eng._deliver_first = other_token
+            bad, good = self._together(
+                [(self.P4, 6, self.GREEDY, 0, {}),
+                 (self.Q4, 6, self.DRAWN, 11, {})])(eng)
+            snap = eng.metrics.snapshot()
+        finally:
+            eng.close()
+        assert "first token mismatch" in str(bad.error)
+        assert len(bad.generated) == 1
+        assert good.error is None and len(good.generated) == 6
+        assert snap["first_token_mismatches"] == 1
+        assert snap["first_tokens_early"] == 2
+        assert snap["requests_failed"] == 1

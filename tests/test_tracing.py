@@ -21,6 +21,7 @@ from megatron_tpu.inference.generation import Generator
 from megatron_tpu.models import language_model as lm
 from megatron_tpu.serving import SamplingOptions, ServingEngine
 from megatron_tpu.training import loop as loop_mod
+from megatron_tpu.utils import tracing
 from megatron_tpu.utils.tracing import start_trace
 
 SERVE = "mtpu/serve/"
@@ -119,9 +120,12 @@ def names(spans):
 def test_plain_engine_writes_every_span_its_path_runs(plain_run):
     want = {SERVE + n for n in (
         "idle_wait", "iteration", "reap", "admit", "prefill", "step",
-        "step.upload", "step.dispatch", "step.fetch", "step.commit",
-        "submit")}
+        "step.upload", "step.dispatch", "step.first", "step.fetch",
+        "step.commit", "submit")}
     assert want <= names(plain_run["spans"])
+    # every span the engine loop writes is a row of the module's table
+    assert all(f"| `{n}` |" in tracing.__doc__
+               for n in names(plain_run["spans"]) if n.startswith(SERVE))
     # what this path never enters is not there
     assert not {SERVE + "prefill_chunk", SERVE + "step.draft",
                 SERVE + "swap"} & names(plain_run["spans"])
@@ -178,7 +182,8 @@ def test_step_and_prefill_spans_lie_inside_an_iteration_on_its_line(
     for parent, kids in ((SERVE + "admit", (SERVE + "prefill",)),
                          (SERVE + "step", tuple(
                              SERVE + "step." + k for k in (
-                                 "upload", "dispatch", "fetch", "commit")))):
+                                 "upload", "dispatch", "first", "fetch",
+                                 "commit")))):
         ps = [s for s in spans if s[1] == parent]
         for _, name, t0, t1, _ in (s for s in spans if s[1] in kids):
             assert any(p0 <= t0 and t1 <= p1 for _, _, p0, p1, _ in ps), name
@@ -191,12 +196,25 @@ def test_dispatch_fetch_commit_are_in_order_in_every_step(plain_run):
     spans = plain_run["spans"]
     steps = [s for s in spans if s[1] == SERVE + "step"]
     assert steps
+    older = [SERVE + "step." + k for k in (
+        "upload", "dispatch", "fetch", "commit")]
+    with_first = 0
     for _, _, s0, s1, _ in steps:
         kids = sorted((t0, n) for _, n, t0, t1, _ in spans
                       if n.startswith(SERVE + "step.")
                       and s0 <= t0 and t1 <= s1)
-        assert [n for _, n in kids] == [SERVE + "step." + k for k in (
-            "upload", "dispatch", "fetch", "commit")]
+        got = [n for _, n in kids]
+        # a window that follows a prefill hands the first tokens over
+        # between its dispatch and its fetch; the others are as they were
+        if SERVE + "step.first" in got:
+            with_first += 1
+            assert got.index(SERVE + "step.first") == 2
+            got.remove(SERVE + "step.first")
+        assert got == older
+    prefills = sum(1 for s in spans if s[1] == SERVE + "prefill")
+    assert 1 <= with_first <= prefills
+    assert with_first == sum(1 for s in spans
+                             if s[1] == SERVE + "step.first")
 
 
 def test_tokens_are_the_same_with_and_without_a_session(tiny_gen, plain_run):
