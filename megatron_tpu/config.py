@@ -66,6 +66,21 @@ class ModelConfig:
     # linear position-interpolation scaling (ref: --rope_scaling_factor,
     # megatron/model/positional_embeddings.py:10-12)
     rope_scaling_factor: float = 1.0
+    # "linear": positions divided by `rope_scaling_factor`. "yarn"
+    # (models/rope.py::yarn_freqs, the published `rope_scaling` block as
+    # DeepSeek-V2/V3's modelling code reads it): the pairs that turn more
+    # than `rope_beta_fast` times over `rope_original_max_position` keep
+    # their frequency, those that turn fewer than `rope_beta_slow` times are
+    # interpolated by 1 / rope_scaling_factor, the ones between are blended;
+    # cos and sin carry m(factor, rope_mscale) / m(factor,
+    # rope_mscale_all_dim), and MLA's softmax scale m(factor,
+    # rope_mscale_all_dim)^2 where that is not 0 (m(s, a) = 0.1 a ln s + 1)
+    rope_scaling_type: str = "linear"
+    rope_original_max_position: Optional[int] = None
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
     # learned absolute position embedding (GPT/BERT style, ref: language_model.py:155-163)
     use_position_embedding: bool = False
 
@@ -211,6 +226,19 @@ class ModelConfig:
     # q_layernorm / k_layernorm). `qk_norm` above is OLMoE's, over all the
     # heads' channels together.
     qk_head_norm: bool = False
+
+    # Manifold-constrained hyper-connections (models/hyper_connections.py;
+    # the published `hc_mult`, `hc_sinkhorn_iters`, `hc_eps`,
+    # `mhc_h_res_clamp_min/max` = -/+ hc_res_clamp). The residual is
+    # `hc_mult` streams of hidden_size a token; each sublayer reads one
+    # mixed stream, writes its output back over all of them and mixes them
+    # among themselves by a doubly stochastic matrix made for every token by
+    # `hc_sinkhorn_iters` Sinkhorn rounds. 1: the one-stream residual, and
+    # none of that code is reached.
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: float = 30.0
 
     # glu activations double the first MLP projection
     @property
@@ -987,6 +1015,15 @@ class ServingConfig:
                     f"layer_types with conv layers: {name} is refused on the "
                     f"pool of keys, values and convolution state: {why} "
                     "(ROADMAP R6)")
+        if model is not None and model.hc_mult > 1:
+            assert not self.adapter_slots and self.serving_pp == 1 \
+                and max(self.serving_tp, self.prefill_tp or 1,
+                        self.decode_tp or 1) == 1, (
+                f"hc_mult={model.hc_mult} (hyper-connections): "
+                "adapter_slots (LoRA adapter banks) and a serving mesh "
+                "(serving_tp / prefill_tp / decode_tp / serving_pp > 1) are "
+                "refused: the adapter scan and a stage's `layer_offset` "
+                "have not been run under a residual of streams")
         if model is not None and model.mla:
             # the latent pool is ONE array [layers, slots, positions, row]
             # (models/mla.py::LatentKVCache): no head axis, no k beside v
@@ -1682,6 +1719,64 @@ class MegatronConfig:
             assert not model.qk_norm and not model.mla, (
                 "qk_head_norm (a norm a head) and qk_norm (one over all "
                 "heads) / MLA are different models' norms")
+        assert model.rope_scaling_type in ("linear", "yarn"), (
+            f"rope_scaling_type={model.rope_scaling_type!r} "
+            "(expected 'linear' or 'yarn')")
+        if model.rope_scaling_type == "yarn":
+            assert model.use_rotary_emb and model.rope_original_max_position \
+                and model.rope_scaling_factor >= 1.0, (
+                "rope_scaling_type 'yarn' needs rotary positions, "
+                "rope_original_max_position and rope_scaling_factor >= 1")
+            assert model.mla or not model.rope_mscale_all_dim, (
+                "rope_mscale_all_dim acts on MLA's softmax scale "
+                "(models/mla.py) and on no other attention's: set it to 0 "
+                "or kv_lora_rank")
+        if model.hc_mult > 1:
+            # models/hyper_connections.py round both sublayers of
+            # transformer.layer_apply; each refusal says what it would need
+            assert model.hc_sinkhorn_iters >= 1 and model.hc_eps > 0 \
+                and model.hc_res_clamp > 0, (
+                f"hc_mult={model.hc_mult} needs hc_sinkhorn_iters >= 1, "
+                "hc_eps > 0 and hc_res_clamp > 0")
+            refused = {
+                "parallel_attn": (
+                    model.parallel_attn,
+                    "one block of two sublayers on one input has no second "
+                    "read of the streams to map"),
+                "use_post_ln": (
+                    model.use_post_ln,
+                    "a norm behind the residual sum would have to be a norm "
+                    "of every stream"),
+                "layer_types": (
+                    model.layer_types is not None,
+                    "the pattern scan's carry and a convolution's state "
+                    "have not been run under a residual of streams"),
+                "window_layer_period": (
+                    bool(model.window_layer_period),
+                    "the period scan's carry has not been run under a "
+                    "residual of streams"),
+                "drop_path_rate": (
+                    model.drop_path_rate > 0.0,
+                    "dropping a sublayer would have to drop its mixing "
+                    "matrix too (H_res to the identity), which is not "
+                    "written"),
+                "pipeline_parallel": (
+                    par.pipeline_parallel > 1,
+                    "a stage's `layer_offset` would carry hc_mult streams "
+                    "over the stage boundary, and the expand and collapse "
+                    "belong to the first and the last stage alone"),
+                "tensor_parallel / context_parallel / data_parallel": (
+                    max(par.tensor_parallel, par.context_parallel,
+                        par.data_parallel or (par.derive_dp(n_devices)
+                                              if n_devices else 1)) > 1,
+                    "the maps' [hc_mult x hidden, ...] product and the "
+                    "streams have no sharding rule, and sequence parallel "
+                    "would split the norm over hc_mult x hidden"),
+            }
+            for name, (on, why) in refused.items():
+                assert not on, (
+                    f"hc_mult={model.hc_mult} (hyper-connections): {name} "
+                    f"is refused: {why}")
         assert model.moe_shared_combination in ("sum", "average"), (
             f"moe_shared_combination={model.moe_shared_combination!r} "
             "(expected 'sum' or 'average')")
@@ -2003,6 +2098,67 @@ def joyai_config(size: str = "llm-flash", **overrides) -> ModelConfig:
     return ModelConfig(**base).derived()
 
 
+def xing_config(size: str = "29b-a4b", **overrides) -> ModelConfig:
+    """Xing4.0 presets: every size of "29b-a4b" is a key of
+    XingChen-AGI/Xing4.0-29B-A4B's config.json (`xing4_0`: 40 layers, hidden
+    3584, 32 heads, MLA with q_lora_rank 768, kv_lora_rank 512,
+    qk_nope_head_dim 128, qk_rope_head_dim 64, v_head_dim 128; layers 0 and
+    1 dense of width 9216 (`intermediate_size`, `first_k_dense_replace` 2),
+    the others 64 experts of width 1024 (`moe_intermediate_size`), 4 a
+    token, beside 1 shared expert; sigmoid scoring, `topk_method` noaux_tc
+    (a choosing bias), `norm_topk_prob` true, `routed_scaling_factor` 2;
+    RMSNorm eps 1e-6, SiLU-gated, no bias; rope_theta 10,000 under YaRN
+    (`rope_scaling`: factor 64 over 4,096 original positions, beta_fast 32,
+    beta_slow 1, mscale 1, mscale_all_dim 1), 262,144 positions; vocabulary
+    131,072, untied head; one multi-token-prediction module; and the
+    residual of `hc_mult` 4 streams mixed by manifold-constrained
+    hyper-connections, `hc_sinkhorn_iters` 20, `hc_eps` 1e-6,
+    `mhc_h_res_clamp_min/max` -/+30, models/hyper_connections.py). Held in
+    bfloat16. Dropless; no auxiliary loss is in the config. A cut of the
+    depth says how many of its leading layers are dense
+    (`--num_dense_layers`)."""
+    presets = {
+        "tiny": dict(num_layers=5, hidden_size=64, num_attention_heads=4,
+                     q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16,
+                     qk_rope_head_dim=16, v_head_dim=16, kv_channels=16,
+                     ffn_hidden_size=32, dense_ffn_hidden_size=128,
+                     vocab_size=512, seq_length=128, num_experts=8,
+                     moe_top_k=2, rope_scaling_factor=4.0,
+                     rope_original_max_position=32, attention_impl="dot"),
+        "29b-a4b": dict(num_layers=40, hidden_size=3584,
+                        num_attention_heads=32, num_kv_heads=32,
+                        q_lora_rank=768, kv_lora_rank=512,
+                        qk_nope_head_dim=128, qk_rope_head_dim=64,
+                        v_head_dim=128, kv_channels=64,
+                        ffn_hidden_size=1024, dense_ffn_hidden_size=9216,
+                        vocab_size=131072, seq_length=4096,
+                        max_position_embeddings=262144,
+                        num_experts=64, moe_top_k=4,
+                        rope_scaling_factor=64.0,
+                        rope_original_max_position=4096,
+                        params_dtype="bfloat16"),
+    }
+    if size not in presets:
+        raise ValueError(f"unknown xing size {size!r}; "
+                         f"valid: {sorted(presets)}")
+    base = dict(
+        use_rotary_emb=True, rope_theta=10000.0, rope_scaling_type="yarn",
+        rope_beta_fast=32.0, rope_beta_slow=1.0, rope_mscale=1.0,
+        rope_mscale_all_dim=1.0, norm_type="rmsnorm", norm_epsilon=1e-6,
+        activation="swiglu", use_bias=False, use_post_ln=False,
+        parallel_attn=False, tie_embed_logits=False,
+        first_k_dense_replace=2, n_shared_experts=1,
+        moe_scoring_func="sigmoid", moe_routed_scaling_factor=2.0,
+        moe_score_correction_bias=True, moe_norm_topk_prob=True,
+        moe_dispatch="dropless", moe_aux_loss_coeff=0.0, mtp_num_layers=1,
+        hc_mult=4, hc_sinkhorn_iters=20, hc_eps=1e-6, hc_res_clamp=30.0,
+        attention_impl="flash",  # see llama2_config
+    )
+    base.update(presets[size])
+    base.update(overrides)
+    return ModelConfig(**base).derived()
+
+
 def command_a_config(size: str = "plus", **overrides) -> ModelConfig:
     """Command A+ presets: every size of "plus" is a key of
     CohereLabs/command-a-plus-05-2026's config.json (`cohere2_moe`,
@@ -2129,6 +2285,8 @@ MODEL_PRESETS = {
     "olmoe-1b-7b": lambda: olmoe_config("1b-7b"),
     "joyai-llm-flash-tiny": lambda: joyai_config("tiny"),
     "joyai-llm-flash": lambda: joyai_config("llm-flash"),
+    "xing4.0-29b-a4b-tiny": lambda: xing_config("tiny"),
+    "xing4.0-29b-a4b": lambda: xing_config("29b-a4b"),
     "command-a-plus-tiny": lambda: command_a_config("tiny"),
     "command-a-plus": lambda: command_a_config("plus"),
     "lfm2-8b-a1b-tiny": lambda: lfm2_config("tiny"),

@@ -25,9 +25,10 @@ import jax
 import jax.numpy as jnp
 
 from megatron_tpu.config import ModelConfig
+from megatron_tpu.models import hyper_connections as hc
 from megatron_tpu.models import transformer as tfm
 from megatron_tpu.models.norms import apply_norm, norm_axes, norm_init
-from megatron_tpu.models.rope import precompute_freqs
+from megatron_tpu.models.rope import precompute_freqs, yarn_freqs
 from megatron_tpu.ops.cross_entropy import cross_entropy_loss
 from megatron_tpu.ops.dropout import dropout
 from megatron_tpu.parallel.sharding import constrain
@@ -113,10 +114,16 @@ def mtp_logits(params, hidden, next_tokens, cfg: ModelConfig, *, rope,
         [apply_norm(cfg.norm_type, mtp["enorm"], e, eps),
          apply_norm(cfg.norm_type, mtp["hnorm"], hidden, eps)], axis=-1)
     x = x @ mtp["eh_proj"].astype(x.dtype)
+    if cfg.hc_mult > 1:
+        # the module's one block has the trunk's residual: its input in
+        # every stream, its output the streams' sum
+        x = hc.expand(x, cfg)
     x, _, aux = tfm.layer_apply(
         mtp["layer"], x, cfg.expert_layers(), rope_cos=rope.cos,
         rope_sin=rope.sin, position_ids=position_ids,
         segment_ids=segment_ids, layer_number=cfg.num_layers + 1)
+    if cfg.hc_mult > 1:
+        x = hc.collapse(x, cfg)
     logits = head_logits({**params, "final_norm": mtp["final_norm"]}, x, cfg,
                          logits_dtype=logits_dtype)
     return logits, aux
@@ -131,6 +138,12 @@ def make_rope(cfg: ModelConfig, max_len: Optional[int] = None) -> Optional[RopeT
     if not cfg.use_rotary_emb:
         return None
     max_len = max_len or cfg.max_position_embeddings
+    if cfg.rope_scaling_type == "yarn":
+        return RopeTables(*yarn_freqs(
+            cfg.kv_channels, max_len, cfg.rope_theta,
+            cfg.rope_scaling_factor, cfg.rope_original_max_position,
+            cfg.rope_beta_fast, cfg.rope_beta_slow, cfg.rope_mscale,
+            cfg.rope_mscale_all_dim))
     cos, sin = precompute_freqs(
         cfg.kv_channels, max_len, theta=cfg.rope_theta,
         scaling_factor=cfg.rope_scaling_factor)
@@ -159,7 +172,8 @@ def model_forward(
     or (logits, kv_caches, moe_aux) with `return_aux=True` (loss_fn uses
     it to add the MoE router's load-balancing loss), and with
     `return_hidden` one more: the last layer's output before the final
-    norm, which the MTP module takes.
+    norm (the streams' sum where `cfg.hc_mult` > 1), which the MTP module
+    takes.
 
     `cp_pre_zigzag`: the caller pre-permuted tokens/positions into the
     ring-cp zigzag order (see loss_fn / parallel/ring_attention.py
@@ -197,6 +211,10 @@ def model_forward(
     # SP: scatter the embedding output along seq (ref: language_model.py:
     # 255-258 scatter_to_sequence_parallel_region); no-op without a mesh ctx
     x = constrain(x, tfm.RESIDUAL_AXES)
+    if cfg.hc_mult > 1:
+        # the residual of hc_mult streams, [b, s, hc_mult x hidden]
+        # (models/hyper_connections.py): the embedding in every stream
+        x = hc.expand(x, cfg)
 
     x, kv_caches, aux = tfm.stack_apply(
         params["transformer"], x, cfg,
@@ -210,6 +228,8 @@ def model_forward(
     # shared with both pp schedules (head_logits below)
     if logits_rows is not None:
         x = jnp.take_along_axis(x, logits_rows[:, None, None], axis=1)
+    if cfg.hc_mult > 1:
+        x = hc.collapse(x, cfg)         # the streams summed: [b, s, hidden]
     logits = head_logits(params, x, cfg, logits_dtype=logits_dtype)
     if return_hidden:
         return logits, kv_caches, aux, x

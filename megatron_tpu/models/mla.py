@@ -54,7 +54,7 @@ import jax.numpy as jnp
 
 from megatron_tpu.config import ModelConfig
 from megatron_tpu.models.norms import rmsnorm, rmsnorm_init
-from megatron_tpu.models.rope import apply_rotary
+from megatron_tpu.models.rope import apply_rotary, yarn_softmax_mscale
 
 # queries a block of the absorbed form of many positions: its scores are
 # [batch, heads, block, cached positions] float32
@@ -215,7 +215,9 @@ def mla_apply(params, x, cfg: ModelConfig, *, rope_cos, rope_sin,
     n, dtype = cfg.num_attention_heads, x.dtype
     dn, dr, r = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
     eps = cfg.norm_epsilon
-    scale = 1.0 / math.sqrt(dn + dr)
+    # under YaRN with `rope_mscale_all_dim`, m(factor, mscale_all_dim)^2: 1.0
+    # for every model that has none
+    scale = yarn_softmax_mscale(cfg) / math.sqrt(dn + dr)
 
     q_offset, per_slot = None, False
     if kv_cache is not None:

@@ -85,6 +85,12 @@ def layer_init(rng, cfg: ModelConfig, dtype=jnp.float32,
         params["post_attn_norm"] = norm_init(cfg.norm_type, cfg.hidden_size, dtype)
     if cfg.parallel_layernorm:
         params["mlp_norm"] = norm_init(cfg.norm_type, cfg.hidden_size, dtype)
+    if cfg.hc_mult > 1:
+        # the maps of the two sublayers' hyper-connections
+        from megatron_tpu.models.hyper_connections import hc_init
+        k_a, k_m = jax.random.split(jax.random.fold_in(rng, 41))
+        params["hc_attn"] = hc_init(k_a, cfg, dtype)
+        params["hc_mlp"] = hc_init(k_m, cfg, dtype)
     return params
 
 
@@ -115,6 +121,9 @@ def layer_axes(cfg: ModelConfig, cross_attn: bool = False,
         axes["post_attn_norm"] = norm_axes(cfg.norm_type)
     if cfg.parallel_layernorm:
         axes["mlp_norm"] = norm_axes(cfg.norm_type)
+    if cfg.hc_mult > 1:
+        from megatron_tpu.models.hyper_connections import hc_axes
+        axes["hc_attn"] = axes["hc_mlp"] = hc_axes(cfg)
     return axes
 
 
@@ -145,6 +154,11 @@ def layer_apply(
 ):
     """One transformer layer. x: [b, s, h]. Returns (x, kv_cache, aux) —
     `aux` is the MoE router's load-balancing loss (0.0 for dense MLPs).
+
+    With `cfg.hc_mult` = n > 1 the residual is n streams, x: [b, s, n h],
+    and each of the two sublayers (with its own pre-norm) is wrapped in its
+    hyper-connection (models/hyper_connections.py): X' = H_res X + H_post^T
+    F(H_pre X). `hc_mult` 1 takes none of that code.
 
     `kv_cache` is the cache stacked over layers and `cache_layer` this
     layer's index in it; both pass through to attention_apply, which
@@ -203,31 +217,26 @@ def layer_apply(
                           read_once=kv_cache is not None),
                 jnp.zeros((), jnp.float32))
 
-    residual = x
-    if cfg.use_post_ln:
-        ln_out = x  # input_layernorm = Identity (ref: transformer.py:630-631)
-    else:
-        ln_out = apply_norm(cfg.norm_type, params["input_norm"], x, eps)
-
-    if mixer == "conv":
-        from megatron_tpu.models.short_conv import short_conv_apply
-        assert causal and encoder_output is None and adapters is None \
-            and segment_ids is None and not cp_pre_zigzag, (
-            "a convolution layer is causal, unsharded, over one document")
-        attn_out, kv_cache = short_conv_apply(
-            params["conv"], ln_out, cfg, kv_cache=kv_cache,
-            kind_layer=kind_layer)
-    elif cfg.mla:
-        from megatron_tpu.models.mla import mla_apply
-        assert causal and encoder_output is None and adapters is None \
-            and not cp_pre_zigzag, "MLA is causal self-attention, unsharded"
-        attn_out, kv_cache = mla_apply(
-            params["attention"], ln_out, cfg,
-            rope_cos=rope_cos, rope_sin=rope_sin, position_ids=position_ids,
-            kv_cache=kv_cache, cache_layer=cache_layer,
-            segment_ids=segment_ids)
-    else:
-        attn_out, kv_cache = attention_apply(
+    def _mixer_branch(ln_out, kv_cache):
+        """The layer's mixer on its normed input: (out, the cache)."""
+        if mixer == "conv":
+            from megatron_tpu.models.short_conv import short_conv_apply
+            assert causal and encoder_output is None and adapters is None \
+                and segment_ids is None and not cp_pre_zigzag, (
+                "a convolution layer is causal, unsharded, over one document")
+            return short_conv_apply(
+                params["conv"], ln_out, cfg, kv_cache=kv_cache,
+                kind_layer=kind_layer)
+        if cfg.mla:
+            from megatron_tpu.models.mla import mla_apply
+            assert causal and encoder_output is None and adapters is None \
+                and not cp_pre_zigzag, "MLA is causal self-attention, unsharded"
+            return mla_apply(
+                params["attention"], ln_out, cfg,
+                rope_cos=rope_cos, rope_sin=rope_sin, position_ids=position_ids,
+                kv_cache=kv_cache, cache_layer=cache_layer,
+                segment_ids=segment_ids)
+        return attention_apply(
             params["attention"], ln_out, cfg,
             rope_cos=rope_cos, rope_sin=rope_sin, position_ids=position_ids,
             kv_cache=kv_cache, cache_layer=cache_layer,
@@ -236,6 +245,36 @@ def layer_apply(
             segment_ids=segment_ids, causal=causal,
             cp_pre_zigzag=cp_pre_zigzag, adapters=adapters,
             kind_layer=kind_layer)
+
+    if cfg.hc_mult > 1:
+        from megatron_tpu.models.hyper_connections import hc_sublayer
+        assert (not cfg.parallel_attn and not cfg.use_post_ln
+                and mixer == "full_attention" and encoder_output is None
+                and adapters is None and drop_path_rate is None), (
+            "hyper-connections wrap a pre-norm attention sublayer and a "
+            "feed-forward sublayer: no parallel block, post-LN, convolution, "
+            "encoder, adapter bank or drop_path (config.validate)")
+
+        def attn_sublayer(inp):
+            out, cache = _mixer_branch(apply_norm(
+                cfg.norm_type, params["input_norm"], inp, eps), kv_cache)
+            return _dropout(r_attn, out, p_drop), cache
+
+        def mlp_sublayer(inp):
+            out, aux = _mlp_branch(apply_norm(
+                cfg.norm_type, params["post_attn_norm"], inp, eps))
+            return _dropout(r_mlp, out, p_drop), aux
+        x, kv_cache = hc_sublayer(params["hc_attn"], x, cfg, attn_sublayer)
+        x, aux = hc_sublayer(params["hc_mlp"], x, cfg, mlp_sublayer)
+        return x, kv_cache, aux
+
+    residual = x
+    if cfg.use_post_ln:
+        ln_out = x  # input_layernorm = Identity (ref: transformer.py:630-631)
+    else:
+        ln_out = apply_norm(cfg.norm_type, params["input_norm"], x, eps)
+
+    attn_out, kv_cache = _mixer_branch(ln_out, kv_cache)
 
     if cfg.parallel_attn:
         # Falcon block: no dropout-add after attention
@@ -420,6 +459,11 @@ def stack_apply(
             position_ids=position_ids, kv_caches=kv_caches, rng=rng,
             deterministic=deterministic, segment_ids=segment_ids)
     k_dense = cfg.first_k_dense_replace
+    if cfg.hc_mult > 1:
+        # x: [b, s, hc_mult x hidden], the streams side by side
+        assert adapters is None and encoder_output is None and causal, (
+            "a residual of streams (hc_mult > 1) has no adapter bank and "
+            "no encoder")
     if k_dense:
         assert layer_offset == 0 and adapters is None, (
             "two stacks have no pipeline stage and no adapter bank")

@@ -407,11 +407,14 @@ class ServingEngine:
         # 200,000 rows made each serialised program 283 MB where 32,768 are
         # served, more than the chip's compile cache takes (PERF.md section
         # 6, PR 33; 128,000 rows, 33 MB in each of thirteen programs, kept
-        # every run of PR 37's cell cold). Every other pool keeps the
-        # generator's, and so the programs it had
+        # every run of PR 37's cell cold), and so under YaRN, whose
+        # published context is the stretched one (Xing4.0's 262,144 rows:
+        # 245 MB a program where 16,384 are served, PR 41). Every other
+        # pool keeps the generator's, and so the programs it had
         # (tests/test_jaxpr_unchanged.py; ROADMAP S22)
         self._rope = generator.rope
-        if (self.pool.hybrid or self.pool.conv_layers) \
+        if (self.pool.hybrid or self.pool.conv_layers
+                or cfg.rope_scaling_type == "yarn") \
                 and self._rope is not None:
             self._rope = type(self._rope)(
                 *(t[:self.max_len] for t in self._rope))

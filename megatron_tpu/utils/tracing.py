@@ -76,7 +76,8 @@ Training (`training/loop.py`, main thread):
 | `mtpu/train/save` | `save_fn(...)` | |
 
 On the device (`jax.named_scope`, so in the `op_name` of every HLO instruction
-traced under it; models/moe.py, models/attention.py and models/mla.py):
+traced under it; models/moe.py, models/attention.py, models/mla.py,
+models/hyper_connections.py and models/rope.py):
 
 | scope | round what |
 |---|---|
@@ -97,6 +98,12 @@ traced under it; models/moe.py, models/attention.py and models/mla.py):
 | `mtpu/mla/latent` | the latent row: down-projection, norm over kv_lora_rank, the rotary on the shared key, the write into the cache |
 | `mtpu/mla/attend_expanded` | the expanded form: keys and values of every head from the rows, causal attention from position 0 (training; a prefill at offset 0) |
 | `mtpu/mla/attend_absorbed` | the absorbed form: the layer of the cache read by the scores and by the weighted sum, each head's query and output through W_uk and W_uv (decode, verify, continuation chunks) |
+| `mtpu/hc/expand` | the model's input put in each of `hc_mult` streams, [b, s, hc_mult x hidden] (models/hyper_connections.py; the MTP block's input too) |
+| `mtpu/hc/map` | a sublayer's maps of a token: the product of the streams with phi [hc_mult x hidden, hc_mult^2 + 2 hc_mult] accumulated in float32, the scale by the root mean square over all streams, the two sigmoids, the exponential of the clipped H~_res and the Sinkhorn rounds, the tokens minor |
+| `mtpu/hc/pre` | H_pre X: the sublayer's input, one mixed stream |
+| `mtpu/hc/post` | H_res X + H_post^T out: the streams behind the sublayer, summed in float32 |
+| `mtpu/hc/collapse` | the streams summed ahead of the final norm (and behind the MTP block) |
+| `mtpu/rope/yarn` | YaRN's blended frequencies and the cos / sin tables made from them, once, where the tables are made (`models/rope.py::yarn_freqs`) |
 
 Counters of the serving metrics' snapshot that a benchmark reader takes:
 `kv_bytes_per_token` and `kv_pool_bytes`, `SlotKVPool.bytes_per_token()` and
