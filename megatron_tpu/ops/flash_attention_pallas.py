@@ -12,8 +12,17 @@ Kernel shape (FlashAttention-2 algorithm on the TPU memory hierarchy):
   CUDA kernel's per-CTA registers.
 - Q/K/V blocks are DMA'd HBM->VMEM by BlockSpec; the MXU does the two GEMMs
   per tile; softmax renormalization runs on the VPU in fp32.
-- Causality skips whole kv blocks past the diagonal (`pl.when`), the partial
-  diagonal block is masked by lane iota.
+- Every product (2 in the forward, 3 in dQ, 4 in dK/dV: `_dot`) takes its
+  operands in the dtype the rows arrived in and sums in float32: q, k, v and
+  dO are multiplied as they are read, the scale goes onto the float32 scores
+  and onto dQ / dK as they leave their accumulators, and p and dS, computed
+  in float32, are rounded to the rows' dtype where they enter a product
+  (`models/attention.py::_dot_attention` rounds its probabilities the same
+  way). Max, sum, exp, lse, delta, masks and accumulators are float32.
+  Float32 rows are multiplied as float32, under the caller's precision.
+- Causality skips whole kv blocks past the diagonal (`pl.when`; the forward
+  does not fetch them either, `_kv_block_index`), the partial diagonal block
+  is masked by lane iota.
 - GQA: the kv-head BlockSpec index maps q-head h -> kv-head h // group, so
   MQA/GQA never materialize broadcast K/V (the reference materializes the
   broadcast at transformer.py:448-455 in the unfused path).
@@ -33,8 +42,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_BLOCK_Q = 512
-DEFAULT_BLOCK_KV = 512
+# The forward kernels' blocks. A grid step pays for every query row of its
+# block whatever the keys' block holds: the running max and sum, the rescale
+# of the accumulator, all on [bq, 1] columns that take a vreg for 8 values.
+# At 512 x 512 that, and not the products, is most of a step (PERF.md
+# section 6, PR 42: masks and scale taken out move nothing), so fewer and
+# larger steps are faster up to what VMEM holds.
+DEFAULT_BLOCK_Q = 1024
+DEFAULT_BLOCK_KV = 1024
+# The two backward kernels and a forward with dropout hold four and more
+# [bq, bkv] float32 arrays at once; 1024 x 1024 of them overrun the 16 MiB
+# a kernel may take of VMEM (the compiler: "Ran out of memory in memory
+# space vmem"), so these keep blocks of 512.
+MANY_TEMPS_BLOCK = 512
 NEG_INF = -1e30
 # exp clamp for rows whose every score in a block is masked (possible with
 # segment masking: a document's rows see zero keys in a foreign-document
@@ -95,6 +115,19 @@ def _dropout_keep(seed_i32, bh, qi, ki, block_q, block_kv, rate):
     return u31 >= thresh
 
 
+def _dot(a, b, a_dim, b_dim):
+    """a · b contracted over (a_dim, b_dim): the operands as they are, the
+    sum in float32. Operands narrower than float32 say their precision
+    themselves, whatever the caller has set as JAX's default: their products
+    are exact in float32 already, and Mosaic refuses "highest" on bf16
+    operands. Float32 operands leave it to that default."""
+    precision = (None if a.dtype == jnp.float32
+                 else jax.lax.Precision.DEFAULT)
+    return jax.lax.dot_general(a, b, (((a_dim,), (b_dim,)), ((), ())),
+                               precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_q,
                 block_kv, num_kv, has_segs=False, window=None,
                 dropout_rate=0.0, q_off=None, kv_start=None):
@@ -147,11 +180,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_q,
 
     @pl.when(run)
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32) * scale      # [bq, d]
-        k = k_ref[0, 0].astype(jnp.float32)              # [bkv, d]
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        q = q_ref[0, 0]                                  # [bq, d]
+        k = k_ref[0, 0]                                  # [bkv, d]
+        v = v_ref[0, 0]
+        s = _dot(q, k, 1, 1) * scale
         if causal:
             q_pos = q_first + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_kv), 0)
@@ -184,9 +216,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_q,
         # value accumulation sees the inverted-dropout mask
         l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         pz = p if drop_z is None else p * drop_z
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            pz, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_ref[:] = acc_ref[:] * alpha + _dot(pz.astype(v.dtype), v, 1, 0)
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
 
     @pl.when(ki == num_kv - 1)
@@ -239,16 +269,15 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(run)
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32) * scale
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
+        q = q_ref[0, 0]
+        k = k_ref[0, 0]
+        v = v_ref[0, 0]
+        do = do_ref[0, 0]
         # clamp like the forward: a fully-masked row's lse is NEG_INF and
         # exp(NEG_INF - NEG_INF) would resurrect its masked entries
         lse = jnp.maximum(lse_ref[0, 0][:, :1], MASK_CLAMP)  # [bq, 1]
         delta = delta_ref[0, 0][:, :1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        s = _dot(q, k, 1, 1) * scale
         if causal:
             q_pos = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_kv), 0)
@@ -263,8 +292,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             k_seg = ks_ref[0][:, 0][None, :]
             s = jnp.where(q_seg == k_seg, s, NEG_INF)
         p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+        dp = _dot(do, v, 1, 1)
         if drop_z is not None:
             # the forward's regenerated mask; with O = (P∘Z)V/l the
             # chain rule gives dS = P ∘ (Z∘dP_raw - delta): delta =
@@ -276,13 +304,11 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if has_dlse:
             rest = rest + dlse_ref[0, 0][:, :1]
         ds = p * rest
-        dq_acc[:] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        dq_acc[:] += _dot(ds.astype(k.dtype), k, 1, 0)
 
     @pl.when(ki == num_kv - 1)
     def _finalize():
-        dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
+        dq_ref[0, 0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -329,14 +355,13 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(run)
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32) * scale
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
+        q = q_ref[0, 0]
+        k = k_ref[0, 0]
+        v = v_ref[0, 0]
+        do = do_ref[0, 0]
         lse = jnp.maximum(lse_ref[0, 0][:, :1], MASK_CLAMP)  # [bq, 1]
         delta = delta_ref[0, 0][:, :1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        s = _dot(q, k, 1, 1) * scale
         if causal:
             q_pos = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_kv), 0)
@@ -352,25 +377,21 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             s = jnp.where(q_seg == k_seg, s, NEG_INF)
         p = jnp.exp(s - lse)                             # [bq, bkv]
         pz = p
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+        dp = _dot(do, v, 1, 1)
         if drop_z is not None:
             pz = p * drop_z  # dV sees the dropped weights: dV = (P∘Z)ᵀdO
             dp = dp * drop_z  # dS = P ∘ (Z∘dP_raw - delta)
-        dv_acc[:] += jax.lax.dot_general(
-            pz, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dv_acc[:] += _dot(pz.astype(do.dtype), do, 0, 0)
         rest = dp - delta
         if has_dlse:
             rest = rest + dlse_ref[0, 0][:, :1]
         ds = p * rest
-        dk_acc[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dk_acc[:] += _dot(ds.astype(q.dtype), q, 0, 0)
 
     @pl.when(qi == num_q - 1)
     def _finalize():
-        dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
+        # q entered its products unscaled: the scale meets dk here, once
+        dk_ref[0, 0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
 
 
@@ -390,8 +411,25 @@ def _pick_block(s: int, bmax: int) -> int:
         "pad the sequence to a multiple of 128 or use the XLA fallback path")
 
 
-def _pick_blocks(sq, sk, block_q, block_kv):
+def _pick_blocks(sq, sk, block_q, block_kv, many_temps=False):
+    if many_temps:
+        block_q = min(block_q, MANY_TEMPS_BLOCK)
+        block_kv = min(block_kv, MANY_TEMPS_BLOCK)
     return _pick_block(sq, block_q), _pick_block(sk, block_kv)
+
+
+def _kv_block_index(ki, q_first, bq, bkv, num_kv, window, kv_start=0):
+    """The keys' block a forward grid step fetches: `ki` where the step
+    runs, else the nearest block that does. Blocks wholly past the query
+    block's diagonal, behind its window or before `kv_start` are skipped by
+    the kernel, and their DMA too: a block index that does not change is
+    not fetched again."""
+    last = (q_first + bq - 1) // bkv
+    first = kv_start
+    if window is not None:
+        first = jnp.maximum(first, q_first - window + 1)
+    first = jnp.maximum(first, 0) // bkv
+    return jnp.clip(ki, first, jnp.minimum(last, num_kv - 1))
 
 
 def _seg_lanes(seg, lanes=STAT_LANES):
@@ -434,11 +472,11 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_kv, interpret,
     g = nq // nkv
     if scale is None:
         scale = d ** -0.5
-    bq, bkv = _pick_blocks(sq, sk, block_q, block_kv)
+    has_drop = dropout_rate > 0.0
+    bq, bkv = _pick_blocks(sq, sk, block_q, block_kv, many_temps=has_drop)
     num_q, num_kv = sq // bq, sk // bkv
     has_segs = q_seg is not None
     assert has_segs == (k_seg is not None), "q_seg/k_seg must come together"
-    has_drop = dropout_rate > 0.0
     assert not has_drop or dropout_seed is not None, (
         "dropout_rate > 0 needs dropout_seed")
 
@@ -448,8 +486,14 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_kv, interpret,
 
     grid = (b, nq, num_q, num_kv)
     q_spec = pl.BlockSpec((1, 1, bq, d), lambda bi, h, qi, ki: (bi, h, qi, 0))
-    kv_spec = pl.BlockSpec((1, 1, bkv, d),
-                           lambda bi, h, qi, ki: (bi, h // g, ki, 0))
+
+    def kv_block(qi, ki):
+        if not causal:
+            return ki
+        return _kv_block_index(ki, qi * bq, bq, bkv, num_kv, sliding_window)
+
+    kv_spec = pl.BlockSpec(
+        (1, 1, bkv, d), lambda bi, h, qi, ki: (bi, h // g, kv_block(qi, ki), 0))
     o_spec = pl.BlockSpec((1, 1, bq, d), lambda bi, h, qi, ki: (bi, h, qi, 0))
     lse_spec = pl.BlockSpec((1, 1, bq, STAT_LANES),
                             lambda bi, h, qi, ki: (bi, h, qi, 0))
@@ -460,7 +504,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_kv, interpret,
             pl.BlockSpec((1, bq, STAT_LANES),
                          lambda bi, h, qi, ki: (bi, qi, 0)),
             pl.BlockSpec((1, bkv, STAT_LANES),
-                         lambda bi, h, qi, ki: (bi, ki, 0)),
+                         lambda bi, h, qi, ki: (bi, kv_block(qi, ki), 0)),
         ]
     drop_inputs, drop_specs = [], []
     if has_drop:
@@ -515,8 +559,7 @@ def pallas_flash_attention_offset(q, k, v, q_offset, kv_start=0, *,
     The kernel is `_fwd_kernel` with its positions shifted. Key blocks
     wholly past a query block's diagonal, behind its window or before
     `kv_start` are skipped as the aligned kernel skips them, and their DMA
-    too: the block index is clamped into the needed range, and a block
-    index that does not change is not fetched again."""
+    too (`_kv_block_index`)."""
     b, sq, nq, d = q.shape
     if not kv_heads_major:
         k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
@@ -530,13 +573,8 @@ def pallas_flash_attention_offset(q, k, v, q_offset, kv_start=0, *,
                       jnp.asarray(kv_start, jnp.int32)])
 
     def kv_block(qi, ki, off):
-        last = (off[0] + qi * bq + bq - 1) // bkv
-        first = off[1]
-        if sliding_window is not None:
-            first = jnp.maximum(first,
-                                off[0] + qi * bq - sliding_window + 1)
-        first = jnp.maximum(first, 0) // bkv
-        return jnp.clip(ki, first, jnp.minimum(last, num_kv - 1))
+        return _kv_block_index(ki, off[0] + qi * bq, bq, bkv, num_kv,
+                               sliding_window, kv_start=off[1])
 
     q_spec = pl.BlockSpec((1, 1, bq, d),
                           lambda bi, h, qi, ki, off: (bi, h, qi, 0))
@@ -574,7 +612,7 @@ def _flash_bwd_core(causal, scale, block_q, block_kv, interpret, res, dout,
     g = nq // nkv
     if scale is None:
         scale = d ** -0.5
-    bq, bkv = _pick_blocks(sq, sk, block_q, block_kv)
+    bq, bkv = _pick_blocks(sq, sk, block_q, block_kv, many_temps=True)
     num_q, num_kv = sq // bq, sk // bkv
     has_segs = q_seg is not None
     has_drop = dropout_rate > 0.0

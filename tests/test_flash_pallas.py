@@ -463,3 +463,255 @@ class TestKernelDropout:
                                     seg, seg, 64, 0.3, self._seed(5))
         np.testing.assert_array_equal(np.asarray(o1), np.asarray(o2))
         assert np.isfinite(np.asarray(o1)).all()
+
+
+# ---- the products' operand dtype is the rows' dtype (PR 42) ---------------
+#
+# A kernel product takes q, k, v, do as they arrive and rounds p / ds to that
+# dtype where they enter a product (`_dot_attention`'s own rounding of its
+# probabilities); sums, softmax and statistics are float32. The cases below
+# run every kernel entry on bf16 and on float32 rows.
+
+BF16_EPS = 2.0 ** -8        # half a step of bf16's grid at 1
+
+# The tolerance of every bf16 comparison below, in those half-steps and
+# relative to the compared array's largest value: each side rounds its
+# result to bf16 (one half-step each), the kernel rounds p and ds, the plain
+# path its scores, its probabilities and every intermediate of its backward
+# pass. The cases read at most 1.7 (each side against float32 arithmetic on
+# the same rows: the kernel at most 1.2, the plain path 1.5); a lost scale
+# or a mask off by a row reads tens.
+BF16_STEPS = 4
+F32_TOL = {"out": 2e-5, "grad": 5e-4}     # this file's float32 tolerances
+
+
+def _rows(dtype, b, sq, sk, nq, nkv, d, seed):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    mk = lambda key, s, n: jax.random.normal(  # noqa: E731
+        key, (b, s, n, d), jnp.float32).astype(dtype)
+    return (mk(ks[0], sq, nq), mk(ks[1], sk, nkv), mk(ks[2], sk, nkv),
+            mk(ks[3], sq, nq))
+
+
+def _close(got, want, dtype, kind):
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    if dtype == jnp.float32:
+        tol = F32_TOL[kind]
+        np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+    else:
+        assert np.abs(got - want).max() <= (
+            BF16_STEPS * BF16_EPS * np.abs(want).max())
+
+
+ALIGNED_CASES = {
+    # name: (nq, nkv, segments, window)
+    "causal": (4, 4, False, None),
+    "gqa": (4, 2, False, None),
+    "mqa": (4, 1, False, None),
+    "segments": (4, 2, True, None),
+    "window": (4, 2, False, 100),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("case", sorted(ALIGNED_CASES))
+def test_rows_dtype_forward_and_gradients(case, dtype):
+    """The forward and all three gradients against `_dot_attention` on the
+    SAME rows: bf16 rows within bf16's grid (above), float32 rows at this
+    file's float32 tolerances, i.e. the numbers the kernel gave before its
+    products followed the rows' dtype."""
+    from megatron_tpu.models.attention import _dot_attention
+    nq, nkv, segs, window = ALIGNED_CASES[case]
+    b, s, d = 1, 256, 64
+    q, k, v, do = _rows(dtype, b, s, s, nq, nkv, d, seed=11)
+    seg = _seg_pattern(b, s) if segs else None
+    segf = seg.astype(jnp.float32) if segs else None
+
+    def kernel(q, k, v):
+        return pallas_flash_attention(q, k, v, True, None, 128, 128, True,
+                                      segf, segf, window)
+
+    def plain(q, k, v):
+        return _dot_attention(q, k, v, causal=True, softmax_fp32=True,
+                              scale=d ** -0.5, segment_ids=seg,
+                              sliding_window=window)
+
+    got, got_vjp = jax.vjp(kernel, q, k, v)
+    want, want_vjp = jax.vjp(plain, q, k, v)
+    assert got.dtype == dtype
+    _close(got, want, dtype, "out")
+    for g, w in zip(got_vjp(do), want_vjp(do)):
+        assert g.dtype == dtype
+        _close(g, w, dtype, "grad")
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("off,start,window", [(512, 0, None),
+                                              (512, 200, 300)])
+def test_rows_dtype_offset_kernel(off, start, window, dtype):
+    """The chunk's kernel, keys heads-major as the cache holds them: 256
+    queries at position `off` of 768 keys, keys before `start` empty."""
+    from megatron_tpu.models.attention import _dot_attention
+    from megatron_tpu.ops.flash_attention_pallas import \
+        pallas_flash_attention_offset
+    b, sq, sk, nq, nkv, d = 1, 256, 768, 4, 2, 128
+    q, k, v, _ = _rows(dtype, b, sq, sk, nq, nkv, d, seed=12)
+    got = pallas_flash_attention_offset(
+        q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), jnp.int32(off),
+        jnp.int32(start), sliding_window=window, block_q=128, block_kv=128,
+        interpret=True, kv_heads_major=True)
+    pos = jnp.arange(sk)
+    want = _dot_attention(
+        q, k, v, causal=True, softmax_fp32=True, scale=d ** -0.5,
+        q_offset=off, sliding_window=window,
+        kv_positions=jnp.where(pos >= start, pos, 2 ** 30))
+    assert got.dtype == dtype
+    _close(got, want, dtype, "out")
+
+
+def _pallas_calls(fn, *args):
+    """{kernel's name: its pallas_call equation} for every kernel that
+    `fn(*args)` traces."""
+    found = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found[eqn.params["jaxpr"].debug_info.func_name] = eqn
+            else:
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+def _dots(jaxpr):
+    """(lhs dtype, rhs dtype, out dtype) of every dot_general in `jaxpr`,
+    the bodies of its `pl.when`s included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            yield tuple(str(x.aval.dtype)
+                        for x in (*eqn.invars, *eqn.outvars))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _dots(sub)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_every_kernel_product_takes_the_rows_dtype(dtype):
+    """Structure, so that no later edit puts a cast back: inside each of the
+    four pallas_calls every dot_general's two operands have the rows' dtype
+    and its result is float32. 2 products in each forward kernel, 3 in the
+    dq kernel, 4 in the dk/dv kernel."""
+    from megatron_tpu.ops.flash_attention_pallas import \
+        pallas_flash_attention_offset
+    q, k, v, do = _rows(dtype, 1, 256, 256, 4, 2, 128, seed=13)
+    segf = _seg_pattern(1, 256).astype(jnp.float32)
+    seed = jnp.full((1, 8), 3.0, jnp.float32)
+
+    def fwd_bwd(q, k, v):      # segments and dropout on: every cast site
+        out, vjp = jax.vjp(
+            lambda *a: pallas_flash_attention(
+                *a, True, None, 128, 128, True, segf, segf, None, 0.1, seed),
+            q, k, v)
+        return out, vjp(do)
+
+    def chunk(q, k, v):
+        return pallas_flash_attention_offset(
+            q, k, v, jnp.int32(0), jnp.int32(0), block_q=128, block_kv=128,
+            interpret=True)
+
+    calls = {**_pallas_calls(fwd_bwd, q, k, v),
+             **_pallas_calls(chunk, q, k, v)}
+    dots = {n: list(_dots(e.params["jaxpr"])) for n, e in calls.items()}
+    name = str(jnp.dtype(dtype))
+    assert {n: len(d) for n, d in dots.items()} == {
+        "_fwd_kernel": 2, "_fwd_kernel_offset": 2, "_bwd_dq_kernel": 3,
+        "_bwd_dkv_kernel": 4}, dots
+    for kernel, ds in dots.items():
+        assert all(d == (name, name, "float32") for d in ds), (kernel, ds)
+
+
+# ---- the forward's blocks and the blocks it does not fetch (PR 42) --------
+
+def _runs(qi, ki, bq, bkv, off, window, start):
+    """The kernels' own skip rule for a causal block pair, on plain ints."""
+    q_first = off + qi * bq
+    run = ki * bkv <= q_first + bq - 1
+    if window is not None:
+        run = run and ki * bkv + bkv - 1 > q_first - window
+    return run and ki * bkv + bkv - 1 >= start
+
+
+@pytest.mark.parametrize("bq,bkv,off,window,start", [
+    (128, 128, 0, None, 0), (256, 128, 0, None, 0), (128, 256, 0, None, 0),
+    (128, 128, 0, 200, 0), (256, 128, 0, 100, 0), (128, 128, 512, None, 0),
+    (128, 128, 384, 300, 200), (128, 256, 512, 512, 512)])
+def test_skipped_blocks_are_not_fetched(bq, bkv, off, window, start):
+    """`_kv_block_index` hands a step that runs its own block, and a step
+    the kernel skips the nearest block that runs, so that the index does
+    not change over a run of skipped steps and nothing is fetched for them
+    (aligned: `off` 0; the chunk's kernel: `off`, `start`)."""
+    from megatron_tpu.ops.flash_attention_pallas import _kv_block_index
+    sq, sk = 512, 1024
+    num_q, num_kv = sq // bq, sk // bkv
+    for qi in range(num_q):
+        ran = [ki for ki in range(num_kv)
+               if _runs(qi, ki, bq, bkv, off, window, start)]
+        assert ran == list(range(ran[0], ran[-1] + 1))
+        for ki in range(num_kv):
+            got = int(_kv_block_index(ki, off + qi * bq, bq, bkv, num_kv,
+                                      window, kv_start=start))
+            assert got == min(max(ki, ran[0]), ran[-1]), (qi, ki)
+
+
+def _grids(fn, *args):
+    return {name: tuple(eqn.params["grid_mapping"].grid)
+            for name, eqn in _pallas_calls(fn, *args).items()}
+
+
+def test_default_blocks():
+    """With no block given (the model's calls) a forward takes blocks of
+    1,024; the two backward kernels and a forward with dropout, which hold
+    four and more [bq, bkv] float32 arrays, keep 512 (what VMEM holds:
+    tests/test_tpu_compile.py compiles both at real widths)."""
+    q, k, v, do = _rows(jnp.bfloat16, 1, 2048, 2048, 2, 1, 64, seed=14)
+    seed = jnp.full((1, 8), 3.0, jnp.float32)
+
+    def plain(q, k, v):
+        out, vjp = jax.vjp(pallas_flash_attention, q, k, v)
+        return out, vjp(do)
+
+    def dropped(q, k, v):
+        return pallas_flash_attention(q, k, v, True, None, 1024, 1024, False,
+                                      None, None, None, 0.1, seed)
+
+    assert _grids(plain, q, k, v) == {
+        "_fwd_kernel": (1, 2, 2, 2), "_bwd_dq_kernel": (1, 2, 4, 4),
+        "_bwd_dkv_kernel": (1, 2, 4, 4)}
+    assert _grids(dropped, q, k, v) == {"_fwd_kernel": (1, 2, 4, 4)}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_default_blocks_match_plain_attention(dtype):
+    """The blocks the models run with (1,024 forward, 512 backward) at the
+    training cells' 2,048 rows, MQA: forward and gradients against
+    `_dot_attention` on the same rows."""
+    from megatron_tpu.models.attention import _dot_attention
+    from megatron_tpu.ops.flash_attention_pallas import (DEFAULT_BLOCK_KV,
+                                                         DEFAULT_BLOCK_Q)
+    d = 64
+    q, k, v, do = _rows(dtype, 1, 2048, 2048, 2, 1, d, seed=15)
+    got, got_vjp = jax.vjp(
+        lambda *a: pallas_flash_attention(*a, True, None, DEFAULT_BLOCK_Q,
+                                          DEFAULT_BLOCK_KV, True), q, k, v)
+    want, want_vjp = jax.vjp(
+        lambda *a: _dot_attention(*a, causal=True, softmax_fp32=True,
+                                  scale=d ** -0.5), q, k, v)
+    _close(got, want, dtype, "out")
+    for g, w in zip(got_vjp(do), want_vjp(do)):
+        _close(g, w, dtype, "grad")

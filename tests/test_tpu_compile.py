@@ -67,6 +67,29 @@ def test_flash_attention_fwd_bwd(one_chip):
     _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, kv, kv)
 
 
+@pytest.mark.parametrize("case", ["mla_256", "segments", "dropout",
+                                  "float32"])
+def test_flash_attention_forward_fits_vmem(one_chip, case):
+    """The forward's blocks of 1,024 (PR 42) against the 16 MiB of VMEM a
+    kernel may take, where a block is largest: MLA's expanded heads padded
+    to 256 channels (JoyAI's 32 heads at 4,096 rows), packed documents,
+    float32 rows; and a forward with dropout, which keeps blocks of 512
+    because at 1,024 the compiler refuses it."""
+    from megatron_tpu.ops.flash_attention import flash_attention
+    n, d = (32, 256) if case == "mla_256" else (8, 128)
+    dtype = jnp.float32 if case == "float32" else jnp.bfloat16
+    rows = jax.ShapeDtypeStruct((1, 4096, n, d), dtype, sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=one_chip)
+
+    def fn(q, k, v, seg):
+        return flash_attention(
+            q, k, v, causal=True, use_pallas=True,
+            segment_ids=seg if case == "segments" else None,
+            dropout_rate=0.1 if case == "dropout" else 0.0,
+            dropout_rng=jax.random.PRNGKey(0) if case == "dropout" else None)
+    _compile(fn, rows, rows, rows, seg)
+
+
 @pytest.mark.parametrize("keys,window", [(8192, 4096), (32768, None)])
 def test_flash_attention_at_an_offset(one_chip, keys, window):
     """A serving chunk that continues a cache (PR 33): 4,096 queries of 128
