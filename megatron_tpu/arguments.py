@@ -630,6 +630,9 @@ _NOOP_FLAGS = [
     "--no_async_tensor_model_parallel_allreduce",
     "--no_bias_dropout_fusion", "--no_bias_gelu_fusion",
     "--no_contiguous_buffers_in_local_ddp", "--no_data_sharding",
+    # the fusion itself (wgrad_gemm_accum_fp32: dW summed into main_grad by
+    # the product) is ops/grad_accum.py, in every step of several
+    # micro-batches: nothing turns it off
     "--no_gradient_accumulation_fusion", "--no_initialization",
     "--mmap_warmup",  # np.memmap needs no page-in pass
     "--no_masked_softmax_fusion", "--no_persist_layer_norm",

@@ -29,6 +29,7 @@ from megatron_tpu.models import hyper_connections as hc
 from megatron_tpu.models import transformer as tfm
 from megatron_tpu.models.norms import apply_norm, norm_axes, norm_init
 from megatron_tpu.models.rope import precompute_freqs, yarn_freqs
+from megatron_tpu.ops import grad_accum
 from megatron_tpu.ops.cross_entropy import cross_entropy_loss
 from megatron_tpu.ops.dropout import dropout
 from megatron_tpu.parallel.sharding import constrain
@@ -297,6 +298,12 @@ def loss_fn(
         inputs, labels = tokens[:, :-1], tokens[:, 1:]
         if loss_mask is not None and loss_mask.shape[1] == tokens.shape[1]:
             loss_mask = loss_mask[:, 1:]
+    # the leaves outside the stacks take their gradient accumulators here,
+    # once, ahead of every use (a tied table has two); the stacks' leaves
+    # take theirs inside the scans over layers (ops/grad_accum.py)
+    params = {k: v if k == "transformer"
+              else grad_accum.join(grad_accum.pairs(v))
+              for k, v in params.items()}
 
     # ring-cp zigzag: permute the batch ONCE here (ints + mask — cheap)
     # so ring attention skips its per-call q/k/v/out permute-gathers. The

@@ -32,6 +32,7 @@ from megatron_tpu.config import ModelConfig
 from megatron_tpu.models.attention import attention_apply, attention_axes, attention_init
 from megatron_tpu.models.mlp import mlp_apply, mlp_axes, mlp_init
 from megatron_tpu.models.norms import apply_norm, norm_axes, norm_init
+from megatron_tpu.ops import grad_accum
 from megatron_tpu.ops.dropout import drop_path as _drop_path
 from megatron_tpu.ops.dropout import dropout as _dropout
 from megatron_tpu.parallel.sharding import constrain
@@ -438,7 +439,14 @@ def stack_apply(
     (each step slices one layer's [n, ...] bank), the per-row
     index is layer-invariant and closes over the body. None compiles to
     exactly today's graph (multi-tenant LoRA serving,
-    models/attention.py)."""
+    models/attention.py).
+
+    Inside a training step of several micro-batches each leaf takes its
+    float32 gradient accumulator with it (`grad_accum.pairs`) and the loop
+    over layers joins the two (`grad_accum.scan`), so that a layer's weight
+    gradients are summed into the accumulators where the backward pass
+    writes them: ops/grad_accum.py. Anywhere else the first finds nothing
+    and the second is `jax.lax.scan`."""
     if cfg.layer_types is not None:
         assert layer_offset == 0 and adapters is None \
             and encoder_output is None and not cp_pre_zigzag and causal \
@@ -480,6 +488,7 @@ def stack_apply(
             kv_caches=kv_caches, layer_offset=k_dense, cache_offset=k_dense,
             **common)
         return x, kv_caches, aux_dense + aux_moe
+    stacked_params = grad_accum.pairs(stacked_params)
     num_layers = jax.tree.leaves(stacked_params)[0].shape[0]
     drop_rates = lima_dropout_rates(cfg, cfg.num_layers)
     drop_rates = jax.lax.dynamic_slice_in_dim(drop_rates, layer_offset, num_layers)
@@ -546,7 +555,7 @@ def stack_apply(
     # None entries are empty pytrees: scan passes them through untouched
     # (the no-adapters case scans the same body shape)
     xs = (stacked_params, drop_rates, dp_rates, layer_ids, lora_stack)
-    (x, aux, kv_caches), _ = jax.lax.scan(body, (x, aux0, kv_caches), xs)
+    (x, aux, kv_caches), _ = grad_accum.scan(body, (x, aux0, kv_caches), xs)
     return x, kv_caches, aux
 
 
@@ -568,6 +577,7 @@ def _period_stack_apply(stacked_params, x, cfg: ModelConfig, *, rope_cos,
     banks' stack, all written in place, as the one-kind loop does it."""
     P = cfg.window_layer_period
     n_win = cfg.window_layers_per_period
+    stacked_params = grad_accum.pairs(stacked_params)
     num_layers = jax.tree.leaves(stacked_params)[0].shape[0]
     periods = num_layers // P
     kinds = [cfg.window_layers()] * n_win + [cfg.full_layers()]
@@ -611,7 +621,7 @@ def _period_stack_apply(stacked_params, x, cfg: ModelConfig, *, rope_cos,
         body = jax.checkpoint(
             body, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
             prevent_cse=False)
-    (x, aux, kv_caches), _ = jax.lax.scan(
+    (x, aux, kv_caches), _ = grad_accum.scan(
         body, (x, jnp.zeros((), jnp.float32), kv_caches),
         (by_period, drop_rates, jnp.arange(periods)))
     return x, kv_caches, aux
@@ -638,7 +648,7 @@ def _pattern_stack_apply(stacked_params, x, cfg: ModelConfig, *, rope_cos,
     aux = jnp.zeros((), jnp.float32)
     first = 0                       # the group's first layer in the model
     for name, group_cfg, kinds, _ in _pattern_groups(cfg):
-        params = stacked_params[name]
+        params = grad_accum.pairs(stacked_params[name])
         banks = {kind: None for kind in params}
         if cached and group_cfg.num_experts > 1:
             from megatron_tpu.models.moe import split_stacked_banks
@@ -691,12 +701,13 @@ def _pattern_stack_apply(stacked_params, x, cfg: ModelConfig, *, rope_cos,
                 lambda t: t[:periods * per[kind]].reshape(
                     periods, per[kind], *t.shape[1:]), params[kind])
             for kind in params if per[kind]}
-        (x, aux, kv_caches), _ = jax.lax.scan(
+        (x, aux, kv_caches), _ = grad_accum.scan(
             body, (x, aux, kv_caches), (by_period, jnp.arange(periods)))
         for i in range(periods * P, len(kinds)):        # the tail
             kind, at = kinds[i], kinds[:i].count(kinds[i])
             x, kv_caches, a = apply_one(
-                x, kv_caches, jax.tree.map(lambda t: t[at], params[kind]),
+                x, kv_caches, grad_accum.join(
+                    jax.tree.map(lambda t: t[at], params[kind])),
                 kind, first + i, at)
             aux = aux + a
         first += len(kinds)

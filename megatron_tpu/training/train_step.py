@@ -7,9 +7,17 @@ zero grad buffers -> per-microbatch fwd/bwd accumulating into `main_grad`
 buffers -> reduce_model_grads (DP allreduce) -> optimizer.step -> lr step.
 Here the same dataflow is one jitted function:
 
-- microbatch loop = `lax.scan` over the leading microbatch dim, accumulating
-  fp32 grads (== the contiguous main_grad buffer of model/distributed.py:75-171
-  without the buffer bookkeeping);
+- microbatch loop = `lax.scan` over the leading microbatch dim, whose carry
+  is the fp32 gradient accumulators (== the contiguous main_grad buffer of
+  model/distributed.py:75-171 without the buffer bookkeeping). A
+  micro-batch's gradients are not added to them after its backward pass:
+  the accumulators go down into it (ops/grad_accum.py), each weight
+  gradient is summed into its accumulator where the product writes it, and
+  what the micro-batch's `grad` hands back ARE the new accumulators (==
+  the reference's --gradient_accumulation_fusion, "dW += dY^T X" into
+  main_grad). A leaf whose forward takes no accumulator with it is added
+  here, as a pass of its own. A step of one micro-batch keeps no
+  accumulator: its gradients are the step's;
 - the DP grad all-reduce (ref: distributed.py:202-232) is emitted by GSPMD
   because batch activations are 'dp'-sharded while params are replicated;
 - loss scaling per microbatch matches schedules.py:176-186
@@ -30,8 +38,10 @@ import jax.numpy as jnp
 
 from megatron_tpu.config import MegatronConfig
 from megatron_tpu.models import language_model as lm
+from megatron_tpu.ops import grad_accum
 from megatron_tpu.training import optimizer as opt
 from megatron_tpu.training import scheduler
+from megatron_tpu.utils import tracing
 
 
 class TrainState(NamedTuple):
@@ -56,10 +66,6 @@ def state_from_params(params, cfg: MegatronConfig) -> TrainState:
 
 def init_train_state(rng, cfg: MegatronConfig) -> TrainState:
     return state_from_params(lm.model_init(rng, cfg.model), cfg)
-
-
-def _tree_add(a, b):
-    return jax.tree.map(jnp.add, a, b)
 
 
 def train_step(
@@ -88,42 +94,70 @@ def train_step(
 
     deterministic = (mcfg.hidden_dropout == 0.0 and mcfg.attention_dropout == 0.0)
 
-    def micro_loss(params, mb, mb_rng):
-        if loss_fn is not None:
-            # pluggable per-microbatch loss — the analogue of the reference's
-            # forward_step_func extension point (ref: training.py:54 pretrain
-            # signature; pretrain_bert.py / pretrain_t5.py forward_step)
-            loss = loss_fn(params, mb, mb_rng)
-        else:
-            loss = lm.loss_fn(params, mb["tokens"], mcfg,
-                              loss_mask=mb["loss_mask"], rope=rope,
-                              rng=mb_rng, deterministic=deterministic,
-                              position_ids=mb.get("position_ids"),
-                              segment_ids=mb.get("segment_ids"))
-        # scaled loss for backward (ref: schedules.py:176-186): the optimizer
-        # unscales; dividing by n_micro here makes the accumulated grad the
-        # mean over microbatches.
-        return loss * loss_scale / n_micro, loss
-
-    grad_fn = jax.value_and_grad(micro_loss, has_aux=True)
-
-    def body(acc, xs):
-        grads_acc, loss_acc = acc
-        mb, i = xs
+    def micro_grads(accs, mb, i):
+        """(micro-batch `i`'s float32 gradients added to `accs`, its loss);
+        the gradients alone where `accs` is None."""
         mb_rng = jax.random.fold_in(rng, i) if rng is not None else None
-        (_, loss), grads = grad_fn(state.params, mb, mb_rng)
-        return (_tree_add(grads_acc, jax.tree.map(
-            lambda g: g.astype(jnp.float32), grads)), loss_acc + loss), None
+        # the leaves, by their place among `jax.tree.leaves`, whose
+        # gradients leave the backward pass already added to `accs`
+        summed = set()
 
-    zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), state.params)
+        def micro_loss(params, accs):
+            with grad_accum.accumulating(params, accs, summed):
+                if loss_fn is not None:
+                    # pluggable per-microbatch loss — the analogue of the
+                    # reference's forward_step_func extension point (ref:
+                    # training.py:54 pretrain signature; pretrain_bert.py /
+                    # pretrain_t5.py forward_step)
+                    loss = loss_fn(params, mb, mb_rng)
+                else:
+                    loss = lm.loss_fn(params, mb["tokens"], mcfg,
+                                      loss_mask=mb["loss_mask"], rope=rope,
+                                      rng=mb_rng, deterministic=deterministic,
+                                      position_ids=mb.get("position_ids"),
+                                      segment_ids=mb.get("segment_ids"))
+            # scaled loss for backward (ref: schedules.py:176-186): the
+            # optimizer unscales; dividing by n_micro here makes the
+            # accumulated grad the mean over microbatches.
+            return loss * loss_scale / n_micro, loss
+
+        (_, loss), (grads, new_accs) = jax.value_and_grad(
+            micro_loss, argnums=(0, 1), has_aux=True)(state.params, accs)
+        grads, treedef = jax.tree.flatten(
+            jax.tree.map(lambda g: g.astype(jnp.float32), grads))
+        nbytes = [4 * g.size for g in grads]
+        tracing.note_grad_accum(sum(nbytes[k] for k in summed), sum(nbytes))
+        if accs is None:
+            return treedef.unflatten(grads), loss
+        # a leaf whose accumulator went down into the backward pass comes
+        # back as the new one, and its own gradient holds what reached it by
+        # another way: as a rule nothing, zeros that XLA drops. Whatever
+        # nobody took is added here, in a pass of its own.
+        sums = [new if k in summed else acc for k, (acc, new) in enumerate(
+            zip(jax.tree.leaves(accs), jax.tree.leaves(new_accs)))]
+        return treedef.unflatten(
+            [acc + g for acc, g in zip(sums, grads)]), loss
+
     mb_stream = dict(batch)
     if "tokens" in mb_stream and mb_stream.get("loss_mask") is None:
         mb_stream["loss_mask"] = jnp.ones(
             (n_micro,) + (batch["tokens"].shape[1], batch["tokens"].shape[2] - 1),
             jnp.float32)
-    (grads, loss_sum), _ = jax.lax.scan(
-        body, (zeros, jnp.zeros((), jnp.float32)),
-        (mb_stream, jnp.arange(n_micro)))
+    if n_micro == 1:
+        # nothing to add to: the micro-batch's gradients are the step's
+        grads, loss_sum = micro_grads(
+            None, jax.tree.map(lambda x: x[0], mb_stream), 0)
+    else:
+        def body(carry, xs):
+            accs, loss_acc = carry
+            accs, loss = micro_grads(accs, *xs)
+            return (accs, loss_acc + loss), None
+
+        zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
+                             state.params)
+        (grads, loss_sum), _ = jax.lax.scan(
+            body, (zeros, jnp.zeros((), jnp.float32)),
+            (mb_stream, jnp.arange(n_micro)))
     return _finish_step(state, grads, loss_sum / n_micro, cfg, wd_mask)
 
 

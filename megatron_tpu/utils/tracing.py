@@ -37,7 +37,14 @@ there.
 `compile_seconds`, `compile_cache_hits`, `compile_cache_misses` (the ledger's
 totals now) and `compiles_after_ready`, the programs compiled or loaded since
 `ready()`: in a steady state it stays where it is, and on a server that warms
-nothing it counts what the first users of a new build waited for.
+nothing it counts what the first users of a new build waited for. Beside them
+`grad_accum_fused_share`, fixed as `training/train_step.py` traces its step:
+of the bytes of the float32 gradient accumulators, the share whose leaf's
+gradient leaves the backward pass already added to it (`ops/grad_accum.py`);
+the rest is added in a pass of its own once a micro-batch. 1.0 where every
+leaf's forward goes through `language_model.loss_fn` or `transformer.py`'s
+scans, 0.0 for a step of one micro-batch (which keeps no accumulator) and in
+a process that traced no step.
 
 A span is a `with` block on the thread that does the work; nesting gives the
 parent. Names are constant strings, stats are integers: keyword arguments for
@@ -174,6 +181,7 @@ class _StartupRecord:
         self.dropped = 0
         self.ready: Optional[float] = None
         self.programs_at_ready = 0
+        self.grad_accum_fused_share = 0.0
 
     def open(self, name: str) -> Optional[list]:
         with self.lock:
@@ -269,11 +277,18 @@ def ready_line() -> str:
             f"cache (saved {led['saved_s']:.0f} s)")
 
 
+def note_grad_accum(fused_bytes: int, accumulated_bytes: int) -> None:
+    """`train_step`, as it traces a micro-batch's backward pass."""
+    with _record.lock:
+        _record.grad_accum_fused_share = fused_bytes / accumulated_bytes
+
+
 def startup_scalars() -> Dict[str, float]:
-    """The six keys of the module docstring, as `/metrics` shows them."""
+    """The seven keys of the module docstring, as `/metrics` shows them."""
     led = compile_cache.totals()
     with _record.lock:
         ready_at, at_ready = _record.ready, _record.programs_at_ready
+        fused = _record.grad_accum_fused_share
     return {
         "startup_seconds":
             float(ready_at - _record.t0) if ready_at is not None else 0.0,
@@ -285,6 +300,7 @@ def startup_scalars() -> Dict[str, float]:
         "compiles_after_ready":
             float(led["programs"] - at_ready) if ready_at is not None
             else 0.0,
+        "grad_accum_fused_share": fused,
     }
 
 

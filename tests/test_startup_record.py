@@ -28,7 +28,8 @@ from megatron_tpu.utils import compile_cache, tracing
 from megatron_tpu.utils.compile_cache import ensure_compile_cache
 
 KEYS = ("startup_seconds", "compile_programs", "compile_seconds",
-        "compile_cache_hits", "compile_cache_misses", "compiles_after_ready")
+        "compile_cache_hits", "compile_cache_misses", "compiles_after_ready",
+        "grad_accum_fused_share")
 
 
 @pytest.fixture(autouse=True)

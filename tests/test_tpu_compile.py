@@ -411,3 +411,85 @@ def test_decode_program_reads_the_kv_pool_where_it_lies(one_chip,
     assert memory.temp_size_in_bytes < S * P * nkv * hd * 2 // 100, memory
     # arguments + outputs - aliased: the pool is updated where it lies
     assert memory.alias_size_in_bytes >= 2 * L * S * P * nkv * hd * 2
+
+
+def _train_step_text(one_chip, step, n_micro=2):
+    """`step` (a train step's signature) at two layers of 1,024 wide under
+    a vocabulary of 4,096, bf16 over float32 parameters, `n_micro`
+    micro-batches of 512 tokens, compiled for the chip; and the shapes of
+    its stacked matrices."""
+    import functools
+    from megatron_tpu.config import (MegatronConfig, ModelConfig,
+                                     OptimizerConfig, TrainingConfig)
+    from megatron_tpu.training import init_train_state
+    model = ModelConfig(num_layers=2, hidden_size=1024,
+                        num_attention_heads=8, vocab_size=4096,
+                        seq_length=512, compute_dtype="bfloat16").derived()
+    cfg = MegatronConfig(
+        model=model, optimizer=OptimizerConfig(lr=1e-4),
+        training=TrainingConfig(micro_batch_size=1,
+                                global_batch_size=n_micro, train_iters=4),
+    ).validate(n_devices=1)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+    state = on_chip(jax.eval_shape(
+        lambda: init_train_state(jax.random.PRNGKey(0), cfg)))
+    batch = on_chip({
+        "tokens": jax.ShapeDtypeStruct((n_micro, 1, 513), jnp.int32),
+        "loss_mask": jax.ShapeDtypeStruct((n_micro, 1, 512), jnp.float32)})
+    rng = on_chip(jax.ShapeDtypeStruct((2,), jnp.uint32))
+    text = jax.jit(functools.partial(step, cfg=cfg), donate_argnums=0).lower(
+        state, batch, rng).compile().as_text()
+    return text, {tuple(x.shape) for x in jax.tree.leaves(
+        state.params["transformer"]) if x.ndim == 3}
+
+
+def _passes_over_a_stack(text, stacks):
+    """The instructions inside the program's loops that make a float32
+    array of a stacked matrix's shape, or of one layer of it, and hold no
+    product: an add, a copy, a slice or a fill of their own. The entry
+    computation is left out: the step's one zero fill and Adam's pass over
+    the state are there, once a step. Nor are the compiler's prefetches
+    into fast memory counted (`copy-start`, `slice-start` and their `done`s:
+    stacks of this test's size fit there, the cells' do not)."""
+    shapes = set(stacks) | {(1,) + s[1:] for s in stacks}
+    holds_product = {m.group(1) for m in re.finditer(
+        r"\n(%\S+) \([^\n]*\{\n(?:[^}][^\n]*\n)*?[^\n]* convolution\(", text)}
+    found = []
+    for block in re.split(r"\n(?=(?:ENTRY )?%\S+ \()", text):
+        if block.startswith("ENTRY") or "fused_computation" in \
+                block.split("(", 1)[0] or block.split(" ", 1)[0] \
+                in holds_product:
+            continue
+        for line in block.splitlines()[1:]:
+            m = _RESULT.match(line)
+            if not m or m.group(1) != "f32":
+                continue
+            dims = tuple(int(d) for d in m.group(2).split(",") if d)
+            calls = re.search(r"calls=(%[\w.\-]+)", line)
+            if dims in shapes and m.group(3) in (
+                    "fusion", "broadcast", "copy", "add", "select",
+                    "convert", "dynamic-slice", "dynamic-update-slice"
+                    ) and not (calls and calls.group(1) in holds_product):
+                found.append(line.strip()[:160])
+    return found
+
+
+def test_train_step_sums_a_layers_gradient_inside_its_product(one_chip):
+    """Several micro-batches: inside the loops every float32 array of a
+    stacked matrix's shape is made by a fusion that holds a product (`dW`
+    added to the accumulator's layer, written where it lies). The step as it
+    was (`tests/test_grad_accum_fused.py::unfused_step`) fills a stack with
+    zeros and adds it to the accumulator in a pass of its own, once a
+    micro-batch: the reader has to see those."""
+    from megatron_tpu.training.train_step import train_step
+    from tests.test_grad_accum_fused import unfused_step
+    import functools
+    text, stacks = _train_step_text(one_chip, train_step)
+    assert len(stacks) >= 3
+    assert _passes_over_a_stack(text, stacks) == []
+    before, _ = _train_step_text(
+        one_chip, functools.partial(unfused_step, loop="scan"))
+    assert _passes_over_a_stack(before, stacks)
