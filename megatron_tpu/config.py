@@ -641,9 +641,9 @@ class ServingConfig:
     # window-band mask (a non-rolling windowed pool would silently
     # attend outside the band), and ROLLING layouts additionally
     # break its contiguous position arithmetic — so windowed pools
-    # keep the resolve/scatter bracket (validate() rejects the
-    # combination loudly; the engine re-asserts). On CPU the kernel
-    # runs in pallas interpret mode (the tier-1 test path).
+    # keep the resolve/scatter bracket (serving/capabilities.py refuses
+    # the combination). On CPU the kernel runs in pallas interpret mode
+    # (the tier-1 test path).
     block_native_attn: bool = False
     # speculative decoding on the slot grid (docs/serving.md
     # "Speculative decoding"): each engine iteration proposes k draft
@@ -660,10 +660,9 @@ class ServingConfig:
     # not bit-reproducing the non-speculative RNG stream). 0 disables.
     # Unsupported on ROLLING pools (a rejected draft's ring write
     # already evicted history — the rewind invariant can't hold, with
-    # or without kv_block_size): validate() rejects it, the engine
-    # re-asserts on the RESOLVED pool layout. flash-impl int8 pools
-    # are supported (the int8 prefill takes the cached dot path —
-    # models/attention.py).
+    # or without kv_block_size): serving/capabilities.py refuses it.
+    # flash-impl int8 pools are supported (the int8 prefill takes the
+    # cached dot path — models/attention.py).
     speculative_k: int = 0
     # --- overload & failure knobs (docs/serving.md "Overload &
     # failure behavior") -----------------------------------------------
@@ -946,136 +945,29 @@ class ServingConfig:
             self.retained_slots)
         assert self.kv_block_size is None or self.kv_block_size >= 1, (
             self.kv_block_size)
-        if model is not None and model.window_layer_period:
-            # the pool holds rings and whole regions side by side
-            # (models/attention.py::HybridKVCache); what a ring cannot do
-            # yet is refused by name, not served wrong (ROADMAP R3)
-            refused = {
-                "enable_prefix_cache": self.enable_prefix_cache,
-                "retained_slots": self.retained_slots,
-                "preemption": self.preemption,
-                "speculative_k": self.speculative_k,
-                "kv_block_size": self.kv_block_size is not None,
-                "block_native_attn": self.block_native_attn,
-                "serving_pp": self.serving_pp > 1,
-                "serving_tp": self.serving_tp > 1,
-                "prefill_tp": (self.prefill_tp or 1) > 1,
-                "decode_tp": (self.decode_tp or 1) > 1,
-                "disaggregate_prefill": self.disaggregate_prefill,
-                "host_kv_bytes": self.host_kv_bytes,
-                "adapter_slots": self.adapter_slots,
-                "kv_dtype int8": (self.kv_dtype or "bfloat16") == "int8",
-            }
-            for name, on in refused.items():
-                assert not on, (
-                    f"window_layer_period={model.window_layer_period} "
-                    f"(window and full attention in one stack): {name} is "
-                    "refused on the pool of rings and whole regions: a "
-                    "ring keeps the last sliding_window rows only, so a "
-                    "retained, parked, cloned or rewound slot has lost the "
-                    "rows it would need, the block arena and its kernel "
-                    "know one region shape, and the two stacks have no "
-                    "stage cut, head shard or adapter bank (ROADMAP R3)")
-        if model is not None and model.layers_of("conv"):
-            # the pool holds a convolution state a slot beside the keys and
-            # values (models/attention.py::ConvKVCache); what a state cannot
-            # do yet is refused by name, not served wrong (ROADMAP R6)
-            cut = ("a state is the last two inputs at the slot's CURRENT "
-                   "length: cutting, cloning or rewinding a slot to a "
-                   "shorter one needs a snapshot of the state taken at the "
-                   "cut")
-            arena = ("the block arena, which the host tier and the handoff "
-                     "move by blocks, has no row for a state")
-            shard = "the state and the depthwise kernel need a channel shard"
-            refused = {
-                "enable_prefix_cache": (self.enable_prefix_cache, cut),
-                "retained_slots": (self.retained_slots, cut),
-                "speculative_k": (self.speculative_k, cut),
-                "preemption": (self.preemption, (
-                    "a parked slot's state has to be read out and put back "
-                    "with its rows: kv_pool.slice_slot cuts keys and values "
-                    "alone")),
-                "kv_block_size": (self.kv_block_size is not None, arena),
-                "block_native_attn": (self.block_native_attn, arena),
-                "host_kv_bytes": (self.host_kv_bytes, arena),
-                "disaggregate_prefill": (self.disaggregate_prefill, arena),
-                "serving_pp": (self.serving_pp > 1, (
-                    "the stages cut ONE stack of identical layers and the "
-                    "arena by layer; the kinds are stacked apart")),
-                "serving_tp": (self.serving_tp > 1, shard),
-                "prefill_tp": ((self.prefill_tp or 1) > 1, shard),
-                "decode_tp": ((self.decode_tp or 1) > 1, shard),
-                "adapter_slots": (self.adapter_slots, (
-                    "the adapter bank is stacked over one kind of layer")),
-                "kv_dtype int8": ((self.kv_dtype or "bfloat16") == "int8", (
-                    "the cache of two kinds of state has no scales")),
-            }
-            for name, (on, why) in refused.items():
-                assert not on, (
-                    f"layer_types with conv layers: {name} is refused on the "
-                    f"pool of keys, values and convolution state: {why} "
-                    "(ROADMAP R6)")
-        if model is not None and model.hc_mult > 1:
-            assert not self.adapter_slots and self.serving_pp == 1 \
-                and max(self.serving_tp, self.prefill_tp or 1,
-                        self.decode_tp or 1) == 1, (
-                f"hc_mult={model.hc_mult} (hyper-connections): "
-                "adapter_slots (LoRA adapter banks) and a serving mesh "
-                "(serving_tp / prefill_tp / decode_tp / serving_pp > 1) are "
-                "refused: the adapter scan and a stage's `layer_offset` "
-                "have not been run under a residual of streams")
-        if model is not None and model.mla:
-            # the latent pool is ONE array [layers, slots, positions, row]
-            # (models/mla.py::LatentKVCache): no head axis, no k beside v
-            widths = (self.serving_tp, self.prefill_tp or 1,
-                      self.decode_tp or 1, self.serving_pp)
-            assert max(widths) == 1, (
-                "MLA (kv_lora_rank set): serving_tp / prefill_tp / "
-                f"decode_tp / serving_pp > 1 are refused (got {widths}): "
-                "the latent row has no head axis to shard over a serving "
-                "mesh and the two stacks have no stage cut (ROADMAP R5)")
-            assert self.kv_block_size is None \
-                and not self.block_native_attn, (
-                "MLA (kv_lora_rank set): kv_block_size / block_native_attn "
-                "are refused: the block arena and its kernel are built "
-                "round k and v of [kv_heads, head_dim] (ROADMAP R5)")
-            assert (self.kv_dtype or "bfloat16") != "int8", (
-                "MLA (kv_lora_rank set): an int8 KV pool is refused: the "
-                "per-(token, head) scales have no head to belong to, and "
-                "a scale a latent row has not been tried against the "
-                "reference (ROADMAP R5)")
-            assert not self.disaggregate_prefill \
-                and not self.host_kv_bytes, (
-                "MLA (kv_lora_rank set): disaggregate_prefill and the "
-                "host tier (host_kv_bytes) are refused: both move "
-                "physical KV blocks, which the latent pool does not have "
-                "(ROADMAP R5)")
-            assert not self.adapter_slots, (
-                "MLA (kv_lora_rank set): adapter_slots is refused: the "
-                "LoRA bank holds factors for wq / wkv / wo, which this "
-                "attention does not have")
-        if self.kv_block_size is not None:
-            if self.enable_prefix_cache:
-                # prefix hits must stay aligned to BOTH the jit-bucket
-                # grid (so suffix shapes keep hitting the existing
-                # compile cache) and block boundaries (so a hit is
-                # pure block-map aliasing, no partial-block
-                # copy-on-write)
-                assert self.kv_block_size % self.prefill_bucket == 0, (
-                    f"kv_block_size={self.kv_block_size} must be a "
-                    f"multiple of prefill_bucket="
-                    f"{self.prefill_bucket} when enable_prefix_cache "
-                    "is set (hits must align to block AND jit-bucket "
-                    "boundaries)")
-            if model is not None:
-                cap = self.max_len or model.max_position_embeddings
-                if (model.sliding_window is not None
-                        and model.attention_impl == "flash"):
-                    cap = min(cap, model.sliding_window)
-                assert cap % self.kv_block_size == 0 \
-                    or self.kv_block_size >= cap, (
-                    f"kv_block_size={self.kv_block_size} must divide "
-                    f"the slot capacity ({cap})")
+        # what this model's pool cannot serve: one table, read here, by the
+        # engine (which calls this), by the pool and by docs/serving.md
+        blocks = self.kv_block_size
+        if model is not None:
+            from megatron_tpu.serving import capabilities
+            refused = capabilities.refusals(self, model)
+            assert not refused, refused[0][2]
+            # the block size the pool will really have (a block as large
+            # as a slot's region is the region): what "requires
+            # kv_block_size" below means
+            blocks = capabilities.resolved_block_size(
+                model, self.max_len or model.max_position_embeddings,
+                self.kv_block_size)
+        if self.kv_block_size is not None and self.enable_prefix_cache:
+            # prefix hits must stay aligned to BOTH the jit-bucket grid
+            # (so suffix shapes keep hitting the existing compile cache)
+            # and block boundaries (so a hit is pure block-map aliasing,
+            # no partial-block copy-on-write)
+            assert self.kv_block_size % self.prefill_bucket == 0, (
+                f"kv_block_size={self.kv_block_size} must be a "
+                f"multiple of prefill_bucket={self.prefill_bucket} when "
+                "enable_prefix_cache is set (hits must align to block "
+                "AND jit-bucket boundaries)")
         assert self.priority_levels >= 1, self.priority_levels
         # preemption triggers only when a QUEUED request outranks a
         # RUNNING one; with a single priority class every request
@@ -1125,20 +1017,6 @@ class ServingConfig:
         assert self.max_engine_restarts >= 0, self.max_engine_restarts
         assert self.engine_step_timeout_s is None or \
             self.engine_step_timeout_s > 0.0, self.engine_step_timeout_s
-        if self.block_native_attn and model is not None:
-            # the block kernel implements plain causal masking only:
-            # no banded-window mask (a non-rolling sliding-window pool
-            # would silently need one) and no ring slot->position map
-            # (a ROLLING pool's layout breaks the kernel's contiguous
-            # position arithmetic) — sliding-window models keep the
-            # resolve_view/scatter_view bracket either way
-            assert model.sliding_window is None, (
-                "block_native_attn is unsupported on sliding-window "
-                "models: the block kernel has no window-band mask, "
-                "and ROLLING layouts additionally break its "
-                "contiguous position arithmetic — sliding-window "
-                "pools keep the resolve_view/scatter_view bracket. "
-                "Serve this model without --block_native_attn.")
         assert self.speculative_k >= 0, self.speculative_k
         if self.speculative_k:
             max_len = self.max_len
@@ -1147,62 +1025,6 @@ class ServingConfig:
             assert max_len is None or self.speculative_k < max_len, (
                 f"speculative_k={self.speculative_k} must be smaller "
                 f"than the slot capacity (max_len={max_len})")
-        if model is not None and model.sliding_window is not None \
-                and not model.window_layer_period:
-            # ROLLING pools (flash impl caps the region to W < max_len)
-            # hold the last W positions ring-ordered by position % W.
-            # WHOLE-REGION rolling pools cannot retain, clone, or park:
-            # a retained ring row still rides every decode step and its
-            # idle garbage writes (at final_length % W) wrap INTO the
-            # live ring content. The BLOCK-GRANULAR pool
-            # (kv_block_size) lifts prefix-cache and preemption —
-            # retained ring blocks hold no grid row, so idle writes
-            # land in the shared trash block and the ring content
-            # survives verbatim; clones continue a retained sequence
-            # at its exact length (or any prefix, while the ring has
-            # not wrapped). Two exclusions REMAIN regardless of
-            # blocks, each pinned by tests:
-            # - prefill_chunk: an offset>0 multi-token chunk's ring
-            #   writes evict history its own early queries still need
-            #   (write-before-read breaks inside one dispatch);
-            # - speculative_k: a rejected draft's ring write already
-            #   evicted the position it displaced, so the
-            #   accepted-length rewind cannot restore it.
-            max_len = self.max_len or model.max_position_embeddings
-            rolling = (model.attention_impl == "flash"
-                       and model.sliding_window < max_len)
-            blocks = self.kv_block_size is not None
-            assert not (rolling and self.enable_prefix_cache
-                        and not blocks), (
-                "enable_prefix_cache on a ROLLING (sliding-window) KV "
-                "pool requires the block-granular pool "
-                "(--kv_block_size): a retained whole-region ring row "
-                "still rides the decode grid and its idle writes wrap "
-                "into the live ring. Set kv_block_size (dividing the "
-                "window) or serve with the prefix cache off.")
-            assert not (rolling and self.preemption and not blocks), (
-                "preemption on a ROLLING (sliding-window) KV pool "
-                "requires the block-granular pool (--kv_block_size): "
-                "whole-region rolling rows cannot park/resume without "
-                "their idle ring writes clobbering retained state. "
-                "Set kv_block_size or serve without preemption.")
-            assert not (rolling and self.prefill_chunk is not None), (
-                "prefill_chunk is unsupported on ROLLING "
-                "(sliding-window) KV pools (with or without "
-                "kv_block_size): an offset>0 chunk's ring writes "
-                "evict history its own queries still need within one "
-                "dispatch. Serve this model unchunked — rolling "
-                "prefix-hit suffixes append single-token steps "
-                "instead.")
-            assert not (rolling and self.speculative_k), (
-                "speculative_k is unsupported on ROLLING "
-                "(sliding-window) KV pools (with or without "
-                "kv_block_size): the verify window's ring writes "
-                "evict history as they land, so rewinding to the "
-                "accepted length cannot restore what a rejected "
-                "draft overwrote — the write-before-read rewind "
-                "invariant breaks. Serve this model without "
-                "speculative decoding.")
         # flash-impl int8 pools: NO exclusions anymore. The offset-0
         # flash prefill shortcut is disabled for quantized caches
         # (models/attention.py): every cached int8 forward — prefill,
@@ -1244,13 +1066,6 @@ class ServingConfig:
                 "builds no serving mesh — drop serial_fallback or the "
                 "tp widths")
             if model is not None:
-                assert not model.qk_norm and not (
-                    model.num_experts > 1
-                    and model.moe_dispatch == "dropless"), (
-                    "serving widths > 1 have not been made to work with "
-                    "qk_norm (a norm across sharded heads) or "
-                    "moe_dispatch='dropless' (one unpartitioned grouped "
-                    "product): serve this model at width 1")
                 for phase, tp in (("prefill", eff_pre),
                                   ("decode", eff_dec)):
                     assert model.num_attention_heads % tp == 0 and \
@@ -1274,22 +1089,12 @@ class ServingConfig:
             assert not self.serial_fallback, (
                 "disaggregate_prefill requires the continuous-batching "
                 "engine (the serial path has no prefill group)")
-            assert self.kv_block_size is not None, (
+            assert blocks is not None, (
                 "disaggregate_prefill requires kv_block_size: the "
                 "prefill->decode handoff unit is the physical KV "
                 "block (ceil(plen/B) live blocks move, never a whole "
-                "cap region) — set --kv_block_size or serve "
-                "single-group")
-            if model is not None and model.sliding_window is not None:
-                max_len = self.max_len or model.max_position_embeddings
-                rolling = (model.attention_impl == "flash"
-                           and model.sliding_window < max_len)
-                assert not rolling, (
-                    "disaggregate_prefill is unsupported on ROLLING "
-                    "(sliding-window) KV pools: the ring's exact-"
-                    "length block handoff is not defined — serve "
-                    "rolling models single-group "
-                    "(chunk-interleave fallback)")
+                "cap region) — set --kv_block_size, smaller than a "
+                "slot's region, or serve single-group")
         # --- pipeline-sharded serving (serving/topology.py stages) ----
         assert self.serving_pp >= 1, self.serving_pp
         assert self.pp_waves >= 1, self.pp_waves
@@ -1298,11 +1103,12 @@ class ServingConfig:
                 "serving_pp > 1 requires the continuous-batching "
                 "engine: the serial fallback path builds no serving "
                 "mesh — drop serial_fallback or serving_pp")
-            assert self.kv_block_size is not None, (
+            assert blocks is not None, (
                 "serving_pp requires kv_block_size: the per-layer KV "
                 "arena partitions on the LAYER axis across stages and "
                 "each stage's slice is a block arena — set "
-                "--kv_block_size or serve with serving_pp=1")
+                "--kv_block_size, smaller than a slot's region, or serve "
+                "with serving_pp=1")
             assert not self.disaggregate_prefill, (
                 "serving_pp does not compose with disaggregate_prefill"
                 ": the staged decode chain already owns the cross-mesh "
@@ -1314,7 +1120,7 @@ class ServingConfig:
                 "runs through the SAME stage chain as decode (each "
                 "stage is decode_tp wide) — drop prefill_tp; "
                 "decode_tp/serving_tp set the per-stage width")
-            assert not getattr(self, "block_native_attn", False), (
+            assert not self.block_native_attn, (
                 "serving_pp is unsupported with block_native_attn: "
                 "the staged arena slices dispatch through the "
                 "resolve/scatter bracket — drop block_native_attn or "
@@ -1336,11 +1142,6 @@ class ServingConfig:
                     f"num_layers={model.num_layers}: stages hold "
                     "equal contiguous layer slices "
                     "(parallel/pipeline.stage_params_reshape)")
-                assert model.sliding_window is None, (
-                    "serving_pp is unsupported on sliding-window "
-                    "models: the rolling ring's per-layer offset "
-                    "arithmetic does not survive the staged arena "
-                    "partition — serve with serving_pp=1")
         if self.pp_waves > 1:
             assert self.serving_pp > 1, (
                 "pp_waves > 1 without serving_pp > 1 is inert: waves "
@@ -1377,11 +1178,11 @@ class ServingConfig:
             # the tier demotes/restores retained BLOCK LISTS — the unit
             # the block-granular pool pins and the prefix index routes
             # hits through; without either there is nothing to demote
-            assert self.enable_prefix_cache \
-                and self.kv_block_size is not None, (
+            assert self.enable_prefix_cache and blocks is not None, (
                 "host_kv_bytes requires enable_prefix_cache AND "
-                "kv_block_size: the host tier demotes retained prefix "
-                "BLOCK lists (docs/serving.md 'Front door')")
+                "kv_block_size (smaller than a slot's region): the host "
+                "tier demotes retained prefix BLOCK lists "
+                "(docs/serving.md 'Front door')")
         assert not (self.num_replicas > 1 and self.serial_fallback), (
             "num_replicas > 1 routes through the continuous-batching "
             "engine; serial_fallback has no replicas to route over")

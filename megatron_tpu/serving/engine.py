@@ -317,11 +317,11 @@ class ServingEngine:
         self.gen = generator
         cfg = generator.cfg
         self.cfg = cfg
-        self.serving = serving if serving is not None else ServingConfig()
+        # the one decision of what this model's pool may serve
+        # (serving/capabilities.py), before anything is built on it
+        self.serving = (serving if serving is not None
+                        else ServingConfig()).validate(cfg)
         self.max_len = self.serving.max_len or cfg.max_position_embeddings
-        assert self.max_len <= cfg.max_position_embeddings, (
-            f"ServingConfig.max_len={self.max_len} exceeds "
-            f"max_position_embeddings={cfg.max_position_embeddings}")
         self.num_slots = self.serving.num_slots
         kv_dtype = (generator.kv_cache_dtype
                     if self.serving.kv_dtype is None
@@ -350,16 +350,13 @@ class ServingEngine:
         # widths lets the optimizer pick the split. Signals only exist
         # later, and a re-plan is only ever applied at the quiesced
         # swap/upgrade barrier (_apply_swap).
-        self._placement_auto = bool(getattr(self.serving,
-                                            "placement_auto", False))
+        self._placement_auto = self.serving.placement_auto
         self._placement_plan = None
         if self._placement_auto:
             from megatron_tpu.serving.placement import plan_placement
             budget = devices_per_engine(self.serving)
-            explicit = (getattr(self.serving, "prefill_tp", None)
-                        or getattr(self.serving, "decode_tp", None)
-                        or not getattr(self.serving, "placement_budget",
-                                       None))
+            explicit = (self.serving.prefill_tp or self.serving.decode_tp
+                        or not self.serving.placement_budget)
             self._placement_plan = plan_placement(
                 budget, cfg, signals=None,
                 current=(resolve_phase_tp(self.serving) if explicit
@@ -418,63 +415,8 @@ class ServingEngine:
                 and self._rope is not None:
             self._rope = type(self._rope)(
                 *(t[:self.max_len] for t in self._rope))
-        if self._pp > 1:
-            # fail BEFORE the staged pool placement tries to slice a
-            # block-less arena (pinned reasons below)
-            assert self.pool.blocks_enabled, (
-                "serving_pp > 1 requires kv_block_size — the per-layer "
-                "KV arena partitions on the layer axis at block "
-                "granularity; see ServingConfig.validate")
         if self.topo is not None:
             self.topo.place_pool(self.pool)
-        # disaggregation re-asserts (engines can be constructed
-        # without ServingConfig.validate): the handoff unit is the
-        # physical block, and a rolling ring's exact-length handoff is
-        # undefined
-        assert not (self._disagg and not self.pool.blocks_enabled), (
-            "disaggregate_prefill requires kv_block_size — see "
-            "ServingConfig.validate")
-        assert not (self._disagg and self.pool.rolling), (
-            "disaggregate_prefill is unsupported on ROLLING pools — "
-            "see ServingConfig.validate")
-        # pipeline-sharded re-asserts (ServingConfig.validate's pinned
-        # reasons, repeated for engines constructed without it): staged
-        # decode partitions the BLOCK arena on layers and crosses the
-        # residual stream between stage meshes, so it needs blocks and
-        # excludes the paths that assume one whole-model mesh
-        if self._pp > 1:
-            assert self.pool.blocks_enabled, (
-                "serving_pp > 1 requires kv_block_size — the per-layer "
-                "KV arena partitions on the layer axis at block "
-                "granularity; see ServingConfig.validate")
-            assert not self._disagg, (
-                "serving_pp > 1 does not compose with "
-                "disaggregate_prefill — the staged decode group IS the "
-                "prefill group; see ServingConfig.validate")
-            assert not self.pool.rolling, (
-                "serving_pp > 1 is unsupported on ROLLING "
-                "(sliding-window) KV pools — see ServingConfig.validate")
-            assert not getattr(self.serving, "block_native_attn", False), (
-                "serving_pp > 1 keeps the resolve/scatter bracket — "
-                "block_native_attn is unsupported; see "
-                "ServingConfig.validate")
-            assert not int(getattr(self.serving, "host_kv_bytes", 0)
-                           or 0), (
-                "serving_pp > 1 does not compose with host_kv_bytes — "
-                "see ServingConfig.validate")
-            assert cfg.num_layers % self._pp == 0, (
-                f"serving_pp={self._pp} must divide "
-                f"num_layers={cfg.num_layers} — see "
-                "ServingConfig.validate")
-            assert self.num_slots % self._pp_waves == 0, (
-                f"pp_waves={self._pp_waves} must divide "
-                f"num_slots={self.num_slots} — see "
-                "ServingConfig.validate")
-            assert not (self._pp_waves > 1
-                        and int(self.serving.speculative_k or 0)), (
-                "pp_waves > 1 does not compose with speculative_k — "
-                "the verify window runs whole-grid; see "
-                "ServingConfig.validate")
         # block-granular pool: the static per-slot block map is
         # resolved at dispatch (kv_pool.resolve_view/scatter_view
         # bracket every compiled program), so the one-compile contract
@@ -489,18 +431,8 @@ class ServingEngine:
         # the bracketed path (test-pinned). Auto-off without
         # kv_block_size (no arena to index); ROLLING pools keep the
         # bracket (the ring's slot->position map breaks the kernel's
-        # position arithmetic) and validate() rejects the combination
-        # before it gets here.
-        self._kernel_on = (self._blocks_on
-                           and bool(getattr(self.serving,
-                                            "block_native_attn", False)))
-        # re-assert ServingConfig.validate for engines constructed
-        # without it: the kernel carries no window-band mask (and no
-        # ring map), so EVERY sliding-window model — rolling or not —
-        # keeps the resolve/scatter bracket
-        assert not (self._kernel_on and cfg.sliding_window is not None), (
-            "block_native_attn is unsupported on sliding-window "
-            "models — see ServingConfig.validate")
+        # position arithmetic), as every sliding-window model does.
+        self._kernel_on = self._blocks_on and self.serving.block_native_attn
         # gather/scatter observability (kv_gather_bytes_per_step /
         # kv_attn_path gauges): one resolve or scatter moves a full
         # contiguous view; dispatch sites accumulate into
@@ -517,43 +449,7 @@ class ServingEngine:
         self._prefix_on = bool(self.serving.enable_prefix_cache)
         self._chunk = self.serving.prefill_chunk
         self._preempt_on = bool(self.serving.preemption)
-        # re-assert ServingConfig.validate for engines constructed
-        # without it: one priority class makes preemption silently
-        # inert (every request clamps to 0 — nothing ever outranks a
-        # running slot)
-        assert not (self._preempt_on
-                    and self.serving.priority_levels < 2), (
-            "preemption requires priority_levels >= 2 — see "
-            "ServingConfig.validate")
-        # ROLLING exclusions, re-asserted with the RESOLVED pool layout
-        # (engines can be constructed without validate): whole-region
-        # rolling rows cannot retain/clone/park — their idle ring
-        # writes wrap into live content — so prefix cache and
-        # preemption need the block pool (where released rows' writes
-        # land in the shared trash block). Chunked prefill and
-        # speculative decoding stay excluded on rolling REGARDLESS of
-        # blocks: an offset>0 multi-token ring write evicts history
-        # its own queries (or a rejected draft's rewind) still needs.
-        assert not (self.pool.rolling and not self._blocks_on
-                    and (self._prefix_on or self._preempt_on)), (
-            "enable_prefix_cache/preemption on ROLLING "
-            "(sliding-window) KV pools requires kv_block_size — see "
-            "ServingConfig.validate")
-        assert not (self.pool.rolling and self._chunk is not None), (
-            "prefill_chunk is unsupported on ROLLING (sliding-window) "
-            "KV pools — see ServingConfig.validate")
-        self._spec_k = int(self.serving.speculative_k or 0)
-        assert not (self._spec_k and self.pool.rolling), (
-            "speculative_k is unsupported on ROLLING (sliding-window) "
-            "KV pools: the verify window's ring writes evict history, "
-            "so the accepted-length rewind cannot restore what a "
-            "rejected draft overwrote — see ServingConfig.validate")
-        # flash-impl int8 pools carry NO exclusions anymore: quantized
-        # caches skip the offset-0 flash prefill shortcut
-        # (models/attention.py), so every cached forward reads the
-        # same dequantized values through the same dot path and the
-        # token-exact contracts hold structurally.
-        assert self._spec_k < self.max_len, (self._spec_k, self.max_len)
+        self._spec_k = self.serving.speculative_k
         self.drafter = drafter if drafter is not None else NGramDrafter()
         # test seam: set to a list to record per-round (window tokens,
         # accept counts) for the serial-replay exactness pin
@@ -576,14 +472,9 @@ class ServingEngine:
         # (a ring restore is only sound at the exact length — not
         # worth a host copy that usually misses).
         self._host_tier = None
-        host_bytes = int(getattr(self.serving, "host_kv_bytes", 0) or 0)
-        if host_bytes > 0:
-            assert self._blocks_on and self._prefix_on, (
-                "host_kv_bytes requires enable_prefix_cache and "
-                "kv_block_size — the tier demotes retained BLOCK "
-                "lists; see ServingConfig.validate")
+        if self.serving.host_kv_bytes:
             from megatron_tpu.serving.host_tier import HostKVTier
-            self._host_tier = HostKVTier(host_bytes,
+            self._host_tier = HostKVTier(self.serving.host_kv_bytes,
                                          self._index.granularity)
             self.pool.on_evict_entry = self._demote_entry
         self._prefilling: List[_PendingPrefill] = []
@@ -627,22 +518,11 @@ class ServingEngine:
         # identical). The bank's stacked pytree is NOT donated: it
         # survives restarts and in-flight dispatches read the buffer
         # they captured while loads replace it functionally.
-        self._adapter_slots = int(getattr(self.serving, "adapter_slots",
-                                          0) or 0)
+        self._adapter_slots = self.serving.adapter_slots
         self._adapters_on = self._adapter_slots > 0
         self.adapters = None
         if self._adapters_on:
             from megatron_tpu.serving.adapters import AdapterBank
-            # re-assert ServingConfig.validate for engines constructed
-            # without it: a rank-0 bank holds no delta at all, and
-            # int8-quantized projections break the factored-vs-merged
-            # token-equivalence the adapter contract rests on
-            assert self.serving.adapter_rank >= 1, (
-                "adapter_slots > 0 requires adapter_rank >= 1 — see "
-                "ServingConfig.validate")
-            assert cfg.quantized_gemm == "none", (
-                "adapter_slots > 0 is unsupported with "
-                "quantized_gemm='int8' — see ServingConfig.validate")
             bank_sh = bank_sh_pre = None
             if self.topo is not None:
                 # tp-sharded bank rows: B factors by their projection
@@ -655,8 +535,7 @@ class ServingEngine:
                         self.topo.prefill_mesh)
             self.adapters = AdapterBank(
                 cfg, self._adapter_slots, self.serving.adapter_rank,
-                host_bytes=int(getattr(self.serving,
-                                       "adapter_host_bytes", 0) or 0),
+                host_bytes=self.serving.adapter_host_bytes,
                 metrics=self.metrics, shardings=bank_sh,
                 prefill_shardings=bank_sh_pre)
 
@@ -1429,8 +1308,7 @@ class ServingEngine:
             self._pending_swap = ticket
             self._cond.notify_all()
         budget = (timeout if timeout is not None
-                  else float(getattr(self.serving, "swap_timeout_s",
-                                     120.0) or 120.0))
+                  else self.serving.swap_timeout_s)
         if not ticket.done.wait(budget):
             with self._cond:
                 if self._pending_swap is ticket and not ticket.taken:
@@ -1699,8 +1577,9 @@ class ServingEngine:
                 f"{phase} serving width {tp} (prefill_tp/decode_tp/"
                 f"serving_tp) must divide the head counts "
                 f"({cfg.num_attention_heads} q / {cfg.num_kv_heads} "
-                f"kv) and the padded vocab ({cfg.padded_vocab_size}) "
-                "— see ServingConfig.validate")
+                f"kv) and the padded vocab ({cfg.padded_vocab_size}): "
+                "ServingConfig.validate's rule, held here for the widths "
+                "of a placement plan, which are not the configuration's")
         if self.topo.serving_pp > 1:
             # pipeline-sharded decode: the model tree splits into
             # per-stage slices, each resident ONLY on its own stage
@@ -2384,8 +2263,8 @@ class ServingEngine:
 
         # unreachable under serving_pp (blocks are REQUIRED, so the
         # whole-region slice/insert never dispatch; disaggregation and
-        # the host tier are rejected by validate + the constructor
-        # re-asserts) — None so an accidental dispatch fails loudly
+        # the host tier are rejected by validate, which the
+        # constructor calls) — None so an accidental dispatch fails loudly
         self._slice = None
         self._insert = None
         self._handoff_insert = None

@@ -57,6 +57,7 @@ from megatron_tpu.inference.generation import (KV_CACHE_AXES, init_kv_caches,
 from megatron_tpu.models.attention import (BlockKVCache, ConvKVCache,
                                             HybridKVCache, KVCache)
 from megatron_tpu.models.mla import LatentKVCache
+from megatron_tpu.serving import capabilities
 from megatron_tpu.utils.logging import print_rank_0
 
 
@@ -397,31 +398,21 @@ class SlotKVPool:
         self.num_slots = num_slots
         self.max_len = max_len
         self.dtype = jnp.dtype(dtype)
-        self.cap = kv_region_cap(cfg, max_len)  # rolling pools clamp to W
-        self.rolling = (cfg.sliding_window is not None
-                        and self.cap == cfg.sliding_window
-                        and self.cap < max_len)
-        # window and full layers in one stack: rings and whole regions
-        # side by side (attention.HybridKVCache). The slot's capacity in
-        # POSITIONS is the regions' (max_len); `rolling`, which means "the
-        # whole slot forgets", stays False: chunks and buckets are taken
-        if self.hybrid:
-            assert block_size is None, (
-                "kv_block_size is refused on a pool of rings and regions "
-                "(ServingConfig.validate)")
-            self.cap, self.rolling = max_len, False
-        assert not (self.conv_layers and block_size is not None), (
-            "kv_block_size is refused on a pool with a convolution state "
-            "(ServingConfig.validate)")
-        if block_size is not None and block_size >= self.cap:
-            # whole-region blocks ARE the regions — EXCEPT on rolling
-            # pools, where block mode is what makes retention possible
-            # at all (row-less entries + the trash map): there a
-            # one-block-per-slot arena is the legitimate degenerate
-            # case, and silently coercing it away would break the
-            # validate()-accepted config at the engine's
-            # rolling-requires-blocks assertion
-            block_size = self.cap if self.rolling else None
+        # what a slot holds, the capacity in POSITIONS and the block size
+        # are serving/capabilities.py's, as ServingConfig.validate read them.
+        # Window and full layers in one stack (rings and whole regions side
+        # by side, attention.HybridKVCache) have the regions' capacity, and
+        # `rolling`, which means "the whole slot forgets", stays False:
+        # chunks and buckets are taken
+        kind = capabilities.pool_kind(cfg, max_len)
+        self.cap = capabilities.slot_cap(cfg, max_len)
+        self.rolling = kind == "rolling"
+        # a pool can be built without an engine, so it keeps this guard
+        assert block_size is None \
+            or "kv_block_size" not in capabilities.REFUSED[kind], \
+            capabilities.refusal(kind, "kv_block_size", cfg)
+        block_size = capabilities.resolved_block_size(cfg, max_len,
+                                                      block_size)
         self.block_size = block_size
         self._free: collections.deque = collections.deque(range(num_slots))
         # retained state, oldest first (OrderedDict as an LRU: touch
@@ -448,9 +439,6 @@ class SlotKVPool:
                 "kv_region_cap drifted from init_kv_caches")
             return
         # ---- block mode ----------------------------------------------
-        assert self.cap % block_size == 0, (
-            f"kv block_size={block_size} must divide the region "
-            f"capacity ({self.cap})")
         self.blocks_per_slot = self.cap // block_size
         # one block set per slot plus the shared TRASH block (last
         # physical index): same usable token capacity as the

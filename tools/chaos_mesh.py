@@ -104,8 +104,11 @@ def sample_config(rng: random.Random, require=()):
     """Sample (model_kwargs, serving_kwargs, rejections) — resampling
     through ServingConfig.validate() until a LEGAL point of the
     capability matrix comes up; every rejection is recorded (matrix
-    exclusions exercised loudly, not skipped). The fault schedule is
-    sampled separately (build_fault_injector / build_actions)."""
+    exclusions exercised loudly, not skipped). What rejects is
+    validate()'s alone to say: its rules between options, and the table
+    of model kind x feature in serving/capabilities.py. The fault
+    schedule is sampled separately (build_fault_injector /
+    build_actions)."""
     from megatron_tpu.config import ServingConfig
     rejections = []
     for _ in range(200):
@@ -136,10 +139,8 @@ def sample_config(rng: random.Random, require=()):
         # per-phase widths (serving/topology.py): disaggregated configs
         # draw independent prefill_tp/decode_tp — asymmetric splits are
         # the point. A small slice deliberately draws ILL-FORMED
-        # corners: per-phase widths without disaggregation (unequal
-        # widths on a shared mesh) or a width that does not divide the
-        # tiny model's kv heads — both must come back as LOUD
-        # validate() rejections, never silent coercion.
+        # corners (validate()'s width rules), which must come back as
+        # LOUD rejections, never silent coercion.
         if kw["disaggregate_prefill"] and rng.random() < 0.35:
             kw["prefill_tp"] = rng.choice([1, 2])
             kw["decode_tp"] = rng.choice([1, 2])
@@ -148,10 +149,9 @@ def sample_config(rng: random.Random, require=()):
         # pipeline-sharded serving axis (serving/topology.py
         # "Pipeline-sharded serving"): a slice draws a 2-stage
         # layer-staged decode chain, half of it wave-interleaved. The
-        # draw deliberately lands on ILLEGAL pairings too (pp x
-        # disagg, pp x whole-region pool, pp x kernel, pp x host
-        # tier, waves x speculative) — all must come back as LOUD
-        # validate() rejections, never silent coercion.
+        # draw deliberately lands on ILLEGAL pairings too (validate()'s
+        # serving_pp rules) — all must come back as LOUD rejections,
+        # never silent coercion.
         if rng.random() < 0.2:
             kw["serving_pp"] = 2
             if rng.random() < 0.5:
@@ -201,10 +201,9 @@ def sample_config(rng: random.Random, require=()):
                       slo_ttft_ms=30_000.0, slo_itl_p99_ms=30_000.0)
         if "pp" in require:
             # layer-staged decode chain (2 stages x width 1) with the
-            # second wave interleaved on the slot grid. The staged
-            # exclusions (disagg, kernel, host tier, explicit prefill
-            # width, speculative under waves) would validate()-reject,
-            # so pin the legal corner; the bare engine keeps fan-out
+            # second wave interleaved on the slot grid. validate()'s
+            # serving_pp rules would reject the other draws, so pin
+            # the legal corner; the bare engine keeps fan-out
             # admissible, exercising COW forks over the staged pool
             kw.update(serving_pp=2, decode_tp=1, pp_waves=2,
                       kv_block_size=16, block_native_attn=False,
