@@ -221,6 +221,20 @@ class ModelConfig:
     # (models/attention.py::ConvKVCache).
     layer_types: Optional[Tuple[str, ...]] = None
     conv_L_cache: int = 3
+    # "mamba" in `layer_types`: a Mamba-1 selective state-space mixer
+    # (models/mamba.py; the published `mamba_*` keys of a Jamba config).
+    # d_inner = mamba_expand x hidden_size channels, each with a state of
+    # `mamba_d_state` values, a depthwise causal kernel of `mamba_d_conv`
+    # taps ahead of the scan, and a step size projected through
+    # `mamba_dt_rank` values. A sequence carries the kernel's last
+    # mamba_d_conv - 1 inputs and the [d_state, d_inner] float32 state a
+    # layer, whatever its length (`ConvKVCache.conv` and `.ssm`).
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_dt_rank: int = 160
+    mamba_expand: int = 2
+    mamba_conv_bias: bool = True
+    mamba_proj_bias: bool = False
     # RMSNorm over each head's channels of q and of k, one scale
     # [kv_channels] shared by the heads, before the rotary (LFM2's
     # q_layernorm / k_layernorm). `qk_norm` above is OLMoE's, over all the
@@ -293,10 +307,45 @@ class ModelConfig:
         return self.layers_of("full_attention")
 
     @property
+    def state_kind(self) -> Optional[str]:
+        """The kind of layer that keeps a state of fixed size a sequence:
+        "conv", "mamba" or None (a model has one: validate refuses both)."""
+        return next((k for k in ("conv", "mamba") if self.layers_of(k)),
+                    None)
+
+    @property
+    def state_layers(self) -> int:
+        """Layers that keep a state of fixed size a sequence and no keys or
+        values."""
+        return self.layers_of("conv") + self.layers_of("mamba")
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_expand * self.hidden_size
+
+    @property
+    def conv_state_shape(self) -> Tuple[int, int]:
+        """(rows, channels) of the depthwise kernel's state a layer: its
+        last taps - 1 inputs, over the hidden size ("conv") or over d_inner
+        ("mamba")."""
+        if self.state_kind == "mamba":
+            return self.mamba_d_conv - 1, self.mamba_d_inner
+        return self.conv_L_cache - 1, self.hidden_size
+
+    @property
     def conv_state_width(self) -> int:
-        """Values one sequence costs one convolution layer, whatever its
-        length: the last `conv_L_cache` - 1 inputs of the depthwise kernel."""
-        return (self.conv_L_cache - 1) * self.hidden_size
+        """Values one sequence costs one state layer's depthwise kernel,
+        whatever its length: its last inputs but the newest."""
+        rows, channels = self.conv_state_shape
+        return rows * channels
+
+    @property
+    def ssm_state_width(self) -> int:
+        """float32 values one sequence costs one "mamba" layer's scan,
+        whatever its length (0 where the model has no such layer)."""
+        if self.state_kind != "mamba":
+            return 0
+        return self.mamba_d_state * self.mamba_d_inner
 
     def dense_layers(self) -> "ModelConfig":
         """The configuration of the `first_k_dense_replace` leading layers:
@@ -1488,13 +1537,25 @@ class MegatronConfig:
             # at a time, the kinds' parameters stacked apart
             kinds = set(model.layer_types)
             assert len(model.layer_types) == model.num_layers \
-                and kinds <= {"conv", "full_attention"}, (
+                and kinds <= {"conv", "mamba", "full_attention"}, (
                 f"layer_types has {len(model.layer_types)} entries "
                 f"{sorted(kinds)} for num_layers={model.num_layers}: one of "
-                "'conv' | 'full_attention' a layer")
-            assert model.conv_L_cache >= 2, (
-                f"conv_L_cache={model.conv_L_cache}: the kernel's length, "
+                "'conv' | 'mamba' | 'full_attention' a layer")
+            assert not {"conv", "mamba"} <= kinds, (
+                "layer_types with 'conv' AND 'mamba' layers: the cache "
+                "holds one kind of fixed-size state "
+                "(models/attention.py::ConvKVCache)")
+            assert model.conv_L_cache >= 2 and model.mamba_d_conv >= 2, (
+                f"conv_L_cache={model.conv_L_cache}, mamba_d_conv="
+                f"{model.mamba_d_conv}: the kernel's length, "
                 "of which the state keeps all but the newest input")
+            if "mamba" in kinds:
+                assert model.mamba_d_state >= 1 and model.mamba_dt_rank >= 1 \
+                    and model.mamba_expand >= 1 \
+                    and not model.first_k_dense_replace, (
+                    "'mamba' layers need mamba_d_state, mamba_dt_rank and "
+                    "mamba_expand >= 1, and have not been run behind a "
+                    "leading dense stack (first_k_dense_replace)")
             assert not model.mla and not model.mtp_num_layers \
                 and model.sliding_window is None \
                 and not model.parallel_attn and not model.use_post_ln \
@@ -1505,11 +1566,11 @@ class MegatronConfig:
                 "then one feed-forward, over whole regions of keys and "
                 "values (ROADMAP R6)")
             assert max(sharded.values()) == 1, (
-                "layer_types (convolution and attention layers in one "
-                f"model) has been made to work on one device only (got "
-                f"{sharded}): the kinds are stacked apart with no stage "
-                "cut, and the convolution has no channel shard "
-                "(ROADMAP R6)")
+                "layer_types (convolution or state-space layers and "
+                f"attention in one model) has been made to work on one "
+                f"device only (got {sharded}): the kinds are stacked apart "
+                "with no stage cut, and the convolution and the scan have "
+                "no channel shard (ROADMAP R6)")
             assert model.attention_impl in ("dot", "flash") \
                 and model.attention_dropout == 0.0 \
                 and model.drop_path_rate == 0.0, (
@@ -2061,6 +2122,57 @@ def lfm2_config(size: str = "8b-a1b", **overrides) -> ModelConfig:
     return ModelConfig(**base).derived()
 
 
+def jamba_layer_types(num_layers: int, attn_layer_period: int = 14,
+                      attn_layer_offset: int = 7) -> Tuple[str, ...]:
+    """The mixers of a Jamba stack from its published `attn_layer_period` /
+    `attn_layer_offset`: layer l is attention where l % period == offset
+    and a Mamba mixer elsewhere (the family's modelling code's rule)."""
+    return tuple(
+        "full_attention" if l % attn_layer_period == attn_layer_offset
+        else "mamba" for l in range(num_layers))
+
+
+def jamba_config(size: str = "2-3b", **overrides) -> ModelConfig:
+    """Jamba presets: every size of "2-3b" is a key of
+    ai21labs/AI21-Jamba2-3B's config.json (`jamba`: 28 layers, hidden 2560;
+    `attn_layer_period` 14 / `attn_layer_offset` 7, so layers 7 and 21 are
+    attention (20 heads of 128 over ONE kv head, no positional term of any
+    kind) and the other 26 Mamba-1 mixers (`mamba_expand` 2: d_inner 5120;
+    `mamba_d_state` 16, `mamba_dt_rank` 160, `mamba_d_conv` 4 with a bias,
+    no bias on the projections; RMSNorm on dt, B and C); `num_experts` 1,
+    so every layer's feed-forward is the dense SiLU-gated MLP of width
+    8192; RMSNorm eps 1e-6; vocabulary 65,536, tied head; 262,144
+    positions). 3,028 M parameters, held in bfloat16. A cut of the depth
+    gives its own `--layer_types`."""
+    presets = {
+        "tiny": dict(num_layers=28, hidden_size=64, num_attention_heads=4,
+                     num_kv_heads=1, kv_channels=16, ffn_hidden_size=96,
+                     vocab_size=512, seq_length=128, mamba_d_state=16,
+                     mamba_dt_rank=4, attention_impl="dot"),
+        "2-3b": dict(num_layers=28, hidden_size=2560,
+                     num_attention_heads=20, num_kv_heads=1,
+                     kv_channels=128, ffn_hidden_size=8192,
+                     vocab_size=65536, seq_length=4096,
+                     max_position_embeddings=262144, mamba_d_state=16,
+                     mamba_dt_rank=160, params_dtype="bfloat16"),
+    }
+    if size not in presets:
+        raise ValueError(f"unknown jamba size {size!r}; "
+                         f"valid: {sorted(presets)}")
+    base = dict(
+        use_rotary_emb=False, use_position_embedding=False,
+        norm_type="rmsnorm", norm_epsilon=1e-6, activation="swiglu",
+        use_bias=False, use_post_ln=False, parallel_attn=False,
+        tie_embed_logits=True, mamba_d_conv=4, mamba_expand=2,
+        mamba_conv_bias=True, mamba_proj_bias=False,
+        attention_impl="flash",  # see llama2_config
+    )
+    base.update(presets[size])
+    base.update(overrides)
+    base.setdefault("layer_types", jamba_layer_types(base["num_layers"]))
+    return ModelConfig(**base).derived()
+
+
 def gpt_config(**overrides) -> ModelConfig:
     base = dict(
         num_layers=12, hidden_size=768, num_attention_heads=12,
@@ -2092,5 +2204,7 @@ MODEL_PRESETS = {
     "command-a-plus": lambda: command_a_config("plus"),
     "lfm2-8b-a1b-tiny": lambda: lfm2_config("tiny"),
     "lfm2-8b-a1b": lambda: lfm2_config("8b-a1b"),
+    "jamba2-3b-tiny": lambda: jamba_config("tiny"),
+    "jamba2-3b": lambda: jamba_config("2-3b"),
     "gpt2": gpt_config,
 }

@@ -123,9 +123,10 @@ def init_kv_caches(cfg: ModelConfig, batch: int, max_len: int,
         # (models/attention.py::HybridKVCache)
         return HybridKVCache.create(cfg, batch, max_len, dtype,
                                     per_slot_offsets=per_slot_offsets)
-    if cfg.layers_of("conv"):
+    if cfg.state_layers:
         # keys and values for the attention layers alone, the convolutions'
-        # state beside them (models/attention.py::ConvKVCache)
+        # (and the scans') state beside them
+        # (models/attention.py::ConvKVCache)
         return ConvKVCache.create(cfg, batch, max_len, dtype,
                                   per_slot_offsets=per_slot_offsets)
     # rolling-cap decision single-sourced in kv_region_cap (the serving
@@ -428,7 +429,7 @@ def beam_search(generator: Generator, prompt: list[int], beam_width: int,
     beam_width by cumulative logprob (length-penalized at finalization,
     matching the reference's scoring)."""
     cfg = generator.cfg
-    assert not cfg.window_layer_period and not cfg.layers_of("conv"), (
+    assert not cfg.window_layer_period and not cfg.state_layers, (
         "beam_search reorders one k/v cache by beam: a stack of window and "
         "full layers (window_layer_period) and a pattern with convolution "
         "layers (layer_types) are refused")

@@ -214,9 +214,10 @@ class HybridKVCache(NamedTuple):
 
 
 class ConvKVCache(NamedTuple):
-    """The cache of a model whose layers are convolutions and attention
-    (`cfg.layer_types`): two kinds of state side by side, each carried
-    through the layer loop and written in place at its own kind's index.
+    """The cache of a model whose layers are convolutions or state-space
+    mixers and attention (`cfg.layer_types`): two or three kinds of state
+    side by side, each carried through the layer loop and written in place
+    at its own kind's index.
 
     - KEYS AND VALUES for the attention layers alone, [attention layers,
       batch, max_seq, n_kv * hd]: a position's row holds every kv head's
@@ -227,7 +228,13 @@ class ConvKVCache(NamedTuple):
       hidden]: the last inputs of the depthwise kernel (`a = B * z`,
       models/short_conv.py), the older first. It costs the same whatever
       the sequence's length, and no mask hides it: whoever takes a slot
-      writes the whole of its state.
+      writes the whole of its state. A "mamba" layer's kernel has
+      `mamba_d_conv` - 1 rows over d_inner channels (models/mamba.py).
+    - THE SCANS' STATE (`ssm`; None where the model has no "mamba" layer),
+      [mamba layers, batch, d_state, d_inner] float32 whatever the cache's
+      dtype: the recurrence's matrix a layer a sequence, the channels minor
+      (ops/selective_scan.py says why). Fixed in size and hidden by no mask,
+      as the convolutions' is.
 
     `live_rows` (scalar or [batch]): how many of the rows given to THIS call
     are real; those behind them are a bucket's padding. Keys and values of
@@ -238,11 +245,12 @@ class ConvKVCache(NamedTuple):
     `NO_PADDING` wherever all the rows given are real)."""
     k: jax.Array       # [attention layers, batch, max_seq, n_kv * head_dim]
     v: jax.Array
-    conv: jax.Array    # [conv layers, batch, conv_L_cache - 1, hidden]
+    conv: jax.Array    # [state layers, batch, taps - 1, channels]
     # tokens already in the cache, one entry an ATTENTION layer: [layers]
     # or [layers, batch], as KVCache.offset
     offset: jax.Array
     live_rows: jax.Array
+    ssm: Optional[jax.Array] = None   # [mamba layers, batch, d_state, d_inner]
 
     NO_PADDING = 2 ** 30
 
@@ -253,11 +261,14 @@ class ConvKVCache(NamedTuple):
         kv = (n_attn, batch, max_seq, cfg.num_kv_heads * cfg.kv_channels)
         return ConvKVCache(
             k=jnp.zeros(kv, dtype), v=jnp.zeros(kv, dtype),
-            conv=jnp.zeros((cfg.layers_of("conv"), batch,
-                            cfg.conv_L_cache - 1, cfg.hidden_size), dtype),
+            conv=jnp.zeros((cfg.state_layers, batch,
+                            *cfg.conv_state_shape), dtype),
             offset=jnp.zeros((n_attn, batch) if per_slot_offsets
                              else (n_attn,), jnp.int32),
-            live_rows=jnp.int32(ConvKVCache.NO_PADDING))
+            live_rows=jnp.int32(ConvKVCache.NO_PADDING),
+            ssm=(jnp.zeros((cfg.layers_of("mamba"), batch, cfg.mamba_d_state,
+                            cfg.mamba_d_inner), jnp.float32)
+                 if cfg.layers_of("mamba") else None))
 
 
 def _layer_of(a, layer):
@@ -580,10 +591,24 @@ def _folded_update_attend(q, k, v, cache: ConvKVCache, layer,
 
     if cfg.attention_impl == "flash" and s > 1 and not per_slot:
         from megatron_tpu.ops.flash_attention import flash_attention
-        out = jax.lax.cond(
-            offset == 0,
-            lambda: flash_attention(q, k, v, causal=True, scale=scale),
-            over_the_region)
+        if nkv == 1:
+            # ONE kv head: the folded row IS a head's row, [b, 1, t, hd]
+            # is the pool as the kernel reads it, and a prefill or a chunk
+            # attends the region it has just been written into through the
+            # kernel at its offset, as `HybridKVCache`'s full layers do.
+            # The products over the region would hold [heads, s, max_seq]
+            # scores, in both arms of a `cond`: 5.4 GB in float32 for a
+            # 2,048-row chunk of 20 heads over 32,768 positions
+            out = flash_attention(
+                q, _layer_of(new_k, layer)[:, None].astype(dtype),
+                _layer_of(new_v, layer)[:, None].astype(dtype),
+                causal=True, scale=scale, q_offset=offset,
+                kv_heads_major=True)
+        else:
+            out = jax.lax.cond(
+                offset == 0,
+                lambda: flash_attention(q, k, v, causal=True, scale=scale),
+                over_the_region)
     else:
         out = over_the_region()
     return out.astype(dtype), cache

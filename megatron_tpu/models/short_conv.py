@@ -23,6 +23,8 @@ reach no state. Each call writes its layer of the state whole, one update in
 place; a row of the batch writes its own state and no other's.
 
 Rows and weights in the compute dtype; the taps are accumulated in float32.
+`depthwise_causal` and `state_after` are what this mixer shares with a Mamba
+layer's depthwise kernel (models/mamba.py: four taps, a bias, SiLU).
 """
 from __future__ import annotations
 
@@ -58,6 +60,30 @@ def short_conv_axes(cfg: ModelConfig):
             "out_proj": (None, "embed")}
 
 
+def depthwise_causal(full, w, bias=None):
+    """The depthwise causal kernel over `full` = [the state ; the call's
+    rows], [b, taps - 1 + s, channels] (the state: the taps - 1 inputs before
+    the rows, the older first), w [taps, channels] -> c [b, s, channels]
+    float32: c_t = bias + sum_j w_j full_{t + j}, accumulated in float32.
+    Shared, with `state_after`, by this file's mixer and models/mamba.py."""
+    taps = w.shape[0]
+    s = full.shape[1] - (taps - 1)
+    w = w.astype(jnp.float32)
+    c = sum(w[j] * full[:, j:j + s].astype(jnp.float32)
+            for j in range(taps))
+    return c if bias is None else c + bias.astype(jnp.float32)
+
+
+def state_after(full, live, keep):
+    """The `keep` inputs up to the last real row: rows n .. n + keep - 1 of
+    `full` = [state ; rows], n [b] the call's count of real rows (`live`;
+    None: a call of one row has one real row, a static cut)."""
+    if live is None:
+        return full[:, -keep:]
+    return jax.vmap(lambda f, i: jax.lax.dynamic_slice_in_dim(
+        f, i, keep, axis=0))(full, live)
+
+
 def short_conv_apply(params, x, cfg: ModelConfig, *, kv_cache=None,
                      kind_layer=None):
     """x [b, s, h] -> (out [b, s, h], kv_cache). `kv_cache`: None, or the
@@ -82,26 +108,18 @@ def short_conv_apply(params, x, cfg: ModelConfig, *, kv_cache=None,
             prev = _layer_of(kv_cache.conv, kind_layer)
         full = jnp.concatenate([prev, a], axis=1).astype(dtype)
     with jax.named_scope("mtpu/conv/mix"):
-        w = params["conv"].astype(jnp.float32)
-        c = sum(w[j] * full[:, j:j + s].astype(jnp.float32)
-                for j in range(L))
+        c = depthwise_causal(full, params["conv"])
         y = (gate_c.astype(jnp.float32) * c).astype(dtype)
     if kv_cache is not None:
         with jax.named_scope("mtpu/conv/state"):
-            # the L - 1 inputs up to the last real row: rows n .. n + L - 2
-            # of [state ; a], n the call's count of real rows
-            # (a call of one row has one real row: a static cut)
-            if s == 1:
-                new = full[:, 1:]
-            else:
-                n = jnp.broadcast_to(
-                    jnp.clip(kv_cache.live_rows, 0, s), (b,))
-                new = jax.vmap(lambda f, i: jax.lax.dynamic_slice_in_dim(
-                    f, i, L - 1, axis=0))(full, n)
+            # the L - 1 inputs up to the last real row
+            live = None if s == 1 else jnp.broadcast_to(
+                jnp.clip(kv_cache.live_rows, 0, s), (b,))
             kv_cache = kv_cache._replace(
                 conv=jax.lax.dynamic_update_index_in_dim(
-                    kv_cache.conv, new.astype(kv_cache.conv.dtype),
-                    kind_layer, 0))
+                    kv_cache.conv,
+                    state_after(full, live, L - 1).astype(
+                        kv_cache.conv.dtype), kind_layer, 0))
     with jax.named_scope("mtpu/conv/out_proj"):
         out = _project(y, params["out_proj"], cfg, read_once=read_once)
     return out, kv_cache

@@ -50,7 +50,9 @@ RESIDUAL_AXES = ("batch", "seq_sp", "act_embed")
 def layer_init(rng, cfg: ModelConfig, dtype=jnp.float32,
                cross_attn: bool = False, mixer: str = "full_attention"):
     """`mixer` "conv": a gated short convolution (`params["conv"]`,
-    models/short_conv.py) where the others have `params["attention"]`.
+    models/short_conv.py), "mamba": a selective state-space mixer
+    (`params["mamba"]`, models/mamba.py), where the others have
+    `params["attention"]`.
 
     Norm layout mirrors ref: transformer.py:606-633 —
     pre-LN: input_layernorm + post_attention_layernorm (output_layernorm=Id);
@@ -66,6 +68,9 @@ def layer_init(rng, cfg: ModelConfig, dtype=jnp.float32,
     if mixer == "conv":
         from megatron_tpu.models.short_conv import short_conv_init
         params = {"conv": short_conv_init(k_attn, cfg, dtype)}
+    elif mixer == "mamba":
+        from megatron_tpu.models.mamba import mamba_init
+        params = {"mamba": mamba_init(k_attn, cfg, dtype)}
     elif cfg.mla:
         from megatron_tpu.models.mla import mla_init
         params = {"attention": mla_init(k_attn, cfg, dtype)}
@@ -105,6 +110,9 @@ def layer_axes(cfg: ModelConfig, cross_attn: bool = False,
     if mixer == "conv":
         from megatron_tpu.models.short_conv import short_conv_axes
         axes = {"conv": short_conv_axes(cfg)}
+    elif mixer == "mamba":
+        from megatron_tpu.models.mamba import mamba_axes
+        axes = {"mamba": mamba_axes(cfg)}
     elif cfg.mla:
         from megatron_tpu.models.mla import mla_axes
         axes = {"attention": mla_axes(cfg)}
@@ -220,11 +228,17 @@ def layer_apply(
 
     def _mixer_branch(ln_out, kv_cache):
         """The layer's mixer on its normed input: (out, the cache)."""
-        if mixer == "conv":
-            from megatron_tpu.models.short_conv import short_conv_apply
+        if mixer in ("conv", "mamba"):
             assert causal and encoder_output is None and adapters is None \
                 and segment_ids is None and not cp_pre_zigzag, (
-                "a convolution layer is causal, unsharded, over one document")
+                "a convolution or state-space layer is causal, unsharded, "
+                "over one document")
+            if mixer == "mamba":
+                from megatron_tpu.models.mamba import mamba_apply
+                return mamba_apply(
+                    params["mamba"], ln_out, cfg, kv_cache=kv_cache,
+                    kind_layer=kind_layer)
+            from megatron_tpu.models.short_conv import short_conv_apply
             return short_conv_apply(
                 params["conv"], ln_out, cfg, kv_cache=kv_cache,
                 kind_layer=kind_layer)
@@ -638,11 +652,12 @@ def _pattern_stack_apply(stacked_params, x, cfg: ModelConfig, *, rope_cos,
     tail off the period) runs behind the scan, layer by layer: right, not
     fast. The kinds' parameters are stacked apart, so the scan takes a
     period's worth of each kind, [periods, layers of the kind a period,
-    ...], and the body applies the period's layers in order. The cache
-    (`attention.ConvKVCache`) is the loop's carry and goes down whole with
-    the layer's index among its own kind in the MODEL; the dropless experts'
-    banks go down whole beside it, each kind's own, with the layer's index
-    among its kind in the GROUP."""
+    ...] (with a cache the stacks stay outside and a layer's are read at
+    its own index), and the body applies the period's layers in order. The
+    cache (`attention.ConvKVCache`) is the loop's carry and goes down whole
+    with the layer's index among its own kind in the MODEL; the dropless
+    experts' banks go down whole beside it, each kind's own, with the
+    layer's index among its kind in the GROUP."""
     rates = lima_dropout_rates(cfg, cfg.num_layers)
     cached = kv_caches is not None
     aux = jnp.zeros((), jnp.float32)
@@ -684,9 +699,17 @@ def _pattern_stack_apply(stacked_params, x, cfg: ModelConfig, *, rope_cos,
             by_kind, period = scanned
             for j, kind in enumerate(kinds[:P]):
                 jk = kinds[:j].count(kind)
-                h, caches, a = apply_one(
-                    h, caches, jax.tree.map(lambda t: t[jk], by_kind[kind]),
-                    kind, first + period * P + j, period * per[kind] + jk)
+                at = period * per[kind] + jk
+                if cached:
+                    # read where the stack lies, at the layer's own index
+                    layer_params = jax.tree.map(
+                        lambda t: jax.lax.dynamic_index_in_dim(
+                            t, at, 0, keepdims=False), params[kind])
+                else:
+                    layer_params = jax.tree.map(lambda t: t[jk],
+                                                by_kind[kind])
+                h, caches, a = apply_one(h, caches, layer_params, kind,
+                                         first + period * P + j, at)
                 aux_sum = aux_sum + a
             return (h, aux_sum, caches), None
 
@@ -696,7 +719,14 @@ def _pattern_stack_apply(stacked_params, x, cfg: ModelConfig, *, rope_cos,
             body = jax.checkpoint(
                 body, prevent_cse=False,
                 policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-        by_period = {
+        # With a cache (serving) the stacks stay whole outside the loop and
+        # each layer's parameters are read at its own index: a scan over
+        # periods cuts a period's parameters out of every stack in every
+        # iteration, a copy of them all (2.5 GiB a decode step at 13 bf16
+        # layers of Jamba2-3B's widths; compile for v5e, PR 47). Training
+        # scans periods: its loop joins each layer's gradient accumulator
+        # (`grad_accum.scan`).
+        by_period = None if cached else {
             kind: jax.tree.map(
                 lambda t: t[:periods * per[kind]].reshape(
                     periods, per[kind], *t.shape[1:]), params[kind])

@@ -99,6 +99,8 @@ def insert_prefill(pool: KVCache, prefill: KVCache, slot, plen) -> KVCache:
             v=dus(pool.v, prefill.v.astype(pool.v.dtype), start4),
             conv=dus(pool.conv, prefill.conv.astype(pool.conv.dtype),
                      start4),
+            ssm=(None if pool.ssm is None
+                 else dus(pool.ssm, prefill.ssm, start4)),
             offset=dus(pool.offset,
                        jnp.full((pool.offset.shape[0], 1), plen, jnp.int32),
                        (zero, slot)))
@@ -491,9 +493,11 @@ class SlotKVPool:
 
     @property
     def conv_layers(self) -> int:
-        """Layers that keep a convolution state a slot and no keys or values
-        (`cfg.layer_types`; models/attention.py::ConvKVCache)."""
-        return self.cfg.layers_of("conv")
+        """Layers that keep a state of fixed size a slot and no keys or
+        values: a convolution's last inputs and, in a "mamba" layer, the
+        scan's matrix beside them (`cfg.layer_types`;
+        models/attention.py::ConvKVCache)."""
+        return self.cfg.state_layers
 
     @property
     def kv_layers(self) -> int:
@@ -951,7 +955,8 @@ class SlotKVPool:
         if isinstance(c, LatentKVCache):
             return c.c.nbytes
         if isinstance(c, ConvKVCache):
-            return c.k.nbytes + c.v.nbytes + c.conv.nbytes
+            return (c.k.nbytes + c.v.nbytes + c.conv.nbytes
+                    + (0 if c.ssm is None else c.ssm.nbytes))
         n = c.k.nbytes + c.v.nbytes
         if c.k_scale is not None:
             n += c.k_scale.nbytes + c.v_scale.nbytes
@@ -960,6 +965,11 @@ class SlotKVPool:
     def conv_state_nbytes(self) -> int:
         """Bytes of the convolutions' state (0 where the pool has none)."""
         return self.caches.conv.nbytes if self.conv_layers else 0
+
+    def ssm_state_nbytes(self) -> int:
+        """Bytes of the scans' state, float32 (0 where the pool has none)."""
+        ssm = getattr(self.caches, "ssm", None)
+        return 0 if ssm is None else ssm.nbytes
 
     def ring_nbytes(self) -> int:
         """Bytes of the window layers' rings (0 where the pool has none)."""
@@ -971,7 +981,8 @@ class SlotKVPool:
         """Bytes of the whole regions: the full layers' of a pool of two
         kinds, else the whole pool."""
         if not self.hybrid:
-            return self.nbytes() - self.conv_state_nbytes()
+            return (self.nbytes() - self.conv_state_nbytes()
+                    - self.ssm_state_nbytes())
         return self.caches.full_k.nbytes + self.caches.full_v.nbytes
 
     def bytes_per_slot(self) -> int:
@@ -1077,9 +1088,10 @@ def slot_nbytes(cfg: ModelConfig, max_len: int,
     n = cfg.kv_layers * cap * cfg.kv_row_width * jnp.dtype(dtype).itemsize
     if jnp.dtype(dtype) == jnp.dtype(jnp.int8):
         n += 2 * (cfg.kv_layers * cap * cfg.num_kv_heads) * 4  # fp32 scales
-    # a convolution layer's state, whatever the length
-    n += (cfg.layers_of("conv") * cfg.conv_state_width
-          * jnp.dtype(dtype).itemsize)
+    # a state layer's fixed size, whatever the length: the depthwise
+    # kernel's inputs in the pool's dtype, the scan's matrix in float32
+    n += cfg.state_layers * cfg.conv_state_width * jnp.dtype(dtype).itemsize
+    n += cfg.layers_of("mamba") * cfg.ssm_state_width * 4
     return n
 
 

@@ -84,7 +84,7 @@ Training (`training/loop.py`, main thread):
 
 On the device (`jax.named_scope`, so in the `op_name` of every HLO instruction
 traced under it; models/moe.py, models/attention.py, models/mla.py,
-models/hyper_connections.py and models/rope.py):
+models/hyper_connections.py, models/mamba.py and models/rope.py):
 
 | scope | round what |
 |---|---|
@@ -100,6 +100,12 @@ models/hyper_connections.py and models/rope.py):
 | `mtpu/conv/state` | the read of the layer's state (the kernel's last inputs, a row each slot) ahead of the rows, and the write of the state after the call's last real row, one update in place a layer |
 | `mtpu/conv/mix` | B * z, the depthwise taps over [state ; rows] accumulated in float32, times C |
 | `mtpu/conv/out_proj` | the layer's second product, rows x [h, h] |
+| `mtpu/ssm/in_proj` | a Mamba layer's first product, rows x [h, 2 d_inner]: the scan's input x and the gate z (`models/mamba.py`) |
+| `mtpu/ssm/state` | the read of the layer's two states (the depthwise kernel's last inputs, the scan's [d_state, d_inner] float32 matrix; a row each slot) ahead of the rows, and their write after the call's last real row, one update in place a layer each |
+| `mtpu/ssm/conv` | the depthwise taps over [state ; rows] accumulated in float32, the bias, SiLU |
+| `mtpu/ssm/params` | rows x W_x [d_inner, dt_rank + 2 d_state], the three RMS norms, dt's product with W_dt in float32, softplus, the padding rows' step size set to 0, A = -exp(A_log) |
+| `mtpu/ssm/scan` | the recurrence: the kernel `_ssm_selective_scan` for a prefill or a chunk (ops/selective_scan.py; its lane spread of B and C), the one-step update over the pool's layer for a decode step, the `lax.scan` with no cache; y gated by SiLU(z) inside |
+| `mtpu/ssm/out_proj` | the layer's second product, rows x [d_inner, h] |
 | `mtpu/moe/shared` | the shared experts' MLP, added beside the routed sum (`n_shared_experts`) |
 | `mtpu/mla/q` | latent attention's query: down-projection, norm, up-projection, the rotary on its rope part |
 | `mtpu/mla/latent` | the latent row: down-projection, norm over kv_lora_rank, the rotary on the shared key, the write into the cache |
@@ -119,7 +125,9 @@ Counters of the serving metrics' snapshot that a benchmark reader takes:
 and `kv_full_bytes` (`.bytes_per_slot()`, `.ring_nbytes()`, `.full_nbytes()`:
 what a slot reserves, and the pool's bytes by kind; `serve_kv_bytes_per_slot`)
 and `conv_state_bytes` (`.conv_state_nbytes()`: the convolution layers' state
-of every slot; a slot's share of it is `serve_state_bytes_per_slot`).
+of every slot; a slot's share of it is `serve_state_bytes_per_slot`) and
+`ssm_state_bytes` (`.ssm_state_nbytes()`: the scans' float32 matrices of every
+slot; a slot's share of it is `serve_ssm_state_bytes_per_slot`).
 `prefill_chunks` counts the chunk programs dispatched. The rows a share's held
 experts took (`moe_rows_held` of ISSUE 33) are NOT counted by the program: no
 serving program hands a scalar out of the layer loop, and the benchmark counts

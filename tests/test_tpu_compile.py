@@ -110,6 +110,32 @@ def test_flash_attention_at_an_offset(one_chip, keys, window):
              S((), jnp.int32))
 
 
+@pytest.mark.parametrize("batch,rows", [(1, 2048), (2, 512)])
+def test_selective_scan_at_jamba_widths(one_chip, batch, rows):
+    """The scan's kernel (PR 47) at AI21-Jamba2-3B's widths: a 2,048-row
+    chunk and a two-prompt bucket of 512 rows of 5,120 channels, a state of
+    [16, 5120] float32 a sequence in and out, bf16 rows, float32 step
+    sizes; and one kv head's region of 32,768 rows through the flash kernel
+    at an offset, as the model's two attention layers read it."""
+    from megatron_tpu.ops.flash_attention import flash_attention
+    from megatron_tpu.ops.selective_scan import _ssm_selective_scan
+
+    def S(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    f32 = jnp.float32
+    by_rows = (batch, rows, 5120)
+    _compile(_ssm_selective_scan, S(by_rows), S(by_rows, f32),
+             S((16, 5120), f32), S((batch, rows, 16), f32),
+             S((batch, rows, 16), f32), S((5120,), f32), S(by_rows),
+             S((batch, 16, 5120), f32))
+
+    def attend(q, k, v, off):
+        return flash_attention(q, k, v, causal=True, use_pallas=True,
+                               q_offset=off, kv_heads_major=True)
+    kv = S((batch, 1, 32768, 128))
+    _compile(attend, S((batch, rows, 20, 128)), kv, kv, S((), jnp.int32))
+
+
 def test_flash_attention_under_tp_mesh(topo):
     """XLA cannot partition a Mosaic call: under a tensor-parallel mesh
     the kernel has to sit in flash_attention's shard_map (Falcon-40B's
