@@ -55,20 +55,6 @@ KV_CACHE_AXES = ("layers", None, None, "kv_heads", None)
 PREFILL_BUCKET = 16
 
 
-# A prefill wants the logits of its last real position alone, and takes
-# them out of the whole bucket's. Whole, in float32, they may not be larger
-# than this: past it the head runs on that position alone
-# (`model_forward(logits_rows=...)`). 16,384 positions of a 129,280-word
-# vocabulary are 7.9 GiB, on a chip of 16; the largest that a program of
-# this repo made before the bound existed is 1.2 GiB (2 x 3,072 x 50,304),
-# and those programs are what they were.
-WHOLE_LOGITS_BYTES_MAX = 2 << 30
-
-
-def whole_logits_fit(batch: int, seq: int, cfg: ModelConfig) -> bool:
-    return batch * seq * cfg.padded_vocab_size * 4 <= WHOLE_LOGITS_BYTES_MAX
-
-
 def kv_region_cap(cfg: ModelConfig, max_len: int,
                   prefill_len=None) -> int:
     """Token capacity of one sequence's KV region — THE single source
@@ -166,7 +152,6 @@ def prefill_chunk(params, tokens, caches, cfg: ModelConfig, *, rope,
     write start right after the real tokens, overwriting the pads
     write-before-read — the same invariant bucketed prefill +
     insert_prefill already rely on for the final pads."""
-    whole = whole_logits_fit(*tokens.shape, cfg)
     if isinstance(caches, HybridKVCache):
         # a ring takes no padding row
         caches = caches._replace(
@@ -179,10 +164,8 @@ def prefill_chunk(params, tokens, caches, cfg: ModelConfig, *, rope,
     logits, caches = lm.model_forward(
         params, tokens, cfg, kv_caches=caches, rope=rope,
         logits_dtype=jnp.float32, adapters=adapters,
-        logits_rows=(None if whole
-                     else jnp.asarray(last_idx, jnp.int32)[None]))
-    last = (jax.lax.dynamic_slice_in_dim(logits[0], last_idx, 1, axis=0)[0]
-            if whole else logits[0, 0])
+        logits_rows=jnp.asarray(last_idx, jnp.int32)[None])
+    last = logits[0, 0]
     caches = caches._replace(offset=jnp.full_like(
         caches.offset, jnp.asarray(next_offset, jnp.int32)))
     return caches, last

@@ -185,8 +185,8 @@ def model_forward(
     serving / LoRA finetuning; models/attention.py).
 
     `logits_rows` [b] int: the head runs on that one position of each
-    sequence alone and the logits are [b, 1, padded_vocab] (a prefill
-    wants its last real position's; generation.whole_logits_fit)."""
+    sequence alone and the logits are [b, 1, padded_vocab] (every served
+    prefill, whole or chunked, wants its last real position's alone)."""
     from megatron_tpu.config import as_dtype
     compute_dtype = as_dtype(cfg.compute_dtype)
     emb = params["embedding"]["word_embeddings"]

@@ -139,8 +139,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from megatron_tpu.inference.generation import (PREFILL_BUCKET, Generator,
-                                               prefill_chunk, verify_tokens,
-                                               whole_logits_fit)
+                                               prefill_chunk, verify_tokens)
 from megatron_tpu.inference.sampling import (rows_need_filter,
                                              sample_batched,
                                              verify_draft_probs)
@@ -2105,13 +2104,12 @@ class ServingEngine:
                                               plens[i])
                 bkv_i = pps.wave_scatter(bkv_i, jnp.int32(0), view)
                 if is_last:
+                    # the head on each row's last real position alone
+                    x2 = jnp.take_along_axis(
+                        x2, (plens - 1)[:, None, None], axis=1)
                     logits = pps.stage_head(params_i, x2, cfg,
                                             logits_dtype=jnp.float32)
-                    lasts = jnp.stack([
-                        jax.lax.dynamic_slice_in_dim(
-                            logits[i], plens[i] - 1, 1, 0)[0]
-                        for i in range(B)])
-                    return bkv_i, lasts
+                    return bkv_i, logits[:, 0]
                 return bkv_i, x2
             return _pre_i
 
@@ -2183,11 +2181,10 @@ class ServingEngine:
                                              adapters=adapters)
                 sub_i = sub_i._replace(
                     offset=jnp.full_like(sub_i.offset, next_offset))
+                x = jax.lax.dynamic_slice_in_dim(x, last_idx, 1, axis=1)
                 logits = pps.stage_head(params_i, x, cfg,
                                         logits_dtype=jnp.float32)
-                last = jax.lax.dynamic_slice_in_dim(
-                    logits[0], last_idx, 1, 0)[0]
-                return sub_i, last
+                return sub_i, logits[0, 0]
             return _chunk_last if is_last else _chunk_mid
 
         # `sub` is deliberately NOT donated across the chunk chain —
@@ -2619,14 +2616,12 @@ class ServingEngine:
             # a state is left as it stood after each row's own last real
             # token, not after the bucket's padding (attention.ConvKVCache)
             caches = caches._replace(live_rows=plens)
-        # the head on each row's last real position alone where the whole
-        # bucket's logits would not fit (generation.whole_logits_fit)
-        whole = whole_logits_fit(*tokens.shape, self.cfg)
+        # the head runs on each row's LAST REAL prompt position alone
+        # (bucket pads sit after it and are causally invisible to it)
         logits, caches = lm.model_forward(
             params, tokens, self.cfg, kv_caches=caches,
             rope=self._rope, logits_dtype=jnp.float32,
-            adapters=adapters,
-            logits_rows=None if whole else plens - 1)
+            adapters=adapters, logits_rows=plens - 1)
         for i in range(B):  # static unroll: B is a trace-time shape
             sub = batch_row(caches, i)
             if self._kernel_on:
@@ -2634,12 +2629,7 @@ class ServingEngine:
                                      jnp.int32(0))
             else:
                 pool = insert_prefill(pool, sub, slots[i], plens[i])
-            # logits at the LAST REAL prompt position (bucket pads sit
-            # after it and are causally invisible to it)
-            last = (jax.lax.dynamic_slice_in_dim(
-                logits[i], plens[i] - 1, 1, axis=0)[0] if whole
-                else logits[i, 0])
-            last_logits = last_logits.at[slots[i]].set(last)
+            last_logits = last_logits.at[slots[i]].set(logits[i, 0])
             rngs = rngs.at[slots[i]].set(rng0s[i])
         if bkv is not None:
             pool = scatter_view(bkv, pool)

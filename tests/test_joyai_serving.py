@@ -39,22 +39,17 @@ def _logprobs(eng, prompt, n_new):
 
 
 @pytest.mark.parametrize("how", ["plain", "chunked_prefill", "prefix_hit",
-                                 "speculative", "head_on_last_rows",
-                                 "chunked_head_on_last_rows"])
-def test_engine_prefill_and_decode_match_reference(model, how, monkeypatch):
+                                 "speculative"])
+def test_engine_prefill_and_decode_match_reference(model, how):
     """A prompt prefilled in a padded bucket (37 tokens in 48), then decoded
     through the latent cache one token at a time beside an unrelated
-    request. `head_on_last_rows`: the same where a bucket's whole logits
-    are counted too large to make (generation.whole_logits_fit)."""
+    request. Every prefill's head runs on each row's last real position
+    alone (tests/test_prefill_head_rows.py)."""
     cfg, params = model
-    if "head_on_last_rows" in how:
-        from megatron_tpu.inference import generation
-        monkeypatch.setattr(generation, "WHOLE_LOGITS_BYTES_MAX", 0)
     gen = Generator(params, cfg, eos_id=-1, pad_id=0,
                     kv_cache_dtype=jnp.float32)
     serving = dict(num_slots=3, max_queue=8, max_len=96, prefill_bucket=16)
     serving.update({"chunked_prefill": dict(prefill_chunk=16),
-                    "chunked_head_on_last_rows": dict(prefill_chunk=16),
                     "prefix_hit": dict(enable_prefix_cache=True),
                     "speculative": dict(speculative_k=2)}.get(how, {}))
     rng = np.random.default_rng(5)

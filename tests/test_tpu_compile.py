@@ -13,6 +13,7 @@ The topology is described inside a module-scoped fixture and nowhere
 else: only one process may load the TPU's library, so nothing here
 touches `jax.experimental.topologies` while a module is imported.
 """
+import functools
 import re
 
 import jax
@@ -45,6 +46,13 @@ def topo():
 @pytest.fixture(scope="module")
 def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
+
+
+def _on_chip(one_chip, tree):
+    """`tree`'s shapes and dtypes as arguments that lie on the described
+    chip."""
+    return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one_chip), tree)
 
 
 def _compile(fn, *shapes):
@@ -311,9 +319,7 @@ def _olmoe_compiled(one_chip, monkeypatch, positions, cap=256, vocab=4096):
     # compiles for the chip from a CPU process
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
-    def on_chip(tree):
-        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
-            s.shape, s.dtype, sharding=one_chip), tree)
+    on_chip = functools.partial(_on_chip, one_chip)
     params = on_chip(jax.eval_shape(
         lambda: lm.model_init(jax.random.PRNGKey(0), cfg)))
     caches = on_chip(jax.eval_shape(lambda: init_kv_caches(
@@ -444,7 +450,6 @@ def _train_step_text(one_chip, step, n_micro=2):
     a vocabulary of 4,096, bf16 over float32 parameters, `n_micro`
     micro-batches of 512 tokens, compiled for the chip; and the shapes of
     its stacked matrices."""
-    import functools
     from megatron_tpu.config import (MegatronConfig, ModelConfig,
                                      OptimizerConfig, TrainingConfig)
     from megatron_tpu.training import init_train_state
@@ -457,9 +462,7 @@ def _train_step_text(one_chip, step, n_micro=2):
                                 global_batch_size=n_micro, train_iters=4),
     ).validate(n_devices=1)
 
-    def on_chip(tree):
-        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
-            s.shape, s.dtype, sharding=one_chip), tree)
+    on_chip = functools.partial(_on_chip, one_chip)
     state = on_chip(jax.eval_shape(
         lambda: init_train_state(jax.random.PRNGKey(0), cfg)))
     batch = on_chip({
@@ -512,10 +515,58 @@ def test_train_step_sums_a_layers_gradient_inside_its_product(one_chip):
     micro-batch: the reader has to see those."""
     from megatron_tpu.training.train_step import train_step
     from tests.test_grad_accum_fused import unfused_step
-    import functools
     text, stacks = _train_step_text(one_chip, train_step)
     assert len(stacks) >= 3
     assert _passes_over_a_stack(text, stacks) == []
     before, _ = _train_step_text(
         one_chip, functools.partial(unfused_step, loop="scan"))
     assert _passes_over_a_stack(before, stacks)
+
+
+def test_xing_prefill_program_makes_no_buckets_logits(one_chip, monkeypatch):
+    """Xing4.0's 4,096-row prefill program (`generation.prefill_chunk`, what
+    the engine's `_chunk_fwd_fn` runs and, at offset 0, its one-shot
+    prefill) at the cell's widths: hidden 3,584 under four residual streams,
+    the 131,072-word untied head in bf16, a 16,384-position latent cache;
+    depth cut to the one dense layer and one expert layer. Compiled for the
+    chip it holds no array over `[4096, 131072]` (the whole bucket's float32
+    logits are exactly 2 GiB), one row's `f32[131072]` in their place, and
+    its temporaries are 958,918,144 B (0.893 GiB) where the parent's
+    (31bbf77, the same program through this test's own code) are
+    2,183,069,184 B (2.033 GiB). The fall is 1.14 GiB and not the logits'
+    2 GiB: the compiler had laid the logits over the layers' temporaries,
+    which are dead by then. At the cell's own depth of 6
+    (`benchmark/fit_chunked.py`) the chunk program's temporaries went
+    2.03 -> 0.91 GiB and the one-shot prefill's 2.24 -> 1.13 (PR 49)."""
+    from megatron_tpu.config import xing_config
+    from megatron_tpu.models import language_model as lm
+    from megatron_tpu.inference.generation import (init_kv_caches,
+                                                   prefill_chunk)
+    rows, cap = 4096, 16384
+    cfg = xing_config("29b-a4b", num_layers=2, first_k_dense_replace=1,
+                      compute_dtype="bfloat16")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    on_chip = functools.partial(_on_chip, one_chip)
+
+    def init():
+        params = lm.model_init(jax.random.PRNGKey(0), cfg)
+        params.pop("mtp")        # a server does not load the module
+        return params
+    params = on_chip(jax.eval_shape(init))
+    caches = on_chip(jax.eval_shape(lambda: init_kv_caches(cfg, 1, cap)))
+    rope = lm.make_rope(cfg, max_len=cap)
+    tokens = jax.ShapeDtypeStruct((1, rows), jnp.int32, sharding=one_chip)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+
+    def chunk(params, tokens, caches, last_idx, next_offset):
+        return prefill_chunk(params, tokens, caches, cfg, rope=rope,
+                             last_idx=last_idx, next_offset=next_offset)
+    compiled = jax.jit(chunk).lower(params, tokens, caches, scalar,
+                                    scalar).compile()
+    text = compiled.as_text()
+    vocab = cfg.padded_vocab_size
+    assert vocab == 131072
+    assert not re.search(rf"\[(\d+,)*{rows},{vocab}\]", text)
+    assert f"f32[{vocab}]" in text
+    parent = 2_183_069_184
+    assert compiled.memory_analysis().temp_size_in_bytes <= parent - (1 << 30)

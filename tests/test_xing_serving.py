@@ -38,23 +38,18 @@ def _logprobs(eng, prompt, n_new):
 
 
 @pytest.mark.parametrize("how", ["plain", "chunked_prefill", "prefix_hit",
-                                 "speculative", "chunked_head_on_last_rows",
-                                 "to_the_last_position"])
-def test_engine_prefill_and_decode_match_reference(model, how, monkeypatch):
+                                 "speculative", "to_the_last_position"])
+def test_engine_prefill_and_decode_match_reference(model, how):
     """A prompt prefilled in a padded bucket (37 tokens in 48: by chunks of
     16, the second and third continuing the sequence's own latent rows in the
     absorbed form), then decoded through the latent cache one token at a time
     beside an unrelated request. `to_the_last_position`: prompt + output =
     `max_len`, which the cell's mix can reach (15,872 + 512 = 16,384)."""
     cfg, params = model
-    if "head_on_last_rows" in how:
-        from megatron_tpu.inference import generation
-        monkeypatch.setattr(generation, "WHOLE_LOGITS_BYTES_MAX", 0)
     gen = Generator(params, cfg, eos_id=-1, pad_id=0,
                     kv_cache_dtype=jnp.float32)
     serving = dict(num_slots=3, max_queue=8, max_len=96, prefill_bucket=16)
     serving.update({"chunked_prefill": dict(prefill_chunk=16),
-                    "chunked_head_on_last_rows": dict(prefill_chunk=16),
                     "to_the_last_position": dict(prefill_chunk=16,
                                                  max_len=49),
                     "prefix_hit": dict(enable_prefix_cache=True),
