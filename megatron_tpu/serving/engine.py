@@ -46,6 +46,22 @@ TPU-native:
   session the watchdog flagged and a preemption resume (it has its
   tokens) are left to the commit whole. Counters:
   `first_tokens_early`, `first_token_mismatches` (stays 0).
+- A prompt that lands while a decode window runs is admitted at once.
+  The engine thread waits for a window's tokens and for a submission
+  alike (`_fetch_admitting`); if a request is ready it runs the same
+  `_admit` the iteration would have run, so the prefill is on the
+  device's queue behind the window and starts as the window's last
+  program ends, not a host round trip later. It is the iteration's ONE
+  prefill program: taken only in a plain window (no verify round, no
+  grammar row), with a slot free, nothing owed in `_prefilling`, no
+  swap pending, the engine neither draining nor flagged, once a
+  window, for the requests at the queue's head that make one
+  `_prefill_group` call (anything else goes back as it came); and the
+  iteration after it skips `_advance_prefill` once, so between two
+  windows a running request waits for what one `_admit` groups plus
+  one chunk, as before, the early program standing in the chunk's
+  place. No option: what the engine sees decides. Counters:
+  `admits_early`, `admits_total`, `early_admit_declined_prefilling`.
 - Prefix-cache KV reuse (`enable_prefix_cache`, SGLang's
   RadixAttention made slot-grid native): finished slots RETAIN their
   KV on an LRU list (serving/kv_pool.py) and a host-side radix index
@@ -132,6 +148,7 @@ import math
 import threading
 import time
 import traceback
+from concurrent.futures import ThreadPoolExecutor
 from typing import List, Optional, Sequence
 
 import jax
@@ -619,6 +636,13 @@ class ServingEngine:
                     self.num_slots, cfg.hidden_size,
                     cfg.compute_dtype, d["serving_pp"]))
         self._steps = 0
+        # a window's fetch runs on this thread where the engine thread
+        # waits for it and for a submission alike (`_fetch_admitting`);
+        # made at the first such window. `_early_program` is the span's
+        # stats of the prefill program that the last window admitted
+        # while it ran: the next iteration's, in the chunk's place
+        self._fetcher: Optional[ThreadPoolExecutor] = None
+        self._early_program: Optional[dict] = None
         self._cond = threading.Condition()
         self._stop = False
         self._draining = False
@@ -915,6 +939,8 @@ class ServingEngine:
             self._thread.join(timeout=30)
         if self._watchdog is not None:
             self._watchdog.stop()
+        if self._fetcher is not None:
+            self._fetcher.shutdown(wait=False)
         for req in self.scheduler.close():
             req.fail("engine shut down")
         for req in self._slot_req:
@@ -2900,7 +2926,9 @@ class ServingEngine:
 
     def _iteration(self):
         """One pass of the engine loop's body: reap, admit (or apply a
-        pending swap), one prefill chunk, one decode window."""
+        pending swap), one prefill chunk, one decode window (which may
+        admit the next iteration's prefill program while it runs)."""
+        early, self._early_program = self._early_program, None
         with span("serve/reap"):
             self._maybe_decay_restarts()
             self._reap_cancelled()
@@ -2935,7 +2963,18 @@ class ServingEngine:
         # ONE chunk per iteration (Sarathi-Serve): prefill work
         # is interleaved with the decode step below, so running
         # slots keep emitting tokens while a long prompt lands
-        self._advance_prefill()
+        if early is None:
+            self._advance_prefill()
+        else:
+            # the program the last window admitted while it ran stands
+            # in the chunk's place: a chunk owed by now (what `_admit`
+            # just put into `_prefilling`) goes one window later, as it
+            # would behind any other prompt's program. The span begins
+            # where the device runs that program, between the last
+            # window's commit and the next one's, which is where a
+            # reader of step periods looks for it
+            with span("serve/prefill", early=1, **early):
+                pass
         self._heartbeat()  # admit/prefill may compile; decode is
         #                    the op the deadline protects
         if self._active.any():
@@ -3066,6 +3105,7 @@ class ServingEngine:
             self.scheduler.requeue(req)
         self.scheduler.clear_parked()
         self._prefilling = []
+        self._early_program = None
         self._sub0 = None
         self._index = PrefixIndex(self.pool.block_size if self._blocks_on
                                   else max(self.serving.prefill_bucket, 1))
@@ -3214,12 +3254,28 @@ class ServingEngine:
         req.state = RequestState.QUEUED
         self.scheduler.requeue(req)
 
-    def _admit(self) -> int:
-        """Place what the scheduler pops; returns how many it popped."""
-        popped = self.scheduler.pop_ready(self.pool.free_count())
+    def _admit(self, early: bool = False) -> int:
+        """Place what the scheduler pops; returns how many it popped.
+        `early` is the call from inside a decode window
+        (`_admit_early`): it places ONE prefill program, the requests
+        at the queue's head that `_prefill_group` takes as one group,
+        and sends the first that is none of them back with all behind
+        it, untried, for the next iteration's call."""
+        room = self.pool.free_count()
+        if early:
+            room = min(room, self._prefill_max_batch)
+        popped = self.scheduler.pop_ready(room)
         if not popped:
             return 0
         pending = list(popped)
+        placed = 0
+
+        def send_back(rest):
+            # a requeued request keeps its arrival id, so the order of
+            # the next pop is what it would have been
+            for rr in rest:
+                self.scheduler.requeue(rr)
+                pending.remove(rr)
         # expose the not-yet-placed pops to the watchdog: a wedge
         # inside a prefill dispatch below leaves them in neither
         # _slot_req nor _prefilling, and the no-stranded-futures
@@ -3237,7 +3293,10 @@ class ServingEngine:
             # admit; arrival ids preserve the order across requeues,
             # so the blocked head is served first once a pin frees.
             bank_blocked = False
-            for r in popped:
+            for i, r in enumerate(popped):
+                if early and r.parked is not None:
+                    send_back(popped[i:])  # a resume is no group's
+                    break
                 if bank_blocked and r.adapter_id is not None:
                     self.scheduler.requeue(r)
                     pending.remove(r)
@@ -3254,6 +3313,7 @@ class ServingEngine:
                     # with ONE insert — no forward at all
                     self._resume_parked(r)
                     pending.remove(r)
+                    placed += 1
                     continue
                 # a resumed request prefills its EFFECTIVE prompt
                 # (prompt + generated); == prompt when never preempted
@@ -3281,27 +3341,44 @@ class ServingEngine:
                     self.scheduler.requeue(r)
                     pending.remove(r)
                     continue
-                if hit or r.resume_rng is not None \
-                        or (self._chunk is not None
-                            and len(toks) > self._chunk) \
-                        or self._disagg:
-                    # disaggregated engines route EVERY admission
-                    # through the pending path: the batch-1 chunk
-                    # forward is the unit that runs on the prefill
-                    # group, and activation is the block handoff
+                # disaggregated engines route EVERY admission
+                # through the pending path: the batch-1 chunk
+                # forward is the unit that runs on the prefill
+                # group, and activation is the block handoff
+                single = bool(
+                    hit or r.resume_rng is not None or self._disagg
+                    or (self._chunk is not None
+                        and len(toks) > self._chunk))
+                if early and (single or (
+                        groupable and self._prefill_bucket(len(r.prompt))
+                        != self._prefill_bucket(
+                            len(groupable[0].prompt)))):
+                    # the pending path's, or another program's
+                    self._release_adapter(r)
+                    send_back(popped[i:])
+                    break
+                if single:
                     self._start_pending(r, src, hit)
                     pending.remove(r)
+                    placed += 1
                 else:
                     groupable.append(r)
             for padded, reqs in AdmissionScheduler.group_by_bucket(
                     groupable,
                     lambda rr: self._prefill_bucket(len(rr.prompt)),
                     self._prefill_max_batch):
-                with span("serve/prefill", n=len(reqs), padded=padded,
-                          rid=reqs[0].id):
+                stats = dict(n=len(reqs), padded=padded, rid=reqs[0].id)
+                # an early program's `serve/prefill` span is the next
+                # iteration's (`_iteration`); its dispatch, here, is
+                # most of its `serve/admit`
+                with span("serve/prefill.early" if early
+                          else "serve/prefill", **stats):
                     self._prefill_group(reqs, padded)
                 for r in reqs:
                     pending.remove(r)
+                placed += len(reqs)
+                if early:
+                    self._early_program = stats
         except Exception as e:
             # anything not yet admitted is in neither _slot_req /
             # _prefilling nor the scheduler — fail it here or its
@@ -3313,6 +3390,9 @@ class ServingEngine:
             raise
         finally:
             self._admitting = []
+            self.metrics.count("admits_total", placed)
+            if early:
+                self.metrics.count("admits_early", placed)
         return len(popped)
 
     def _acquire_adapter(self, req: GenRequest) -> str:
@@ -4360,14 +4440,85 @@ class ServingEngine:
                 # returns when the prefill and the draw are done: the
                 # window is queued behind them and the device goes on
                 early = self._deliver_first(fresh, *jax.device_get(drawn))
+        # the rows this window decodes: a prompt admitted while it runs
+        # is active by the commit, and none of them
+        window = np.nonzero(self._active)[0]
         with span("serve/step.fetch"):
-            fetched = self._fetch(
+            fetched, admit_error = self._fetch_admitting(
                 (tok_steps, lp_steps,
-                 [x for x in acc_steps if x is not None], self._d_reject))
+                 [x for x in acc_steps if x is not None], self._d_reject),
+                plain=not (structured_on or spec_k))
         with span("serve/step.commit") as sp:
-            sp.set_metadata(
-                tokens=self._commit(fetched, K, spec_round, grids, early))
+            sp.set_metadata(tokens=self._commit(
+                fetched, K, spec_round, grids, window, early))
+        if admit_error is not None:
+            # as from the iteration's own `_admit`, once the window's
+            # rows have their tokens
+            raise admit_error
         return K
+
+    def _fetch_admitting(self, tree, plain: bool):
+        """The window's fetch, and the one place a prompt is admitted
+        inside an iteration. The window is dispatched whole by now (its
+        dispatch donates what it reads; nothing goes between its
+        rounds), so a prefill dispatched here is queued on the device
+        behind it and starts as its last program ends. Where that may
+        happen the fetch runs on a helper thread and this one waits for
+        it and for a submission alike (`scheduler.notify` is `_wake`);
+        where it may not, whatever lands, this is the fetch alone.
+
+        `plain`: every round of the window is a plain decode round, no
+        row under a grammar (after one, every row's residual carry is
+        -1 on the device, which is what a fresh row's is on the host,
+        so the commit's mirror of it is right for a row admitted here
+        too). A free slot and an empty `_prefilling` cannot come about
+        while the window runs; a pending swap, a drain and the
+        watchdog's flag are looked at again as a prompt lands
+        (`_admit_early`). One attempt a window: one program between two
+        windows. Returns (the fetched tree, the exception an early
+        admission raised or None)."""
+        if not plain or self._disagg or not self.pool.free_count():
+            return self._fetch(tree), None
+        if self._prefilling:
+            # a chunk, a prefix hit or a resume is owed the next
+            # iteration's program: a prompt that landed meanwhile waits
+            # for the iteration, as it always did
+            fetched = self._fetch(tree)
+            if self.scheduler.depth():
+                self.metrics.count("early_admit_declined_prefilling")
+            return fetched, None
+        if self._fetcher is None:
+            self._fetcher = ThreadPoolExecutor(
+                1, thread_name_prefix="serving-fetch")
+        fut = self._fetcher.submit(self._fetch, tree)
+        fut.add_done_callback(lambda _: self._wake())
+        error, untried = None, True
+        while True:
+            with self._cond:
+                while not fut.done() and not (
+                        untried and self.scheduler.depth()):
+                    self._cond.wait(timeout=self._idle_wait)
+            if fut.done():
+                return fut.result(), error
+            untried = False
+            error = self._admit_early()
+
+    def _admit_early(self) -> Optional[Exception]:
+        """`_admit` from inside a decode window, for one prefill program
+        (`_fetch_admitting`). `_admit` fails what it had popped and
+        releases its pins before it raises; the exception is handed
+        back for `_step` to raise once the window is committed."""
+        if self._pending_swap is not None or self._draining \
+                or self._stop or self._wedged:
+            return None
+        try:
+            with span("serve/admit", early=1) as sp:
+                sp.set_metadata(popped=self._admit(early=True))
+        except Exception as e:  # noqa: BLE001 — raised after the commit
+            return e
+        finally:
+            self._heartbeat()  # the prefill may compile
+        return None
 
     def _append_token(self, req: GenRequest, tok: int, lp: float):
         """Append one token; a request's first also records its TTFT
@@ -4397,10 +4548,13 @@ class ServingEngine:
         self.metrics.count("first_tokens_early", len(early))
         return early
 
-    def _commit(self, fetched, K: int, spec_round, grids, early) -> int:
+    def _commit(self, fetched, K: int, spec_round, grids, active_slots,
+                early) -> int:
         """The host's half of a decode window, after the fetch: append
         each slot's tokens in order, step its FSM, evict what finished,
-        set the gauges. A row in `early` has its first token already
+        set the gauges. `active_slots` are the rows the window decoded
+        (a prompt admitted while it ran is active by now and has no
+        token in it). A row in `early` has its first token already
         (`_deliver_first`): round 0's token is that token, checked and
         not appended again, and everything else runs on it as on any
         other. Returns the tokens delivered."""
@@ -4425,7 +4579,6 @@ class ServingEngine:
                 self._spec_trace.append((toks[r], accs[r]))
         # host mirror of the residual carry — exact as of this boundary
         self._reject = np.asarray(fetched[3]).astype(np.int32).copy()
-        active_slots = np.nonzero(self._active)[0]
         n_active = len(active_slots)
         consumed = np.zeros(K, np.int64)  # tokens delivered per step
         # the host-visible commit moment for this whole sync window —

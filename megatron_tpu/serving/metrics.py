@@ -66,6 +66,13 @@ _BASE_COUNTERS = (
     # drew another token than the one handed over (the request fails;
     # stays 0)
     "first_tokens_early", "first_token_mismatches",
+    # admissions (placements, resumes included) by `_admit`, and those of
+    # them made while a decode window ran (engine._fetch_admitting): their
+    # ratio is how often a prompt did not wait for the host to come round.
+    # early_admit_declined_prefilling = windows that ended with a prompt
+    # queued which the one-program rule held back (a chunk, a prefix hit
+    # or a resume was owed the next iteration's prefill program)
+    "admits_total", "admits_early", "early_admit_declined_prefilling",
     "prefill_calls", "prefill_prompts",
     # prefix cache / chunked prefill (docs/serving.md):
     # prefix_hit_tokens counts tokens MATCHED at lookup (including

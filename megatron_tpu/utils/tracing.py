@@ -59,8 +59,9 @@ Serving (`serving/engine.py`, engine thread unless said):
 | `mtpu/serve/idle_wait` | the `_cond.wait` loop at the top of `_session`: nothing queued, active or prefilling | |
 | `mtpu/serve/iteration` | one pass of the loop's body, `_iteration`; parent of all below but `submit` | `active`, `queued` |
 | `mtpu/serve/reap` | `_maybe_decay_restarts`, `_reap_cancelled`, `_reap_expired`, `_evaluate_degrade` | |
-| `mtpu/serve/admit` | `_preempt_for_priority` + `_admit` (pop, adapter, prefix lookup, grouping) | `popped`+ |
-| `mtpu/serve/prefill` | each `_prefill_group` call (host arrays, the group's sampling keys as one compiled call, `_initial_rngs`, then the dispatch), child of `admit` | `n`, `padded`, `rid` of the first |
+| `mtpu/serve/admit` | `_preempt_for_priority` + `_admit` (pop, adapter, prefix lookup, grouping). With `early=1`: `_admit` alone, for a prompt that landed while a decode window ran (`_admit_early`), inside that window's `step.fetch` | `popped`+, `early` |
+| `mtpu/serve/prefill` | each `_prefill_group` call (host arrays, the group's sampling keys as one compiled call, `_initial_rngs`, then the dispatch), child of `admit`. With `early=1` it holds nothing: `_iteration` opens it where `_advance_prefill` would run, for the program the window before admitted while it ran. It begins where the device runs that program (behind that window, ahead of the next), so between two `step.commit` starts there is still the span of the prefill that lengthened the interval | `n`, `padded`, `rid` of the first, `early` |
+| `mtpu/serve/prefill.early` | the `_prefill_group` call of an early admission, child of `admit` with `early=1`: the host's work and the dispatch, while the device runs the window | `n`, `padded`, `rid` of the first |
 | `mtpu/serve/prefill_chunk` | `_advance_prefill` when it dispatches, `_activate_pending` included | `rid`, `tokens`+ |
 | `mtpu/serve/swap` | `_apply_swap` | |
 | `mtpu/serve/step` | `_step`; parent of the six below | `active`, `K`+ |
@@ -68,7 +69,7 @@ Serving (`serving/engine.py`, engine thread unless said):
 | `mtpu/serve/step.draft` | `build_draft_rounds` (only entered with `speculative_k`) | |
 | `mtpu/serve/step.dispatch` | the chain of K `_decode` / `_verify` calls, behind the draw of the fresh rows' first tokens (`_draw_ahead`) in a window that has any | |
 | `mtpu/serve/step.first` | only in a window with fresh rows whose round 0 is a plain decode round: the fetch of that draw (it returns when the prefill and the draw are done, the window queued behind them) and `_deliver_first`, which hands each token to its request | |
-| `mtpu/serve/step.fetch` | `self._fetch(...)`: the host waits, the device works | |
+| `mtpu/serve/step.fetch` | `_fetch_admitting`: the host waits for the window's tokens (`self._fetch(...)`, on a helper thread where a prompt may be admitted meanwhile), the device works; parent of an `admit` with `early=1` | |
 | `mtpu/serve/step.commit` | `_commit`, everything after the fetch: per-slot token append, FSM, evictions, gauges, writer | `tokens`+ |
 | `mtpu/serve/submit` | `submit()`, on the caller's thread | `rid`+ |
 
@@ -128,7 +129,11 @@ and `conv_state_bytes` (`.conv_state_nbytes()`: the convolution layers' state
 of every slot; a slot's share of it is `serve_state_bytes_per_slot`) and
 `ssm_state_bytes` (`.ssm_state_nbytes()`: the scans' float32 matrices of every
 slot; a slot's share of it is `serve_ssm_state_bytes_per_slot`).
-`prefill_chunks` counts the chunk programs dispatched. The rows a share's held
+`prefill_chunks` counts the chunk programs dispatched. `admits_total`,
+`admits_early` and `early_admit_declined_prefilling` (placements by `_admit`,
+those made while a decode window ran, and windows that ended with a prompt
+queued because a chunk was owed the next prefill program) are in the snapshot
+and `/metrics`; no benchmark reader takes them yet. The rows a share's held
 experts took (`moe_rows_held` of ISSUE 33) are NOT counted by the program: no
 serving program hands a scalar out of the layer loop, and the benchmark counts
 them with the reference's router on the window's own tokens (PERF.md section 7).
