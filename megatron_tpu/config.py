@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import math
 from dataclasses import dataclass, field
 from typing import Any, Optional, Sequence, Tuple
 
@@ -35,6 +36,12 @@ _DTYPES = {
 
 def as_dtype(name: str):
     return _DTYPES[name]
+
+
+# the kinds of `ModelConfig.layer_types` that keep a state of fixed size a
+# sequence, and those whose layer is ONE sublayer (`ModelConfig.one_sublayer`)
+STATE_KINDS = ("conv", "mamba", "mamba2")
+ONE_SUBLAYER_KINDS = ("mamba2", "moe", "mlp")
 
 
 @dataclass(frozen=True)
@@ -207,6 +214,16 @@ class ModelConfig:
     # how the shared experts combine: "sum" (one MLP of their widths
     # together) or "average" (the mean of their outputs: the same MLP / n)
     moe_shared_combination: str = "sum"
+    # Experts in a latent (the published `moe_latent_size`): the routed
+    # experts' rows are projected hidden_size -> moe_latent_size ahead of
+    # the banks, which are [E, latent, f] and [E, f, latent], and the
+    # weighted sum back behind them; the router and the shared experts read
+    # the hidden rows. None: the banks' rows are hidden_size wide.
+    moe_latent_size: Optional[int] = None
+    # the shared experts' OWN width together (the published
+    # `moe_shared_expert_intermediate_size`); None: n_shared_experts x
+    # ffn_hidden_size
+    moe_shared_expert_ffn: Optional[int] = None
 
     # The mixer of each layer (the published `layer_types`): "conv", a gated
     # short convolution that keeps its last `conv_L_cache` - 1 inputs a
@@ -219,6 +236,12 @@ class ModelConfig:
     # (models/transformer.py); the cache holds keys and values for the
     # attention layers alone and the convolutions' state beside them
     # (models/attention.py::ConvKVCache).
+    #
+    # A pattern with "mamba2" or "moe" in it is of layers that hold ONE
+    # pre-norm sublayer each, x + F(norm(x)) (a `nemotron_h`
+    # `hybrid_override_pattern`): F is a Mamba-2 mixer ("mamba2"),
+    # attention alone ("full_attention") or the feed-forward alone ("moe":
+    # the experts; no cache row). `one_sublayer` says which reading holds.
     layer_types: Optional[Tuple[str, ...]] = None
     conv_L_cache: int = 3
     # "mamba" in `layer_types`: a Mamba-1 selective state-space mixer
@@ -235,6 +258,20 @@ class ModelConfig:
     mamba_expand: int = 2
     mamba_conv_bias: bool = True
     mamba_proj_bias: bool = False
+    # "mamba2" in `layer_types`: a Mamba-2 mixer (models/mamba2.py; the
+    # published `mamba_num_heads`, `mamba_head_dim`, `n_groups`,
+    # `ssm_state_size`, `conv_kernel`, `chunk_size` of a `nemotron_h`
+    # config). `mamba_num_heads` heads of `mamba_head_dim` channels, a
+    # scalar decay a head, B and C shared by the heads of each of
+    # `mamba_n_groups` groups, `mamba_d_state` values a channel: the state
+    # is [heads, head_dim, d_state] float32 a layer a sequence
+    # (`ConvKVCache.ssm`), scanned `mamba_chunk_size` rows at a time
+    # (ops/ssd_scan.py). One depthwise kernel of `mamba_d_conv` taps runs
+    # over x, B and C together.
+    mamba_num_heads: int = 128
+    mamba_head_dim: int = 64
+    mamba_n_groups: int = 8
+    mamba_chunk_size: int = 128
     # RMSNorm over each head's channels of q and of k, one scale
     # [kv_channels] shared by the heads, before the rotary (LFM2's
     # q_layernorm / k_layernorm). `qk_norm` above is OLMoE's, over all the
@@ -307,29 +344,49 @@ class ModelConfig:
         return self.layers_of("full_attention")
 
     @property
+    def one_sublayer(self) -> bool:
+        """Every layer of the pattern is ONE pre-norm sublayer (a mixer or a
+        feed-forward alone), not a mixer and then a feed-forward."""
+        return self.layer_types is not None and any(
+            k in ONE_SUBLAYER_KINDS for k in self.layer_types)
+
+    @property
     def state_kind(self) -> Optional[str]:
         """The kind of layer that keeps a state of fixed size a sequence:
-        "conv", "mamba" or None (a model has one: validate refuses both)."""
-        return next((k for k in ("conv", "mamba") if self.layers_of(k)),
-                    None)
+        "conv", "mamba", "mamba2" or None (a model has one: validate
+        refuses a cross)."""
+        return next((k for k in STATE_KINDS if self.layers_of(k)), None)
 
     @property
     def state_layers(self) -> int:
         """Layers that keep a state of fixed size a sequence and no keys or
         values."""
-        return self.layers_of("conv") + self.layers_of("mamba")
+        return sum(self.layers_of(k) for k in STATE_KINDS)
 
     @property
     def mamba_d_inner(self) -> int:
+        """Channels of a state-space mixer: expand x hidden ("mamba"),
+        heads x head_dim ("mamba2")."""
+        if self.state_kind == "mamba2":
+            return self.mamba_num_heads * self.mamba_head_dim
         return self.mamba_expand * self.hidden_size
+
+    @property
+    def mamba2_conv_channels(self) -> int:
+        """What a "mamba2" layer's one depthwise kernel runs over: x and
+        every group's B and C."""
+        return self.mamba_d_inner \
+            + 2 * self.mamba_n_groups * self.mamba_d_state
 
     @property
     def conv_state_shape(self) -> Tuple[int, int]:
         """(rows, channels) of the depthwise kernel's state a layer: its
-        last taps - 1 inputs, over the hidden size ("conv") or over d_inner
-        ("mamba")."""
+        last taps - 1 inputs, over the hidden size ("conv"), over d_inner
+        ("mamba") or over x, B and C together ("mamba2")."""
         if self.state_kind == "mamba":
             return self.mamba_d_conv - 1, self.mamba_d_inner
+        if self.state_kind == "mamba2":
+            return self.mamba_d_conv - 1, self.mamba2_conv_channels
         return self.conv_L_cache - 1, self.hidden_size
 
     @property
@@ -340,12 +397,22 @@ class ModelConfig:
         return rows * channels
 
     @property
+    def ssm_state_shape(self) -> Optional[Tuple[int, ...]]:
+        """The scan's float32 state a layer a sequence: [d_state, d_inner]
+        ("mamba", the channels minor), [heads, head_dim, d_state]
+        ("mamba2", a matrix a head); None where no layer has one."""
+        if self.state_kind == "mamba":
+            return self.mamba_d_state, self.mamba_d_inner
+        if self.state_kind == "mamba2":
+            return (self.mamba_num_heads, self.mamba_head_dim,
+                    self.mamba_d_state)
+        return None
+
+    @property
     def ssm_state_width(self) -> int:
-        """float32 values one sequence costs one "mamba" layer's scan,
+        """float32 values one sequence costs one state-space layer's scan,
         whatever its length (0 where the model has no such layer)."""
-        if self.state_kind != "mamba":
-            return 0
-        return self.mamba_d_state * self.mamba_d_inner
+        return math.prod(self.ssm_state_shape or (0,))
 
     def dense_layers(self) -> "ModelConfig":
         """The configuration of the `first_k_dense_replace` leading layers:
@@ -1536,11 +1603,24 @@ class MegatronConfig:
             # models/transformer.py scans each group a period of the pattern
             # at a time, the kinds' parameters stacked apart
             kinds = set(model.layer_types)
+            allowed = ({"mamba2", "full_attention", "moe"}
+                       if model.one_sublayer
+                       else {"conv", "mamba", "full_attention"})
+            assert "mlp" not in kinds, (
+                "layer_types 'mlp' (a layer that is a dense feed-forward "
+                "alone, a nemotron_h pattern's '-') is refused: "
+                "models/transformer.py::layer_init builds the one "
+                "feed-forward `num_experts` names, and no dense width "
+                "beside the experts' has been given a one-sublayer layer "
+                "(ROADMAP R6)")
             assert len(model.layer_types) == model.num_layers \
-                and kinds <= {"conv", "mamba", "full_attention"}, (
+                and kinds <= allowed, (
                 f"layer_types has {len(model.layer_types)} entries "
                 f"{sorted(kinds)} for num_layers={model.num_layers}: one of "
-                "'conv' | 'mamba' | 'full_attention' a layer")
+                "'conv' | 'mamba' | 'full_attention' a layer (a mixer and "
+                "then a feed-forward), or one of 'mamba2' | "
+                "'full_attention' | 'moe' a layer (ONE sublayer each), "
+                "never both readings in one model")
             assert not {"conv", "mamba"} <= kinds, (
                 "layer_types with 'conv' AND 'mamba' layers: the cache "
                 "holds one kind of fixed-size state "
@@ -1556,6 +1636,35 @@ class MegatronConfig:
                     "'mamba' layers need mamba_d_state, mamba_dt_rank and "
                     "mamba_expand >= 1, and have not been run behind a "
                     "leading dense stack (first_k_dense_replace)")
+            if model.one_sublayer:
+                # models/transformer.py::layer_apply: x + F(norm(x)), F a
+                # Mamba-2 mixer, attention or the experts alone
+                assert "full_attention" in kinds and "mamba2" in kinds, (
+                    "a pattern of one-sublayer layers needs a 'mamba2' and "
+                    "a 'full_attention' layer: the cache's offsets are the "
+                    "attention layers' and its state the Mamba-2 layers' "
+                    "(models/attention.py::ConvKVCache)")
+                assert model.mamba_num_heads % model.mamba_n_groups == 0 \
+                    and model.mamba_d_state >= 1 \
+                    and model.mamba_head_dim >= 1 \
+                    and model.mamba_chunk_size >= 1, (
+                    f"'mamba2' layers need mamba_num_heads="
+                    f"{model.mamba_num_heads} a multiple of mamba_n_groups="
+                    f"{model.mamba_n_groups} (a group's B and C serve its "
+                    "heads), and mamba_d_state, mamba_head_dim and "
+                    "mamba_chunk_size >= 1")
+                assert not model.first_k_dense_replace \
+                    and model.hc_mult == 1 and not model.mamba_proj_bias, (
+                    "one-sublayer layers ('mamba2' | 'moe') are refused "
+                    "with first_k_dense_replace (a leading dense stack is "
+                    "a second GROUP of two-sublayer layers), hc_mult > 1 "
+                    "(hyper-connections wrap a layer's TWO sublayers) and "
+                    "mamba_proj_bias (ROADMAP R6)")
+                assert ("moe" in kinds) == (model.num_experts > 1), (
+                    "layer_types 'moe' is the experts' sublayer: it needs "
+                    "num_experts > 1, and a model with experts needs it "
+                    "(every feed-forward of a one-sublayer pattern is a "
+                    "layer of its own)")
             assert not model.mla and not model.mtp_num_layers \
                 and model.sliding_window is None \
                 and not model.parallel_attn and not model.use_post_ln \
@@ -1563,8 +1672,8 @@ class MegatronConfig:
                 "layer_types is refused with MLA (kv_lora_rank), "
                 "mtp_num_layers, sliding_window, parallel_attn, use_post_ln "
                 "and use_bias: the pattern's layers are pre-norm, one mixer "
-                "then one feed-forward, over whole regions of keys and "
-                "values (ROADMAP R6)")
+                "then one feed-forward (or ONE sublayer each: 'mamba2' | "
+                "'moe'), over whole regions of keys and values (ROADMAP R6)")
             assert max(sharded.values()) == 1, (
                 "layer_types (convolution or state-space layers and "
                 f"attention in one model) has been made to work on one "
@@ -1642,6 +1751,20 @@ class MegatronConfig:
         assert model.moe_shared_combination in ("sum", "average"), (
             f"moe_shared_combination={model.moe_shared_combination!r} "
             "(expected 'sum' or 'average')")
+        if model.moe_latent_size is not None \
+                or model.moe_shared_expert_ffn is not None:
+            assert model.num_experts > 1 \
+                and model.moe_dispatch == "dropless", (
+                "moe_latent_size / moe_shared_expert_ffn (experts in a "
+                "latent, a shared expert of its own width) are the "
+                "dropless path's (--moe_dispatch dropless, num_experts > 1)")
+            assert model.moe_latent_size is None \
+                or model.moe_latent_size >= 1
+            assert model.moe_shared_expert_ffn is None \
+                or (model.n_shared_experts
+                    and model.moe_shared_expert_ffn >= 1), (
+                "moe_shared_expert_ffn is the width of n_shared_experts "
+                ">= 1 shared experts together")
         if model.moe_router_experts is not None or model.moe_first_expert:
             # models/moe.py: one chip's share of an expert layer
             assert model.num_experts > 1 \
@@ -2173,6 +2296,76 @@ def jamba_config(size: str = "2-3b", **overrides) -> ModelConfig:
     return ModelConfig(**base).derived()
 
 
+NEMOTRON_3_SUPER_PATTERN = (
+    "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+    "EMEMEMEMEM*EMEMEMEMEM*EMEMEMEM*EMEMEMEME")
+_NEMOTRON_KINDS = {"M": "mamba2", "*": "full_attention", "E": "moe",
+                   "-": "mlp"}
+
+
+def nemotron_h_layer_types(pattern: str) -> Tuple[str, ...]:
+    """The kinds of a `nemotron_h` `hybrid_override_pattern`, a letter a
+    layer: M a Mamba-2 mixer, * attention, E the experts, - a dense
+    feed-forward (refused by `validate`); each layer ONE sublayer."""
+    return tuple(_NEMOTRON_KINDS[c] for c in pattern)
+
+
+def nemotron_h_config(size: str = "3-super", **overrides) -> ModelConfig:
+    """Nemotron-H presets: every size of "3-super" is a key of
+    nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16's config.json
+    (`nemotron_h`, 120B-A12B: 88 layers by `hybrid_override_pattern`, 40
+    Mamba-2 mixers (128 heads of 64, 8 groups, state 128, kernel 4, chunk
+    128), 8 attention layers (32 heads of 128 over 2 kv heads, no
+    positional term) and 40 expert layers (512 experts of width 2688 in a
+    latent of 1024, 22 a token, sigmoid scores with a choosing bias, scale
+    5, relu^2, one shared expert of width 5376); hidden 4096; RMSNorm eps
+    1e-5; vocabulary 131,072, untied head; 262,144 positions). Held in
+    bfloat16. Dropless. The multi-token-prediction module is not built. A
+    cut of the depth gives its own `--layer_types`."""
+    presets = {
+        "tiny": dict(num_layers=11, hidden_size=64, num_attention_heads=4,
+                     num_kv_heads=2, kv_channels=16, ffn_hidden_size=32,
+                     vocab_size=512, seq_length=128, num_experts=8,
+                     moe_top_k=3, moe_latent_size=32,
+                     moe_shared_expert_ffn=48, mamba_num_heads=8,
+                     mamba_head_dim=8, mamba_n_groups=2, mamba_d_state=16,
+                     mamba_chunk_size=16, attention_impl="dot",
+                     layer_types=nemotron_h_layer_types(
+                         NEMOTRON_3_SUPER_PATTERN[:11])),
+        "3-super": dict(num_layers=88, hidden_size=4096,
+                        num_attention_heads=32, num_kv_heads=2,
+                        kv_channels=128, ffn_hidden_size=2688,
+                        vocab_size=131072, seq_length=4096,
+                        max_position_embeddings=262144, num_experts=512,
+                        moe_top_k=22, moe_latent_size=1024,
+                        moe_shared_expert_ffn=5376, mamba_num_heads=128,
+                        mamba_head_dim=64, mamba_n_groups=8,
+                        mamba_d_state=128, mamba_chunk_size=128,
+                        params_dtype="bfloat16",
+                        layer_types=nemotron_h_layer_types(
+                            NEMOTRON_3_SUPER_PATTERN)),
+    }
+    if size not in presets:
+        raise ValueError(f"unknown nemotron_h size {size!r}; "
+                         f"valid: {sorted(presets)}")
+    base = dict(
+        use_rotary_emb=False, use_position_embedding=False,
+        norm_type="rmsnorm", norm_epsilon=1e-5, activation="squared_relu",
+        use_bias=False, use_post_ln=False, parallel_attn=False,
+        tie_embed_logits=False, mamba_d_conv=4, mamba_conv_bias=True,
+        mamba_proj_bias=False, n_shared_experts=1,
+        moe_scoring_func="sigmoid", moe_routed_scaling_factor=5.0,
+        moe_score_correction_bias=True, moe_norm_topk_prob=True,
+        moe_dispatch="dropless", moe_aux_loss_coeff=0.0,
+        attention_impl="flash",  # see llama2_config
+    )
+    base.update(presets[size])
+    # the router scores every published expert, held here or not
+    base["moe_router_experts"] = base["num_experts"]
+    base.update(overrides)
+    return ModelConfig(**base).derived()
+
+
 def gpt_config(**overrides) -> ModelConfig:
     base = dict(
         num_layers=12, hidden_size=768, num_attention_heads=12,
@@ -2206,5 +2399,7 @@ MODEL_PRESETS = {
     "lfm2-8b-a1b": lambda: lfm2_config("8b-a1b"),
     "jamba2-3b-tiny": lambda: jamba_config("tiny"),
     "jamba2-3b": lambda: jamba_config("2-3b"),
+    "nemotron-3-super-tiny": lambda: nemotron_h_config("tiny"),
+    "nemotron-3-super": lambda: nemotron_h_config("3-super"),
     "gpt2": gpt_config,
 }

@@ -233,7 +233,11 @@ class ConvKVCache(NamedTuple):
     - THE SCANS' STATE (`ssm`; None where the model has no "mamba" layer),
       [mamba layers, batch, d_state, d_inner] float32 whatever the cache's
       dtype: the recurrence's matrix a layer a sequence, the channels minor
-      (ops/selective_scan.py says why). Fixed in size and hidden by no mask,
+      (ops/selective_scan.py says why). A "mamba2" layer's is a matrix a
+      HEAD, [mamba2 layers, batch, heads, head_dim, d_state]
+      (models/mamba2.py, ops/ssd_scan.py), over a depthwise state of
+      d_inner + 2 groups x d_state channels; the layers that are a
+      feed-forward alone hold nothing. Fixed in size and hidden by no mask,
       as the convolutions' is.
 
     `live_rows` (scalar or [batch]): how many of the rows given to THIS call
@@ -250,7 +254,9 @@ class ConvKVCache(NamedTuple):
     # or [layers, batch], as KVCache.offset
     offset: jax.Array
     live_rows: jax.Array
-    ssm: Optional[jax.Array] = None   # [mamba layers, batch, d_state, d_inner]
+    # [mamba layers, batch, d_state, d_inner], or
+    # [mamba2 layers, batch, heads, head_dim, d_state]
+    ssm: Optional[jax.Array] = None
 
     NO_PADDING = 2 ** 30
 
@@ -266,9 +272,9 @@ class ConvKVCache(NamedTuple):
             offset=jnp.zeros((n_attn, batch) if per_slot_offsets
                              else (n_attn,), jnp.int32),
             live_rows=jnp.int32(ConvKVCache.NO_PADDING),
-            ssm=(jnp.zeros((cfg.layers_of("mamba"), batch, cfg.mamba_d_state,
-                            cfg.mamba_d_inner), jnp.float32)
-                 if cfg.layers_of("mamba") else None))
+            ssm=(jnp.zeros((cfg.state_layers, batch, *cfg.ssm_state_shape),
+                           jnp.float32)
+                 if cfg.ssm_state_shape else None))
 
 
 def _layer_of(a, layer):

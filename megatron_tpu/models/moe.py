@@ -67,6 +67,13 @@ whatever the skew. What the absent experts would have added is left out:
 that is another chip's part, and no code here stands in for that chip or
 for the exchange with it (ROADMAP R1). The shared experts are computed on
 every chip alike.
+
+And with EXPERTS IN A LATENT (`cfg.moe_latent_size`, a `nemotron_h`
+model's): the rows are projected hidden -> latent by one plain product ahead
+of the routing's gather, the banks are [E, latent, f] and [E, f, latent],
+and the tokens' weighted sums go back latent -> hidden by one more. The
+router and the shared experts read the hidden rows. The shared experts may
+have a width of their own (`cfg.moe_shared_expert_ffn`).
 """
 from __future__ import annotations
 
@@ -80,6 +87,7 @@ import dataclasses
 from megatron_tpu.config import ModelConfig
 from megatron_tpu.models.mlp import (activation_fn, mlp_apply, mlp_axes,
                                      mlp_init)
+from megatron_tpu.ops.quantized import qdense, wcast
 
 
 def moe_capacity(cfg: ModelConfig, seq: int) -> int:
@@ -94,13 +102,16 @@ def _shared_cfg(cfg: ModelConfig) -> ModelConfig:
     number)."""
     return dataclasses.replace(
         cfg, num_experts=1,
-        ffn_hidden_size=cfg.n_shared_experts * cfg.ffn_hidden_size)
+        ffn_hidden_size=(cfg.moe_shared_expert_ffn
+                         or cfg.n_shared_experts * cfg.ffn_hidden_size))
 
 
 def moe_init(rng, cfg: ModelConfig, dtype=jnp.float32):
     E = cfg.num_experts
     h = cfg.hidden_size
     ffn = cfg.ffn_hidden_size
+    # the banks' rows: the hidden size, or the latent the experts live in
+    rows = cfg.moe_latent_size or h
     kr, k1, k2 = jax.random.split(rng, 3)
     std = cfg.init_method_std
     out_std = (std / math.sqrt(2.0 * cfg.num_layers)
@@ -112,12 +123,18 @@ def moe_init(rng, cfg: ModelConfig, dtype=jnp.float32):
     # that as [E, h, 2f] is a copy of the whole bank, 2.6 ms a layer in
     # every step at OLMoE's widths; PERF.md section 6, PR 27.)
     w1 = jax.random.normal(
-        k1, (E, h, 2 * ffn if cfg.is_glu else ffn), dtype) * std
+        k1, (E, rows, 2 * ffn if cfg.is_glu else ffn), dtype) * std
     params = {
         "router": jax.random.normal(kr, (h, cfg.router_experts), dtype) * std,
         "w1": w1,
-        "w2": jax.random.normal(k2, (E, ffn, h), dtype) * out_std,
+        "w2": jax.random.normal(k2, (E, ffn, rows), dtype) * out_std,
     }
+    if cfg.moe_latent_size:
+        k_in, k_out = jax.random.split(jax.random.fold_in(rng, 5))
+        params["latent_in"] = jax.random.normal(
+            k_in, (h, rows), dtype) * std
+        params["latent_out"] = jax.random.normal(
+            k_out, (rows, h), dtype) * out_std
     if cfg.use_bias:
         b1_shape = (E, 2, ffn) if cfg.is_glu else (E, ffn)
         params["b1"] = jnp.zeros(b1_shape, dtype)
@@ -141,6 +158,10 @@ def moe_axes(cfg: ModelConfig):
         "w1": ("experts", "embed", None),
         "w2": ("experts", None, "embed"),
     }
+    if cfg.moe_latent_size:
+        # the latent is unsharded: the banks' rows are its whole width
+        axes.update(w1=("experts", None, None), w2=("experts", None, None),
+                    latent_in=("embed", None), latent_out=(None, "embed"))
     if cfg.use_bias:
         axes["b1"] = (("experts", None, None) if cfg.is_glu
                       else ("experts", None))
@@ -219,7 +240,8 @@ def split_stacked_banks(stacked, cfg: ModelConfig):
 
 def _dropless_experts(params, x, idx, gates, cfg: ModelConfig, layer=None):
     """The dropless expert products and their combination. x [b, s, h],
-    idx / gates [b, s, K] -> y [b, s, h].
+    idx / gates [b, s, K] -> y [b, s, h]; h is the banks' row width (the
+    hidden size, or the latent's).
 
     `layer` None: `params` are one layer's, and its banks are cast to x's
     dtype here, where the backward pass finds the cast to differentiate.
@@ -331,12 +353,23 @@ def moe_apply(params, x, cfg: ModelConfig, *, bank_layer=None):
     if dropless:
         assert not cfg.use_bias and cfg.quantized_gemm == "none", (
             "the dropless path has no expert bias and no int8 product")
-        y = _dropless_experts(params, x, idx, gates, cfg, bank_layer)
+        # only a program with a cache hands down `bank_layer`
+        read_once = bank_layer is not None
+        rows = x
+        if cfg.moe_latent_size:
+            with jax.named_scope("mtpu/moe/latent_in"):
+                rows = qdense(x, wcast(params["latent_in"], dtype,
+                                       read_once=read_once),
+                              cfg.quantized_gemm)
+        y = _dropless_experts(params, rows, idx, gates, cfg, bank_layer)
+        if cfg.moe_latent_size:
+            with jax.named_scope("mtpu/moe/latent_out"):
+                y = qdense(y, wcast(params["latent_out"], dtype,
+                                    read_once=read_once), cfg.quantized_gemm)
         if cfg.n_shared_experts:
             with jax.named_scope("mtpu/moe/shared"):
-                # only a program with a cache hands down `bank_layer`
                 shared = mlp_apply(params["shared"], x, _shared_cfg(cfg),
-                                   read_once=bank_layer is not None)
+                                   read_once=read_once)
                 if cfg.moe_shared_combination == "average":
                     shared = shared / cfg.n_shared_experts
                 y = y + shared

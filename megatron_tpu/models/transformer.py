@@ -58,8 +58,19 @@ def layer_init(rng, cfg: ModelConfig, dtype=jnp.float32,
     pre-LN: input_layernorm + post_attention_layernorm (output_layernorm=Id);
     post-LN: input_layernorm=Id, post_attention_layernorm + output_layernorm;
     parallel_attn drops post_attention_layernorm; parallel_layernorm adds a
-    dedicated mlp norm."""
+    dedicated mlp norm.
+
+    A layer of ONE sublayer (`cfg.one_sublayer`) is `input_norm` and the one
+    thing `mixer` names: "mamba2" (`params["mamba2"]`, models/mamba2.py),
+    "full_attention" (`params["attention"]`) or "moe" (`params["mlp"]`, the
+    experts)."""
     k_attn, k_mlp, k_inter = jax.random.split(rng, 3)
+    if cfg.one_sublayer:
+        assert not cross_attn
+        name, init, _ = _one_sublayer(mixer)
+        return {"input_norm": norm_init(cfg.norm_type, cfg.hidden_size,
+                                        dtype),
+                name: init(k_mlp if mixer == "moe" else k_attn, cfg, dtype)}
     if cfg.num_experts > 1:
         from megatron_tpu.models.moe import moe_init
         mlp_params = moe_init(k_mlp, cfg, dtype)
@@ -100,8 +111,24 @@ def layer_init(rng, cfg: ModelConfig, dtype=jnp.float32,
     return params
 
 
+def _one_sublayer(mixer: str):
+    """(its name in the layer's parameters, its init, its axes) of the ONE
+    sublayer a layer of kind `mixer` holds (`cfg.one_sublayer`)."""
+    if mixer == "mamba2":
+        from megatron_tpu.models.mamba2 import mamba2_axes, mamba2_init
+        return "mamba2", mamba2_init, mamba2_axes
+    if mixer == "moe":
+        from megatron_tpu.models.moe import moe_axes, moe_init
+        return "mlp", moe_init, moe_axes
+    assert mixer == "full_attention", mixer
+    return "attention", attention_init, attention_axes
+
+
 def layer_axes(cfg: ModelConfig, cross_attn: bool = False,
                mixer: str = "full_attention"):
+    if cfg.one_sublayer:
+        name, _, axes = _one_sublayer(mixer)
+        return {"input_norm": norm_axes(cfg.norm_type), name: axes(cfg)}
     if cfg.num_experts > 1:
         from megatron_tpu.models.moe import moe_axes
         mlp_ax = moe_axes(cfg)
@@ -178,7 +205,9 @@ def layer_apply(
     stack. `kind_layer`: in a stack of window and full layers
     (`_period_stack_apply`) or of convolution and attention layers
     (`_pattern_stack_apply`; `mixer` says which this one is), the layer's
-    index in its own kind's cache stack.
+    index in its own kind's cache stack. In a pattern of ONE-sublayer layers
+    (`cfg.one_sublayer`) `mixer` names the layer's only sublayer, which may
+    be the feed-forward ("moe": no cache row, no mixer).
 
     `adapters`: (per-layer LoraAdapter bank, adapter_idx [b]) for the
     SELF-attention projections only (multi-tenant LoRA serving —
@@ -228,11 +257,16 @@ def layer_apply(
 
     def _mixer_branch(ln_out, kv_cache):
         """The layer's mixer on its normed input: (out, the cache)."""
-        if mixer in ("conv", "mamba"):
+        if mixer in ("conv", "mamba", "mamba2"):
             assert causal and encoder_output is None and adapters is None \
                 and segment_ids is None and not cp_pre_zigzag, (
                 "a convolution or state-space layer is causal, unsharded, "
                 "over one document")
+            if mixer == "mamba2":
+                from megatron_tpu.models.mamba2 import mamba2_apply
+                return mamba2_apply(
+                    params["mamba2"], ln_out, cfg, kv_cache=kv_cache,
+                    kind_layer=kind_layer)
             if mixer == "mamba":
                 from megatron_tpu.models.mamba import mamba_apply
                 return mamba_apply(
@@ -260,6 +294,17 @@ def layer_apply(
             segment_ids=segment_ids, causal=causal,
             cp_pre_zigzag=cp_pre_zigzag, adapters=adapters,
             kind_layer=kind_layer)
+
+    if cfg.one_sublayer:
+        # x + F(norm(x)): F the mixer, or the feed-forward alone ("moe")
+        ln_out = apply_norm(cfg.norm_type, params["input_norm"], x, eps)
+        aux = jnp.zeros((), jnp.float32)
+        if mixer == "moe":
+            out, aux = _mlp_branch(ln_out)
+        else:
+            out, kv_cache = _mixer_branch(ln_out, kv_cache)
+        out = x + _branch(r_dp1, _dropout(r_attn, out, p_drop))
+        return constrain(out, RESIDUAL_AXES), kv_cache, aux
 
     if cfg.hc_mult > 1:
         from megatron_tpu.models.hyper_connections import hc_sublayer
@@ -645,7 +690,8 @@ def _pattern_stack_apply(stacked_params, x, cfg: ModelConfig, *, rope_cos,
                          rope_sin, position_ids, kv_caches, rng,
                          deterministic):
     """`stack_apply` for a model whose layers' mixers follow a pattern
-    (`cfg.layer_types`: convolutions and attention). Group by group
+    (`cfg.layer_types`: convolutions, state-space mixers and attention, each
+    with its feed-forward, or layers of ONE sublayer each). Group by group
     (`_pattern_groups`: the leading dense layers, then the expert layers),
     ONE `lax.scan` over the whole PERIODS of the group's pattern
     (`_pattern_period`); what lies past the last whole period (a published
@@ -668,6 +714,8 @@ def _pattern_stack_apply(stacked_params, x, cfg: ModelConfig, *, rope_cos,
         if cached and group_cfg.num_experts > 1:
             from megatron_tpu.models.moe import split_stacked_banks
             for kind in list(params):
+                if "mlp" not in params[kind]:   # a mixer alone
+                    continue
                 banks[kind], rest = split_stacked_banks(
                     params[kind]["mlp"], group_cfg)
                 params = {**params, kind: {**params[kind], "mlp": rest}}

@@ -28,7 +28,8 @@ def pool_kind(model, max_len: int) -> str:
         return "latent"  # models/mla.py::LatentKVCache
     if model.state_layers:
         # models/attention.py::ConvKVCache: a state of fixed size a slot (a
-        # convolution's last inputs; a scan's matrix beside them)
+        # convolution's last inputs; a scan's matrix, or a matrix a head,
+        # beside them)
         return "conv-state"
     return "rolling" if kv_region_cap(model, max_len) < max_len else "regions"
 
@@ -115,7 +116,8 @@ ROWS: Dict[str, Tuple[str, str, str]] = {
     "latent": ("MLA (kv_lora_rank set)", " on the latent pool",
                " (ROADMAP R5)"),
     "conv-state": (
-        "layer_types with a state of fixed size a slot, mamba or conv layers",
+        "layer_types with a state of fixed size a slot, mamba, mamba2 or conv "
+        "layers",
         " on the pool of keys, values and a state of fixed size",
         " (ROADMAP R6)"),
     "streams": ("hc_mult={m.hc_mult} (hyper-connections)",

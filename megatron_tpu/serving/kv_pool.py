@@ -100,7 +100,8 @@ def insert_prefill(pool: KVCache, prefill: KVCache, slot, plen) -> KVCache:
             conv=dus(pool.conv, prefill.conv.astype(pool.conv.dtype),
                      start4),
             ssm=(None if pool.ssm is None
-                 else dus(pool.ssm, prefill.ssm, start4)),
+                 else dus(pool.ssm, prefill.ssm,
+                          (zero, slot) + (zero,) * (pool.ssm.ndim - 2))),
             offset=dus(pool.offset,
                        jnp.full((pool.offset.shape[0], 1), plen, jnp.int32),
                        (zero, slot)))
@@ -494,8 +495,8 @@ class SlotKVPool:
     @property
     def conv_layers(self) -> int:
         """Layers that keep a state of fixed size a slot and no keys or
-        values: a convolution's last inputs and, in a "mamba" layer, the
-        scan's matrix beside them (`cfg.layer_types`;
+        values: a convolution's last inputs and, in a "mamba" or "mamba2"
+        layer, the scan's state beside them (`cfg.layer_types`;
         models/attention.py::ConvKVCache)."""
         return self.cfg.state_layers
 
@@ -966,10 +967,20 @@ class SlotKVPool:
         """Bytes of the convolutions' state (0 where the pool has none)."""
         return self.caches.conv.nbytes if self.conv_layers else 0
 
-    def ssm_state_nbytes(self) -> int:
-        """Bytes of the scans' state, float32 (0 where the pool has none)."""
+    def _scan_state_nbytes(self, kind: str) -> int:
         ssm = getattr(self.caches, "ssm", None)
-        return 0 if ssm is None else ssm.nbytes
+        held = ssm is not None and self.cfg.state_kind == kind
+        return ssm.nbytes if held else 0
+
+    def ssm_state_nbytes(self) -> int:
+        """Bytes of the Mamba-1 scans' state, [d_state, d_inner] float32 a
+        layer a slot (0 where the pool has none)."""
+        return self._scan_state_nbytes("mamba")
+
+    def ssd_state_nbytes(self) -> int:
+        """Bytes of the Mamba-2 scans' state, [heads, head_dim, d_state]
+        float32 a layer a slot (0 where the pool has none)."""
+        return self._scan_state_nbytes("mamba2")
 
     def ring_nbytes(self) -> int:
         """Bytes of the window layers' rings (0 where the pool has none)."""
@@ -982,7 +993,7 @@ class SlotKVPool:
         kinds, else the whole pool."""
         if not self.hybrid:
             return (self.nbytes() - self.conv_state_nbytes()
-                    - self.ssm_state_nbytes())
+                    - self.ssm_state_nbytes() - self.ssd_state_nbytes())
         return self.caches.full_k.nbytes + self.caches.full_v.nbytes
 
     def bytes_per_slot(self) -> int:
@@ -1091,7 +1102,7 @@ def slot_nbytes(cfg: ModelConfig, max_len: int,
     # a state layer's fixed size, whatever the length: the depthwise
     # kernel's inputs in the pool's dtype, the scan's matrix in float32
     n += cfg.state_layers * cfg.conv_state_width * jnp.dtype(dtype).itemsize
-    n += cfg.layers_of("mamba") * cfg.ssm_state_width * 4
+    n += cfg.state_layers * cfg.ssm_state_width * 4
     return n
 
 

@@ -85,7 +85,8 @@ Training (`training/loop.py`, main thread):
 
 On the device (`jax.named_scope`, so in the `op_name` of every HLO instruction
 traced under it; models/moe.py, models/attention.py, models/mla.py,
-models/hyper_connections.py, models/mamba.py and models/rope.py):
+models/hyper_connections.py, models/mamba.py, models/mamba2.py and
+models/rope.py):
 
 | scope | round what |
 |---|---|
@@ -107,6 +108,14 @@ models/hyper_connections.py, models/mamba.py and models/rope.py):
 | `mtpu/ssm/params` | rows x W_x [d_inner, dt_rank + 2 d_state], the three RMS norms, dt's product with W_dt in float32, softplus, the padding rows' step size set to 0, A = -exp(A_log) |
 | `mtpu/ssm/scan` | the recurrence: the kernel `_ssm_selective_scan` for a prefill or a chunk (ops/selective_scan.py; its lane spread of B and C), the one-step update over the pool's layer for a decode step, the `lax.scan` with no cache; y gated by SiLU(z) inside |
 | `mtpu/ssm/out_proj` | the layer's second product, rows x [d_inner, h] |
+| `mtpu/ssd/in_proj` | a Mamba-2 layer's first product, rows x [h, d_inner + (d_inner + 2 G N) + H]: the gate z, the depthwise kernel's input (x, B, C) and the step sizes (`models/mamba2.py`) |
+| `mtpu/ssd/state` | the read of the layer's two states (the depthwise kernel's last inputs over x, B and C; the scan's [heads, head_dim, d_state] float32 matrices; a row each slot) ahead of the rows, and their write after the call's last real row, one update in place a layer each |
+| `mtpu/ssd/conv` | the ONE depthwise kernel's taps over [state ; rows] of x, B and C accumulated in float32, the bias, SiLU, the split, softplus of the step sizes in float32, the padding rows' step size set to 0, A = -exp(A_log) |
+| `mtpu/ssd/scan` | the recurrence: the kernel `_ssd_chunk_scan` for a prefill or a chunk (ops/ssd_scan.py; four products a chunk a head, the running sums of dt A made outside it), the one-step update over the pool's layer for a decode step, the `einsum` form with no cache |
+| `mtpu/ssd/norm` | y gated by SiLU(z), then the RMSNorm over each group's channels, float32 statistics, the learned scale |
+| `mtpu/ssd/out_proj` | the layer's second product, rows x [d_inner, h] |
+| `mtpu/moe/latent_in` | experts in a latent (`cfg.moe_latent_size`): rows x [h, latent] ahead of the routing's gather (`models/moe.py`) |
+| `mtpu/moe/latent_out` | the tokens' weighted sums x [latent, h], behind the combination |
 | `mtpu/moe/shared` | the shared experts' MLP, added beside the routed sum (`n_shared_experts`) |
 | `mtpu/mla/q` | latent attention's query: down-projection, norm, up-projection, the rotary on its rope part |
 | `mtpu/mla/latent` | the latent row: down-projection, norm over kv_lora_rank, the rotary on the shared key, the write into the cache |
@@ -128,7 +137,10 @@ what a slot reserves, and the pool's bytes by kind; `serve_kv_bytes_per_slot`)
 and `conv_state_bytes` (`.conv_state_nbytes()`: the convolution layers' state
 of every slot; a slot's share of it is `serve_state_bytes_per_slot`) and
 `ssm_state_bytes` (`.ssm_state_nbytes()`: the scans' float32 matrices of every
-slot; a slot's share of it is `serve_ssm_state_bytes_per_slot`).
+slot; a slot's share of it is `serve_ssm_state_bytes_per_slot`), and
+`ssd_state_bytes` (`.ssd_state_nbytes()`: the Mamba-2 scans' float32 matrices
+a head of every slot; a slot's share of it is
+`serve_ssd_state_bytes_per_slot`).
 `prefill_chunks` counts the chunk programs dispatched. `admits_total`,
 `admits_early` and `early_admit_declined_prefilling` (placements by `_admit`,
 those made while a decode window ran, and windows that ended with a prompt

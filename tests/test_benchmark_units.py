@@ -9,7 +9,8 @@ pytest.register_assert_rewrite(
     "benchmark.tests.test_moe_roofline", "benchmark.tests.test_reference",
     "benchmark.tests.test_moe_share_roofline",
     "benchmark.tests.test_conv_kinds", "benchmark.tests.test_hc_kinds",
-    "benchmark.tests.test_ssm_kinds", "benchmark.tests.test_ssm_roofline")
+    "benchmark.tests.test_ssm_kinds", "benchmark.tests.test_ssm_roofline",
+    "benchmark.tests.test_ssd_kinds", "benchmark.tests.test_ssd_roofline")
 
 from benchmark.tests.test_conv_kinds import *  # noqa: E402,F401,F403
 from benchmark.tests.test_hc_kinds import *  # noqa: E402,F401,F403
@@ -17,6 +18,8 @@ from benchmark.tests.test_moe_roofline import *  # noqa: E402,F401,F403
 from benchmark.tests.test_moe_share_roofline import *  # noqa: E402,F401,F403
 from benchmark.tests.test_program_spans import *  # noqa: E402,F401,F403
 from benchmark.tests.test_reference import *  # noqa: E402,F401,F403
+from benchmark.tests.test_ssd_kinds import *  # noqa: E402,F401,F403
+from benchmark.tests.test_ssd_roofline import *  # noqa: E402,F401,F403
 from benchmark.tests.test_ssm_kinds import *  # noqa: E402,F401,F403
 from benchmark.tests.test_ssm_roofline import *  # noqa: E402,F401,F403
 from benchmark.tests.test_yardstick import *  # noqa: E402,F401,F403

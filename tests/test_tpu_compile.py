@@ -204,6 +204,55 @@ def test_fused_norm_fwd_bwd(one_chip, norm):
     _compile(jax.value_and_grad(loss, argnums=argnums), x, s, s)
 
 
+@pytest.mark.parametrize("batch,rows", [(1, 2048), (1, 512)])
+def test_ssd_chunk_scan_at_nemotron_widths(one_chip, batch, rows):
+    """The chunked scan's kernel (PR 52) at NVIDIA-Nemotron-3-Super's
+    widths: a 2,048-row chunk and a 512-row bucket of 128 heads of 64
+    channels over 8 groups of B and C of 128, chunks of 128 rows, a state of
+    [128, 64, 128] float32 a sequence in and out, bf16 rows, float32 step
+    sizes: a group's sixteen heads a grid step, 64-channel slices of a
+    1,024-lane block."""
+    from megatron_tpu.ops.ssd_scan import _ssd_chunk_scan, ssd_block_heads
+
+    def S(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    f32 = jnp.float32
+    assert ssd_block_heads(rows, 128, 64, 8, 128, 128) == 16
+    text = jax.jit(functools.partial(_ssd_chunk_scan, chunk=128)).lower(
+        S((batch, rows, 128, 64)), S((batch, rows, 128), f32),
+        S((128,), f32), S((batch, rows, 8, 128)), S((batch, rows, 8, 128)),
+        S((128,), f32), S((batch, 128, 64, 128), f32)).compile().as_text()
+    # the trace finds the kernel by this name (benchmark/ssd_roofline.py)
+    assert any("%_ssd_chunk_scan" in line and "tpu_custom_call" in line
+               for line in text.splitlines())
+
+
+@pytest.mark.parametrize("rows,k,n", [
+    (1408, 1024, 2688),     # a decode step: 64 slots x 22 choices, w1
+    (1408, 2688, 1024),     # ... and w2: k over its tile, 640 rows behind
+    (45056, 1024, 2688),    # a 2,048-row chunk's (token, choice) rows
+    (45056, 2688, 1024)])
+def test_grouped_matmul_at_nemotron_latent_widths(one_chip, rows, k, n):
+    """The dropless experts' grouped product over banks whose rows are the
+    LATENT's 1,024 and not the hidden size: 128 held experts of width 2,688
+    stacked over 5 expert layers in bf16, read where they lie at the
+    layer's index. 2,688 is 21 lane tiles: the first product's columns
+    overhang its 2,048-column tile, the second's contraction its 2,048-row
+    tile (`ops/grouped_matmul.py::_tiling`, `past_k`)."""
+    from megatron_tpu.ops.grouped_matmul import grouped_matmul
+
+    def S(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def product(lhs, bank, layer, sizes):
+        return grouped_matmul(lhs, bank, sizes, layer=layer, use_kernel=True)
+    text = jax.jit(product).lower(
+        S((rows, k)), S((5, 128, k, n)), S((), jnp.int32),
+        S((128,), jnp.int32)).compile().as_text()
+    assert any("%_moe_grouped_matmul." in line and "tpu_custom_call" in line
+               for line in text.splitlines())
+
+
 @pytest.mark.parametrize("rows,k,n,grad", [
     (640, 2048, 2048, False),      # a decode step of 80 slots, first product
     (640, 1024, 2048, False),      # ... and the second
