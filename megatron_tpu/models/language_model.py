@@ -32,6 +32,7 @@ from megatron_tpu.models.rope import precompute_freqs, yarn_freqs
 from megatron_tpu.ops import grad_accum
 from megatron_tpu.ops.cross_entropy import cross_entropy_loss
 from megatron_tpu.ops.dropout import dropout
+from megatron_tpu.ops.embed_gather import embed_tokens
 from megatron_tpu.parallel.sharding import constrain
 
 
@@ -190,7 +191,9 @@ def model_forward(
     from megatron_tpu.config import as_dtype
     compute_dtype = as_dtype(cfg.compute_dtype)
     emb = params["embedding"]["word_embeddings"]
-    x = emb[tokens].astype(compute_dtype)
+    # the rows where the table lies, in a served program whose table would
+    # be copied whole to gather them; `emb[tokens]` everywhere else
+    x = embed_tokens(emb, tokens, compute_dtype, cached=kv_caches is not None)
     if cfg.use_position_embedding:
         if position_ids is None:
             pos = jnp.arange(tokens.shape[1])[None, :]

@@ -201,10 +201,11 @@ def wcast(w, dtype, *, read_once: bool = False):
     (`models/attention.py::_project`: left free, a decode step copied wq
     three times over and a two-prompt prefill copied wq's and wo's slice
     into another order, 20 bytes a weight). The embedding's lookup is not
-    a cast of this kind and does not come through here: a tied table's
-    bf16 copy is read by the token gather alone (the head's product reads
-    the float32 table in place), and rounding after the gather made it a
-    float32 copy (compile, ISSUE 34).
+    a cast of this kind and does not come through here: the copy of a
+    whole tied table that a served program of Falcon-7B's made was the
+    token gather's, for the table's layout and not for its dtype (the
+    head's product reads the float32 table in place), and went with
+    `ops/embed_gather.py` (PR 53).
 
     The rule covers a narrowing that keeps the exponent's width (float32
     to bfloat16, the served case). `reduce_precision` flushes what would be

@@ -619,3 +619,97 @@ def test_xing_prefill_program_makes_no_buckets_logits(one_chip, monkeypatch):
     assert f"f32[{vocab}]" in text
     parent = 2_183_069_184
     assert compiled.memory_analysis().temp_size_in_bytes <= parent - (1 << 30)
+
+
+# Falcon-7B's tied word embedding table
+TABLE = (65024, 4544)
+
+
+def _table_shaped(text, vocab, hidden):
+    """The instructions of an HLO text that RUN and whose result is an array
+    of the table's shape, in any type: what `serve_weight_copy_ms_per_step`
+    reads of a trace (`benchmark/layer_metrics`). A parameter is not made, a
+    bitcast moves nothing, and what stands inside a fusion's own computation
+    (the head's product narrows the table it reads on the way in) is no
+    operation of its own."""
+    fused = set(re.findall(r" fusion\(.*calls=%([\w.-]+)", text))
+    made, inside = [], None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.-]+) \(.*\{$", line)
+        if head:
+            inside = head.group(1)
+        m = _RESULT.match(line)
+        if m and inside not in fused and m.group(2) == f"{vocab},{hidden}" \
+                and m.group(3) not in ("parameter", "get-tuple-element",
+                                       "bitcast"):
+            made.append(line.strip()[:160])
+    return made
+
+
+def _gather_and_tied_head(one_chip, monkeypatch, rows, *, hidden=TABLE[1],
+                          table_dtype=jnp.float32, cached=True):
+    """A program's first and last uses of a tied table compiled for the
+    chip: the token gather as `model_forward` asks for it
+    (`ops/embed_gather.py::embed_tokens`, the rule deciding), and the
+    head's product over the same parameter."""
+    from megatron_tpu.ops.embed_gather import embed_tokens
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(emb, tokens, x):
+        got = embed_tokens(emb, tokens, jnp.bfloat16, cached=cached)
+        return got, (x @ emb.T.astype(jnp.bfloat16)).astype(jnp.float32)
+    return jax.jit(fn).lower(
+        S((TABLE[0], hidden), table_dtype), S((1, rows), jnp.int32),
+        S((64, hidden), jnp.bfloat16)).compile().as_text()
+
+
+@pytest.mark.parametrize("table_dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("rows", [64, 512], ids=["decode", "prefill_512"])
+def test_served_token_gather_makes_no_copy_of_the_table(one_chip,
+                                                        monkeypatch, rows,
+                                                        table_dtype):
+    """A decode step's 64 rows and a 512-row prefill's from Falcon-7B's
+    table (PR 53): the kernel is in the program under its own scope, its
+    operand is the parameter seen transposed (a bitcast: the table lies
+    vocabulary-minor), and nothing in the program makes an array of the
+    table's shape."""
+    text = _gather_and_tied_head(one_chip, monkeypatch, rows,
+                                 table_dtype=jnp.dtype(table_dtype))
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "custom-call(" in line]
+    assert len(calls) == 1 and "mtpu/embed/gather" in calls[0], calls
+    name = re.search(r"custom-call\(%\S+, %(\S+?)\)", calls[0]).group(1)
+    operand = [line for line in text.splitlines()
+               if line.lstrip().startswith(f"%{name} = ")]
+    assert len(operand) == 1 and " bitcast(" in operand[0], operand
+    assert f"[{TABLE[1]},{TABLE[0]}]{{1,0:" in operand[0], operand
+    assert _table_shaped(text, *TABLE) == []
+
+
+@pytest.mark.parametrize("table_dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("rows,cached", [(64, False), (1536, True)],
+                         ids=["no_cache", "over_the_edge"])
+def test_plain_token_gather_copies_the_whole_table(one_chip, monkeypatch,
+                                                   rows, cached,
+                                                   table_dtype):
+    """What the rule's (c) stands on: `emb[tokens]` of a table whose hidden
+    is not a multiple of 128 copies it whole, a bf16 table too. The day
+    this fails the compiler has stopped copying, and the rule can go."""
+    text = _gather_and_tied_head(one_chip, monkeypatch, rows, cached=cached,
+                                 table_dtype=jnp.dtype(table_dtype))
+    assert "tpu_custom_call" not in text
+    made = _table_shaped(text, *TABLE)
+    assert made and all(" copy(" in line for line in made), made
+
+
+def test_a_table_of_whole_lane_tiles_is_gathered_in_place(one_chip,
+                                                          monkeypatch):
+    """... and the other side of (c): at a hidden of 36 lane tiles the table
+    lies row-major, the rule keeps `emb[tokens]` for a served program, and
+    the compiler gathers the rows where they lie."""
+    text = _gather_and_tied_head(one_chip, monkeypatch, 64, hidden=4608)
+    assert "tpu_custom_call" not in text
+    assert _table_shaped(text, TABLE[0], 4608) == []
