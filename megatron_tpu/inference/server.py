@@ -1161,7 +1161,9 @@ class MegatronServer:
         (each replica stages itself from shared storage; a router-
         fronted process runs its own rolling_upgrade), register_adapter
         (path-only: factors cannot cross the process boundary), drain,
-        trace (the operator's profiler capture, `_capture_trace`).
+        trace (the operator's profiler capture, `_capture_trace`),
+        requests (the engines' own record of their newest requests,
+        `_request_rows`).
         Refusals stay typed: 409 for a rejected swap (the process
         keeps serving its old weights) or a second trace, 400 for bad
         requests."""
@@ -1216,8 +1218,39 @@ class MegatronServer:
             return 200, {"drained": bool(drained)}
         if op == "trace":
             return self._capture_trace(payload)
+        if op == "requests":
+            return self._request_rows(payload)
         return 400, {"message": f"unknown admin op {op!r} (swap_weights"
-                                " | register_adapter | drain | trace)"}
+                                " | register_adapter | drain | trace"
+                                " | requests)"}
+
+    REQUESTS_DEFAULT_N = 32
+
+    def _request_rows(self, payload: dict) -> Tuple[int, dict]:
+        """`{"op": "requests", "n": N}`: the newest N rows (by
+        `t_submit`) of the record this process's engines keep of their
+        requests (`utils/tracing.py::request_record`, a row's fields in
+        its docstring), each with its four segments worked out in
+        seconds, the slowest first token first and a request that has
+        none yet ahead of all: why a request was slow, with no profiler
+        session. `now` is `time.monotonic()`, the clock of the stamps."""
+        import time as _time
+        from megatron_tpu.utils.tracing import request_record
+        try:
+            n = int(payload.get("n", self.REQUESTS_DEFAULT_N))
+        except (TypeError, ValueError):
+            n = 0
+        if n < 1:
+            return 400, {"message": "requests n must be a positive "
+                                    "integer"}
+        newest = sorted(request_record(), key=lambda r: r.t_submit)[-n:]
+
+        def first_token_s(row):
+            return (math.inf if row.t_first is None
+                    else row.t_first - row.t_submit)
+        newest.sort(key=first_token_s, reverse=True)
+        return 200, {"now": _time.monotonic(),
+                     "requests": [r.as_dict() for r in newest]}
 
     TRACE_MAX_S = 30.0
 

@@ -184,7 +184,7 @@ from megatron_tpu.serving.structured import (GrammarCompileError,
                                              compile_response_format)
 from megatron_tpu.utils import compile_cache
 from megatron_tpu.utils.logging import print_rank_0
-from megatron_tpu.utils.tracing import phase, span
+from megatron_tpu.utils.tracing import keep_requests, phase, span
 
 from megatron_tpu.config import SERVING_KV_DTYPES as _KV_DTYPES
 
@@ -508,6 +508,17 @@ class ServingEngine:
             lambda: int(self._active.sum()) + len(self._prefilling))
         self.metrics = metrics if metrics is not None else ServingMetrics()
         self.metrics.set_pool_gauges(self.pool)
+        # a request's own record (utils/tracing.py's table): the ring is
+        # the metrics', the process keeps it past `close()`. Engine
+        # thread only: the rows admitted and still without a first token;
+        # while a window's fetch is awaited, and only then, the list of
+        # the rows admitted meanwhile (`_step` stamps their `t_device` as
+        # the fetch returns); the prefill programs [count, padded rows]
+        # dispatched and not yet known finished
+        self.engine_id = keep_requests(self.metrics.requests)
+        self._awaiting: list = []
+        self._behind_window: Optional[list] = None
+        self._unfetched = [0, 0]
         # graceful degradation (serving/degrade.py): None when the
         # brownout ladder is disabled — the None path is the
         # bit-identical pre-ladder engine (test-pinned). The
@@ -858,8 +869,10 @@ class ServingEngine:
                 self.scheduler.check_admissible(children[0])
                 for req in children:
                     req.mark_admitted()
+                    req.record.t_admit = req.record.t_device = \
+                        req.admit_time
+                    self.metrics.record_admitted(req.record)
                     req.finish()
-                    self.metrics.record_admitted(0.0)
             elif best_of == 1:
                 self.scheduler.submit(children[0])
             else:
@@ -886,9 +899,16 @@ class ServingEngine:
         choke point for ALL terminal accounting, so the request-
         conservation invariant cannot drift as failure paths are
         added. Completions count here too (record_completed, with the
-        latency/token payload) — do NOT add per-site record_completed
+        token payload) — do NOT add per-site record_completed
         calls, they would double-count requests_completed and break
-        the law."""
+        the law. The request's row is closed here, and enters the ring
+        here if the request was never admitted."""
+        row = req.record
+        row.prompt_tokens = len(req.prompt)
+        row.generated = len(req.generated)
+        row.t_submit, row.t_finish = req.submit_time, req.finish_time
+        row.outcome = outcome  # last: who reads it reads a whole row
+        self.metrics.requests.keep(row)
         if outcome == "completed":
             # goodput ledger: a completed request whose first token
             # blew the TTFT SLO delivered its tokens too late to be
@@ -901,9 +921,7 @@ class ServingEngine:
             if self._slo_ttft_s is not None and ttft is not None \
                     and ttft > self._slo_ttft_s:
                 good = 0
-            self.metrics.record_completed(
-                (req.finish_time or req.submit_time) - req.submit_time,
-                gen, good_tokens=good)
+            self.metrics.record_completed(gen, good_tokens=good)
         else:
             self.metrics.count("requests_" + outcome)
 
@@ -3106,6 +3124,8 @@ class ServingEngine:
         self.scheduler.clear_parked()
         self._prefilling = []
         self._early_program = None
+        self._behind_window = None
+        self._unfetched = [0, 0]
         self._sub0 = None
         self._index = PrefixIndex(self.pool.block_size if self._blocks_on
                                   else max(self.serving.prefill_bucket, 1))
@@ -3220,6 +3240,7 @@ class ServingEngine:
         else:
             req.parked = None  # replay fallback
         req.preemptions += 1
+        req.record.preempted += 1
         self.metrics.count("preemptions")
         self._slot_req[slot] = None
         self._active[slot] = False
@@ -3530,11 +3551,7 @@ class ServingEngine:
             # a parked sub was sliced on the decode group and resumes
             # there with one insert — no cross-group handoff
             st.on_decode = True
-            first = req.admit_time is None
-            req.mark_admitted()  # no-op on a concurrently-failed req
-            if first and req.admit_time is not None:
-                self.metrics.record_admitted(req.admit_time
-                                             - req.submit_time)
+            self._mark_admitted(req)
             self._activate_pending(st, plen)
         except Exception:
             if blocks is not None and not (st is not None
@@ -3572,6 +3589,7 @@ class ServingEngine:
             # below forfeits the hit, so hit_tokens - tokens_saved
             # measures slot-pressure forfeits
             self.metrics.count("prefix_hit_tokens", prefix_len)
+            req.record.prefix_hit_tokens += prefix_len
         blocks = None
         pfx_blocks = 0
         device_hit = prefix_len and host_sub is None
@@ -3713,11 +3731,7 @@ class ServingEngine:
             st = _PendingPrefill(req, slot, sub, prefix_len, rng0,
                                  tokens=tokens, blocks=blocks,
                                  pfx_blocks=pfx_blocks)
-            first = req.admit_time is None
-            req.mark_admitted()  # no-op on a concurrently-failed req
-            if first and req.admit_time is not None:
-                self.metrics.record_admitted(req.admit_time
-                                             - req.submit_time)
+            self._mark_admitted(req)
             self._prefilling.append(st)
         except Exception:
             if blocks is not None:
@@ -3842,6 +3856,7 @@ class ServingEngine:
             jnp.int32(n - 1), jnp.int32(st.pos + n), lora, aidx1)
         st.pos += n
         st.req.prefill_chunks += 1
+        self._note_prefill([st.req.record], padded)
         self.metrics.count("prefill_chunks")
         # REAL tokens forwarded — the cache-on/off A/B seam: prefix
         # hits forward strictly fewer tokens than the cache-off run
@@ -3945,6 +3960,51 @@ class ServingEngine:
         self.pool.release(st.slot)
         st.req.fail(msg, kind=kind)  # terminal hook counts the bucket
 
+    def _mark_admitted(self, req: GenRequest):
+        """`GenRequest.mark_admitted`, and at a request's FIRST admission
+        (a restart-requeued or preempted request re-enters through the
+        same paths and is not admitted twice) its row's `t_admit`,
+        `early`, `t_device` and what stands ahead of it, the row into
+        the ring and `requests_admitted`."""
+        first = req.admit_time is None
+        req.mark_admitted()  # no-op on a concurrently-failed req
+        if not first or req.admit_time is None:
+            return
+        row = req.record
+        row.t_admit = req.admit_time
+        if self._behind_window is not None:
+            # admitted inside a window (`_admit_early`), which is in
+            # front: `_step`, as its fetch returns
+            row.early = 1
+            self._behind_window.append(row)
+        else:
+            row.t_device = row.t_admit
+        row.ahead_programs += self._unfetched[0]
+        row.ahead_rows += self._unfetched[1]
+        self._awaiting.append(row)
+        self.metrics.record_admitted(row)
+
+    def _awaiting_first(self) -> list:
+        """The rows admitted and still without a first token."""
+        self._awaiting = [r for r in self._awaiting
+                          if r.t_first is None and r.outcome is None]
+        return self._awaiting
+
+    def _note_prefill(self, own: list, rows: int):
+        """A prefill program of `rows` padded rows was dispatched for the
+        requests whose rows are `own`: theirs, and in front of every
+        other request that waits for its first token."""
+        for row in self._awaiting_first():
+            if row not in own:
+                row.ahead_programs += 1
+                row.ahead_rows += rows
+        for row in own:
+            if row.t_first is None:
+                row.programs += 1
+                row.rows += rows
+        self._unfetched[0] += 1
+        self._unfetched[1] += rows
+
     def _prefill_group(self, reqs: List[GenRequest], padded: int):
         """One batched prefill for same-bucket admissions. The batch
         dim rounds up to a power of two; pad rows replicate row 0
@@ -4003,14 +4063,9 @@ class ServingEngine:
             if req.fsm is not None:
                 self._set_slot_mask(slot, req)
             # restart-requeued requests re-enter through this path
-            # too (the rebuilt PrefixIndex is empty): record the
-            # queue wait only for the FIRST admission, like
-            # _start_pending/_resume_parked
-            first = req.admit_time is None
-            req.mark_admitted()  # no-op on a concurrently-failed req
-            if first and req.admit_time is not None:
-                self.metrics.record_admitted(req.admit_time
-                                             - req.submit_time)
+            # too (the rebuilt PrefixIndex is empty)
+            self._mark_admitted(req)
+        self._note_prefill([r.record for r in reqs], B * padded)
         self._sampling_dirty = True
         self._kv_dirty = True
         self._lengths_dirty = True
@@ -4434,20 +4489,39 @@ class ServingEngine:
                 self._d_reject = out[-1]
                 tok_steps.append(out[3])
                 lp_steps.append(out[4])
+        # the rows this window decodes: a prompt admitted while it runs
+        # is active by the commit, and none of them
+        window = np.nonzero(self._active)[0]
+        if self._awaiting:
+            # a window between a chunked prompt's first program and the
+            # window that draws its first token
+            drawing = [self._slot_req[s].record for s in window]
+            for row in self._awaiting_first():
+                if row.programs and row not in drawing:
+                    row.windows_between += 1
         early = {}
         if fresh:
             with span("serve/step.first"):
                 # returns when the prefill and the draw are done: the
                 # window is queued behind them and the device goes on
                 early = self._deliver_first(fresh, *jax.device_get(drawn))
-        # the rows this window decodes: a prompt admitted while it runs
-        # is active by the commit, and none of them
-        window = np.nonzero(self._active)[0]
+            self._unfetched = [0, 0]    # the draw read what they wrote
+        done_programs, done_rows = self._unfetched
+        self._behind_window = []
         with span("serve/step.fetch"):
             fetched, admit_error = self._fetch_admitting(
                 (tok_steps, lp_steps,
                  [x for x in acc_steps if x is not None], self._d_reject),
                 plain=not (structured_on or spec_k))
+        # what was dispatched ahead of the window is finished; a program
+        # admitted while it ran is not, and has the device from now
+        self._unfetched = [self._unfetched[0] - done_programs,
+                           self._unfetched[1] - done_rows]
+        behind, self._behind_window = self._behind_window, None
+        if behind:
+            now = time.monotonic()
+            for row in behind:
+                row.t_device = now
         with span("serve/step.commit") as sp:
             sp.set_metadata(tokens=self._commit(
                 fetched, K, spec_round, grids, window, early))
@@ -4484,8 +4558,11 @@ class ServingEngine:
             # iteration's program: a prompt that landed meanwhile waits
             # for the iteration, as it always did
             fetched = self._fetch(tree)
-            if self.scheduler.depth():
+            waiting = self.scheduler.queued()
+            if waiting:
                 self.metrics.count("early_admit_declined_prefilling")
+                for req in waiting:
+                    req.record.held += 1
             return fetched, None
         if self._fetcher is None:
             self._fetcher = ThreadPoolExecutor(
@@ -4526,7 +4603,7 @@ class ServingEngine:
         first = not req.generated
         req.append_token(tok, lp)
         if first:
-            self.metrics.record_first_token(req.ttft)
+            req.record.t_first = req.first_token_time
             if self._slo_ttft_s is not None \
                     and req.ttft > self._slo_ttft_s:
                 self.metrics.count("slo_ttft_violations")

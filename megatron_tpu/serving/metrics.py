@@ -13,7 +13,8 @@ import threading
 import time
 from typing import Deque, Dict, Optional, Tuple
 
-from megatron_tpu.utils.tracing import startup_scalars
+from megatron_tpu.utils.tracing import (RequestRing, RequestRow,
+                                        startup_scalars)
 
 
 def _percentile(sorted_vals, q: float) -> float:
@@ -211,11 +212,10 @@ class ServingMetrics:
                  throughput_window_s: float = 30.0):
         self._lock = threading.Lock()
         self._counters: Dict[str, int] = collections.defaultdict(int)
-        self._ttft: Deque[float] = collections.deque(maxlen=max_samples)
-        self._queue_wait: Deque[float] = collections.deque(
-            maxlen=max_samples)
-        self._req_latency: Deque[float] = collections.deque(
-            maxlen=max_samples)
+        # one row a request (utils/tracing.py's table): what the
+        # ttft / queue-wait / latency percentiles below are made from,
+        # and what `tracing.request_record()` hands out
+        self.requests = RequestRing(max_samples)
         # (timestamp, tokens emitted that step) for the tokens/s window
         self._token_events: Deque[Tuple[float, int]] = collections.deque(
             maxlen=max_samples)
@@ -324,16 +324,13 @@ class ServingMetrics:
         with self._lock:
             self._counters[name] += n
 
-    def record_admitted(self, queue_wait_s: float):
+    def record_admitted(self, row: RequestRow):
+        """A request's first admission: its row enters the ring."""
         with self._lock:
             self._counters["requests_admitted"] += 1
-            self._queue_wait.append(queue_wait_s)
+        self.requests.keep(row)
 
-    def record_first_token(self, ttft_s: float):
-        with self._lock:
-            self._ttft.append(ttft_s)
-
-    def record_completed(self, latency_s: float, gen_tokens: int,
+    def record_completed(self, gen_tokens: int,
                          good_tokens: Optional[int] = None):
         """`good_tokens` is the SLO-conformant share of `gen_tokens`
         (the goodput ledger); callers without an SLO pass None and
@@ -343,7 +340,6 @@ class ServingMetrics:
             self._counters["tokens_generated"] += gen_tokens
             self._counters["goodput_tokens"] += (
                 gen_tokens if good_tokens is None else good_tokens)
-            self._req_latency.append(latency_s)
 
     def set_kv_gauges(self, blocks_used: int, blocks_retained: int,
                       bytes_wasted: int):
@@ -468,9 +464,6 @@ class ServingMetrics:
     def snapshot(self) -> Dict[str, float]:
         with self._lock:
             counters = dict(self._counters)
-            ttft = sorted(self._ttft)
-            qwait = sorted(self._queue_wait)
-            lat = sorted(self._req_latency)
             occ = (self._busy_slot_steps / self._total_slot_steps
                    if self._total_slot_steps else 0.0)
             # always present (0.0 before traffic) like the base
@@ -478,6 +471,15 @@ class ServingMetrics:
             # Built from _BASE_GAUGES so the gauge schema lives in ONE
             # place — attribute names ARE the scrape keys.
             gauges = {k: float(getattr(self, k)) for k in _BASE_GAUGES}
+        # the rows themselves, not copies: a stamp is written once and
+        # `outcome` last, so a live row reads as far as it has come
+        rows = self.requests.live()
+        ttft = sorted(r.t_first - r.t_submit for r in rows
+                      if r.t_first is not None)
+        qwait = sorted(r.t_admit - r.t_submit for r in rows
+                       if r.t_admit is not None)
+        lat = sorted(r.t_finish - r.t_submit for r in rows
+                     if r.outcome == "completed")
         out = {k: 0.0 for k in _BASE_COUNTERS}
         out.update({k: float(v) for k, v in counters.items()})
         out.update(gauges)

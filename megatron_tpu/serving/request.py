@@ -17,6 +17,8 @@ import threading
 import time
 from typing import List, Optional
 
+from megatron_tpu.utils.tracing import RequestRow
+
 
 class RequestState(enum.Enum):
     QUEUED = "queued"        # accepted, waiting for a free slot
@@ -121,6 +123,10 @@ class GenRequest:
         self.admit_time: Optional[float] = None
         self.first_token_time: Optional[float] = None
         self.finish_time: Optional[float] = None
+        # where its first token's time went (utils/tracing.py's table):
+        # the engine that takes the request fills it, keeps it in its
+        # ring from admission on and closes it in `_count_terminal`
+        self.record = RequestRow(self.id, self.submit_time)
         self._done = threading.Event()
         # terminal transitions are check-then-act (finish/fail race
         # between the engine loop, the watchdog thread, and HTTP

@@ -382,6 +382,11 @@ class AdmissionScheduler:
         with self._lock:
             return len(self._q)
 
+    def queued(self) -> List[GenRequest]:
+        """The queue as it stands, in no order."""
+        with self._lock:
+            return list(self._q)
+
     def live_depth(self) -> int:
         """Queued requests that are NOT already terminal (a cancelled
         request stays in the queue until the next pop drops it, but it

@@ -8,19 +8,23 @@ C++ "is anyone tracing" check. The loops have no recorder, flag or option: a
 span there runs thousands of times a second and a list of them would grow for
 the life of the process.
 
-Start-up is the one exception, because no profiler session is ever on round it
-and it happens once. `phase(name)` is a span `mtpu/setup/<name>` AND a row
-`(name, start, end)` on `time.monotonic()` in the process's start-up record,
-which `startup_record()` returns. It is for code that runs once a process: no
-loop body calls it. `ready()` stamps the moment the process can do its work (a
-server as it starts to listen, a training job as its first step's flush
-returns), prints one line, and closes the record: from then on, and past
-`MAX_PHASES` rows in any case, `phase` is the span alone (a hot swap's `load`
-or a re-plan's `engine.programs` shows in a profile and adds no row;
-`startup_record()["dropped"]` counts them). What compiling cost is not here but
-in `utils/compile_cache.py`'s ledger, which JAX's own events fill: an engine's
-programs compile at their first dispatch, inside the loop, and no phase goes
-there.
+Two things have a recorder, both on `time.monotonic()`, both always on and
+both bounded: the process's start (below), which happens once, and a request
+(further down), of which a server sees a few a second where its loop turns
+thousands of times.
+
+Start-up, because no profiler session is ever on round it. `phase(name)` is a
+span `mtpu/setup/<name>` AND a row `(name, start, end)` on `time.monotonic()`
+in the process's start-up record, which `startup_record()` returns. It is for
+code that runs once a process: no loop body calls it. `ready()` stamps the
+moment the process can do its work (a server as it starts to listen, a
+training job as its first step's flush returns), prints one line, and closes
+the record: from then on, and past `MAX_PHASES` rows in any case, `phase` is
+the span alone (a hot swap's `load` or a re-plan's `engine.programs` shows in
+a profile and adds no row; `startup_record()["dropped"]` counts them). What
+compiling cost is not here but in `utils/compile_cache.py`'s ledger, which
+JAX's own events fill: an engine's programs compile at their first dispatch,
+inside the loop, and no phase goes there.
 
 | phase | round what |
 |---|---|
@@ -45,6 +49,42 @@ the rest is added in a pass of its own once a micro-batch. 1.0 where every
 leaf's forward goes through `language_model.loss_fn` or `transformer.py`'s
 scans, 0.0 for a step of one micro-batch (which keeps no accumulator) and in
 a process that traced no step.
+
+A request's own record: one `RequestRow` a request, where its first token's
+time went. The serving engine makes the row with the request, puts it into its
+ring where the request is admitted (at its end, for one that never is), fills
+it in place from stamps it takes where the work happens, and closes it at the
+one place every request ends (`ServingEngine._count_terminal`). The ring is
+`ServingMetrics.requests`, `MAX_REQUEST_ROWS` long, and `/metrics`' `ttft_*`,
+`queue_wait_*` and `latency_*` percentiles are made from its rows: a live
+engine's see a first token when it is drawn. `request_record()` hands out
+copies of the rows of the process's last `MAX_ENGINES` engines, a closed
+engine's too; each engine takes a small integer as it registers its ring
+(`keep_requests`) and its rows carry it. No flag and no option. A request costs
+a dozen clock readings and one row, a decode window one clock reading where a
+prompt was admitted inside it, a token nothing. All stamps are
+`time.monotonic()`'s; one that could not be taken is `None` and the row is
+still written.
+
+| field | taken where (`serving/engine.py`) | what |
+|---|---|---|
+| `engine`, `seq` | `RequestRing.keep` | the engine's integer; the row's ordinal in its ring |
+| `rid`, `outcome`, `prompt_tokens`, `generated` | `_count_terminal` (`rid` with the row) | `GenRequest.id`; "completed", "failed", "cancelled" or "expired", `None` while it lives; lengths |
+| `t_submit`, `t_first`, `t_finish` | `GenRequest`'s own stamps, copied with the row, in `_append_token` and in `_count_terminal` | |
+| `t_admit` | `_mark_admitted`, `GenRequest.mark_admitted`'s stamp: behind the dispatch of a group's program, ahead of a chunked prompt's first chunk | |
+| `early` | `_mark_admitted` | 1 if placed while a decode window ran (`_fetch_admitting`, `_admit_early`) |
+| `held` | `_fetch_admitting`, where it counts `early_admit_declined_prefilling` | windows that ended with THIS request queued because a chunk, a prefix hit or a resume was owed the next program (the counter counts windows; the row says whose prompt paid) |
+| `t_device` | `_step`, as the fetch of the window that ran at `t_admit` returns (`step.fetch`'s end); `t_admit` itself where no window was in flight | the first moment the host knows the device had nothing older than this request's first program in front of it, other prompts' prefill programs apart (`ahead_*`) |
+| `programs`, `rows` | `_note_prefill`, from `_prefill_group` and `_prefill_one_chunk` | this request's own prefill programs up to its first token (1, or its chunks) and their padded rows (batch bucket x padded length) |
+| `ahead_programs`, `ahead_rows` | `_mark_admitted` and `_note_prefill` | prefill programs of OTHER requests, and their padded rows, that stood between `t_device` and this request's first token: those dispatched and not yet known finished as it was admitted, and those dispatched between its admission and its first token (another prompt's group or chunk, ahead of its own first chunk or between two of them) |
+| `windows_between` | `_step` | decode windows dispatched between its first program and the one that draws its first token (0 for an unchunked prompt) |
+| `prefix_hit_tokens`, `preempted` | `_start_pending`, `_preempt` | as the engine's counters `prefix_hit_tokens` and `preemptions` count them, for this request |
+
+From a row (`RequestRow.segments()`): queue = `t_admit - t_submit`; behind the
+window = `t_device - t_admit`; prefill = `t_first - t_device` (its own
+programs, whatever ran between them, the draw, the fetch and the hand-over);
+decode = `t_finish - t_first`. The first three tile `[t_submit, t_first]`.
+Which benchmark metric reads which field: PERF.md section 3.
 
 A span is a `with` block on the thread that does the work; nesting gives the
 parent. Names are constant strings, stats are integers: keyword arguments for
@@ -155,16 +195,20 @@ does not hold the `op_name` (PERF.md section 7, PR 27): the scopes are in the
 trace file's HLO metadata, for a viewer, and the expert kernels are found by
 their own name, `%_moe_grouped_matmul.N`.
 
-Which benchmark metric reads which span: PERF.md section 3. How an operator
-reads an idle gap off a trace: docs/serving.md "Observability & drills".
+Which benchmark metric reads which span, and which a row's field: PERF.md
+section 3. How an operator reads an idle gap off a trace, and a slow first
+token off `PUT /admin {"op": "requests"}`: docs/serving.md "Observability &
+drills".
 """
 from __future__ import annotations
 
+import collections
 import functools
+import itertools
 import os
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Deque, Dict, List, Optional
 
 import jax
 
@@ -327,6 +371,110 @@ def startup_scalars() -> Dict[str, float]:
             else 0.0,
         "grad_accum_fused_share": fused,
     }
+
+
+MAX_REQUEST_ROWS = 4096
+MAX_ENGINES = 8
+
+
+class RequestRow:
+    """One request's record (the module docstring's table)."""
+
+    __slots__ = ("engine", "seq", "rid", "outcome", "prompt_tokens",
+                 "generated", "t_submit", "t_admit", "t_device", "t_first",
+                 "t_finish", "early", "held", "programs", "rows",
+                 "ahead_programs", "ahead_rows", "windows_between",
+                 "prefix_hit_tokens", "preempted")
+
+    def __init__(self, rid: int, t_submit: float):
+        self.engine = 0
+        self.seq: Optional[int] = None
+        self.rid = rid
+        self.outcome: Optional[str] = None
+        self.prompt_tokens = self.generated = 0
+        self.t_submit = t_submit
+        self.t_admit: Optional[float] = None
+        self.t_device: Optional[float] = None
+        self.t_first: Optional[float] = None
+        self.t_finish: Optional[float] = None
+        self.early = self.held = 0
+        self.programs = self.rows = 0
+        self.ahead_programs = self.ahead_rows = 0
+        self.windows_between = 0
+        self.prefix_hit_tokens = self.preempted = 0
+
+    def copy(self) -> "RequestRow":
+        out = RequestRow.__new__(RequestRow)
+        for k in self.__slots__:
+            setattr(out, k, getattr(self, k))
+        return out
+
+    def segments(self) -> Dict[str, Optional[float]]:
+        """Seconds in the queue, behind the running window, in the
+        prefill (its own programs and whatever stood between them and the
+        first token) and in decode; `None` where a stamp is missing."""
+        def between(a, b):
+            return None if a is None or b is None else b - a
+        return {"queue_s": between(self.t_submit, self.t_admit),
+                "behind_window_s": between(self.t_admit, self.t_device),
+                "prefill_s": between(self.t_device, self.t_first),
+                "decode_s": between(self.t_first, self.t_finish)}
+
+    def as_dict(self) -> Dict[str, object]:
+        return {**{k: getattr(self, k) for k in self.__slots__},
+                **self.segments()}
+
+
+class RequestRing:
+    """The newest `maxlen` rows of one engine, in the order they entered."""
+
+    def __init__(self, maxlen: int = MAX_REQUEST_ROWS):
+        self.lock = threading.Lock()
+        self.rows: Deque[RequestRow] = collections.deque(maxlen=maxlen)
+        self.engine = 0
+        self.written = 0
+
+    def keep(self, row: RequestRow) -> None:
+        """Put the row in, once: a second call changes nothing."""
+        with self.lock:
+            if row.seq is None:
+                row.engine, row.seq = self.engine, self.written
+                self.written += 1
+                self.rows.append(row)
+
+    def live(self) -> List[RequestRow]:
+        """The rows themselves, for a reader in this package that only
+        reads."""
+        with self.lock:
+            return list(self.rows)
+
+    def copies(self) -> List[RequestRow]:
+        return [r.copy() for r in self.live()]
+
+
+_rings_lock = threading.Lock()
+_rings: Deque[RequestRing] = collections.deque(maxlen=MAX_ENGINES)
+_engines = itertools.count(1)
+
+
+def keep_requests(ring: RequestRing) -> int:
+    """An engine registers its ring as it is built and takes its integer:
+    `request_record()` answers from the ring after the engine is closed,
+    until `MAX_ENGINES` later engines have pushed it out."""
+    with _rings_lock:
+        if not ring.engine:              # a ring registers once
+            ring.engine = next(_engines)
+            _rings.append(ring)
+    return ring.engine
+
+
+def request_record() -> List[RequestRow]:
+    """Copies of the rows of the process's engines, oldest engine first and
+    within an engine in the order the rows entered its ring. A row of a
+    live request is as far as its request has come."""
+    with _rings_lock:
+        rings = list(_rings)
+    return [row for ring in rings for row in ring.copies()]
 
 
 def step_span(name: str, step: int):

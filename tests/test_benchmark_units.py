@@ -1,7 +1,8 @@
 """The yardstick's own unit tests, collected by tier-1: the statistics,
-the FLOP count, the load generator, the trace reductions and the float32
-references of `benchmark/`. The bodies live in `benchmark/tests/`; the cell
-rehearsals there spawn child runs and stay by hand."""
+the FLOP count, the load generator, the trace reductions, the readers of the
+program's record of its requests and the float32 references of `benchmark/`.
+The bodies live in `benchmark/tests/`; the cell rehearsals there spawn child
+runs and stay by hand."""
 import pytest
 
 pytest.register_assert_rewrite(
@@ -10,7 +11,8 @@ pytest.register_assert_rewrite(
     "benchmark.tests.test_moe_share_roofline",
     "benchmark.tests.test_conv_kinds", "benchmark.tests.test_hc_kinds",
     "benchmark.tests.test_ssm_kinds", "benchmark.tests.test_ssm_roofline",
-    "benchmark.tests.test_ssd_kinds", "benchmark.tests.test_ssd_roofline")
+    "benchmark.tests.test_ssd_kinds", "benchmark.tests.test_ssd_roofline",
+    "benchmark.tests.test_request_timeline")
 
 from benchmark.tests.test_conv_kinds import *  # noqa: E402,F401,F403
 from benchmark.tests.test_hc_kinds import *  # noqa: E402,F401,F403
@@ -18,6 +20,7 @@ from benchmark.tests.test_moe_roofline import *  # noqa: E402,F401,F403
 from benchmark.tests.test_moe_share_roofline import *  # noqa: E402,F401,F403
 from benchmark.tests.test_program_spans import *  # noqa: E402,F401,F403
 from benchmark.tests.test_reference import *  # noqa: E402,F401,F403
+from benchmark.tests.test_request_timeline import *  # noqa: E402,F401,F403
 from benchmark.tests.test_ssd_kinds import *  # noqa: E402,F401,F403
 from benchmark.tests.test_ssd_roofline import *  # noqa: E402,F401,F403
 from benchmark.tests.test_ssm_kinds import *  # noqa: E402,F401,F403

@@ -384,9 +384,9 @@ class TestMetricsSchema:
 
     def test_goodput_accounting(self):
         m = ServingMetrics()
-        m.record_completed(0.5, 10)                  # no SLO verdict
-        m.record_completed(0.5, 10, good_tokens=0)   # TTFT-late
-        m.record_completed(0.5, 10, good_tokens=10)
+        m.record_completed(10)                  # no SLO verdict
+        m.record_completed(10, good_tokens=0)   # TTFT-late
+        m.record_completed(10, good_tokens=10)
         assert m.snapshot()["goodput_tokens"] == 20.0
 
     def test_every_base_gauge_has_an_aggregation_rule(self):

@@ -374,11 +374,13 @@ class TestEngineKvVariants:
 
 class TestServingMetrics:
     def test_snapshot_and_percentiles(self):
+        from megatron_tpu.utils.tracing import RequestRow
         m = ServingMetrics()
-        for t in (0.1, 0.2, 0.3, 0.4):
-            m.record_first_token(t)
-        m.record_admitted(0.05)
-        m.record_completed(0.5, 8)
+        for rid, t in enumerate((0.1, 0.2, 0.3, 0.4)):
+            row = RequestRow(rid, 0.0)
+            row.t_admit, row.t_first = 0.05, t
+            m.record_admitted(row)
+        m.record_completed(8)
         m.record_step(2, 4, 2, 1)
         snap = m.snapshot()
         assert snap["requests_completed"] == 1
@@ -2043,10 +2045,10 @@ class TestOverloadServerEndpoints:
         try:
             r = eng.submit([1, 2], 2)
             r.mark_admitted()  # a pre-restart admission already happened
-            before = len(eng.metrics._queue_wait)
+            before = eng.metrics.requests.written
             eng._admit()       # groupable path (no chunk, no hit)
             assert eng._slot_req[0] is r  # it WAS re-admitted
-            assert len(eng.metrics._queue_wait) == before  # no resample
+            assert eng.metrics.requests.written == before  # no resample
         finally:
             eng.close()
 
