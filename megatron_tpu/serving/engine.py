@@ -62,6 +62,27 @@ TPU-native:
   one chunk, as before, the early program standing in the chunk's
   place. No option: what the engine sees decides. Counters:
   `admits_early`, `admits_total`, `early_admit_declined_prefilling`.
+- Compile-ahead. Nothing compiles in the loop that the engine could have
+  known about earlier: an engine on one device owns a small pool of
+  compile threads (`COMPILE_THREADS`) and hands a program over the moment
+  it knows the program will be called: the decode step and the first
+  tokens' draw at construction; a chunked prompt's key, chunk programs
+  and landing at `submit()`, once it is queued (`_hand_programs`); a
+  group's one prefill where its batch is known, as the loop starts for
+  what was queued before it (`_hand_groups`) and for every group of a
+  pop before the first is dispatched (`_hand_group`). The functions that
+  pick a program in the loop pick it there, and each program's argument
+  list is made in one place for both (`_decode_args`, `_prefill_args`,
+  `_chunk_args`, `_insert_args`): the pool lowers the jitted program for
+  those arguments as shapes and compiles it, which fills JAX's own
+  caches, so the loop's call compiles nothing. The loop, reaching a
+  program for the first time (`_await_program`), finds it compiled,
+  waits for that one future, or compiles it itself as it always did
+  (`_verify`, a prefix hit's programs, everything on a serving mesh or
+  over a pipeline's stages). A compile that raises in the pool raises in
+  the loop, where its own would have. No option: what the engine sees
+  decides. Counters: `programs_compiled_ahead`, `programs_awaited`,
+  `programs_awaited_s`, `programs_compiled_inline`.
 - Prefix-cache KV reuse (`enable_prefix_cache`, SGLang's
   RadixAttention made slot-grid native): finished slots RETAIN their
   KV on an LRU list (serving/kv_pool.py) and a host-side radix index
@@ -145,10 +166,13 @@ bit-identical to `sample`.
 from __future__ import annotations
 
 import math
+import os
 import threading
 import time
 import traceback
-from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import (CancelledError, Future,
+                                ThreadPoolExecutor)
+from concurrent.futures import TimeoutError as FutureTimeout
 from typing import List, Optional, Sequence
 
 import jax
@@ -184,7 +208,8 @@ from megatron_tpu.serving.structured import (GrammarCompileError,
                                              compile_response_format)
 from megatron_tpu.utils import compile_cache
 from megatron_tpu.utils.logging import print_rank_0
-from megatron_tpu.utils.tracing import keep_requests, phase, span
+from megatron_tpu.utils.tracing import (keep_requests, note_program, phase,
+                                        span)
 
 from megatron_tpu.config import SERVING_KV_DTYPES as _KV_DTYPES
 
@@ -223,8 +248,35 @@ def _draw_ahead(last_logits, rngs, temps, top_ks, top_ps, rejects, masks,
     return toks, jnp.take_along_axis(lp, toks[:, None], axis=-1)[:, 0]
 
 
-# one shape an engine (the whole grid), so its first prefill compiles it
+# one shape an engine (the whole grid): compiled with the decode step
 _draw_ahead_jit = jax.jit(_draw_ahead, static_argnames=("vocab_size",))
+
+# threads of an engine's compile pool (the module docstring's
+# "Compile-ahead"), never more than the host's cores. 0: no pool, every
+# program compiles where the loop first calls it. Tracing and lowering
+# hold the interpreter's lock and so run one at a time whatever this
+# says; what overlaps is the backend (the compile, or the cache's read
+# and the executable's load) with the next program's tracing. From one
+# sweep of 2, 3 and 4 on the chip (CHANGES.md, PR 56)
+COMPILE_THREADS = 3
+
+_COMPILED = Future()        # a program the loop's own call compiled
+_COMPILED.set_result(None)
+
+
+def _i32(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+
+def _shape_of(x):
+    """What `lower` reads of an argument the loop will pass: its shape
+    and type and, where it is committed, its placement. The buffer is
+    not touched: the loop may have donated it by now."""
+    if not isinstance(x, jax.Array):
+        return x        # a ShapeDtypeStruct, a numpy scalar
+    return jax.ShapeDtypeStruct(
+        x.shape, x.dtype, weak_type=x.weak_type,
+        sharding=x.sharding if x.committed else None)
 
 
 class EngineHungError(RuntimeError):
@@ -630,6 +682,26 @@ class ServingEngine:
         self._prefill_max_batch = max(
             min(self.serving.prefill_max_batch, self.num_slots), 1)
 
+        # compile-ahead: the pool, the programs handed to it (any thread,
+        # under the lock) and those the loop has reached (its own set).
+        # No pool on a mesh: there the grid's arrays take the placement
+        # of the first program that writes them (a committed array's
+        # type names its mesh), so a program lowered for them as they
+        # stand now is not the one the loop will call
+        threads = min(COMPILE_THREADS, os.cpu_count() or 1)
+        if self.topo is not None or generator.mesh is not None:
+            threads = 0
+        self._compiler = None
+        if threads > 0:
+            self._compiler = ThreadPoolExecutor(
+                threads, thread_name_prefix="serving-compile")
+            # every thread starts here (each waits for the last): handing
+            # a program over from `submit()` never waits for one to start
+            gate = threading.Barrier(threads)
+            for _ in range(threads):
+                self._compiler.submit(gate.wait)
+        self._programs_lock = threading.Lock()
+        self._sub0_lock = threading.Lock()
         self._compile_programs(_jit_dec, _jit_pre)
         # per-phase topology gauges + the placement plan, visible from
         # the first scrape (0s on topology-free engines — the schema
@@ -889,6 +961,8 @@ class ServingEngine:
         except Exception:
             self.metrics.count("requests_rejected", best_of)
             raise
+        if max_new_tokens:      # queued: its programs go to the pool
+            self._hand_programs(len(children[0].prompt))
         if best_of == 1:
             return children[0]
         return FanoutRequest(children, n)
@@ -959,6 +1033,16 @@ class ServingEngine:
             self._watchdog.stop()
         if self._fetcher is not None:
             self._fetcher.shutdown(wait=False)
+        if self._compiler is not None:
+            self._compiler.shutdown(wait=False, cancel_futures=True)
+            snap = self.metrics.snapshot()
+            print_rank_0(
+                "serving engine closed: of the programs its loop reached, "
+                f"{snap['programs_compiled_ahead']:.0f} compiled ahead, "
+                f"{snap['programs_awaited']:.0f} awaited "
+                f"({snap['programs_awaited_s']:.1f} s), "
+                f"{snap['programs_compiled_inline']:.0f} compiled by the "
+                "loop itself")
         for req in self.scheduler.close():
             req.fail("engine shut down")
         for req in self._slot_req:
@@ -1671,7 +1755,8 @@ class ServingEngine:
             # pipeline-sharded decode: per-stage program chains behind
             # wrappers with the EXACT mono signatures — every dispatch
             # site below stays untouched
-            return self._compile_pp_programs()
+            self._compile_pp_programs()
+            return self._new_program_set()
         S, Vp = self.num_slots, self.cfg.padded_vocab_size
         self._decode_traces = 0  # trace count — MUST stay 1 in steady state
         self._prefill_traces = 0  # one per (batch, prompt-length) bucket
@@ -1742,6 +1827,248 @@ class ServingEngine:
                                         donate_argnums=(1, 2, 3))
         self._pad_sub_pre = _jit_pre(self._pad_sub_pre_fn,
                                      n_array_args=2)
+        self._new_program_set()
+
+    # ------------------------------------------------------------------
+    # compile-ahead (the module docstring's paragraph)
+    # ------------------------------------------------------------------
+    def _new_program_set(self):
+        """The jits are new (construction, a re-plan's re-mesh): none is
+        compiled, handed over or reached. What every window runs
+        whatever arrives goes to the pool now: the decode step and,
+        where no window opens with a verify round, the first tokens'
+        draw. `_verify` waits for its first round in the loop, as it
+        did: a drafter may never propose."""
+        with self._programs_lock:
+            self._programs = {}
+        self._reached = set()
+        self._compile_ahead(("decode",), self._decode, self._decode_args)
+        if not self._spec_k:
+            self._compile_ahead(("draw",), _draw_ahead_jit,
+                                self._draw_args,
+                                vocab_size=self.cfg.vocab_size)
+
+    def _compile_ahead(self, key, program, make_args, **static):
+        """Any thread: hand `program` to the pool unless `key` is
+        compiled or under way. The pool lowers it for what `make_args()`
+        gives there (the loop's own argument list, every array of it as
+        `_shape_of` sees it) and compiles it, which fills JAX's own
+        caches (trace, lowering, executable, the persistent cache): the
+        loop's later call compiles nothing. What has no `lower` (the
+        per-stage chains of `serving_pp`: no one program) stays the
+        loop's."""
+        if self._compiler is None or not hasattr(program, "lower") \
+                or key in self._programs:
+            return
+
+        def compile_():
+            args = jax.tree.map(_shape_of, make_args())
+            program.lower(*args, **static).compile()
+
+        with self._programs_lock:
+            if key in self._programs:
+                return
+            try:
+                self._programs[key] = self._compiler.submit(compile_)
+            except RuntimeError:        # the engine is closed
+                pass
+
+    def _await_program(self, key, reqs=()):
+        """Engine thread, ahead of a program's dispatch: a set lookup
+        every time but the program's first, where it counts how the loop
+        found it. Compiled by the pool: `programs_compiled_ahead`. Under
+        way there: the loop waits for that one future
+        (`programs_awaited`, `programs_awaited_s`), no longer than the
+        latest deadline of `reqs`, the requests the dispatch is for, and
+        not past a stop or the watchdog's flag; where it gives up, as
+        where nobody handed the program over, its own call compiles
+        (`programs_compiled_inline`), as every call did before there was
+        a pool. A compile that raised in the pool raises here, where the
+        loop's own would have, and the key is forgotten: the next
+        request of that shape hands it over again."""
+        if key in self._reached:
+            return
+        self._reached.add(key)
+        with self._programs_lock:
+            fut = self._programs.setdefault(key, _COMPILED)
+        if fut is _COMPILED:
+            return self._count_program("programs_compiled_inline")
+        waited = 0.0
+        if not fut.done():
+            ends = [r.absolute_deadline(self._deadline_s) for r in reqs]
+            end = max(ends) if ends and None not in ends else None
+            t0 = time.monotonic()
+            while not (fut.done() or self._stop or self._wedged
+                       or (end is not None and time.monotonic() > end)):
+                try:
+                    fut.exception(timeout=self._idle_wait)
+                except FutureTimeout:
+                    pass
+                except CancelledError:      # by close()
+                    break
+            waited = time.monotonic() - t0
+        if not fut.done() or fut.cancelled():
+            return self._count_program("programs_compiled_inline", waited)
+        if fut.exception() is not None:
+            self._reached.discard(key)
+            with self._programs_lock:
+                if self._programs.get(key) is fut:
+                    del self._programs[key]
+            raise fut.exception()
+        self._count_program("programs_awaited" if waited
+                            else "programs_compiled_ahead", waited)
+
+    def _count_program(self, counter: str, waited: float = 0.0):
+        self.metrics.count(counter)
+        if waited:
+            self.metrics.count("programs_awaited_s", waited)
+        note_program(counter, waited)
+
+    # The argument lists of the programs the pool compiles, each made in
+    # ONE place: the loop passes what these return, the pool lowers for it
+    def _decode_args(self):
+        on = self._adapters_on
+        return (self._p_dec, self.pool.caches, self._last_logits,
+                self._rngs, self._d_lengths, self._d_temps, self._d_top_ks,
+                self._d_top_ps, self._d_reject, self._d_masks,
+                self.adapters.stacked if on else None,
+                self._d_adapter_idx if on else None)
+
+    def _draw_args(self):
+        return (self._last_logits, self._rngs, self._d_temps,
+                self._d_top_ks, self._d_top_ps, self._d_reject,
+                self._d_masks)
+
+    def _prefill_args(self, tokens, plens, slots, rng0s, aidxs):
+        return (self._p_dec, self.pool.caches, self._last_logits,
+                self._rngs, tokens, plens, slots, rng0s,
+                self.adapters.stacked if self._adapters_on else None,
+                aidxs)
+
+    def _chunk_args(self, sub, tokens, last_idx, next_offset, aidx1):
+        # the PREFILL-group bank copy (== stacked on single-group
+        # topologies; serving/adapters.py stacked_prefill)
+        return (self._p_pre, sub, tokens, last_idx, next_offset,
+                self.adapters.stacked_prefill if self._adapters_on
+                else None, aidx1)
+
+    def _insert_args(self, sub, slot, plen, pfx_blocks, last, rng0):
+        """Of the program that lands a finished prefill in its slot:
+        `_insert_blk` over a block-granular pool, else `_insert`."""
+        tail = (pfx_blocks, last, rng0) if self._blocks_on else (last, rng0)
+        return (self._p_dec, self.pool.caches, self._last_logits,
+                self._rngs, sub, slot, plen) + tail
+
+    def _hand_programs(self, plen: int):
+        """`submit`'s thread, with the request queued: the programs its
+        prompt of `plen` tokens is certain to call go to the pool, picked
+        by the functions that pick them in the loop (`_single`,
+        `_chunk_shape`, `_insert_args`). That is the pending path's: the
+        key, each chunk's program, the landing. With the prefix cache
+        on, a hit decides path and shapes at admission, and nothing is
+        handed over here; a disaggregated engine's landing has the shape
+        of the blocks that crossed and stays the loop's; a group's one
+        prefill has the batch the loop pops (`_hand_groups`,
+        `_hand_group`)."""
+        if self._compiler is None or self._prefix_on \
+                or not self._single(plen):
+            return
+        aidx1 = _i32(1) if self._adapters_on else None
+        self._compile_ahead(("key",), _burned_key_jit,
+                            lambda: (np.int64(0), np.int32(0)))
+        pos = 0
+        while pos < plen:
+            n, padded = self._chunk_shape(pos, plen)
+            self._compile_ahead(
+                ("chunk", padded), self._chunk_fwd,
+                lambda padded=padded: self._chunk_args(
+                    self._zero_sub(), _i32(1, padded), _i32(), _i32(),
+                    aidx1))
+            pos += n
+        if not self._disagg:
+            row = self._last_logits     # a chunk's last logits are a row
+            self._compile_ahead(
+                ("insert",),
+                self._insert_blk if self._blocks_on else self._insert,
+                lambda: self._insert_args(
+                    self._zero_sub(), _i32(), _i32(), _i32(),
+                    jax.ShapeDtypeStruct(row.shape[1:], row.dtype),
+                    jax.ShapeDtypeStruct((2,), jnp.uint32)))
+
+    def _hand_group(self, B: int, padded: int):
+        """The two programs of one `_prefill_group` call: `_admit` hands
+        over every group of a pop before it dispatches the first."""
+        aidxs = _i32(B) if self._adapters_on else None
+        self._compile_ahead(
+            ("keys", B), _burned_keys_jit,
+            lambda: (np.zeros(B, np.int64), np.zeros(B, np.int32)))
+        self._compile_ahead(
+            ("prefill", B, padded), self._prefill,
+            lambda: self._prefill_args(
+                _i32(B, padded), _i32(B), _i32(B),
+                jax.ShapeDtypeStruct((B, 2), jnp.uint32), aidxs))
+
+    def _hand_groups(self):
+        """The loop's first act: what was queued before it ran (a
+        warm-up queued whole, a restart's requeued prompts) will be
+        popped together, so its groups are known now: of each padded
+        length, groups of `prefill_max_batch` and what is left over
+        (`AdmissionScheduler.group_by_bucket`). Not with the prefix
+        cache on: a hit is no group's."""
+        if self._compiler is None or self._prefix_on:
+            return
+        count, cap = {}, self._prefill_max_batch
+        for r in self.scheduler.queued():
+            if r.parked is None and r.resume_rng is None \
+                    and not self._single(len(r.prompt)):
+                padded = self._prefill_bucket(len(r.prompt))
+                count[padded] = count.get(padded, 0) + 1
+        for padded, n in count.items():
+            self._hand_group(self._batch_bucket(min(n, cap)), padded)
+            if n > cap and n % cap:
+                self._hand_group(self._batch_bucket(n % cap), padded)
+
+    def _single(self, plen: int, hit: bool = False,
+                resumed: bool = False) -> bool:
+        """Does a prompt go the pending path (batch-1 chunks, then one
+        landing) and not into a group's one prefill? Disaggregated
+        engines route EVERY admission through it: the batch-1 chunk
+        forward is the unit that runs on the prefill group, and
+        activation is the block handoff."""
+        return bool(hit or resumed or self._disagg
+                    or (self._chunk is not None and plen > self._chunk))
+
+    def _zero_sub(self):
+        """The shared ZERO template a miss starts from, in place of a
+        full region copy out of the pool for content the offset-0 mask
+        never reads. Sharing one template across admissions is safe
+        because _chunk_fwd never donates its input — every chunk returns
+        fresh buffers. Made once, by whoever asks first (the loop, or
+        the pool as it lowers a chunk program)."""
+        with self._sub0_lock:
+            if self._sub0 is None:
+                full0 = self.pool.make_prefill_caches(1)
+                if self._pp > 1:
+                    # staged template: stage i's [L/S]-layer zero
+                    # slice committed to stage i's sub-mesh — the
+                    # chunk chain consumes the list stage-for-stage
+                    from megatron_tpu.serving import pp as pps
+                    self._sub0 = [
+                        self.topo.place_kv_tree(
+                            pps.stage_kv(full0, self._pp, i), mesh)
+                        for i, mesh in enumerate(self.topo.stage_meshes)]
+                elif self.topo is not None:
+                    # commit the template to the PREFILL mesh once:
+                    # left uncommitted, every miss admission's
+                    # first chunk would re-transfer a full
+                    # cap-region of zeros to the prefill group —
+                    # the exact cross-group cap-region copy the
+                    # disaggregation design exists to avoid
+                    self._sub0 = self.topo.place_kv_tree(
+                        full0, self.topo.prefill_mesh)
+                else:
+                    self._sub0 = full0
+            return self._sub0
 
     # ------------------------------------------------------------------
     # pipeline-sharded program chains (serving_pp > 1)
@@ -2904,6 +3231,9 @@ class ServingEngine:
         """The engine loop proper. Returns True on clean exit (stop /
         drain complete); raises on a crashed or watchdog-flagged
         iteration — the supervisor decides what survives."""
+        # what was queued before the loop ran (or went back to the queue
+        # at a restart): its groups are known now
+        self._hand_groups()
         while True:
             with self._cond:
                 if self._nothing_to_do():
@@ -3362,14 +3692,8 @@ class ServingEngine:
                     self.scheduler.requeue(r)
                     pending.remove(r)
                     continue
-                # disaggregated engines route EVERY admission
-                # through the pending path: the batch-1 chunk
-                # forward is the unit that runs on the prefill
-                # group, and activation is the block handoff
-                single = bool(
-                    hit or r.resume_rng is not None or self._disagg
-                    or (self._chunk is not None
-                        and len(toks) > self._chunk))
+                single = self._single(len(toks), hit,
+                                      r.resume_rng is not None)
                 if early and (single or (
                         groupable and self._prefill_bucket(len(r.prompt))
                         != self._prefill_bucket(
@@ -3384,10 +3708,12 @@ class ServingEngine:
                     placed += 1
                 else:
                     groupable.append(r)
-            for padded, reqs in AdmissionScheduler.group_by_bucket(
-                    groupable,
-                    lambda rr: self._prefill_bucket(len(rr.prompt)),
-                    self._prefill_max_batch):
+            groups = AdmissionScheduler.group_by_bucket(
+                groupable, lambda rr: self._prefill_bucket(len(rr.prompt)),
+                self._prefill_max_batch)
+            for padded, reqs in groups:     # all, ahead of the first's wait
+                self._hand_group(self._batch_bucket(len(reqs)), padded)
+            for padded, reqs in groups:
                 stats = dict(n=len(reqs), padded=padded, rid=reqs[0].id)
                 # an early program's `serve/prefill` span is the next
                 # iteration's (`_iteration`); its dispatch, here, is
@@ -3696,38 +4022,12 @@ class ServingEngine:
                         jnp.asarray(blocks, jnp.int32),
                         jnp.int32(prefix_len))
             else:
-                # miss: start from the shared ZERO template instead of
-                # paying a full region copy out of the pool for content
-                # the offset-0 mask never reads. Sharing one template
-                # across admissions is safe because _chunk_fwd never
-                # donates its input — every chunk returns fresh buffers
-                if self._sub0 is None:
-                    full0 = self.pool.make_prefill_caches(1)
-                    if self._pp > 1:
-                        # staged template: stage i's [L/S]-layer zero
-                        # slice committed to stage i's sub-mesh — the
-                        # chunk chain consumes the list stage-for-stage
-                        from megatron_tpu.serving import pp as pps
-                        self._sub0 = [
-                            self.topo.place_kv_tree(
-                                pps.stage_kv(full0, self._pp, i), mesh)
-                            for i, mesh in enumerate(
-                                self.topo.stage_meshes)]
-                    elif self.topo is not None:
-                        # commit the template to the PREFILL mesh once:
-                        # left uncommitted, every miss admission's
-                        # first chunk would re-transfer a full
-                        # cap-region of zeros to the prefill group —
-                        # the exact cross-group cap-region copy the
-                        # disaggregation design exists to avoid
-                        self._sub0 = self.topo.place_kv_tree(
-                            full0, self.topo.prefill_mesh)
-                    else:
-                        self._sub0 = full0
-                sub = self._sub0
-            rng0 = (jnp.asarray(req.resume_rng)
-                    if req.resume_rng is not None
-                    else self._initial_rng(req.seed, plen))
+                sub = self._zero_sub()      # miss
+            if req.resume_rng is not None:
+                rng0 = jnp.asarray(req.resume_rng)
+            else:
+                self._await_program(("key",), (req,))
+                rng0 = self._initial_rng(req.seed, plen)
             st = _PendingPrefill(req, slot, sub, prefix_len, rng0,
                                  tokens=tokens, blocks=blocks,
                                  pfx_blocks=pfx_blocks)
@@ -3812,10 +4112,34 @@ class ServingEngine:
         """`_advance_prefill`'s dispatch; returns the real tokens
         forwarded."""
         plen = len(st.tokens)
-        n = plen - st.pos
+        n, padded = self._chunk_shape(st.pos, plen)
+        toks = np.full((1, padded), self.gen.pad_id, np.int32)
+        toks[0, :n] = st.tokens[st.pos:st.pos + n]
+        aidx1 = (jnp.asarray([st.aidx], jnp.int32) if self._adapters_on
+                 else None)
+        self._await_program(("chunk", padded), (st.req,))
+        st.sub, st.last = self._chunk_fwd(*self._chunk_args(
+            st.sub, jnp.asarray(toks), jnp.int32(n - 1),
+            jnp.int32(st.pos + n), aidx1))
+        st.pos += n
+        st.req.prefill_chunks += 1
+        self._note_prefill([st.req.record], padded)
+        self.metrics.count("prefill_chunks")
+        # REAL tokens forwarded — the cache-on/off A/B seam: prefix
+        # hits forward strictly fewer tokens than the cache-off run
+        self.metrics.count("prefill_forward_tokens", n)
+        if st.pos >= plen:
+            self._prefilling.pop(0)
+            self._activate_pending(st, plen)
+        return n
+
+    def _chunk_shape(self, pos: int, plen: int):
+        """(real tokens, padded length) of the chunk that takes a prompt
+        of `plen` tokens on from `pos`."""
+        n = plen - pos
         if self._chunk is not None:
             n = min(n, self._chunk)
-        if self.pool.rolling and st.pos > 0:
+        if self.pool.rolling and pos > 0:
             # rolling prefix-hit suffix: an offset>0 MULTI-token ring
             # write evicts history its own early queries still need
             # within one dispatch (the reason prefill_chunk stays
@@ -3841,30 +4165,9 @@ class ServingEngine:
             if self._chunk is not None:
                 padded = min(padded, max(self._chunk, n))
         if not self.pool.rolling:
-            padded = min(padded, self.max_len - st.pos)
-        assert n <= padded, (n, padded, st.pos)
-        toks = np.full((1, padded), self.gen.pad_id, np.int32)
-        toks[0, :n] = st.tokens[st.pos:st.pos + n]
-        # the PREFILL-group bank copy (== stacked on single-group
-        # topologies; serving/adapters.py stacked_prefill)
-        lora = (self.adapters.stacked_prefill if self._adapters_on
-                else None)
-        aidx1 = (jnp.asarray([st.aidx], jnp.int32) if self._adapters_on
-                 else None)
-        st.sub, st.last = self._chunk_fwd(
-            self._p_pre, st.sub, jnp.asarray(toks),
-            jnp.int32(n - 1), jnp.int32(st.pos + n), lora, aidx1)
-        st.pos += n
-        st.req.prefill_chunks += 1
-        self._note_prefill([st.req.record], padded)
-        self.metrics.count("prefill_chunks")
-        # REAL tokens forwarded — the cache-on/off A/B seam: prefix
-        # hits forward strictly fewer tokens than the cache-off run
-        self.metrics.count("prefill_forward_tokens", n)
-        if st.pos >= plen:
-            self._prefilling.pop(0)
-            self._activate_pending(st, plen)
-        return n
+            padded = min(padded, self.max_len - pos)
+        assert n <= padded, (n, padded, pos)
+        return n, padded
 
     def _activate_pending(self, st: _PendingPrefill, plen: int):
         slot, req = st.slot, st.req
@@ -3889,6 +4192,7 @@ class ServingEngine:
                                       self.topo.decode_mesh))
             self.pool.install_row(slot, st.blocks)
             st.installed = True
+            self._await_program(("handoff", nb_live), (req,))
             out = self._handoff_insert(self._p_dec, self.pool.caches,
                                        self._last_logits, self._rngs,
                                        moved, jnp.int32(slot),
@@ -3896,25 +4200,21 @@ class ServingEngine:
             hbytes = nb_live * B * self.pool.bytes_per_token()
             self.metrics.count("handoffs")
             self.metrics.set_handoff_gauge(hbytes)
-        elif self._blocks_on:
-            # install the row's block map NOW (not at admission): until
-            # this moment the row's map pointed at trash, so the
-            # K-chained decode dispatches that ran between chunks could
-            # never write into the reserved (and possibly aliased)
-            # blocks
-            self.pool.install_row(slot, st.blocks)
-            st.installed = True
-            out = self._insert_blk(self._p_dec, self.pool.caches,
-                                   self._last_logits, self._rngs,
-                                   st.sub, jnp.int32(slot),
-                                   jnp.int32(plen),
-                                   jnp.int32(st.pfx_blocks), st.last,
-                                   st.rng0)
         else:
-            out = self._insert(self._p_dec, self.pool.caches,
-                               self._last_logits, self._rngs, st.sub,
-                               jnp.int32(slot), jnp.int32(plen),
-                               st.last, st.rng0)
+            if self._blocks_on:
+                # install the row's block map NOW (not at admission):
+                # until this moment the row's map pointed at trash, so
+                # the K-chained decode dispatches that ran between
+                # chunks could never write into the reserved (and
+                # possibly aliased) blocks
+                self.pool.install_row(slot, st.blocks)
+                st.installed = True
+            self._await_program(("insert",), (req,))
+            insert = self._insert_blk if self._blocks_on else self._insert
+            out = insert(*self._insert_args(
+                st.sub, jnp.int32(slot), jnp.int32(plen),
+                jnp.int32(st.pfx_blocks) if self._blocks_on else None,
+                st.last, st.rng0))
         self.pool.caches, self._last_logits, self._rngs = out
         self._lengths[slot] = plen
         self._active[slot] = True
@@ -4011,6 +4311,10 @@ class ServingEngine:
         (identical re-write of the same slot — harmless)."""
         B_real = len(reqs)
         B = self._batch_bucket(B_real)
+        # ahead of the slots: a compile that failed in the pool raises
+        # here with nothing allocated
+        self._await_program(("keys", B), reqs)
+        self._await_program(("prefill", B, padded), reqs)
         if self._blocks_on:
             slots = []
             for _ in reqs:
@@ -4034,18 +4338,16 @@ class ServingEngine:
         seeds = [r.seed for r in reqs]
         rng0s = self._initial_rngs(seeds + [seeds[0]] * (B - B_real),
                                    plens_a)
-        lora = aidxs = None
+        aidxs = None
         if self._adapters_on:
             # per-row bank indices (resolved + pinned in _admit):
             # mixed-adapter groups batch into the same compiled call
-            lora = self.adapters.stacked
             rows = [r.bank_idx for r in reqs]
             aidxs = jnp.asarray(rows + [rows[0]] * (B - B_real),
                                 jnp.int32)
         self.pool.caches, self._last_logits, self._rngs = self._prefill(
-            self._p_dec, self.pool.caches, self._last_logits,
-            self._rngs, jnp.asarray(toks), jnp.asarray(plens_a),
-            jnp.asarray(slots_a), rng0s, lora, aidxs)
+            *self._prefill_args(jnp.asarray(toks), jnp.asarray(plens_a),
+                                jnp.asarray(slots_a), rng0s, aidxs))
         if self._blocks_on and not self._kernel_on:
             # the batched-prefill program bracketed with resolve +
             # scatter (block-native lands through insert_blocks
@@ -4445,10 +4747,9 @@ class ServingEngine:
         with span("serve/step.dispatch"):
             if fresh:
                 # ahead of the decode dispatch, which donates what it reads
-                drawn = _draw_ahead_jit(
-                    self._last_logits, self._rngs, self._d_temps,
-                    self._d_top_ks, self._d_top_ps, self._d_reject,
-                    self._d_masks, vocab_size=self.cfg.vocab_size)
+                self._await_program(("draw",))
+                drawn = _draw_ahead_jit(*self._draw_args(),
+                                        vocab_size=self.cfg.vocab_size)
             # adapter bank args: the stacked factor pytree + per-slot rows
             # (None/None with adapters off — the empty-pytree args lower to
             # exactly the pre-adapter graph)
@@ -4467,6 +4768,7 @@ class ServingEngine:
                             grids[r], guesses[r], spec_k)
                     else:
                         d_dm, d_g0 = self._d_free_dmask, self._d_no_guess
+                    self._await_program(("verify",))
                     out = self._verify(
                         self._p_dec, self.pool.caches,
                         self._last_logits, self._rngs, self._d_lengths,
@@ -4476,11 +4778,8 @@ class ServingEngine:
                     acc_steps.append(out[5])
                     self.metrics.count("spec_rounds")
                 else:
-                    out = self._decode(
-                        self._p_dec, self.pool.caches,
-                        self._last_logits, self._rngs, self._d_lengths,
-                        self._d_temps, self._d_top_ks, self._d_top_ps,
-                        self._d_reject, self._d_masks, lora, d_aidx)
+                    self._await_program(("decode",))
+                    out = self._decode(*self._decode_args())
                     acc_steps.append(None)
                     if spec_k:
                         self.metrics.count("spec_fallback_steps")

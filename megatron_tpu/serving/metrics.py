@@ -74,6 +74,12 @@ _BASE_COUNTERS = (
     # queued which the one-program rule held back (a chunk, a prefix hit
     # or a resume was owed the next iteration's prefill program)
     "admits_total", "admits_early", "early_admit_declined_prefilling",
+    # compile-ahead (engine._await_program), once a program the loop
+    # reached: found compiled by the engine's pool; still under way there
+    # (the loop waited, programs_awaited_s seconds in all); compiled by the
+    # loop's own call (nobody had handed it over, or the pool's size is 0)
+    "programs_compiled_ahead", "programs_awaited", "programs_awaited_s",
+    "programs_compiled_inline",
     "prefill_calls", "prefill_prompts",
     # prefix cache / chunked prefill (docs/serving.md):
     # prefix_hit_tokens counts tokens MATCHED at lookup (including

@@ -23,8 +23,14 @@ the record: from then on, and past `MAX_PHASES` rows in any case, `phase` is
 the span alone (a hot swap's `load` or a re-plan's `engine.programs` shows in
 a profile and adds no row; `startup_record()["dropped"]` counts them). What
 compiling cost is not here but in `utils/compile_cache.py`'s ledger, which
-JAX's own events fill: an engine's programs compile at their first dispatch,
-inside the loop, and no phase goes there.
+JAX's own events fill: an engine's programs compile on its compile pool from
+the moment the engine knows of them (`serving/engine.py`, "Compile-ahead"),
+beside the loop, and no phase goes there. How the loops found their programs
+is in the record all the same, as four counters (`PROGRAM_COUNTERS`:
+`programs_compiled_ahead`, `programs_awaited` with `programs_awaited_s`,
+`programs_compiled_inline`), counted by `ServingEngine._await_program` once a
+program and summed over the process's engines; each engine's own are in its
+`/metrics`.
 
 | phase | round what |
 |---|---|
@@ -40,8 +46,9 @@ inside the loop, and no phase goes there.
 `startup_seconds` (process start to `ready()`, 0 before), `compile_programs`,
 `compile_seconds`, `compile_cache_hits`, `compile_cache_misses` (the ledger's
 totals now) and `compiles_after_ready`, the programs compiled or loaded since
-`ready()`: in a steady state it stays where it is, and on a server that warms
-nothing it counts what the first users of a new build waited for. Beside them
+`ready()`: in a steady state it stays where it is; right after a start it
+counts the programs of the shapes the first users brought (compiled beside
+the loop; what they waited is `programs_awaited_s`). Beside them
 `grad_accum_fused_share`, fixed as `training/train_step.py` traces its step:
 of the bytes of the float32 gradient accumulators, the share whose leaf's
 gradient leaves the backward pass already added to it (`ops/grad_accum.py`);
@@ -251,6 +258,7 @@ class _StartupRecord:
         self.ready: Optional[float] = None
         self.programs_at_ready = 0
         self.grad_accum_fused_share = 0.0
+        self.programs = dict.fromkeys(PROGRAM_COUNTERS, 0)
 
     def open(self, name: str) -> Optional[list]:
         with self.lock:
@@ -262,7 +270,20 @@ class _StartupRecord:
             return row
 
 
+# how a serving engine's loop found each program it reached (the engine
+# counts the same in its own metrics; here over the process's engines)
+PROGRAM_COUNTERS = ("programs_compiled_ahead", "programs_awaited",
+                    "programs_awaited_s", "programs_compiled_inline")
+
 _record = _StartupRecord()
+
+
+def note_program(counter: str, seconds: float = 0.0) -> None:
+    """`ServingEngine._await_program`, once a program: one of
+    `PROGRAM_COUNTERS`, with the seconds the loop waited."""
+    with _record.lock:
+        _record.programs[counter] += 1
+        _record.programs["programs_awaited_s"] += seconds
 
 
 class phase:
@@ -300,11 +321,14 @@ def startup_record() -> Dict[str, object]:
     """`t0` the process's start, `rows` the phases as (name, start, end)
     in the order they began (`end` None while one is open), `ready` the
     stamp or None, `dropped` the phases that added no row; all clock
-    readings are `time.monotonic()`'s."""
+    readings are `time.monotonic()`'s. And the `PROGRAM_COUNTERS`: of the
+    programs the process's serving loops reached, how many they found
+    compiled by the engine's pool, waited for (with the seconds) and
+    compiled themselves."""
     with _record.lock:
         return {"t0": _record.t0, "ready": _record.ready,
                 "rows": [tuple(r) for r in _record.rows],
-                "dropped": _record.dropped}
+                "dropped": _record.dropped, **_record.programs}
 
 
 def ready() -> float:
