@@ -1,0 +1,12 @@
+"""Layer: serving/kv_pool.py. Device time on the first device, per
+`mtpu/serve/step` span of the traced window, of every operation whose text
+holds the KDA layers' depthwise kernels' last inputs as the pool holds them,
+a layer or a slot of them (`benchmark/kda_kinds.py`, "conv"): the read ahead
+of a call's rows and the write behind its last real row. `None` where the
+configuration has no KDA layers, the trace is not a TPU's, or no operation
+holds such an array (a parent commit)."""
+from benchmark.kda_kinds import ms_per_step
+
+
+def read(run):
+    return ms_per_step(run, "conv")

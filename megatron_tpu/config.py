@@ -40,7 +40,7 @@ def as_dtype(name: str):
 
 # the kinds of `ModelConfig.layer_types` that keep a state of fixed size a
 # sequence, and those whose layer is ONE sublayer (`ModelConfig.one_sublayer`)
-STATE_KINDS = ("conv", "mamba", "mamba2")
+STATE_KINDS = ("conv", "mamba", "mamba2", "kda")
 ONE_SUBLAYER_KINDS = ("mamba2", "moe", "mlp")
 
 
@@ -167,11 +167,17 @@ class ModelConfig:
     # attention is MLA: the cache then holds one row of kv_lora_rank +
     # qk_rope_head_dim values a token a layer (models/mla.py::LatentKVCache)
     # and `kv_channels` (the published `head_dim`) is the rotary width.
+    # `q_lora_rank` None with `kv_lora_rank` set (a published `q_lora_rank`
+    # null): the query is ONE matrix [h, n (nope + rope)] with no norm.
     q_lora_rank: Optional[int] = None
     kv_lora_rank: Optional[int] = None
     qk_nope_head_dim: Optional[int] = None
     qk_rope_head_dim: Optional[int] = None
     v_head_dim: Optional[int] = None
+    # the published `mla_use_nope`: no rotation anywhere, neither of the
+    # queries' "rope" channels nor of the shared key channels; the row the
+    # cache keeps and the softmax scale are the same
+    mla_nope: bool = False
     # the first k layers keep a dense MLP of this width (the published
     # `intermediate_size`) where the others have experts: a stack of its
     # own, run ahead of the experts' (models/transformer.py)
@@ -272,6 +278,20 @@ class ModelConfig:
     mamba_head_dim: int = 64
     mamba_n_groups: int = 8
     mamba_chunk_size: int = 128
+    # "kda" in `layer_types`: a Kimi Delta Attention mixer (models/kda.py;
+    # the published `linear_attn_config` of a `kimi_linear` config:
+    # `num_heads`, `head_dim`, `short_conv_kernel_size`). `kda_num_heads`
+    # heads of `kda_head_dim` key AND value channels, a gated delta rule
+    # with a decay a CHANNEL over a matrix [head_dim, head_dim] float32 a
+    # head a layer a sequence (`LatentStateCache.ssm`), behind three
+    # depthwise kernels of `kda_conv_kernel` taps over q, k and v. The decay
+    # and the output gate come through two low-rank pairs of width
+    # `kda_gate_rank`. (The rows the chunked scan's kernel takes a step are
+    # the kernel's own constant, ops/kda_chunk.py::CHUNK.)
+    kda_num_heads: int = 32
+    kda_head_dim: int = 128
+    kda_conv_kernel: int = 4
+    kda_gate_rank: int = 128
     # RMSNorm over each head's channels of q and of k, one scale
     # [kv_channels] shared by the heads, before the rotary (LFM2's
     # q_layernorm / k_layernorm). `qk_norm` above is OLMoE's, over all the
@@ -353,7 +373,7 @@ class ModelConfig:
     @property
     def state_kind(self) -> Optional[str]:
         """The kind of layer that keeps a state of fixed size a sequence:
-        "conv", "mamba", "mamba2" or None (a model has one: validate
+        "conv", "mamba", "mamba2", "kda" or None (a model has one: validate
         refuses a cross)."""
         return next((k for k in STATE_KINDS if self.layers_of(k)), None)
 
@@ -372,6 +392,11 @@ class ModelConfig:
         return self.mamba_expand * self.hidden_size
 
     @property
+    def kda_d_inner(self) -> int:
+        """Channels of each of a "kda" layer's q, k and v."""
+        return self.kda_num_heads * self.kda_head_dim
+
+    @property
     def mamba2_conv_channels(self) -> int:
         """What a "mamba2" layer's one depthwise kernel runs over: x and
         every group's B and C."""
@@ -382,11 +407,14 @@ class ModelConfig:
     def conv_state_shape(self) -> Tuple[int, int]:
         """(rows, channels) of the depthwise kernel's state a layer: its
         last taps - 1 inputs, over the hidden size ("conv"), over d_inner
-        ("mamba") or over x, B and C together ("mamba2")."""
+        ("mamba"), over x, B and C together ("mamba2") or over q, k and v
+        together ("kda": three kernels side by side)."""
         if self.state_kind == "mamba":
             return self.mamba_d_conv - 1, self.mamba_d_inner
         if self.state_kind == "mamba2":
             return self.mamba_d_conv - 1, self.mamba2_conv_channels
+        if self.state_kind == "kda":
+            return self.kda_conv_kernel - 1, 3 * self.kda_d_inner
         return self.conv_L_cache - 1, self.hidden_size
 
     @property
@@ -400,12 +428,15 @@ class ModelConfig:
     def ssm_state_shape(self) -> Optional[Tuple[int, ...]]:
         """The scan's float32 state a layer a sequence: [d_state, d_inner]
         ("mamba", the channels minor), [heads, head_dim, d_state]
-        ("mamba2", a matrix a head); None where no layer has one."""
+        ("mamba2", a matrix a head), [heads, head_dim (k), head_dim (v)]
+        ("kda", a matrix a head); None where no layer has one."""
         if self.state_kind == "mamba":
             return self.mamba_d_state, self.mamba_d_inner
         if self.state_kind == "mamba2":
             return (self.mamba_num_heads, self.mamba_head_dim,
                     self.mamba_d_state)
+        if self.state_kind == "kda":
+            return (self.kda_num_heads, self.kda_head_dim, self.kda_head_dim)
         return None
 
     @property
@@ -1550,10 +1581,14 @@ class MegatronConfig:
                 f"made to work with heads sharded (got {sharded})")
         if model.mla:
             # models/mla.py: one latent row a token, shared by every head
-            for name in ("q_lora_rank", "qk_nope_head_dim",
-                         "qk_rope_head_dim", "v_head_dim"):
+            # q_lora_rank may be None: one query matrix, no norm
+            for name in ("qk_nope_head_dim", "qk_rope_head_dim",
+                         "v_head_dim"):
                 assert getattr(model, name), (
                     f"kv_lora_rank is set (MLA): {name} must be too")
+            assert model.q_lora_rank is None or model.q_lora_rank >= 1, (
+                f"q_lora_rank={model.q_lora_rank}: the query's latent "
+                "width, or None for ONE query matrix with no norm")
             assert model.kv_channels == model.qk_rope_head_dim, (
                 f"MLA: kv_channels={model.kv_channels} is the rotary "
                 f"width and must equal qk_rope_head_dim="
@@ -1563,12 +1598,18 @@ class MegatronConfig:
                 f"device only (got {sharded}): the latent row has no "
                 "head axis to shard and the up-projections have not been "
                 "split by head (ROADMAP R5)")
-            assert (model.use_rotary_emb and model.sliding_window is None
+            assert model.use_rotary_emb != model.mla_nope, (
+                "MLA (kv_lora_rank set) is causal rotary attention "
+                "(use_rotary_emb) or, with mla_nope, attention that rotates "
+                "nothing (use_rotary_emb false): "
+                f"use_rotary_emb={model.use_rotary_emb}, "
+                f"mla_nope={model.mla_nope}")
+            assert (model.sliding_window is None
                     and not model.qk_norm and not model.use_bias
                     and model.quantized_gemm == "none"
                     and model.attention_dropout == 0.0
                     and model.attention_impl in ("dot", "flash")), (
-                "MLA (kv_lora_rank set) is causal rotary attention over the "
+                "MLA (kv_lora_rank set) is causal attention over the "
                 "whole context: no sliding_window, qk_norm, use_bias, "
                 "quantized_gemm, attention_dropout or context-parallel "
                 "attention_impl")
@@ -1605,6 +1646,7 @@ class MegatronConfig:
             kinds = set(model.layer_types)
             allowed = ({"mamba2", "full_attention", "moe"}
                        if model.one_sublayer
+                       else {"kda", "full_attention"} if "kda" in kinds
                        else {"conv", "mamba", "full_attention"})
             assert "mlp" not in kinds, (
                 "layer_types 'mlp' (a layer that is a dense feed-forward "
@@ -1618,7 +1660,8 @@ class MegatronConfig:
                 f"layer_types has {len(model.layer_types)} entries "
                 f"{sorted(kinds)} for num_layers={model.num_layers}: one of "
                 "'conv' | 'mamba' | 'full_attention' a layer (a mixer and "
-                "then a feed-forward), or one of 'mamba2' | "
+                "then a feed-forward), 'kda' | 'full_attention' (the same, "
+                "the attention layers MLA), or one of 'mamba2' | "
                 "'full_attention' | 'moe' a layer (ONE sublayer each), "
                 "never both readings in one model")
             assert not {"conv", "mamba"} <= kinds, (
@@ -1665,15 +1708,39 @@ class MegatronConfig:
                     "num_experts > 1, and a model with experts needs it "
                     "(every feed-forward of a one-sublayer pattern is a "
                     "layer of its own)")
-            assert not model.mla and not model.mtp_num_layers \
+            if "kda" in kinds:
+                # models/kda.py beside models/mla.py: the one cross of a
+                # state of fixed size and a latent pool that has been built
+                # (models/attention.py::LatentStateCache)
+                assert model.mla and "full_attention" in kinds, (
+                    "'kda' layers stand beside MLA attention layers "
+                    "(kv_lora_rank set, a 'full_attention' layer in the "
+                    "pattern): the cache's offsets are the attention "
+                    "layers' and its rows latent "
+                    "(models/attention.py::LatentStateCache)")
+                assert model.kda_num_heads >= 1 and model.kda_head_dim >= 1 \
+                    and model.kda_conv_kernel >= 2 \
+                    and model.kda_gate_rank >= 1, (
+                    "'kda' layers need kda_num_heads, kda_head_dim, "
+                    "kda_gate_rank >= 1 and kda_conv_kernel >= 2")
+                assert model.hc_mult == 1, (
+                    "'kda' layers are refused with hc_mult > 1: the "
+                    "streams' maps have not been run round a delta rule's "
+                    "mixer (ROADMAP R6)")
+            assert (not model.mla or "kda" in kinds) \
+                and not model.mtp_num_layers \
                 and model.sliding_window is None \
                 and not model.parallel_attn and not model.use_post_ln \
                 and not model.use_bias, (
-                "layer_types is refused with MLA (kv_lora_rank), "
-                "mtp_num_layers, sliding_window, parallel_attn, use_post_ln "
-                "and use_bias: the pattern's layers are pre-norm, one mixer "
-                "then one feed-forward (or ONE sublayer each: 'mamba2' | "
-                "'moe'), over whole regions of keys and values (ROADMAP R6)")
+                "layer_types is refused with MLA (kv_lora_rank) but for "
+                "the pattern 'kda' | 'full_attention' (the other state "
+                "kinds, 'conv' | 'mamba' | 'mamba2', hold keys and values "
+                "beside their state and no latent row; hc_mult > 1 and any "
+                "mesh stay refused for 'kda' too), mtp_num_layers, "
+                "sliding_window, parallel_attn, use_post_ln and use_bias: "
+                "the pattern's layers are pre-norm, one mixer then one "
+                "feed-forward (or ONE sublayer each: 'mamba2' | 'moe'), "
+                "over whole regions (ROADMAP R6)")
             assert max(sharded.values()) == 1, (
                 "layer_types (convolution or state-space layers and "
                 f"attention in one model) has been made to work on one "
@@ -2366,6 +2433,72 @@ def nemotron_h_config(size: str = "3-super", **overrides) -> ModelConfig:
     return ModelConfig(**base).derived()
 
 
+def kimi_linear_layer_types(num_layers: int,
+                             full_attn_layers=(4, 8, 12, 16, 20, 24, 27)
+                             ) -> Tuple[str, ...]:
+    """The mixers of a `kimi_linear` stack from its published
+    `linear_attn_config`: layer l (1-INDEXED there) is MLA where it is among
+    `full_attn_layers` and a Kimi Delta Attention mixer elsewhere."""
+    return tuple("full_attention" if l + 1 in full_attn_layers else "kda"
+                 for l in range(num_layers))
+
+
+def kimi_linear_config(size: str = "48b-a3b", **overrides) -> ModelConfig:
+    """Kimi Linear presets: every size of "48b-a3b" is a key of
+    moonshotai/Kimi-Linear-48B-A3B-Instruct's config.json (`kimi_linear`,
+    arXiv:2510.26692: 27 layers, hidden 2304; `linear_attn_config`: 20 Kimi
+    Delta Attention layers (32 heads of 128 key and value channels, three
+    depthwise kernels of 4 taps) and 7 MLA layers (1-indexed 4, 8, ..., 24,
+    27: three to one) with `q_lora_rank` null (ONE query matrix),
+    kv_lora_rank 512, qk_nope_head_dim 128, qk_rope_head_dim 64, v_head_dim
+    128 and `mla_use_nope` true (nothing is rotated); layer 1 a dense
+    SiLU-gated MLP of width 9216 (`first_k_dense_replace` 1), the others 256
+    experts of width 1024, 8 a token, beside 1 shared expert; sigmoid
+    scoring with a choosing bias, gates renormalised, scale 2.446, no
+    groups; RMSNorm eps 1e-5; vocabulary 163,840, untied head; 1,048,576
+    positions). Held in bfloat16. Dropless. A cut of the depth gives its own
+    `--layer_types`."""
+    presets = {
+        "tiny": dict(num_layers=8, hidden_size=64, num_attention_heads=4,
+                     kv_lora_rank=32, qk_nope_head_dim=16,
+                     qk_rope_head_dim=8, v_head_dim=16, kv_channels=8,
+                     ffn_hidden_size=32, dense_ffn_hidden_size=96,
+                     vocab_size=512, seq_length=128, num_experts=8,
+                     moe_top_k=2, kda_num_heads=4, kda_head_dim=16,
+                     kda_gate_rank=8, attention_impl="dot"),
+        "48b-a3b": dict(num_layers=27, hidden_size=2304,
+                        num_attention_heads=32, num_kv_heads=32,
+                        kv_lora_rank=512, qk_nope_head_dim=128,
+                        qk_rope_head_dim=64, v_head_dim=128, kv_channels=64,
+                        ffn_hidden_size=1024, dense_ffn_hidden_size=9216,
+                        vocab_size=163840, seq_length=4096,
+                        max_position_embeddings=1048576, num_experts=256,
+                        moe_top_k=8, kda_num_heads=32, kda_head_dim=128,
+                        kda_gate_rank=128, params_dtype="bfloat16"),
+    }
+    if size not in presets:
+        raise ValueError(f"unknown kimi_linear size {size!r}; "
+                         f"valid: {sorted(presets)}")
+    base = dict(
+        use_rotary_emb=False, use_position_embedding=False, mla_nope=True,
+        q_lora_rank=None, norm_type="rmsnorm", norm_epsilon=1e-5,
+        activation="swiglu", use_bias=False, use_post_ln=False,
+        parallel_attn=False, tie_embed_logits=False, kda_conv_kernel=4,
+        first_k_dense_replace=1, n_shared_experts=1,
+        moe_scoring_func="sigmoid", moe_routed_scaling_factor=2.446,
+        moe_score_correction_bias=True, moe_norm_topk_prob=True,
+        moe_dispatch="dropless", moe_aux_loss_coeff=0.0,
+        attention_impl="flash",  # see llama2_config
+    )
+    base.update(presets[size])
+    # the router scores every published expert, held here or not
+    base["moe_router_experts"] = base["num_experts"]
+    base.update(overrides)
+    base.setdefault("layer_types",
+                    kimi_linear_layer_types(base["num_layers"]))
+    return ModelConfig(**base).derived()
+
+
 def gpt_config(**overrides) -> ModelConfig:
     base = dict(
         num_layers=12, hidden_size=768, num_attention_heads=12,
@@ -2401,5 +2534,7 @@ MODEL_PRESETS = {
     "jamba2-3b": lambda: jamba_config("2-3b"),
     "nemotron-3-super-tiny": lambda: nemotron_h_config("tiny"),
     "nemotron-3-super": lambda: nemotron_h_config("3-super"),
+    "kimi-linear-tiny": lambda: kimi_linear_config("tiny"),
+    "kimi-linear": lambda: kimi_linear_config("48b-a3b"),
     "gpt2": gpt_config,
 }

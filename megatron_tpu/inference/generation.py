@@ -33,7 +33,7 @@ from megatron_tpu.config import ModelConfig
 from megatron_tpu.inference.sampling import sample
 from megatron_tpu.models import language_model as lm
 from megatron_tpu.models.attention import (ConvKVCache, HybridKVCache,
-                                            KVCache)
+                                           KVCache, LatentStateCache)
 from megatron_tpu.utils.tracing import phase
 
 
@@ -110,11 +110,12 @@ def init_kv_caches(cfg: ModelConfig, batch: int, max_len: int,
         return HybridKVCache.create(cfg, batch, max_len, dtype,
                                     per_slot_offsets=per_slot_offsets)
     if cfg.state_layers:
-        # keys and values for the attention layers alone, the convolutions'
-        # (and the scans') state beside them
-        # (models/attention.py::ConvKVCache)
-        return ConvKVCache.create(cfg, batch, max_len, dtype,
-                                  per_slot_offsets=per_slot_offsets)
+        # keys and values (or, under MLA, latent rows) for the attention
+        # layers alone, the convolutions' (and the scans') state beside them
+        # (models/attention.py::ConvKVCache, LatentStateCache)
+        kind = LatentStateCache if cfg.mla else ConvKVCache
+        return kind.create(cfg, batch, max_len, dtype,
+                           per_slot_offsets=per_slot_offsets)
     # rolling-cap decision single-sourced in kv_region_cap (the serving
     # pool's slot_nbytes sizes from the same helper)
     max_len = kv_region_cap(cfg, max_len, prefill_len)
@@ -156,7 +157,7 @@ def prefill_chunk(params, tokens, caches, cfg: ModelConfig, *, rope,
         # a ring takes no padding row
         caches = caches._replace(
             live_end=jnp.asarray(next_offset, jnp.int32))
-    if isinstance(caches, ConvKVCache):
+    if isinstance(caches, (ConvKVCache, LatentStateCache)):
         # a state is left as it stood after the chunk's last real row; the
         # chunk starts where every attention layer's offset stands
         caches = caches._replace(live_rows=jnp.asarray(

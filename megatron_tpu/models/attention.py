@@ -277,6 +277,47 @@ class ConvKVCache(NamedTuple):
                  if cfg.ssm_state_shape else None))
 
 
+class LatentStateCache(NamedTuple):
+    """The cache of a model whose layers are Kimi Delta Attention mixers and
+    MLA attention (`cfg.layer_types` "kda" | "full_attention" with
+    `cfg.mla`): `ConvKVCache`'s sibling with LATENT ROWS in the place of keys
+    and values, three parts side by side, each written in place at its own
+    kind's index.
+
+    - THE LATENT ROWS of the attention layers alone, `c` [attention layers,
+      batch, kv_lora_rank + qk_rope, max_seq], the positions minor:
+      `mla.LatentKVCache.c`'s layout and readers (models/mla.py reads and
+      writes `c` and `offset` of either cache).
+    - THE DEPTHWISE KERNELS' STATE, `conv` [kda layers, batch, taps - 1, 3 x
+      heads x head_dim]: the last inputs of the three kernels over q, k and
+      v, the older first, in the cache's dtype.
+    - THE RULE'S STATE, `ssm` [kda layers, batch, heads, head_dim, head_dim]
+      float32 whatever the cache's dtype: a matrix a head (models/kda.py,
+      ops/kda_chunk.py).
+
+    `offset` (one entry an ATTENTION layer) and `live_rows` are
+    `ConvKVCache`'s."""
+    c: jax.Array
+    conv: jax.Array
+    ssm: jax.Array
+    offset: jax.Array
+    live_rows: jax.Array
+
+    @staticmethod
+    def create(cfg: ModelConfig, batch: int, max_seq: int,
+               dtype=jnp.bfloat16, per_slot_offsets: bool = False):
+        n_attn = cfg.layers_of("full_attention")
+        return LatentStateCache(
+            c=jnp.zeros((n_attn, batch, cfg.kv_row_width, max_seq), dtype),
+            conv=jnp.zeros((cfg.state_layers, batch,
+                            *cfg.conv_state_shape), dtype),
+            ssm=jnp.zeros((cfg.state_layers, batch, *cfg.ssm_state_shape),
+                          jnp.float32),
+            offset=jnp.zeros((n_attn, batch) if per_slot_offsets
+                             else (n_attn,), jnp.int32),
+            live_rows=jnp.int32(ConvKVCache.NO_PADDING))
+
+
 def _layer_of(a, layer):
     """Layer `layer` (a traced scalar) of an array stacked over layers."""
     return jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)

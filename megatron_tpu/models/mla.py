@@ -88,9 +88,10 @@ class LatentKVCache(NamedTuple):
 
 
 def mla_init(rng, cfg: ModelConfig, dtype=jnp.float32):
-    """wq_a [h, q_lora], wq_b [q_lora, n (nope + rope)], wkv_a [h, kv_lora +
-    rope], wkv_b [kv_lora, n (nope + v)] (a head's k_nope columns, then its
-    v columns), wo [n v, h], and the two norms' scales."""
+    """wq_a [h, q_lora], wq_b [q_lora, n (nope + rope)] (or, with
+    `q_lora_rank` None, ONE wq [h, n (nope + rope)] and no norm), wkv_a [h,
+    kv_lora + rope], wkv_b [kv_lora, n (nope + v)] (a head's k_nope columns,
+    then its v columns), wo [n v, h], and the norms' scales."""
     h, n = cfg.hidden_size, cfg.num_attention_heads
     rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -101,10 +102,14 @@ def mla_init(rng, cfg: ModelConfig, dtype=jnp.float32):
 
     def draw(k, shape, s=std):
         return jax.random.normal(k, shape, dtype) * s
+    if rq is None:      # ONE query matrix, no norm (a published q_lora_rank null)
+        query = {"wq": draw(keys[0], (h, n * (dn + dr)))}
+    else:
+        query = {"wq_a": draw(keys[0], (h, rq)),
+                 "q_norm": rmsnorm_init(rq, dtype),
+                 "wq_b": draw(keys[1], (rq, n * (dn + dr)))}
     return {
-        "wq_a": draw(keys[0], (h, rq)),
-        "q_norm": rmsnorm_init(rq, dtype),
-        "wq_b": draw(keys[1], (rq, n * (dn + dr))),
+        **query,
         "wkv_a": draw(keys[2], (h, rkv + dr)),
         "kv_norm": rmsnorm_init(rkv, dtype),
         "wkv_b": draw(keys[3], (rkv, n * (dn + dv))),
@@ -115,9 +120,11 @@ def mla_init(rng, cfg: ModelConfig, dtype=jnp.float32):
 def mla_axes(cfg: ModelConfig):
     """Every matrix whole on its device (validate refuses a mesh that
     would split them)."""
+    query = ({"wq": ("embed", None)} if cfg.q_lora_rank is None else
+             {"wq_a": ("embed", None), "q_norm": {"scale": (None,)},
+              "wq_b": (None, None)})
     return {
-        "wq_a": ("embed", None), "q_norm": {"scale": (None,)},
-        "wq_b": (None, None), "wkv_a": ("embed", None),
+        **query, "wkv_a": ("embed", None),
         "kv_norm": {"scale": (None,)}, "wkv_b": (None, None),
         "wo": (None, "embed"),
     }
@@ -210,7 +217,9 @@ def mla_apply(params, x, cfg: ModelConfig, *, rope_cos, rope_sin,
               position_ids=None, kv_cache: LatentKVCache | None = None,
               cache_layer=None, segment_ids=None):
     """x [b, s, h] -> (out [b, s, h], the cache). `kv_cache` is the latent
-    cache stacked over layers and `cache_layer` this layer's index in it."""
+    cache stacked over layers (`LatentKVCache`, or
+    `attention.LatentStateCache` in a pattern of mixers) and `cache_layer`
+    this layer's index in it."""
     b, s, _ = x.shape
     n, dtype = cfg.num_attention_heads, x.dtype
     dn, dr, r = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
@@ -229,18 +238,26 @@ def mla_apply(params, x, cfg: ModelConfig, *, rope_cos, rope_sin,
                 (q_offset[:, None] if per_slot else q_offset)
                 + jnp.arange(s)[None, :], (b, s))
 
+    def rotary(t):
+        # `mla_nope`: NEITHER the queries' rope channels NOR the shared key
+        # channels are rotated; the row and the scale stay what they are
+        return t if cfg.mla_nope else apply_rotary(t, rope_cos, rope_sin,
+                                                   position_ids)
+
     with jax.named_scope("mtpu/mla/q"):
-        c_q = rmsnorm(params["q_norm"], x @ params["wq_a"].astype(dtype), eps)
-        q = (c_q @ params["wq_b"].astype(dtype)).reshape(b, s, n, dn + dr)
-        q = jnp.concatenate(
-            [q[..., :dn],
-             apply_rotary(q[..., dn:], rope_cos, rope_sin, position_ids)],
-            axis=-1)
+        if cfg.q_lora_rank is None:
+            q = x @ params["wq"].astype(dtype)
+        else:
+            c_q = rmsnorm(params["q_norm"], x @ params["wq_a"].astype(dtype),
+                          eps)
+            q = c_q @ params["wq_b"].astype(dtype)
+        q = q.reshape(b, s, n, dn + dr)
+        if not cfg.mla_nope:
+            q = jnp.concatenate([q[..., :dn], rotary(q[..., dn:])], axis=-1)
     with jax.named_scope("mtpu/mla/latent"):
         down = x @ params["wkv_a"].astype(dtype)            # [b, s, r + dr]
         c = rmsnorm(params["kv_norm"], down[..., :r], eps)
-        k_r = apply_rotary(down[:, :, None, r:], rope_cos, rope_sin,
-                           position_ids)[:, :, 0]
+        k_r = rotary(down[:, :, None, r:])[:, :, 0]
         if kv_cache is not None:
             # written at (layer, row, position) of the STACKED buffer, the
             # layer loop's carry: in place, only the new rows move
@@ -264,8 +281,10 @@ def mla_apply(params, x, cfg: ModelConfig, *, rope_cos, rope_sin,
             else:
                 stack = jax.lax.dynamic_update_slice(
                     kv_cache.c, new, (cache_layer, 0, 0, q_offset))
-            kv_cache = LatentKVCache(
-                stack, jax.lax.dynamic_update_index_in_dim(
+            # `_replace`: the cache may be `attention.LatentStateCache`, the
+            # latent rows beside a state of fixed size
+            kv_cache = kv_cache._replace(
+                c=stack, offset=jax.lax.dynamic_update_index_in_dim(
                     kv_cache.offset, q_offset + s, cache_layer, 0))
 
     wkv_b = params["wkv_b"].astype(dtype)

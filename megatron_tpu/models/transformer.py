@@ -49,7 +49,8 @@ RESIDUAL_AXES = ("batch", "seq_sp", "act_embed")
 
 def layer_init(rng, cfg: ModelConfig, dtype=jnp.float32,
                cross_attn: bool = False, mixer: str = "full_attention"):
-    """`mixer` "conv": a gated short convolution (`params["conv"]`,
+    """`mixer` "kda": a Kimi Delta Attention mixer (`params["kda"]`,
+    models/kda.py), "conv": a gated short convolution (`params["conv"]`,
     models/short_conv.py), "mamba": a selective state-space mixer
     (`params["mamba"]`, models/mamba.py), where the others have
     `params["attention"]`.
@@ -82,6 +83,9 @@ def layer_init(rng, cfg: ModelConfig, dtype=jnp.float32,
     elif mixer == "mamba":
         from megatron_tpu.models.mamba import mamba_init
         params = {"mamba": mamba_init(k_attn, cfg, dtype)}
+    elif mixer == "kda":
+        from megatron_tpu.models.kda import kda_init
+        params = {"kda": kda_init(k_attn, cfg, dtype)}
     elif cfg.mla:
         from megatron_tpu.models.mla import mla_init
         params = {"attention": mla_init(k_attn, cfg, dtype)}
@@ -140,6 +144,9 @@ def layer_axes(cfg: ModelConfig, cross_attn: bool = False,
     elif mixer == "mamba":
         from megatron_tpu.models.mamba import mamba_axes
         axes = {"mamba": mamba_axes(cfg)}
+    elif mixer == "kda":
+        from megatron_tpu.models.kda import kda_axes
+        axes = {"kda": kda_axes(cfg)}
     elif cfg.mla:
         from megatron_tpu.models.mla import mla_axes
         axes = {"attention": mla_axes(cfg)}
@@ -257,11 +264,16 @@ def layer_apply(
 
     def _mixer_branch(ln_out, kv_cache):
         """The layer's mixer on its normed input: (out, the cache)."""
-        if mixer in ("conv", "mamba", "mamba2"):
+        if mixer in ("conv", "mamba", "mamba2", "kda"):
             assert causal and encoder_output is None and adapters is None \
                 and segment_ids is None and not cp_pre_zigzag, (
                 "a convolution or state-space layer is causal, unsharded, "
                 "over one document")
+            if mixer == "kda":
+                from megatron_tpu.models.kda import kda_apply
+                return kda_apply(
+                    params["kda"], ln_out, cfg, kv_cache=kv_cache,
+                    kind_layer=kind_layer)
             if mixer == "mamba2":
                 from megatron_tpu.models.mamba2 import mamba2_apply
                 return mamba2_apply(
@@ -280,10 +292,13 @@ def layer_apply(
             from megatron_tpu.models.mla import mla_apply
             assert causal and encoder_output is None and adapters is None \
                 and not cp_pre_zigzag, "MLA is causal self-attention, unsharded"
+            # in a pattern of mixers the latent rows are the attention
+            # layers' alone: the layer's index among its own kind
             return mla_apply(
                 params["attention"], ln_out, cfg,
                 rope_cos=rope_cos, rope_sin=rope_sin, position_ids=position_ids,
-                kv_cache=kv_cache, cache_layer=cache_layer,
+                kv_cache=kv_cache,
+                cache_layer=cache_layer if kind_layer is None else kind_layer,
                 segment_ids=segment_ids)
         return attention_apply(
             params["attention"], ln_out, cfg,

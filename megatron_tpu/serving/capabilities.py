@@ -24,6 +24,11 @@ def pool_kind(model, max_len: int) -> str:
     `MegatronConfig.validate` refuses the crosses."""
     if model.window_layer_period:
         return "rings+regions"  # models/attention.py::HybridKVCache
+    if model.mla and model.state_layers:
+        # models/attention.py::LatentStateCache: latent rows for the
+        # attention layers beside a state of fixed size a slot (the
+        # depthwise kernels' last inputs and a matrix a head)
+        return "latent+state"
     if model.mla:
         return "latent"  # models/mla.py::LatentKVCache
     if model.state_layers:
@@ -120,6 +125,11 @@ ROWS: Dict[str, Tuple[str, str, str]] = {
         "layers",
         " on the pool of keys, values and a state of fixed size",
         " (ROADMAP R6)"),
+    "latent+state": (
+        "MLA (kv_lora_rank set) beside layer_types with a state of fixed "
+        "size a slot, kda layers",
+        " on the pool of latent rows and a state of fixed size",
+        " (ROADMAP R5, R6)"),
     "streams": ("hc_mult={m.hc_mult} (hyper-connections)",
                 " under a residual of streams", ""),
     "sliding-window": (
@@ -216,6 +226,32 @@ _WHY: Dict[str, List[Tuple[Tuple[str, ...], str]]] = {
         (("adapter_slots",),
          "the adapter bank is stacked over one kind of layer"),
         (("kv_dtype int8",), "the cache of two kinds of state has no scales"),
+    ],
+    # `conv-state`'s refusals and reasons, over latent rows
+    "latent+state": [
+        (("enable_prefix_cache", "retained_slots", "speculative_k"),
+         "a state is the one at the slot's CURRENT length (the depthwise "
+         "kernels' last three inputs and a matrix a head): cutting, cloning "
+         "or rewinding a slot to a shorter one needs a snapshot of the "
+         "state taken at the cut"),
+        (("preemption",),
+         "a parked slot's state has to be read out and put back with its "
+         "rows: kv_pool.slice_slot cuts latent rows alone"),
+        (_ARENA,
+         "the block arena, which the host tier and the handoff move by "
+         "blocks, is built round k and v of [kv_heads, head_dim] and has no "
+         "row for a state"),
+        (("serving_pp",),
+         "the stages cut ONE stack of identical layers and the arena by "
+         "layer; the kinds are stacked apart"),
+        (_MESH,
+         "the latent row has no head axis to shard, and the state and the "
+         "depthwise kernels need a head shard"),
+        (("adapter_slots",),
+         "the LoRA bank holds factors for wq / wkv / wo, which neither "
+         "mixer has"),
+        (("kv_dtype int8",),
+         "the cache of latent rows and two kinds of state has no scales"),
     ],
     "streams": [
         (("adapter_slots",),

@@ -55,7 +55,8 @@ from megatron_tpu.config import ModelConfig
 from megatron_tpu.inference.generation import (KV_CACHE_AXES, init_kv_caches,
                                                kv_region_cap)
 from megatron_tpu.models.attention import (BlockKVCache, ConvKVCache,
-                                            HybridKVCache, KVCache)
+                                            HybridKVCache, KVCache,
+                                            LatentStateCache)
 from megatron_tpu.models.mla import LatentKVCache
 from megatron_tpu.serving import capabilities
 from megatron_tpu.utils.logging import print_rank_0
@@ -105,6 +106,18 @@ def insert_prefill(pool: KVCache, prefill: KVCache, slot, plen) -> KVCache:
             offset=dus(pool.offset,
                        jnp.full((pool.offset.shape[0], 1), plen, jnp.int32),
                        (zero, slot)))
+    if isinstance(pool, LatentStateCache):
+        # the slot's three parts WHOLE: its latent rows, the depthwise
+        # kernels' inputs and the rule's matrices (no mask hides a state)
+        def land(name):
+            into, part = getattr(pool, name), getattr(prefill, name)
+            return dus(into, part.astype(into.dtype),
+                       (zero, slot) + (zero,) * (into.ndim - 2))
+        return pool._replace(
+            c=land("c"), conv=land("conv"), ssm=land("ssm"),
+            offset=dus(pool.offset,
+                       jnp.full((pool.offset.shape[0], 1), plen, jnp.int32),
+                       (zero, slot)))
     if isinstance(pool, LatentKVCache):
         return LatentKVCache(
             c=dus(pool.c, prefill.c.astype(pool.c.dtype),
@@ -144,7 +157,7 @@ def slice_slot(pool: KVCache, slot, offset) -> KVCache:
         "a slot of rings cannot be cut out at a shorter length: the rows "
         "of its earlier positions are gone (ServingConfig.validate refuses "
         "prefix cache and preemption on window_layer_period)")
-    assert not isinstance(pool, ConvKVCache), (
+    assert not isinstance(pool, (ConvKVCache, LatentStateCache)), (
         "a slot with a convolution state cannot be cut out at a shorter "
         "length: the state is the one at the slot's current length "
         "(ServingConfig.validate refuses prefix cache, retained slots and "
@@ -495,9 +508,9 @@ class SlotKVPool:
     @property
     def conv_layers(self) -> int:
         """Layers that keep a state of fixed size a slot and no keys or
-        values: a convolution's last inputs and, in a "mamba" or "mamba2"
-        layer, the scan's state beside them (`cfg.layer_types`;
-        models/attention.py::ConvKVCache)."""
+        values: a convolution's last inputs and, in a "mamba", "mamba2" or
+        "kda" layer, the scan's state beside them (`cfg.layer_types`;
+        models/attention.py::ConvKVCache, LatentStateCache)."""
         return self.cfg.state_layers
 
     @property
@@ -958,6 +971,8 @@ class SlotKVPool:
         if isinstance(c, ConvKVCache):
             return (c.k.nbytes + c.v.nbytes + c.conv.nbytes
                     + (0 if c.ssm is None else c.ssm.nbytes))
+        if isinstance(c, LatentStateCache):
+            return c.c.nbytes + c.conv.nbytes + c.ssm.nbytes
         n = c.k.nbytes + c.v.nbytes
         if c.k_scale is not None:
             n += c.k_scale.nbytes + c.v_scale.nbytes
@@ -982,6 +997,11 @@ class SlotKVPool:
         float32 a layer a slot (0 where the pool has none)."""
         return self._scan_state_nbytes("mamba2")
 
+    def kda_state_nbytes(self) -> int:
+        """Bytes of the delta rule's state, [heads, head_dim, head_dim]
+        float32 a layer a slot (0 where the pool has none)."""
+        return self._scan_state_nbytes("kda")
+
     def ring_nbytes(self) -> int:
         """Bytes of the window layers' rings (0 where the pool has none)."""
         if not self.hybrid:
@@ -993,7 +1013,8 @@ class SlotKVPool:
         kinds, else the whole pool."""
         if not self.hybrid:
             return (self.nbytes() - self.conv_state_nbytes()
-                    - self.ssm_state_nbytes() - self.ssd_state_nbytes())
+                    - self.ssm_state_nbytes() - self.ssd_state_nbytes()
+                    - self.kda_state_nbytes())
         return self.caches.full_k.nbytes + self.caches.full_v.nbytes
 
     def bytes_per_slot(self) -> int:

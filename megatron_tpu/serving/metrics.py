@@ -201,6 +201,7 @@ _BASE_GAUGES = (
     "kv_bytes_per_token", "kv_pool_bytes",
     "kv_bytes_per_slot", "kv_ring_bytes", "kv_full_bytes",
     "conv_state_bytes", "ssm_state_bytes", "ssd_state_bytes",
+    "kda_state_bytes",
     "active_adapters", "handoff_bytes_per_req",
     "prefill_group_busy", "decode_group_busy",
     "prefill_tp", "decode_tp", "prefill_devices", "decode_devices",
@@ -271,6 +272,9 @@ class ServingMetrics:
         # the chunked scans' float32 state, a matrix a head (0 where no
         # layer is a "mamba2" mixer)
         self.ssd_state_bytes = 0
+        # the delta rule's float32 state, a matrix a head (0 where no layer
+        # is a "kda" mixer)
+        self.kda_state_bytes = 0
         # multi-tenant LoRA serving: device-resident (non-identity)
         # adapters right now — 0 on adapterless engines, pushed by the
         # engine on pool churn like the KV gauges
@@ -422,8 +426,8 @@ class ServingMetrics:
     def set_pool_gauges(self, pool):
         """`SlotKVPool.bytes_per_token()`, `.nbytes()`, `.bytes_per_slot()`,
         `.ring_nbytes()`, `.full_nbytes()`, `.conv_state_nbytes()`,
-        `.ssm_state_nbytes()` and `.ssd_state_nbytes()`, as the pool counts
-        them."""
+        `.ssm_state_nbytes()`, `.ssd_state_nbytes()` and
+        `.kda_state_nbytes()`, as the pool counts them."""
         with self._lock:
             self.kv_bytes_per_token = int(pool.bytes_per_token())
             self.kv_pool_bytes = int(pool.nbytes())
@@ -433,6 +437,7 @@ class ServingMetrics:
             self.conv_state_bytes = int(pool.conv_state_nbytes())
             self.ssm_state_bytes = int(pool.ssm_state_nbytes())
             self.ssd_state_bytes = int(pool.ssd_state_nbytes())
+            self.kda_state_bytes = int(pool.kda_state_nbytes())
 
     def set_attn_gauges(self, gather_bytes_per_step: int, path: int):
         """Engine-pushed attention-path gauges (per sync window):

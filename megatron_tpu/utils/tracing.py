@@ -132,8 +132,8 @@ Training (`training/loop.py`, main thread):
 
 On the device (`jax.named_scope`, so in the `op_name` of every HLO instruction
 traced under it; models/moe.py, models/attention.py, models/mla.py,
-models/hyper_connections.py, models/mamba.py, models/mamba2.py and
-models/rope.py):
+models/hyper_connections.py, models/mamba.py, models/mamba2.py,
+models/kda.py and models/rope.py):
 
 | scope | round what |
 |---|---|
@@ -161,6 +161,11 @@ models/rope.py):
 | `mtpu/ssd/scan` | the recurrence: the kernel `_ssd_chunk_scan` for a prefill or a chunk (ops/ssd_scan.py; four products a chunk a head, the running sums of dt A made outside it), the one-step update over the pool's layer for a decode step, the `einsum` form with no cache |
 | `mtpu/ssd/norm` | y gated by SiLU(z), then the RMSNorm over each group's channels, float32 statistics, the learned scale |
 | `mtpu/ssd/out_proj` | the layer's second product, rows x [d_inner, h] |
+| `mtpu/kda/proj` | a Kimi Delta Attention layer's two first products, rows x [h, 3 H D] (q, k, v) and rows x [h, r + r + H] (the decay's and the output gate's low-rank inputs, beta) (`models/kda.py`) |
+| `mtpu/kda/conv` | the read of the layer's two states (the three depthwise kernels' last inputs, the rule's [heads, head_dim, head_dim] float32 matrices; a row each slot), the taps over [state ; rows] of q, k and v accumulated in float32, SiLU, the L2 norm a head of q and k, q's 1 / sqrt(D) |
+| `mtpu/kda/gate` | the log-decay a channel, -exp(A_log) softplus(f W_fb + dt_bias) in float32, beta = sigmoid, and the padding rows' decay and beta set to 0 (the rule's step is then the identity) |
+| `mtpu/kda/scan` | the gated delta rule: the kernel `_kda_chunk` for a prefill or a chunk (ops/kda_chunk.py; the running sums of the decays made outside it), the one-row update over the pool's layer for a decode step, the recurrence with no cache; and the write of both states behind the call's last real row, one update in place a layer each |
+| `mtpu/kda/out` | the RMSNorm a head, float32 statistics, ONE learned scale [D]; the output gate sigmoid(z W_gb + b); the layer's last product, rows x [H D, h] |
 | `mtpu/moe/latent_in` | experts in a latent (`cfg.moe_latent_size`): rows x [h, latent] ahead of the routing's gather (`models/moe.py`) |
 | `mtpu/moe/latent_out` | the tokens' weighted sums x [latent, h], behind the combination |
 | `mtpu/moe/shared` | the shared experts' MLP, added beside the routed sum (`n_shared_experts`) |
@@ -187,7 +192,11 @@ of every slot; a slot's share of it is `serve_state_bytes_per_slot`) and
 slot; a slot's share of it is `serve_ssm_state_bytes_per_slot`), and
 `ssd_state_bytes` (`.ssd_state_nbytes()`: the Mamba-2 scans' float32 matrices
 a head of every slot; a slot's share of it is
-`serve_ssd_state_bytes_per_slot`).
+`serve_ssd_state_bytes_per_slot`), and `kda_state_bytes`
+(`.kda_state_nbytes()`: the delta rule's float32 matrices a head of every
+slot; a slot's share of it is `serve_kda_state_bytes_per_slot`). The device
+trace names the chunked rule's kernel calls `%_kda_chunk.N`
+(`serve_kda_scan_ms_per_step`, `kda_chunk_roofline_pct`).
 `prefill_chunks` counts the chunk programs dispatched. `admits_total`,
 `admits_early` and `early_admit_declined_prefilling` (placements by `_admit`,
 those made while a decode window ran, and windows that ended with a prompt

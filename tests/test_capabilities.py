@@ -41,12 +41,14 @@ MODELS = {
     "rings+regions": _preset("command-a-plus-tiny"),
     "latent": _preset("joyai-llm-flash-tiny"),
     "conv-state": _preset("lfm2-8b-a1b-tiny"),
+    "latent+state": _preset("kimi-linear-tiny"),
     "streams": _preset("llama2-tiny", hc_mult=2),
     "sliding-window": _preset("llama2-tiny", sliding_window=16),
     "qk_norm": _preset("olmoe-tiny"),
     "dropless-experts": _preset("olmoe-tiny", qk_norm=False),
 }
-KINDS = ("regions", "rolling", "rings+regions", "latent", "conv-state")
+KINDS = ("regions", "rolling", "rings+regions", "latent", "conv-state",
+         "latent+state")
 
 # the options that turn a feature on
 ON = {
@@ -120,6 +122,9 @@ def test_the_refused_cells_are_the_parents():
                                   "kv_dtype int8"},
         "conv-state": cut | arena | mesh | {
             "serving_pp", "adapter_slots", "kv_dtype int8"},
+        # PR 58's row: `conv-state`'s refusals over latent rows
+        "latent+state": cut | arena | mesh | {
+            "serving_pp", "adapter_slots", "kv_dtype int8"},
         "streams": mesh | {"serving_pp", "adapter_slots"},
         "sliding-window": {"block_native_attn", "serving_pp"},
         "qk_norm": mesh,
@@ -177,6 +182,7 @@ ENGINE_MODELS = {
     "rolling": MODELS["rolling"],
     "rings+regions": MODELS["rings+regions"],
     "conv-state": MODELS["conv-state"],
+    "latent+state": MODELS["latent+state"],
     "latent": MODELS["latent"],
     "latent, streams": _preset("xing4.0-29b-a4b-tiny"),
 }
@@ -235,7 +241,8 @@ def test_a_block_as_large_as_the_region_is_no_block(generator, feature):
     assert SlotKVPool(roll, 2, 64, block_size=64).block_size == 16
 
 
-@pytest.mark.parametrize("kind", ["rings+regions", "latent", "conv-state"])
+@pytest.mark.parametrize("kind", ["rings+regions", "latent", "conv-state",
+                                  "latent+state"])
 def test_a_pool_built_alone_refuses_blocks_in_the_tables_words(kind):
     model = MODELS[kind]()
     with pytest.raises(AssertionError) as refused:

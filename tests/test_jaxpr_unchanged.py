@@ -55,6 +55,15 @@ AT_PARENT = {
     "olmoe-tiny": {"decode": "338d2003810367f2",
                    "prefill": "4e3decc3ccd67b58",
                    "plain_loop": "c88e314638cb0a8f"},
+    # PR 58 opened models/mla.py (one-matrix queries, NoPE, a cache of
+    # latent rows beside a state): the two MLA models' programs, taken at
+    # its parent's tree (f15e986), came out the same on its own
+    "joyai-llm-flash-tiny": {"decode": "bddfdbff9197e616",
+                             "prefill": "8261f95d31eb9035",
+                             "plain_loop": "073a800e6993f50c"},
+    "xing4.0-29b-a4b-tiny": {"decode": "82beae2cd9e46c81",
+                             "prefill": "21603a803e31715d",
+                             "plain_loop": "3c572c42d9b52012"},
 }
 
 
@@ -105,5 +114,4 @@ def test_programs_are_the_parents(model):
 
 if __name__ == "__main__":
     import json
-    print(json.dumps({m: digests(m) for m in ("falcon-tiny", "olmoe-tiny")},
-                     indent=4))
+    print(json.dumps({m: digests(m) for m in sorted(AT_PARENT)}, indent=4))

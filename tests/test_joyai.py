@@ -357,7 +357,8 @@ def test_preset_through_parse_cli():
     (dict(parallel=ParallelConfig(pipeline_parallel=2)), "one device only"),
     (dict(model=dict(sliding_window=16)), "sliding_window"),
     (dict(model=dict(kv_channels=16)), "rotary"),
-    (dict(model=dict(q_lora_rank=None)), "q_lora_rank"),
+    # (None is ONE query matrix since PR 58: tests/test_kimi_linear.py)
+    (dict(model=dict(q_lora_rank=0)), "q_lora_rank"),
     (dict(model=dict(mtp_num_layers=2)), "depth 1"),
     (dict(model=dict(moe_dispatch="sort", moe_capacity_factor=4.0)),
      "dropless router"),

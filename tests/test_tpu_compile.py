@@ -713,3 +713,134 @@ def test_a_table_of_whole_lane_tiles_is_gathered_in_place(one_chip,
     text = _gather_and_tied_head(one_chip, monkeypatch, 64, hidden=4608)
     assert "tpu_custom_call" not in text
     assert _table_shaped(text, TABLE[0], 4608) == []
+
+
+@pytest.mark.parametrize("batch,rows,chunk", [(1, 4096, 64), (1, 512, 64),
+                                              (2, 1024, 128)])
+def test_kda_chunk_at_kimi_linear_widths(one_chip, batch, rows, chunk):
+    """The chunked delta rule's kernel (PR 58) at Kimi Linear's widths: a
+    4,096-row chunk and shorter ones of 32 heads of 128 key and value
+    channels, chunks of 64 rows (four sub-chunks of 16) or 128, a state of
+    [32, 128, 128] float32 a sequence in and out, bf16 rows, float32
+    log-decays: two heads a grid step, 128-channel slices of a 256-lane
+    block."""
+    from megatron_tpu.ops.kda_chunk import _kda_chunk, kda_block_heads
+
+    def S(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    f32 = jnp.float32
+    assert kda_block_heads(32, 128, 128) == 2
+    by_rows = (batch, rows, 32, 128)
+    text = jax.jit(functools.partial(_kda_chunk, chunk=chunk)).lower(
+        S(by_rows), S(by_rows), S(by_rows), S(by_rows, f32),
+        S((batch, rows, 32), f32), S((batch, 32, 128, 128), f32)
+    ).compile().as_text()
+    # the trace finds the kernel by this name (benchmark/kda_roofline.py)
+    assert any("%_kda_chunk" in line and "tpu_custom_call" in line
+               for line in text.splitlines())
+
+
+def _made_in_memory(text):
+    """The instructions of a compiled module whose result is an array in
+    memory: every line but those inside a computation that a fusion calls
+    (a slice there is part of the fusion's own operand read)."""
+    fused = set(re.findall(r"fusion\(.*calls=%([\w.-]+)", text))
+    inside = None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.-]+) \(.*\{\s*$", line)
+        if head:
+            inside = head.group(1)
+        elif inside not in fused:
+            yield line
+
+
+def _kimi_linear_programs(one_chip, monkeypatch):
+    """The three served programs (a decode step over the slot grid, a
+    one-shot prefill and a continuation chunk of one sequence) of a
+    `kimi-linear-tiny` whose KDA heads are as wide as the published ones
+    (2 heads of 128: the chunk kernel's shape rule holds), over a pool of
+    256 slots of 256 positions (a state of 200 MB: a smaller one the
+    compiler moves whole into the chip's fast memory and back, which is no
+    copy in HBM and not what 2.4 GiB at the cell's size can do), compiled
+    for the chip with the cache donated as the engine donates it."""
+    import dataclasses
+
+    from megatron_tpu.config import MODEL_PRESETS
+    from megatron_tpu.inference.generation import (init_kv_caches,
+                                                   prefill_chunk)
+    from megatron_tpu.models import language_model as lm
+
+    cfg = dataclasses.replace(
+        MODEL_PRESETS["kimi-linear-tiny"](), compute_dtype="bfloat16",
+        params_dtype="bfloat16", kda_num_heads=2, kda_head_dim=128,
+        attention_impl="flash")
+    slots, cap, bucket = 256, 256, 128
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    on_chip = functools.partial(_on_chip, one_chip)
+    params = on_chip(jax.eval_shape(
+        lambda: lm.model_init(jax.random.PRNGKey(0), cfg)))
+    pool = on_chip(jax.eval_shape(lambda: init_kv_caches(
+        cfg, slots, cap, per_slot_offsets=True)))
+    one = on_chip(jax.eval_shape(lambda: init_kv_caches(cfg, 1, cap)))
+    ids = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one_chip)
+
+    def forward(params, tokens, caches):
+        return lm.model_forward(params, tokens, cfg, kv_caches=caches)
+
+    def chunk(params, tokens, caches, last, nxt):
+        return prefill_chunk(params, tokens, caches, cfg, rope=None,
+                             last_idx=last, next_offset=nxt)
+    return cfg, (slots, cap), {
+        "decode": jax.jit(forward, donate_argnums=2).lower(
+            params, ids(slots, 1), pool).compile(),
+        "prefill": jax.jit(forward, donate_argnums=2).lower(
+            params, ids(1, bucket), one).compile(),
+        "chunk": jax.jit(chunk, donate_argnums=2).lower(
+            params, ids(1, bucket), one, ids(), ids()).compile()}
+
+
+def test_kimi_linear_served_programs_copy_no_state_and_no_latent_layer(
+        one_chip, monkeypatch):
+    """No program of the three makes a copy of the rule's state (float32
+    [6 KDA layers, batch, 2, 128, 128]) or of a layer of latent rows: what
+    has the state's shape is handed on or written in place, the cache is
+    aliased whole, and the temporaries are smaller than the state."""
+    cfg, (slots, cap), programs = _kimi_linear_programs(one_chip,
+                                                        monkeypatch)
+    row = cfg.kv_row_width
+    for name, compiled in programs.items():
+        batch = slots if name == "decode" else 1
+        text = compiled.as_text()
+        state = (6, batch, 2, 128, 128)
+        layer = (batch, row, cap)
+        for line in _made_in_memory(text):
+            m = _RESULT.match(line)
+            if not m:
+                continue
+            dims = tuple(int(d) for d in m.group(2).split(",") if d)
+            if dims == state:
+                assert m.group(3) in (
+                    "parameter", "get-tuple-element", "bitcast",
+                    "dynamic-update-slice", "while", "tuple",
+                    "custom-call") or (
+                    m.group(3) == "fusion"
+                    and ("dynamic-update-slice" in line
+                         or "kind=kCustom" in line)) or (
+                    # ONE sequence's state (12 MiB at the cell's size) may
+                    # be staged in the chip's fast memory round its use
+                    name != "decode"
+                    and m.group(3) in ("copy-start", "copy-done")), \
+                    (name, line[:300])
+            # a layer of the latent rows cut out of the stack, or the stack
+            # in another order: never made
+            assert dims not in (layer, (1, *layer),
+                                (batch, cap, row)), (name, line[:300])
+        if name != "decode":
+            assert len(_kernel_calls(text, "_kda_chunk")) >= 1, name
+        memory = compiled.memory_analysis()
+        nbytes = 6 * batch * 2 * 128 * 128 * 4
+        assert memory.alias_size_in_bytes >= nbytes \
+            + 2 * batch * row * cap * 2, (name, memory)
+        if name == "decode":
+            assert memory.temp_size_in_bytes < nbytes, (name, memory)
