@@ -37,9 +37,19 @@ Two forms of the same mathematics, chosen from what the code sees:
 
 A cached forward of many positions at a scalar offset holds both under a
 `lax.cond` on `offset == 0`, as models/attention.py's `prefill_flash` does.
-The absorbed form of many query positions runs a block of queries at a time
-(`ABSORBED_Q_BLOCK`), so that the scores of a long chunk against a long
-cache are never whole in memory.
+
+What the absorbed form reads. A decode step, a verify window and a short
+suffix (at most `ABSORBED_Q_BLOCK` queries) take their queries at once over
+the WHOLE region of `max_seq` positions, whatever the offsets. A longer
+chunk (PR 59) runs a block of `ABSORBED_Q_BLOCK` queries at a time, and a
+block reads the cached positions in blocks of `ABSORBED_KEY_BLOCK` keys up
+to the last block any of its queries can see and no further
+(`absorbed_key_blocks`, the one rule: the program's trip count, which is
+data, and the engine's `latent_chunk_blocks_read`), under the running
+maximum, sum and weighted sum in float32 that the flash kernels keep: a
+chunk at offset 4,096 of a region of 32,768 reads a fifth of it, and the
+scores are `[batch, query block, heads, key block]`, never a chunk's
+against a region's.
 
 Sharding: none. The latent row has no head axis; `config.validate` refuses
 a tensor-parallel mesh and ROADMAP R5 says what a sharded MLA would need.
@@ -51,14 +61,41 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from megatron_tpu.config import ModelConfig
 from megatron_tpu.models.norms import rmsnorm, rmsnorm_init
 from megatron_tpu.models.rope import apply_rotary, yarn_softmax_mscale
 
-# queries a block of the absorbed form of many positions: its scores are
-# [batch, heads, block, cached positions] float32
+# queries and cached positions a block of the absorbed form of many
+# positions: its scores are [batch, query block, heads, key block] float32
+# (PERF.md section 6, PR 59: the blocks tried on the chip and their times)
 ABSORBED_Q_BLOCK = 256
+ABSORBED_KEY_BLOCK = 1024
+
+
+def absorbed_query_block(s: int) -> int:
+    """The queries a block of the absorbed form of `s` positions, or 0
+    where it takes them at once over the whole region (a decode step, a
+    verify window, a short suffix)."""
+    blk = ABSORBED_Q_BLOCK
+    return blk if s > blk and s % blk == 0 else 0
+
+
+def _key_block(t: int) -> int:
+    """The keys a block of `t` cached positions: a shorter region is one."""
+    return min(ABSORBED_KEY_BLOCK, t)
+
+
+def absorbed_key_blocks(last_pos, t: int):
+    """The key blocks that a block of queries reads of `t` cached
+    positions, `last_pos` the largest position of its queries: those up to
+    the last one any of them can see. THE rule: the program's trip count
+    (a traced scalar) and the engine's `latent_chunk_blocks_read` (numpy)
+    both come from here."""
+    xp = jnp if isinstance(last_pos, jax.Array) else np
+    kb = _key_block(t)
+    return (xp.minimum(last_pos + 1, t) + kb - 1) // kb
 
 
 class LatentKVCache(NamedTuple):
@@ -72,7 +109,9 @@ class LatentKVCache(NamedTuple):
     # order it was written in and back, every step, and a layer of it
     # transposed for each product (compile for the chip, PR 31: 17.0 GiB
     # where 15.75 are allowed). Held this way the products read a layer
-    # where it lies; a new token's 576 values land in 72 tiles of 2 KiB.
+    # where it lies; a new token's 576 values land in 72 tiles of 2 KiB,
+    # and a chunk's key block (PR 59) is a cut of whole lane tiles, a
+    # multiple of 128 positions of every row.
     c: jax.Array
     # tokens already in the cache: [layers], or per row [layers, batch]
     # (the serving engine's slot grid), as KVCache.offset
@@ -178,7 +217,8 @@ def _attend_absorbed(q, stack, layer, wkv_b, cfg: ModelConfig, scale, q_pos):
     scores the whole rows and the weighted sum their first kv_lora values:
     one cut that fed both was made in memory, a copy of a layer of the pool
     in every pass, where a cut with one reader is part of the product's own
-    operand load (compile for the chip, PR 31)."""
+    operand load (compile for the chip, PR 31). A long chunk's key block
+    (`attend_key_blocks`) is one cut for both: a megabyte, not a layer."""
     b, s, n, _ = q.shape
     dn, dv, r = cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
     w = wkv_b.reshape(r, n, dn + dv)
@@ -201,10 +241,48 @@ def _attend_absorbed(q, stack, layer, wkv_b, cfg: ModelConfig, scale, q_pos):
         probs = _masked_softmax(scores, pos_blk, kv_pos)
         return jnp.einsum("bnst,brt->bsnr", probs.astype(dtype),
                           layer_rows(r))
-    blk = ABSORBED_Q_BLOCK
-    if s > blk and s % blk == 0:
+    kb = _key_block(t)
+    lowest = jnp.finfo(jnp.float32).min
+
+    def attend_key_blocks(qt_blk, pos_blk):
+        """A block of queries over the key blocks its queries can see, the
+        flash kernels' running softmax in plain XLA: the trip count is
+        data. Block 0 holds position 0, which every query sees, so the
+        running maximum is finite from the first iteration on and a block
+        that a row cannot see at all adds exact zeros."""
+        def body(j, carry):
+            m, l, acc = carry
+            # the last block of a `t` that `kb` does not divide starts where
+            # it still fits, and leaves the positions before j kb to block
+            # j - 1, which counted them
+            start = jnp.minimum(j * kb, t - kb)
+            rows = jax.lax.dynamic_slice(
+                stack, (layer, 0, 0, start),
+                (1, b, stack.shape[2], kb))[0].astype(dtype)
+            pos = start + jnp.arange(kb)
+            scores = jnp.einsum("bsnr,brt->bsnt", qt_blk, rows) * scale
+            mask = pos_blk[:, :, None] >= pos
+            if t % kb:
+                mask &= pos >= j * kb
+            scores = jnp.where(mask[:, :, None], scores.astype(jnp.float32),
+                               lowest)
+            m_new = jnp.maximum(m, scores.max(axis=-1))
+            p = jnp.exp(scores - m_new[..., None])
+            alpha = jnp.exp(m - m_new)
+            acc = acc * alpha[..., None] + jnp.einsum(
+                "bsnt,brt->bsnr", p.astype(dtype), rows[:, :r],
+                preferred_element_type=jnp.float32)
+            return m_new, l * alpha + p.sum(axis=-1), acc
+        rows_q = qt_blk.shape[:3]
+        _, l, acc = jax.lax.fori_loop(
+            0, absorbed_key_blocks(pos_blk.max(), t), body,
+            (jnp.full(rows_q, lowest), jnp.zeros(rows_q, jnp.float32),
+             jnp.zeros((*rows_q, r), jnp.float32)))
+        return (acc / l[..., None]).astype(dtype)
+    blk = absorbed_query_block(s)
+    if blk:
         o = jax.lax.map(
-            lambda xs: attend(*xs),
+            lambda xs: attend_key_blocks(*xs),
             (qt.reshape(b, s // blk, blk, n, -1).swapaxes(0, 1),
              q_pos.reshape(b, s // blk, blk).swapaxes(0, 1)))
         o = o.swapaxes(0, 1).reshape(b, s, n, r)

@@ -185,6 +185,7 @@ from megatron_tpu.inference.sampling import (rows_need_filter,
                                              sample_batched,
                                              verify_draft_probs)
 from megatron_tpu.models import language_model as lm
+from megatron_tpu.models import mla
 from megatron_tpu.models.attention import KVCache
 from megatron_tpu.resilience.faults import get_fault_injector
 from megatron_tpu.serving.kv_pool import (SlotKVPool, block_native_cache,
@@ -4118,6 +4119,8 @@ class ServingEngine:
         aidx1 = (jnp.asarray([st.aidx], jnp.int32) if self._adapters_on
                  else None)
         self._await_program(("chunk", padded), (st.req,))
+        if st.pos > 0 and self.cfg.mla:
+            self._count_latent_chunk_blocks(st.pos, padded)
         st.sub, st.last = self._chunk_fwd(*self._chunk_args(
             st.sub, jnp.asarray(toks), jnp.int32(n - 1),
             jnp.int32(st.pos + n), aidx1))
@@ -4132,6 +4135,22 @@ class ServingEngine:
             self._prefilling.pop(0)
             self._activate_pending(st, plen)
         return n
+
+    def _count_latent_chunk_blocks(self, pos: int, padded: int):
+        """`latent_chunk_blocks_read` / `_held`: what the absorbed form of
+        a chunk of `padded` rows at offset `pos` reads of the sequence's
+        region of latent rows, by models/mla.py's own rule, over its query
+        blocks and the MLA layers, against the whole region."""
+        layers, t = self.pool.caches.c.shape[0], self.pool.cap
+        blk = mla.absorbed_query_block(padded)
+        # the last position of each block of queries; rows taken at once
+        # (blk 0) read the whole region
+        last = (pos + np.arange(blk, padded + 1, blk) - 1 if blk
+                else np.array([t - 1]))
+        self.metrics.count("latent_chunk_blocks_read", layers * int(
+            mla.absorbed_key_blocks(last, t).sum()))
+        self.metrics.count("latent_chunk_blocks_held", layers * len(last)
+                           * int(mla.absorbed_key_blocks(t - 1, t)))
 
     def _chunk_shape(self, pos: int, plen: int):
         """(real tokens, padded length) of the chunk that takes a prompt

@@ -60,6 +60,13 @@ _BASE_COUNTERS = (
     # regions it would have read whole: read / held is the share of the
     # pool's bytes a step moves. 0 / 0 where every region is read whole
     "kv_blocks_read", "kv_blocks_held",
+    # key blocks of a sequence's latent rows that a continuation chunk's
+    # absorbed attention read, over its query blocks and MLA layers
+    # (models/mla.py::absorbed_key_blocks: up to the last block a query
+    # block can see), and the blocks of the whole region over the same:
+    # read / held is the share of the region a chunk reads. 0 / 0 without
+    # latent rows and where no prompt is chunked
+    "latent_chunk_blocks_read", "latent_chunk_blocks_held",
     # first tokens handed to their requests ahead of the decode window
     # that commits them (engine._deliver_first): over the requests
     # admitted less the resumes, the share of first tokens that waited

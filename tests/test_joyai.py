@@ -123,6 +123,104 @@ def test_absorbed_form_in_query_blocks(monkeypatch):
     np.testing.assert_allclose(got, want[:, 4:], rtol=0, atol=2e-6)
 
 
+def _small_blocks(monkeypatch, q_block=4, key_block=8):
+    monkeypatch.setattr(mla, "ABSORBED_Q_BLOCK", q_block)
+    monkeypatch.setattr(mla, "ABSORBED_KEY_BLOCK", key_block)
+
+
+@pytest.mark.parametrize("first, rows, t, why", [
+    (8, 8, 32, "the offset a multiple of the key block"),
+    (5, 8, 32, "an offset inside a key block"),
+    (6, 4, 32, "one query block, 6..9, across the edge of key block 0"),
+    (4, 8, 12, "a region the key block does not divide: the last block "
+               "starts where it fits and counts no position twice"),
+    (8, 8, 16, "the chunk ends where the region does"),
+    (8, 8, 5 * 8, "the region's last blocks never reached"),
+])
+def test_absorbed_form_in_key_blocks(monkeypatch, first, rows, t, why):
+    """A block of queries reads the cached positions a key block at a time
+    and stops at the last block any of its queries can see: the same
+    numbers as the forward without a cache."""
+    cfg = tiny()
+    params, x, rope, want = _attention_case(cfg)
+    _small_blocks(monkeypatch)
+    kw = dict(rope_cos=rope.cos, rope_sin=rope.sin, cache_layer=1)
+    cache = mla.LatentKVCache.create(2, 2, t, cfg.kv_row_width, jnp.float32)
+    _, cache = mla.mla_apply(params, x[:, :first], cfg, kv_cache=cache, **kw)
+    got, cache = mla.mla_apply(params, x[:, first:first + rows], cfg,
+                               kv_cache=cache, **kw)
+    np.testing.assert_allclose(got, want[:, first:first + rows], rtol=0,
+                               atol=2e-6, err_msg=why)
+    assert cache.offset.tolist() == [0, first + rows]
+
+
+def test_key_blocks_of_a_padded_last_chunk(monkeypatch):
+    """A last chunk padded to its bucket: the padding rows stand past the
+    real length (their bound reaches further than any real row's) and
+    change no real row."""
+    cfg = tiny()
+    params, x, rope, want = _attention_case(cfg)
+    _small_blocks(monkeypatch)
+    kw = dict(rope_cos=rope.cos, rope_sin=rope.sin, cache_layer=0)
+    cache = mla.LatentKVCache.create(1, 2, 32, cfg.kv_row_width, jnp.float32)
+    _, cache = mla.mla_apply(params, x[:, :7], cfg, kv_cache=cache, **kw)
+    padded = jnp.concatenate([x[:, 7:10], jnp.full_like(x[:, :5], 7.0)],
+                             axis=1)
+    got, _ = mla.mla_apply(params, padded, cfg, kv_cache=cache, **kw)
+    np.testing.assert_allclose(got[:, :3], want[:, 7:10], rtol=0, atol=2e-6)
+    assert np.isfinite(np.asarray(got)).all()
+
+
+def test_key_blocks_bound_is_clamped_at_the_region(monkeypatch):
+    """On the slot grid a row parked at the capacity has queries past the
+    region: its bound is the region, and the row beside it is exact."""
+    cfg = tiny()
+    params, x, rope, want = _attention_case(cfg)
+    _small_blocks(monkeypatch)
+    assert mla.absorbed_key_blocks(16 + 7, 16) == 2
+    assert mla.absorbed_key_blocks(np.array([0, 7, 8, 40]), 20).tolist() \
+        == [1, 1, 2, 3]
+    kw = dict(rope_cos=rope.cos, rope_sin=rope.sin, cache_layer=0)
+    cache = mla.LatentKVCache.create(1, 2, 16, cfg.kv_row_width, jnp.float32)
+    _, cache = mla.mla_apply(params, x[:, :4], cfg, kv_cache=cache, **kw)
+    grid = cache._replace(offset=jnp.array([[4, 16]]))
+    got, _ = mla.mla_apply(params, x[:, 4:12], cfg, kv_cache=grid, **kw)
+    np.testing.assert_allclose(got[0], want[0, 4:12], rtol=0, atol=2e-6)
+    assert np.isfinite(np.asarray(got[1])).all()
+
+
+def test_key_blocks_past_the_last_visible_one_are_not_read(monkeypatch):
+    """The proof that the read stops: every key block past the last one a
+    chunk may see is filled with NaN, and the output is the same finite
+    numbers (read and masked, a NaN would come through the weighted sum as
+    0 x NaN). And block by block of queries: with block 3 poisoned, the
+    queries that see blocks 0..2 alone are untouched."""
+    cfg = tiny()
+    params, x, rope, want = _attention_case(cfg)
+    _small_blocks(monkeypatch, key_block=4)
+    kw = dict(rope_cos=rope.cos, rope_sin=rope.sin, cache_layer=0)
+    cache = mla.LatentKVCache.create(1, 2, 32, cfg.kv_row_width, jnp.float32)
+    _, cache = mla.mla_apply(params, x[:, :8], cfg, kv_cache=cache, **kw)
+    poisoned = cache._replace(c=cache.c.at[..., 16:].set(jnp.nan))
+    got, after = mla.mla_apply(params, x[:, 8:], cfg, kv_cache=poisoned, **kw)
+    np.testing.assert_allclose(got, want[:, 8:], rtol=0, atol=2e-6)
+
+    # queries 8..11 see key blocks 0..2, queries 12..15 block 3 too
+    scale = 1.0 / np.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    q = jax.random.normal(jax.random.PRNGKey(4), (
+        2, 8, cfg.num_attention_heads,
+        cfg.qk_nope_head_dim + cfg.qk_rope_head_dim))
+    wkv_b = params["wkv_b"]
+    clean = mla._attend_absorbed(q, after.c[..., :16], 0, wkv_b, cfg, scale,
+                                 8 + jnp.arange(8)[None])
+    assert np.isfinite(np.asarray(clean)).all()
+    stack = after.c[..., :16].at[..., 12:].set(jnp.nan)
+    got = mla._attend_absorbed(q, stack, 0, wkv_b, cfg, scale,
+                               8 + jnp.arange(8)[None])
+    np.testing.assert_array_equal(got[:, :4], clean[:, :4])
+    assert np.isnan(np.asarray(got[:, 4:])).all()
+
+
 # ---------------------------------------------------------------------------
 # (b) the router and the shared expert
 # ---------------------------------------------------------------------------

@@ -11,9 +11,10 @@ import numpy as np
 import pytest
 
 from benchmark.reference import joyai as reference
-from megatron_tpu.config import MODEL_PRESETS, ServingConfig
+from megatron_tpu.config import MODEL_PRESETS, ModelConfig, ServingConfig
 from megatron_tpu.inference import Generator
 from megatron_tpu.models import language_model as lm
+from megatron_tpu.models import mla
 from megatron_tpu.models.mla import LatentKVCache
 from megatron_tpu.serving import SamplingOptions, ServingEngine
 from megatron_tpu.serving.kv_pool import SlotKVPool, slot_nbytes
@@ -80,6 +81,50 @@ def test_engine_prefill_and_decode_match_reference(model, how):
     # the pool's own count, in the metrics' snapshot
     assert snap["kv_bytes_per_token"] == 4 * 40 * 4
     assert snap["kv_pool_bytes"] == 4 * 3 * 96 * 40 * 4
+
+
+@pytest.mark.parametrize("how, want", [
+    # 37 tokens by chunks of 16 over 96 positions, 4 MLA layers, blocks of 4
+    # queries and 8 keys: the chunk at 16 has query blocks ending at 19, 23,
+    # 27, 31 (3, 3, 4, 4 key blocks), the chunk at 32 (5 rows padded to 16)
+    # at 35, 39, 43, 47 (5, 5, 6, 6), of 12 a region
+    ("key_blocks", (4 * (14 + 22), 4 * 2 * 4 * 12)),
+    # the blocks as they are: a chunk of 16 rows is taken at once over the
+    # whole region, one key block (96 < 1,024), twice
+    ("taken_at_once", (4 * 2, 4 * 2)),
+    ("unchunked", (0, 0)),
+    ("no_latent_rows", (0, 0)),
+])
+def test_latent_chunk_blocks_counted_by_the_programs_rule(model, monkeypatch,
+                                                          how, want):
+    """`latent_chunk_blocks_read` / `_held`: what a continuation chunk's
+    absorbed attention reads of its sequence's region against the whole of
+    it, from `mla.absorbed_key_blocks`, the rule the program loops by."""
+    cfg, params = model
+    if how == "no_latent_rows":
+        cfg = ModelConfig(num_layers=2, hidden_size=64,
+                          num_attention_heads=4, vocab_size=96,
+                          seq_length=96, make_vocab_size_divisible_by=32,
+                          compute_dtype="float32").derived()
+        params = lm.model_init(jax.random.PRNGKey(0), cfg)
+    if how == "key_blocks":
+        monkeypatch.setattr(mla, "ABSORBED_Q_BLOCK", 4)
+        monkeypatch.setattr(mla, "ABSORBED_KEY_BLOCK", 8)
+    gen = Generator(params, cfg, eos_id=-1, pad_id=0,
+                    kv_cache_dtype=jnp.float32)
+    serving = dict(num_slots=2, max_queue=8, max_len=96, prefill_bucket=16,
+                   prefill_chunk=None if how == "unchunked" else 16)
+    prompt = np.random.default_rng(5).integers(1, 90, size=37).tolist()
+    with ServingEngine(gen, ServingConfig(**serving).validate(cfg)) as eng:
+        req, tokens, got = _logprobs(eng, prompt, 3)
+        snap = eng.metrics.snapshot()
+    assert req.prefill_chunks == (1 if how == "unchunked" else 3)
+    assert (snap["latent_chunk_blocks_read"],
+            snap["latent_chunk_blocks_held"]) == want
+    if cfg.mla:
+        ref = np.asarray(reference.token_logprobs(
+            params, jnp.asarray(tokens, jnp.int32), cfg), np.float64)[36:]
+        np.testing.assert_allclose(got, ref, rtol=0, atol=5e-4)
 
 
 @pytest.mark.parametrize("name, want", [

@@ -18,6 +18,7 @@ from megatron_tpu.inference import Generator
 from megatron_tpu.inference.generation import (SamplingParams, init_kv_caches,
                                                prefill_chunk)
 from megatron_tpu.models import language_model as lm
+from megatron_tpu.models import mla
 from megatron_tpu.models.attention import LatentStateCache
 from megatron_tpu.serving import SamplingOptions, ServingEngine, capabilities
 from megatron_tpu.serving.kv_pool import (SlotKVPool, insert_prefill,
@@ -105,6 +106,30 @@ def test_chunked_prefill_is_one_shot_prefill(model, chunk, chunks):
     assert [n for n, _ in seen] == [chunks, 1]
     assert np.abs(seen[0][1] - seen[1][1]).max() < TOL
     assert snap["prefill_chunks"] == 0          # the one-shot engine's
+
+
+@pytest.mark.parametrize("chunk,chunks", [(8, 3), (16, 2)])
+def test_chunked_prefill_in_key_blocks(model, monkeypatch, chunk, chunks):
+    """The same with the absorbed form's blocks cut small (PR 59): a
+    continuation chunk runs blocks of 4 queries, each over the key blocks
+    of 8 positions it can see of the 96 its sequence may hold, and the
+    engine counts them by the rule the program loops by."""
+    cfg, params = model
+    monkeypatch.setattr(mla, "ABSORBED_Q_BLOCK", 4)
+    monkeypatch.setattr(mla, "ABSORBED_KEY_BLOCK", 8)
+    prompt = np.random.default_rng(17).integers(1, cfg.vocab_size, 21).tolist()
+    with _engine(cfg, params, prefill_chunk=chunk) as eng:
+        req = eng.submit(prompt, 6, SamplingOptions(temperature=0.0), seed=1)
+        _check(req, params, cfg, 6)
+        assert req.prefill_chunks == chunks
+        snap = eng.metrics.snapshot()
+    # 2 MLA layers; chunks of 8 at 8 and 16: query blocks ending at 11, 15
+    # (2 key blocks each) and 19, 23 (3 each); a chunk of 5 padded to 8 at
+    # 16: 19, 23
+    assert snap["latent_chunk_blocks_read"] == \
+        2 * {8: 2 + 2 + 3 + 3, 16: 3 + 3}[chunk]
+    assert snap["latent_chunk_blocks_held"] == \
+        2 * {8: 4, 16: 2}[chunk] * (96 // 8)
 
 
 def test_prefill_through_the_flash_kernels_form():

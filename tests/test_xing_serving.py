@@ -18,6 +18,7 @@ import pytest
 from benchmark.reference import xing4 as reference
 from megatron_tpu.config import ServingConfig
 from megatron_tpu.inference import Generator
+from megatron_tpu.models import mla
 from megatron_tpu.models.mla import LatentKVCache
 from megatron_tpu.serving import SamplingOptions, ServingEngine
 from tests.test_xing import seeded, tiny
@@ -38,20 +39,32 @@ def _logprobs(eng, prompt, n_new):
 
 
 @pytest.mark.parametrize("how", ["plain", "chunked_prefill", "prefix_hit",
-                                 "speculative", "to_the_last_position"])
-def test_engine_prefill_and_decode_match_reference(model, how):
+                                 "speculative", "to_the_last_position",
+                                 "chunked_in_key_blocks",
+                                 "key_blocks_to_the_last_position"])
+def test_engine_prefill_and_decode_match_reference(model, how, monkeypatch):
     """A prompt prefilled in a padded bucket (37 tokens in 48: by chunks of
     16, the second and third continuing the sequence's own latent rows in the
     absorbed form), then decoded through the latent cache one token at a time
     beside an unrelated request. `to_the_last_position`: prompt + output =
-    `max_len`, which the cell's mix can reach (15,872 + 512 = 16,384)."""
+    `max_len`, which the cell's mix can reach (15,872 + 512 = 16,384).
+    `key_blocks`: the blocks cut small, so that a chunk of 16 rows runs four
+    blocks of queries, each over the key blocks of 8 positions it can see
+    (PR 59); to the last position, over a region of 49 that 8 does not
+    divide."""
     cfg, params = model
+    if "key_blocks" in how:
+        monkeypatch.setattr(mla, "ABSORBED_Q_BLOCK", 4)
+        monkeypatch.setattr(mla, "ABSORBED_KEY_BLOCK", 8)
     gen = Generator(params, cfg, eos_id=-1, pad_id=0,
                     kv_cache_dtype=jnp.float32)
     serving = dict(num_slots=3, max_queue=8, max_len=96, prefill_bucket=16)
     serving.update({"chunked_prefill": dict(prefill_chunk=16),
+                    "chunked_in_key_blocks": dict(prefill_chunk=16),
                     "to_the_last_position": dict(prefill_chunk=16,
                                                  max_len=49),
+                    "key_blocks_to_the_last_position": dict(
+                        prefill_chunk=16, max_len=49),
                     "prefix_hit": dict(enable_prefix_cache=True),
                     "speculative": dict(speculative_k=2)}.get(how, {}))
     rng = np.random.default_rng(5)
@@ -74,8 +87,11 @@ def test_engine_prefill_and_decode_match_reference(model, how):
         snap = eng.metrics.snapshot()
     if how == "prefix_hit":
         assert snap["prefix_hits"] >= 1 and req.prefix_len >= 16
-    if "chunked" in how or how == "to_the_last_position":
+    if "chunked" in how or "to_the_last_position" in how:
         assert snap["prefill_chunks"] >= 3 and req.prefill_chunks == 3
+        read, held = (snap["latent_chunk_blocks_read"],
+                      snap["latent_chunk_blocks_held"])
+        assert 0 < read < held if "key_blocks" in how else 0 < read == held
     if how == "speculative":
         assert snap["spec_rounds"] > 0 and snap["draft_tokens"] > 0
     assert len(got) == 12 and tokens[:37] == prompt
