@@ -40,7 +40,7 @@ def as_dtype(name: str):
 
 # the kinds of `ModelConfig.layer_types` that keep a state of fixed size a
 # sequence, and those whose layer is ONE sublayer (`ModelConfig.one_sublayer`)
-STATE_KINDS = ("conv", "mamba", "mamba2", "kda")
+STATE_KINDS = ("conv", "mamba", "mamba2", "kda", "linear_attention")
 ONE_SUBLAYER_KINDS = ("mamba2", "moe", "mlp")
 
 
@@ -92,7 +92,10 @@ class ModelConfig:
     use_position_embedding: bool = False
 
     # norms / activations / structure
-    norm_type: str = "rmsnorm"  # "rmsnorm" | "layernorm" | "layernorm_nobias"
+    # "rmsnorm" | "layernorm" | "layernorm_nobias" | "rmsnorm_1p" (an RMSNorm
+    # whose learned scale is ZERO-CENTRED, x / rms(x) * (1 + w), w drawn at
+    # 0: Qwen3-Next's, of every hidden-size norm and of `qk_head_norm`'s)
+    norm_type: str = "rmsnorm"
     norm_epsilon: float = 1e-5
     activation: str = "swiglu"  # swiglu|geglu|reglu|liglu|gelu|relu|squared_relu
     use_bias: bool = False  # bias on linear layers (ref: --use_bias)
@@ -292,6 +295,32 @@ class ModelConfig:
     kda_head_dim: int = 128
     kda_conv_kernel: int = 4
     kda_gate_rank: int = 128
+    # "linear_attention" in `layer_types` (the published word): a Gated
+    # DeltaNet mixer (models/gated_delta.py; a `qwen3_next` config's
+    # `linear_num_key_heads`, `linear_num_value_heads`, `linear_key_head_dim`,
+    # `linear_value_head_dim`, `linear_conv_kernel_dim`). `gdn_key_heads` key
+    # heads serve `gdn_value_heads` value heads (value head j reads key head
+    # j // (value heads / key heads)); the delta rule's decay is ONE number a
+    # value head a row; the state a matrix [key_head_dim, value_head_dim]
+    # float32 a value head a layer a sequence (`ConvKVCache.ssm`), behind
+    # ONE depthwise kernel of `gdn_conv_kernel` taps over q, k and v
+    # together. The attention layers beside it hold keys and values.
+    gdn_key_heads: int = 16
+    gdn_value_heads: int = 32
+    gdn_key_head_dim: int = 128
+    gdn_value_head_dim: int = 128
+    gdn_conv_kernel: int = 4
+    # the share of a head's channels that the rotary turns (the published
+    # `partial_rotary_factor`): the FIRST kv_channels x factor of them, the
+    # others are left as they are (models/rope.py::apply_rotary)
+    partial_rotary_factor: float = 1.0
+    # the attention's output gate (Qwen3-Next's): wq is [h, heads x 2 x
+    # kv_channels], a head's query and its gate side by side, and the
+    # attention's output is multiplied by sigmoid(gate) ahead of wo
+    attn_output_gate: bool = False
+    # the shared experts' own gate (the published `shared_expert_gate`): their
+    # output is multiplied by sigmoid(x w), w [h, 1], a number a token
+    moe_shared_expert_gate: bool = False
     # RMSNorm over each head's channels of q and of k, one scale
     # [kv_channels] shared by the heads, before the rotary (LFM2's
     # q_layernorm / k_layernorm). `qk_norm` above is OLMoE's, over all the
@@ -373,8 +402,8 @@ class ModelConfig:
     @property
     def state_kind(self) -> Optional[str]:
         """The kind of layer that keeps a state of fixed size a sequence:
-        "conv", "mamba", "mamba2", "kda" or None (a model has one: validate
-        refuses a cross)."""
+        "conv", "mamba", "mamba2", "kda", "linear_attention" or None (a model
+        has one: validate refuses a cross)."""
         return next((k for k in STATE_KINDS if self.layers_of(k)), None)
 
     @property
@@ -397,6 +426,18 @@ class ModelConfig:
         return self.kda_num_heads * self.kda_head_dim
 
     @property
+    def gdn_conv_channels(self) -> int:
+        """What a "linear_attention" layer's one depthwise kernel runs
+        over: q and k of the key heads and v of the value heads."""
+        return (2 * self.gdn_key_heads * self.gdn_key_head_dim
+                + self.gdn_value_heads * self.gdn_value_head_dim)
+
+    @property
+    def rotary_dim(self) -> int:
+        """The channels of a head that the rotary turns (the first ones)."""
+        return int(self.kv_channels * self.partial_rotary_factor)
+
+    @property
     def mamba2_conv_channels(self) -> int:
         """What a "mamba2" layer's one depthwise kernel runs over: x and
         every group's B and C."""
@@ -408,13 +449,16 @@ class ModelConfig:
         """(rows, channels) of the depthwise kernel's state a layer: its
         last taps - 1 inputs, over the hidden size ("conv"), over d_inner
         ("mamba"), over x, B and C together ("mamba2") or over q, k and v
-        together ("kda": three kernels side by side)."""
+        together ("kda": three kernels side by side; "linear_attention":
+        one kernel, the key heads' q and k and the value heads' v)."""
         if self.state_kind == "mamba":
             return self.mamba_d_conv - 1, self.mamba_d_inner
         if self.state_kind == "mamba2":
             return self.mamba_d_conv - 1, self.mamba2_conv_channels
         if self.state_kind == "kda":
             return self.kda_conv_kernel - 1, 3 * self.kda_d_inner
+        if self.state_kind == "linear_attention":
+            return self.gdn_conv_kernel - 1, self.gdn_conv_channels
         return self.conv_L_cache - 1, self.hidden_size
 
     @property
@@ -429,7 +473,9 @@ class ModelConfig:
         """The scan's float32 state a layer a sequence: [d_state, d_inner]
         ("mamba", the channels minor), [heads, head_dim, d_state]
         ("mamba2", a matrix a head), [heads, head_dim (k), head_dim (v)]
-        ("kda", a matrix a head); None where no layer has one."""
+        ("kda", a matrix a head), [value heads, key_head_dim, value_head_dim]
+        ("linear_attention", a matrix a value head); None where no layer has
+        one."""
         if self.state_kind == "mamba":
             return self.mamba_d_state, self.mamba_d_inner
         if self.state_kind == "mamba2":
@@ -437,6 +483,9 @@ class ModelConfig:
                     self.mamba_d_state)
         if self.state_kind == "kda":
             return (self.kda_num_heads, self.kda_head_dim, self.kda_head_dim)
+        if self.state_kind == "linear_attention":
+            return (self.gdn_value_heads, self.gdn_key_head_dim,
+                    self.gdn_value_head_dim)
         return None
 
     @property
@@ -1647,6 +1696,8 @@ class MegatronConfig:
             allowed = ({"mamba2", "full_attention", "moe"}
                        if model.one_sublayer
                        else {"kda", "full_attention"} if "kda" in kinds
+                       else {"linear_attention", "full_attention"}
+                       if "linear_attention" in kinds
                        else {"conv", "mamba", "full_attention"})
             assert "mlp" not in kinds, (
                 "layer_types 'mlp' (a layer that is a dense feed-forward "
@@ -1661,7 +1712,9 @@ class MegatronConfig:
                 f"{sorted(kinds)} for num_layers={model.num_layers}: one of "
                 "'conv' | 'mamba' | 'full_attention' a layer (a mixer and "
                 "then a feed-forward), 'kda' | 'full_attention' (the same, "
-                "the attention layers MLA), or one of 'mamba2' | "
+                "the attention layers MLA), 'linear_attention' | "
+                "'full_attention' (the same, over keys and values), or one "
+                "of 'mamba2' | "
                 "'full_attention' | 'moe' a layer (ONE sublayer each), "
                 "never both readings in one model")
             assert not {"conv", "mamba"} <= kinds, (
@@ -1727,6 +1780,32 @@ class MegatronConfig:
                     "'kda' layers are refused with hc_mult > 1: the "
                     "streams' maps have not been run round a delta rule's "
                     "mixer (ROADMAP R6)")
+            if "linear_attention" in kinds:
+                # models/gated_delta.py beside models/attention.py: a delta
+                # rule's matrix a value head beside keys and values
+                # (models/attention.py::ConvKVCache, as a "mamba" layer's)
+                assert not model.mla and "full_attention" in kinds, (
+                    "'linear_attention' layers stand beside attention "
+                    "layers over keys and values (no kv_lora_rank; a "
+                    "'full_attention' layer in the pattern): the cache's "
+                    "offsets are the attention layers' "
+                    "(models/attention.py::ConvKVCache); a delta rule "
+                    "beside MLA is 'kda' (models/kda.py)")
+                assert min(model.gdn_key_heads, model.gdn_key_head_dim,
+                           model.gdn_value_head_dim) >= 1 \
+                    and model.gdn_value_heads % model.gdn_key_heads == 0 \
+                    and model.gdn_conv_kernel >= 2, (
+                    f"'linear_attention' layers need gdn_value_heads="
+                    f"{model.gdn_value_heads} a multiple of gdn_key_heads="
+                    f"{model.gdn_key_heads} (a key head serves its value "
+                    "heads), gdn_key_head_dim and gdn_value_head_dim >= 1 "
+                    "and gdn_conv_kernel >= 2")
+                assert model.hc_mult == 1 \
+                    and not model.first_k_dense_replace, (
+                    "'linear_attention' layers are refused with hc_mult > 1 "
+                    "(the streams' maps have not been run round a delta "
+                    "rule's mixer) and first_k_dense_replace (they have not "
+                    "been run behind a leading dense stack) (ROADMAP R6)")
             assert (not model.mla or "kda" in kinds) \
                 and not model.mtp_num_layers \
                 and model.sliding_window is None \
@@ -1734,9 +1813,10 @@ class MegatronConfig:
                 and not model.use_bias, (
                 "layer_types is refused with MLA (kv_lora_rank) but for "
                 "the pattern 'kda' | 'full_attention' (the other state "
-                "kinds, 'conv' | 'mamba' | 'mamba2', hold keys and values "
-                "beside their state and no latent row; hc_mult > 1 and any "
-                "mesh stay refused for 'kda' too), mtp_num_layers, "
+                "kinds, 'conv' | 'mamba' | 'mamba2' | 'linear_attention', "
+                "hold keys and values beside their state and no latent row; "
+                "hc_mult > 1 and any mesh stay refused for both delta "
+                "rules), mtp_num_layers, "
                 "sliding_window, parallel_attn, use_post_ln and use_bias: "
                 "the pattern's layers are pre-norm, one mixer then one "
                 "feed-forward (or ONE sublayer each: 'mamba2' | 'moe'), "
@@ -1753,6 +1833,32 @@ class MegatronConfig:
                 "layer_types is refused with context-parallel "
                 "attention_impl (ring / ulysses), attention_dropout and "
                 "drop_path_rate")
+        assert model.norm_type in ("rmsnorm", "layernorm", "layernorm_nobias",
+                                   "rmsnorm_1p"), (
+            f"norm_type={model.norm_type!r} (expected 'rmsnorm', "
+            "'layernorm', 'layernorm_nobias' or 'rmsnorm_1p')")
+        assert 0.0 < model.partial_rotary_factor <= 1.0 \
+            and model.rotary_dim % 2 == 0 and model.rotary_dim >= 2, (
+            f"partial_rotary_factor={model.partial_rotary_factor}: the share "
+            f"of a head's kv_channels={model.kv_channels} that the rotary "
+            "turns, a whole number of pairs of them")
+        assert model.partial_rotary_factor == 1.0 or model.use_rotary_emb, (
+            "partial_rotary_factor is the rotary's (use_rotary_emb)")
+        if model.partial_rotary_factor != 1.0 or model.attn_output_gate:
+            assert not model.mla and not model.window_layer_period \
+                and model.rope_scaling_type == "linear" \
+                and max(sharded.values()) == 1, (
+                "partial_rotary_factor < 1 and attn_output_gate are "
+                "models/attention.py's on one device: MLA has rotary "
+                "channels of its own, a stack of window and full layers "
+                "(window_layer_period) and YaRN's tables have not been run "
+                "with either, and the gate's columns of wq have no head "
+                f"shard (got {sharded})")
+        if model.moe_shared_expert_gate:
+            assert model.n_shared_experts \
+                and model.moe_dispatch == "dropless", (
+                "moe_shared_expert_gate gates n_shared_experts >= 1 shared "
+                "experts on the dropless path (--moe_dispatch dropless)")
         if model.qk_head_norm:
             assert not model.qk_norm and not model.mla, (
                 "qk_head_norm (a norm a head) and qk_norm (one over all "
@@ -2499,6 +2605,69 @@ def kimi_linear_config(size: str = "48b-a3b", **overrides) -> ModelConfig:
     return ModelConfig(**base).derived()
 
 
+def qwen3_next_layer_types(num_layers: int, interval: int = 4
+                           ) -> Tuple[str, ...]:
+    """The mixers of a `qwen3_next` stack from its published
+    `full_attention_interval`: layer i (0-indexed) is attention where (i +
+    1) % interval == 0 and a Gated DeltaNet mixer elsewhere."""
+    return tuple("full_attention" if (i + 1) % interval == 0
+                 else "linear_attention" for i in range(num_layers))
+
+
+def qwen3_next_config(size: str = "80b-a3b", **overrides) -> ModelConfig:
+    """Qwen3-Next presets: every size of "80b-a3b" is a key of
+    Qwen/Qwen3-Next-80B-A3B-Instruct's config.json (`qwen3_next`: 48 layers,
+    hidden 2048, `full_attention_interval` 4: 36 Gated DeltaNet layers (16
+    key heads under 32 value heads of 128 channels, one depthwise kernel of
+    4 taps, arXiv:2412.06464) and 12 attention layers (16 heads over 2 kv
+    heads of 256 channels, `partial_rotary_factor` 0.25 at theta 1e7, a
+    zero-centred RMSNorm a head on q and k, an output gate); every
+    hidden-size norm zero-centred, eps 1e-6; every layer 512 SiLU-gated
+    experts of width 512, 10 a token by a softmax router with the gates
+    renormalised, beside ONE shared expert of width 512 under a gate of its
+    own; vocabulary 151,936, untied head; 262,144 positions). The published
+    multi-token-prediction module has no key in the config and is not
+    built. Held in bfloat16. Dropless. A cut of the depth keeps the
+    interval."""
+    presets = {
+        "tiny": dict(num_layers=8, hidden_size=64, num_attention_heads=4,
+                     num_kv_heads=2, kv_channels=16, ffn_hidden_size=32,
+                     moe_shared_expert_ffn=32, vocab_size=512, seq_length=128,
+                     num_experts=8, moe_top_k=2, gdn_key_heads=2,
+                     gdn_value_heads=4, gdn_key_head_dim=16,
+                     gdn_value_head_dim=16, attention_impl="dot"),
+        "80b-a3b": dict(num_layers=48, hidden_size=2048,
+                        num_attention_heads=16, num_kv_heads=2,
+                        kv_channels=256, ffn_hidden_size=512,
+                        moe_shared_expert_ffn=512, vocab_size=151936,
+                        seq_length=4096, max_position_embeddings=262144,
+                        num_experts=512, moe_top_k=10, gdn_key_heads=16,
+                        gdn_value_heads=32, gdn_key_head_dim=128,
+                        gdn_value_head_dim=128, params_dtype="bfloat16"),
+    }
+    if size not in presets:
+        raise ValueError(f"unknown qwen3_next size {size!r}; "
+                         f"valid: {sorted(presets)}")
+    base = dict(
+        use_rotary_emb=True, use_position_embedding=False, rope_theta=1e7,
+        partial_rotary_factor=0.25, attn_output_gate=True,
+        qk_head_norm=True, norm_type="rmsnorm_1p", norm_epsilon=1e-6,
+        activation="swiglu", use_bias=False, use_post_ln=False,
+        parallel_attn=False, tie_embed_logits=False, gdn_conv_kernel=4,
+        n_shared_experts=1, moe_shared_expert_gate=True,
+        moe_scoring_func="softmax", moe_norm_topk_prob=True,
+        moe_dispatch="dropless", moe_aux_loss_coeff=0.0,
+        attention_impl="flash",  # see llama2_config
+    )
+    base.update(presets[size])
+    # the router scores every published expert, held here or not
+    base["moe_router_experts"] = base["num_experts"]
+    base.update(overrides)
+    base.setdefault("layer_types",
+                    qwen3_next_layer_types(base["num_layers"]))
+    return ModelConfig(**base).derived()
+
+
 def gpt_config(**overrides) -> ModelConfig:
     base = dict(
         num_layers=12, hidden_size=768, num_attention_heads=12,
@@ -2536,5 +2705,7 @@ MODEL_PRESETS = {
     "nemotron-3-super": lambda: nemotron_h_config("3-super"),
     "kimi-linear-tiny": lambda: kimi_linear_config("tiny"),
     "kimi-linear": lambda: kimi_linear_config("48b-a3b"),
+    "qwen3-next-tiny": lambda: qwen3_next_config("tiny"),
+    "qwen3-next": lambda: qwen3_next_config("80b-a3b"),
     "gpt2": gpt_config,
 }

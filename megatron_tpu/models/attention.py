@@ -37,7 +37,8 @@ import jax
 import jax.numpy as jnp
 
 from megatron_tpu.config import ModelConfig
-from megatron_tpu.models.norms import rmsnorm, rmsnorm_init
+from megatron_tpu.models.norms import (apply_norm, norm_init, rmsnorm,
+                                       rmsnorm_init)
 from megatron_tpu.models.rope import apply_rotary
 from megatron_tpu.ops.dropout import dropout
 from megatron_tpu.ops.quantized import W8, qdense, wcast
@@ -318,6 +319,9 @@ class LatentStateCache(NamedTuple):
             live_rows=jnp.int32(ConvKVCache.NO_PADDING))
 
 
+LANES = 128     # the channels of one lane tile
+
+
 def _layer_of(a, layer):
     """Layer `layer` (a traced scalar) of an array stacked over layers."""
     return jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
@@ -590,7 +594,9 @@ def _folded_update_attend(q, k, v, cache: ConvKVCache, layer,
     chunk at the scalar offset. A prefill at offset 0 under
     `attention_impl="flash"` attends its own fresh k and v through the flash
     kernel, as `KVCache`'s does (`lax.cond` on the offset: a continuation
-    chunk takes the products over the region)."""
+    chunk takes the products over the region). ONE kv head, or several of
+    whole lane tiles each, are read by the flash kernel out of the folded
+    rows at whatever offset the chunk has."""
     b, s, nq, hd = q.shape
     nkv = k.shape[2]
     width, group = nkv * hd, nq // nkv
@@ -651,6 +657,16 @@ def _folded_update_attend(q, k, v, cache: ConvKVCache, layer,
                 _layer_of(new_v, layer)[:, None].astype(dtype),
                 causal=True, scale=scale, q_offset=offset,
                 kv_heads_major=True)
+        elif hd % LANES == 0:
+            # SEVERAL kv heads of whole lane tiles: kv head g's channels of
+            # the folded row are a block the kernel can index, so the pool
+            # is read where it lies, at the chunk's offset, and no [heads,
+            # s, max_seq] scores are made (16 x 4,096 x 32,768 float32 =
+            # 8.6 GB at Qwen3-Next's widths)
+            out = flash_attention(
+                q, _layer_of(new_k, layer).astype(dtype),
+                _layer_of(new_v, layer).astype(dtype),
+                causal=True, scale=scale, q_offset=offset, kv_folded=nkv)
         else:
             out = jax.lax.cond(
                 offset == 0,
@@ -662,7 +678,9 @@ def _folded_update_attend(q, k, v, cache: ConvKVCache, layer,
 
 
 def attention_init(rng, cfg: ModelConfig, dtype=jnp.float32):
-    """Params: wq [h, nq*hd], wkv [h, 2*nkv*hd], wo [nq*hd, h]."""
+    """Params: wq [h, nq*hd] (`cfg.attn_output_gate`: [h, nq*2*hd], a
+    head's query and its gate side by side), wkv [h, 2*nkv*hd], wo [nq*hd,
+    h]."""
     h = cfg.hidden_size
     hd = cfg.kv_channels
     nq = cfg.num_attention_heads
@@ -670,8 +688,9 @@ def attention_init(rng, cfg: ModelConfig, dtype=jnp.float32):
     k1, k2, k3 = jax.random.split(rng, 3)
     std = cfg.init_method_std
     out_std = std / math.sqrt(2.0 * cfg.num_layers) if cfg.use_scaled_init else std
+    q_cols = nq * hd * (2 if cfg.attn_output_gate else 1)
     params = {
-        "wq": jax.random.normal(k1, (h, nq * hd), dtype) * std,
+        "wq": jax.random.normal(k1, (h, q_cols), dtype) * std,
         "wkv": jax.random.normal(k2, (h, 2 * nkv * hd), dtype) * std,
         "wo": jax.random.normal(k3, (nq * hd, h), dtype) * out_std,
     }
@@ -683,8 +702,9 @@ def attention_init(rng, cfg: ModelConfig, dtype=jnp.float32):
         params["q_norm"] = rmsnorm_init(nq * hd, dtype)
         params["k_norm"] = rmsnorm_init(nkv * hd, dtype)
     if cfg.qk_head_norm:
-        params["q_norm"] = rmsnorm_init(hd, dtype)
-        params["k_norm"] = rmsnorm_init(hd, dtype)
+        # a zero-centred scale where the model's norms are ("rmsnorm_1p")
+        params["q_norm"] = norm_init(_head_norm_type(cfg), hd, dtype)
+        params["k_norm"] = norm_init(_head_norm_type(cfg), hd, dtype)
     return params
 
 
@@ -720,13 +740,28 @@ def qk_norm(params, q, k, eps: float):
         return whole(params["q_norm"], q), whole(params["k_norm"], k)
 
 
-def qk_head_norm(params, q, k, eps: float):
+def _head_norm_type(cfg: ModelConfig) -> str:
+    return "rmsnorm_1p" if cfg.norm_type == "rmsnorm_1p" else "rmsnorm"
+
+
+def qk_head_norm(params, q, k, eps: float, norm_type: str = "rmsnorm"):
     """LFM2's q_layernorm / k_layernorm: RMSNorm over EACH head's channels,
     one scale [head_dim] shared by the heads, before the rotary. q: [b, s,
-    nq, hd], k: [b, t, nkv, hd]."""
+    nq, hd], k: [b, t, nkv, hd]. `norm_type` "rmsnorm_1p": the scale is
+    zero-centred, 1 + w (Qwen3-Next's q_norm / k_norm)."""
     with jax.named_scope("mtpu/attn/head_norm"):
-        return (rmsnorm(params["q_norm"], q, eps),
-                rmsnorm(params["k_norm"], k, eps))
+        return (apply_norm(norm_type, params["q_norm"], q, eps),
+                apply_norm(norm_type, params["k_norm"], k, eps))
+
+
+def _gated(out, gate):
+    """The attention's output gate (`cfg.attn_output_gate`): out [b, s, nq *
+    hd] times sigmoid of the gate the query's projection carried."""
+    if gate is None:
+        return out
+    with jax.named_scope("mtpu/attn/gate"):
+        return (out.astype(jnp.float32)
+                * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(out.dtype)
 
 
 def _dot_attention(q, k, v, *, causal: bool, softmax_fp32: bool,
@@ -905,6 +940,14 @@ def attention_apply(
         # rope): (W + A·B) @ x semantics, the merged-weights oracle the
         # exactness tests pin against
         q = q + _lora(x, lw.aq, lw.bq)
+    gate = None
+    if cfg.attn_output_gate:
+        assert lw is None and not cross and not isinstance(
+            kv_cache, (HybridKVCache, BlockKVCache)), (
+            "attn_output_gate serves causal self-attention over whole "
+            "regions, without adapters (config.validate)")
+        q = q.reshape(b, s, nq, 2 * hd)
+        q, gate = q[..., :hd], q[..., hd:].reshape(b, s, nq * hd)
     q = q.reshape(b, s, nq, hd)
     kv = kv.reshape(b, kv.shape[1], 2, nkv, hd)
     k, v = kv[:, :, 0], kv[:, :, 1]
@@ -943,7 +986,8 @@ def attention_apply(
         q, k = qk_norm(params, q, k, cfg.norm_epsilon)
     if cfg.qk_head_norm:
         assert not cross, "qk_head_norm is self-attention's (LFM2)"
-        q, k = qk_head_norm(params, q, k, cfg.norm_epsilon)
+        q, k = qk_head_norm(params, q, k, cfg.norm_epsilon,
+                            _head_norm_type(cfg))
 
     if isinstance(kv_cache, HybridKVCache) and s == 1:
         # a decode step's q and k stay the projections' outputs: left free,
@@ -992,7 +1036,7 @@ def attention_apply(
             out, kv_cache = _folded_update_attend(
                 q, k, v, kv_cache, cache_layer, cfg,
                 scale=1.0 / math.sqrt(hd))
-        out = out.reshape(b, s, nq * hd)
+        out = _gated(out.reshape(b, s, nq * hd), gate)
         return _project(out, params["wo"], cfg,
                         read_once=read_once), kv_cache
     if isinstance(kv_cache, BlockKVCache):
@@ -1297,7 +1341,7 @@ def attention_apply(
             sliding_window=cfg.sliding_window,
             kv_positions=kv_positions)
 
-    out = out.reshape(b, s, nq * hd)
+    out = _gated(out.reshape(b, s, nq * hd), gate)
     proj = _project(out, params["wo"], cfg, read_once=read_once)
     if lw is not None:
         proj = proj + _lora(out, lw.ao, lw.bo)

@@ -147,7 +147,7 @@ def make_rope(cfg: ModelConfig, max_len: Optional[int] = None) -> Optional[RopeT
             cfg.rope_beta_fast, cfg.rope_beta_slow, cfg.rope_mscale,
             cfg.rope_mscale_all_dim))
     cos, sin = precompute_freqs(
-        cfg.kv_channels, max_len, theta=cfg.rope_theta,
+        cfg.rotary_dim, max_len, theta=cfg.rope_theta,
         scaling_factor=cfg.rope_scaling_factor)
     return RopeTables(cos, sin)
 

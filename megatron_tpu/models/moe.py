@@ -147,6 +147,9 @@ def moe_init(rng, cfg: ModelConfig, dtype=jnp.float32):
     if cfg.n_shared_experts:
         params["shared"] = mlp_init(jax.random.fold_in(rng, 3),
                                     _shared_cfg(cfg), dtype)
+    if cfg.moe_shared_expert_gate:
+        params["shared_gate"] = jax.random.normal(
+            jax.random.fold_in(rng, 7), (h, 1), dtype) * std
     return params
 
 
@@ -170,6 +173,8 @@ def moe_axes(cfg: ModelConfig):
         axes["e_score_correction_bias"] = (None,)
     if cfg.n_shared_experts:
         axes["shared"] = mlp_axes(_shared_cfg(cfg))
+    if cfg.moe_shared_expert_gate:
+        axes["shared_gate"] = ("embed", None)
     return axes
 
 
@@ -372,6 +377,15 @@ def moe_apply(params, x, cfg: ModelConfig, *, bank_layer=None):
                                    read_once=read_once)
                 if cfg.moe_shared_combination == "average":
                     shared = shared / cfg.n_shared_experts
+                if cfg.moe_shared_expert_gate:
+                    # a number a token, sigmoid(x . w): a float32 sum on the
+                    # vector unit (a product of ONE column is no matrix
+                    # unit's work, whatever the default precision)
+                    gate = jax.nn.sigmoid(jnp.sum(
+                        x.astype(jnp.float32)
+                        * params["shared_gate"].astype(jnp.float32)[:, 0],
+                        axis=-1, keepdims=True))
+                    shared = (shared.astype(jnp.float32) * gate).astype(dtype)
                 y = y + shared
         return y, aux
 

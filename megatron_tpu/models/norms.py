@@ -36,6 +36,16 @@ def rmsnorm(params, x, eps: float = 1e-5):
     return xf.astype(dtype) * params["scale"].astype(dtype)
 
 
+def rmsnorm_1p(params, x, eps: float = 1e-6):
+    """RMSNorm whose learned scale is ZERO-CENTRED (Qwen3-Next's, Gemma's):
+    x / rms(x) * (1 + w), w held around 0. The statistics AND the scale in
+    float32, then the cast, as the public modelling code has it."""
+    xf = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    xf = xf * lax.rsqrt(var + eps)
+    return (xf * (1.0 + params["scale"].astype(jnp.float32))).astype(x.dtype)
+
+
 def layernorm_init(hidden_size: int, dtype=jnp.float32):
     return {
         "scale": jnp.ones((hidden_size,), dtype=dtype),
@@ -70,6 +80,8 @@ def layernorm_nobias(params, x, eps: float = 1e-5):
 def norm_init(norm_type: str, hidden_size: int, dtype=jnp.float32):
     if norm_type in ("rmsnorm", "layernorm_nobias"):
         return rmsnorm_init(hidden_size, dtype)  # a scale alone
+    elif norm_type == "rmsnorm_1p":
+        return {"scale": jnp.zeros((hidden_size,), dtype=dtype)}
     elif norm_type == "layernorm":
         return layernorm_init(hidden_size, dtype)
     raise ValueError(norm_type)
@@ -87,4 +99,6 @@ def apply_norm(norm_type: str, params, x, eps: float = 1e-5):
         return layernorm(params, x, eps)
     elif norm_type == "layernorm_nobias":
         return layernorm_nobias(params, x, eps)
+    elif norm_type == "rmsnorm_1p":
+        return rmsnorm_1p(params, x, eps)
     raise ValueError(norm_type)

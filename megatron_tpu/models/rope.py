@@ -102,7 +102,17 @@ def apply_rotary(x, cos, sin, position_ids=None):
 
     Supports non-monotonic `position_ids` [batch, seq] the same way the
     reference indexes freqs_cis by position_ids
-    (ref: positional_embeddings.py:34-43)."""
+    (ref: positional_embeddings.py:34-43).
+
+    Tables narrower than head_dim / 2 (`cfg.partial_rotary_factor` < 1:
+    `make_rope` builds them for `cfg.rotary_dim`) turn the FIRST 2 x their
+    width of a head's channels and leave the others as they are."""
+    turned = 2 * cos.shape[-1]
+    if turned < x.shape[-1]:
+        with jax.named_scope("mtpu/rope/partial"):
+            return jnp.concatenate(
+                [apply_rotary(x[..., :turned], cos, sin, position_ids),
+                 x[..., turned:]], axis=-1)
     b, s, n, d = x.shape
     if position_ids is None:
         c = cos[:s][None, :, None, :]  # [1, s, 1, d/2]

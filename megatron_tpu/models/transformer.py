@@ -28,7 +28,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from megatron_tpu.config import ModelConfig
+from megatron_tpu.config import STATE_KINDS, ModelConfig
 from megatron_tpu.models.attention import attention_apply, attention_axes, attention_init
 from megatron_tpu.models.mlp import mlp_apply, mlp_axes, mlp_init
 from megatron_tpu.models.norms import apply_norm, norm_axes, norm_init
@@ -50,7 +50,8 @@ RESIDUAL_AXES = ("batch", "seq_sp", "act_embed")
 def layer_init(rng, cfg: ModelConfig, dtype=jnp.float32,
                cross_attn: bool = False, mixer: str = "full_attention"):
     """`mixer` "kda": a Kimi Delta Attention mixer (`params["kda"]`,
-    models/kda.py), "conv": a gated short convolution (`params["conv"]`,
+    models/kda.py), "linear_attention": a Gated DeltaNet mixer
+    (`params["gdn"]`, models/gated_delta.py), "conv": a gated short convolution (`params["conv"]`,
     models/short_conv.py), "mamba": a selective state-space mixer
     (`params["mamba"]`, models/mamba.py), where the others have
     `params["attention"]`.
@@ -86,6 +87,9 @@ def layer_init(rng, cfg: ModelConfig, dtype=jnp.float32,
     elif mixer == "kda":
         from megatron_tpu.models.kda import kda_init
         params = {"kda": kda_init(k_attn, cfg, dtype)}
+    elif mixer == "linear_attention":
+        from megatron_tpu.models.gated_delta import gdn_init
+        params = {"gdn": gdn_init(k_attn, cfg, dtype)}
     elif cfg.mla:
         from megatron_tpu.models.mla import mla_init
         params = {"attention": mla_init(k_attn, cfg, dtype)}
@@ -147,6 +151,9 @@ def layer_axes(cfg: ModelConfig, cross_attn: bool = False,
     elif mixer == "kda":
         from megatron_tpu.models.kda import kda_axes
         axes = {"kda": kda_axes(cfg)}
+    elif mixer == "linear_attention":
+        from megatron_tpu.models.gated_delta import gdn_axes
+        axes = {"gdn": gdn_axes(cfg)}
     elif cfg.mla:
         from megatron_tpu.models.mla import mla_axes
         axes = {"attention": mla_axes(cfg)}
@@ -264,7 +271,7 @@ def layer_apply(
 
     def _mixer_branch(ln_out, kv_cache):
         """The layer's mixer on its normed input: (out, the cache)."""
-        if mixer in ("conv", "mamba", "mamba2", "kda"):
+        if mixer in STATE_KINDS:
             assert causal and encoder_output is None and adapters is None \
                 and segment_ids is None and not cp_pre_zigzag, (
                 "a convolution or state-space layer is causal, unsharded, "
@@ -273,6 +280,11 @@ def layer_apply(
                 from megatron_tpu.models.kda import kda_apply
                 return kda_apply(
                     params["kda"], ln_out, cfg, kv_cache=kv_cache,
+                    kind_layer=kind_layer)
+            if mixer == "linear_attention":
+                from megatron_tpu.models.gated_delta import gdn_apply
+                return gdn_apply(
+                    params["gdn"], ln_out, cfg, kv_cache=kv_cache,
                     kind_layer=kind_layer)
             if mixer == "mamba2":
                 from megatron_tpu.models.mamba2 import mamba2_apply

@@ -35,7 +35,7 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
                     segment_ids=None, sliding_window: int | None = None,
                     dropout_rate: float = 0.0, dropout_rng=None,
                     q_offset=None, kv_start=None,
-                    kv_heads_major: bool = False):
+                    kv_heads_major: bool = False, kv_folded: int = 0):
     """Blockwise attention with online softmax. Returns [b, sq, nq, d].
 
     `q_offset` (a traced scalar; None: 0) puts query row i at position
@@ -44,6 +44,8 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
     (models/attention.py::HybridKVCache). Causal, forward only, no
     segments, no dropout, no mesh. `kv_heads_major`: k and v come [b, nkv,
     skv, d], as that cache holds them and as the kernel reads them.
+    `kv_folded` = nkv > 0: k and v come [b, skv, nkv * d], a position's row
+    the kv heads' channels side by side (`ConvKVCache`), d whole lane tiles.
 
     `segment_ids` [b, s] (shared q/k length) masks attention across
     EOD-separated documents (ref: --reset_attention_mask) — the flash
@@ -67,7 +69,8 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
     independent per (batch row, head), so no collective is needed."""
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
-    assert not kv_heads_major or q_offset is not None
+    assert not (kv_heads_major or kv_folded) or q_offset is not None
+    assert not (kv_heads_major and kv_folded)
     if use_pallas and (q.shape[1] % 128 != 0
                        or k.shape[2 if kv_heads_major else 1] % 128 != 0):
         # kernel blocks need 128-divisible sequence lengths; odd shapes take
@@ -90,7 +93,8 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
             q, k, v, jnp.asarray(q_offset, jnp.int32),
             jnp.asarray(0 if kv_start is None else kv_start, jnp.int32),
             scale=scale, block_kv=block_kv, use_pallas=use_pallas,
-            sliding_window=sliding_window, kv_heads_major=kv_heads_major)
+            sliding_window=sliding_window, kv_heads_major=kv_heads_major,
+            kv_folded=kv_folded)
     static = dict(causal=causal, scale=scale, block_kv=block_kv,
                   use_pallas=use_pallas, sliding_window=sliding_window,
                   dropout_rate=dropout_rate)
@@ -183,9 +187,11 @@ def _flash_attention(q, k, v, segment_ids, dropout_rng, *, causal, scale,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "scale", "block_kv", "use_pallas", "sliding_window", "kv_heads_major"))
+    "scale", "block_kv", "use_pallas", "sliding_window", "kv_heads_major",
+    "kv_folded"))
 def _flash_attention_offset(q, k, v, q_offset, kv_start, *, scale, block_kv,
-                            use_pallas, sliding_window, kv_heads_major):
+                            use_pallas, sliding_window, kv_heads_major,
+                            kv_folded=0):
     """The chunk form (`flash_attention(q_offset=...)`); jitted under its
     own name so that the device trace names the kernel after it."""
     if use_pallas:
@@ -193,7 +199,10 @@ def _flash_attention_offset(q, k, v, q_offset, kv_start, *, scale, block_kv,
             pallas_flash_attention_offset
         return pallas_flash_attention_offset(
             q, k, v, q_offset, kv_start, scale=scale,
-            sliding_window=sliding_window, kv_heads_major=kv_heads_major)
+            sliding_window=sliding_window, kv_heads_major=kv_heads_major,
+            kv_folded=kv_folded)
+    if kv_folded:
+        k, v = (t.reshape(*t.shape[:2], kv_folded, -1) for t in (k, v))
     if kv_heads_major:
         k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
     return _blockwise_attention(q, k, v, causal=True, scale=scale,

@@ -130,7 +130,8 @@ def _dot(a, b, a_dim, b_dim):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_q,
                 block_kv, num_kv, has_segs=False, window=None,
-                dropout_rate=0.0, q_off=None, kv_start=None):
+                dropout_rate=0.0, q_off=None, kv_start=None,
+                kv_folded=False):
     # q_off / kv_start (traced scalars, `_fwd_kernel_offset` alone): query
     # row i stands at position q_off + i of the keys' own numbering, and
     # keys before kv_start hold nothing
@@ -181,8 +182,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, block_q,
     @pl.when(run)
     def _body():
         q = q_ref[0, 0]                                  # [bq, d]
-        k = k_ref[0, 0]                                  # [bkv, d]
-        v = v_ref[0, 0]
+        if kv_folded:   # blocks [1, bkv, d] of rows [b, sk, nkv * d]
+            k, v = k_ref[0], v_ref[0]
+        else:
+            k = k_ref[0, 0]                              # [bkv, d]
+            v = v_ref[0, 0]
         s = _dot(q, k, 1, 1) * scale
         if causal:
             q_pos = q_first + jax.lax.broadcasted_iota(
@@ -545,11 +549,15 @@ def pallas_flash_attention_offset(q, k, v, q_offset, kv_start=0, *,
                                   block_q=DEFAULT_BLOCK_Q,
                                   block_kv=DEFAULT_BLOCK_KV,
                                   interpret=False,
-                                  kv_heads_major: bool = False):
+                                  kv_heads_major: bool = False,
+                                  kv_folded: int = 0):
     """Causal attention of a CHUNK of queries against keys that begin before
     it: q [b, sq, nq, d] (row i at position `q_offset` + i of the keys'
     numbering), k/v [b, sk, nkv, d] (or, `kv_heads_major`, [b, nkv, sk, d]:
-    the order the kernel reads, so a cache held that way is not transposed)
+    the order the kernel reads, so a cache held that way is not transposed;
+    or, `kv_folded` = nkv, [b, sk, nkv * d]: a position's row holds the kv
+    heads' channels side by side, d whole lane tiles, and kv head g's
+    channels are the g-th block of d along the row, read where they lie)
     with sk >= q_offset + sq -> [b, sq, nq, d]. `q_offset` and `kv_start` are traced scalars (one compiled program
     serves every offset); keys before `kv_start` hold nothing and are masked.
     What a serving prefill that continues a cache needs (models/attention.py:
@@ -561,9 +569,13 @@ def pallas_flash_attention_offset(q, k, v, q_offset, kv_start=0, *,
     `kv_start` are skipped as the aligned kernel skips them, and their DMA
     too (`_kv_block_index`)."""
     b, sq, nq, d = q.shape
-    if not kv_heads_major:
-        k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
-    nkv, sk = k.shape[1], k.shape[2]
+    if kv_folded:
+        assert d % 128 == 0 and k.shape[2] == kv_folded * d, (k.shape, d)
+        nkv, sk = kv_folded, k.shape[1]
+    else:
+        if not kv_heads_major:
+            k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+        nkv, sk = k.shape[1], k.shape[2]
     g = nq // nkv
     if scale is None:
         scale = d ** -0.5
@@ -581,12 +593,17 @@ def pallas_flash_attention_offset(q, k, v, q_offset, kv_start=0, *,
     kv_spec = pl.BlockSpec(
         (1, 1, bkv, d),
         lambda bi, h, qi, ki, off: (bi, h // g, kv_block(qi, ki, off), 0))
+    if kv_folded:
+        kv_spec = pl.BlockSpec(
+            (1, bkv, d),
+            lambda bi, h, qi, ki, off: (bi, kv_block(qi, ki, off), h // g))
     lse_spec = pl.BlockSpec((1, 1, bq, STAT_LANES),
                             lambda bi, h, qi, ki, off: (bi, h, qi, 0))
     out, _ = pl.pallas_call(
         functools.partial(_fwd_kernel_offset, scale=scale, causal=True,
                           block_q=bq, block_kv=bkv, num_kv=num_kv,
-                          window=sliding_window),
+                          window=sliding_window,
+                          kv_folded=bool(kv_folded)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(b, nq, num_q, num_kv),
             in_specs=[q_spec, kv_spec, kv_spec],

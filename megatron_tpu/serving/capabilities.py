@@ -121,8 +121,8 @@ ROWS: Dict[str, Tuple[str, str, str]] = {
     "latent": ("MLA (kv_lora_rank set)", " on the latent pool",
                " (ROADMAP R5)"),
     "conv-state": (
-        "layer_types with a state of fixed size a slot, mamba, mamba2 or conv "
-        "layers",
+        "layer_types with a state of fixed size a slot, mamba, mamba2, "
+        "linear_attention or conv layers",
         " on the pool of keys, values and a state of fixed size",
         " (ROADMAP R6)"),
     "latent+state": (

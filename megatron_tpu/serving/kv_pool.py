@@ -508,8 +508,8 @@ class SlotKVPool:
     @property
     def conv_layers(self) -> int:
         """Layers that keep a state of fixed size a slot and no keys or
-        values: a convolution's last inputs and, in a "mamba", "mamba2" or
-        "kda" layer, the scan's state beside them (`cfg.layer_types`;
+        values: a convolution's last inputs and, in a "mamba", "mamba2",
+        "kda" or "linear_attention" layer, the scan's state beside them (`cfg.layer_types`;
         models/attention.py::ConvKVCache, LatentStateCache)."""
         return self.cfg.state_layers
 
@@ -1002,6 +1002,12 @@ class SlotKVPool:
         float32 a layer a slot (0 where the pool has none)."""
         return self._scan_state_nbytes("kda")
 
+    def gdn_state_nbytes(self) -> int:
+        """Bytes of a Gated DeltaNet rule's state, [value heads,
+        key_head_dim, value_head_dim] float32 a layer a slot (0 where the
+        pool has none)."""
+        return self._scan_state_nbytes("linear_attention")
+
     def ring_nbytes(self) -> int:
         """Bytes of the window layers' rings (0 where the pool has none)."""
         if not self.hybrid:
@@ -1014,7 +1020,7 @@ class SlotKVPool:
         if not self.hybrid:
             return (self.nbytes() - self.conv_state_nbytes()
                     - self.ssm_state_nbytes() - self.ssd_state_nbytes()
-                    - self.kda_state_nbytes())
+                    - self.kda_state_nbytes() - self.gdn_state_nbytes())
         return self.caches.full_k.nbytes + self.caches.full_v.nbytes
 
     def bytes_per_slot(self) -> int:

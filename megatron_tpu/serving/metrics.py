@@ -208,7 +208,7 @@ _BASE_GAUGES = (
     "kv_bytes_per_token", "kv_pool_bytes",
     "kv_bytes_per_slot", "kv_ring_bytes", "kv_full_bytes",
     "conv_state_bytes", "ssm_state_bytes", "ssd_state_bytes",
-    "kda_state_bytes",
+    "kda_state_bytes", "gdn_state_bytes",
     "active_adapters", "handoff_bytes_per_req",
     "prefill_group_busy", "decode_group_busy",
     "prefill_tp", "decode_tp", "prefill_devices", "decode_devices",
@@ -282,6 +282,9 @@ class ServingMetrics:
         # the delta rule's float32 state, a matrix a head (0 where no layer
         # is a "kda" mixer)
         self.kda_state_bytes = 0
+        # a Gated DeltaNet rule's float32 state, a matrix a value head (0
+        # where no layer is a "linear_attention" mixer)
+        self.gdn_state_bytes = 0
         # multi-tenant LoRA serving: device-resident (non-identity)
         # adapters right now — 0 on adapterless engines, pushed by the
         # engine on pool churn like the KV gauges
@@ -433,8 +436,9 @@ class ServingMetrics:
     def set_pool_gauges(self, pool):
         """`SlotKVPool.bytes_per_token()`, `.nbytes()`, `.bytes_per_slot()`,
         `.ring_nbytes()`, `.full_nbytes()`, `.conv_state_nbytes()`,
-        `.ssm_state_nbytes()`, `.ssd_state_nbytes()` and
-        `.kda_state_nbytes()`, as the pool counts them."""
+        `.ssm_state_nbytes()`, `.ssd_state_nbytes()`,
+        `.kda_state_nbytes()` and `.gdn_state_nbytes()`, as the pool counts
+        them."""
         with self._lock:
             self.kv_bytes_per_token = int(pool.bytes_per_token())
             self.kv_pool_bytes = int(pool.nbytes())
@@ -445,6 +449,7 @@ class ServingMetrics:
             self.ssm_state_bytes = int(pool.ssm_state_nbytes())
             self.ssd_state_bytes = int(pool.ssd_state_nbytes())
             self.kda_state_bytes = int(pool.kda_state_nbytes())
+            self.gdn_state_bytes = int(pool.gdn_state_nbytes())
 
     def set_attn_gauges(self, gather_bytes_per_step: int, path: int):
         """Engine-pushed attention-path gauges (per sync window):

@@ -63,7 +63,7 @@ _SUM_GAUGES = ("queue_depth", "active_slots", "num_slots",
                "kv_blocks_used", "kv_blocks_retained", "kv_bytes_wasted",
                "kv_pool_bytes", "kv_ring_bytes", "kv_full_bytes",
                "conv_state_bytes", "ssm_state_bytes", "ssd_state_bytes",
-               "kda_state_bytes",
+               "kda_state_bytes", "gdn_state_bytes",
                "active_adapters", "prefill_devices", "decode_devices")
 # gauges reported as the WORST replica (max) — per-request /
 # per-group readings where summing fractions would be meaningless

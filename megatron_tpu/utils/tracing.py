@@ -133,7 +133,7 @@ Training (`training/loop.py`, main thread):
 On the device (`jax.named_scope`, so in the `op_name` of every HLO instruction
 traced under it; models/moe.py, models/attention.py, models/mla.py,
 models/hyper_connections.py, models/mamba.py, models/mamba2.py,
-models/kda.py and models/rope.py):
+models/kda.py, models/gated_delta.py and models/rope.py):
 
 | scope | round what |
 |---|---|
@@ -166,9 +166,16 @@ models/kda.py and models/rope.py):
 | `mtpu/kda/gate` | the log-decay a channel, -exp(A_log) softplus(f W_fb + dt_bias) in float32, beta = sigmoid, and the padding rows' decay and beta set to 0 (the rule's step is then the identity) |
 | `mtpu/kda/scan` | the gated delta rule: the kernel `_kda_chunk` for a prefill or a chunk (ops/kda_chunk.py; the running sums of the decays made outside it), the one-row update over the pool's layer for a decode step, the recurrence with no cache; and the write of both states behind the call's last real row, one update in place a layer each |
 | `mtpu/kda/out` | the RMSNorm a head, float32 statistics, ONE learned scale [D]; the output gate sigmoid(z W_gb + b); the layer's last product, rows x [H D, h] |
+| `mtpu/gdn/proj` | a Gated DeltaNet layer's two first products, rows x [h, 2 H_k D + 2 H D] (q, k, v and the output gate's z) and rows x [h, 2 H] (beta's and the decay's inputs) (`models/gated_delta.py`) |
+| `mtpu/gdn/conv` | the read of the layer's two states (the depthwise kernel's last inputs, the rule's [value heads, D, D] float32 matrices; a row each slot), the taps over [state ; rows] of q, k and v together accumulated in float32, SiLU, the L2 norm a head of q and k, q's 1 / sqrt(D) |
+| `mtpu/gdn/gate` | the log-decay a HEAD, -exp(A_log) softplus(a + dt_bias) in float32, beta = sigmoid, and the padding rows' decay and beta set to 0 |
+| `mtpu/gdn/scan` | the gated delta rule with one decay a head: the kernel `_gdn_chunk` for a prefill or a chunk (ops/kda_chunk.py, form (d): one K K^T and one Q K^T a chunk a key head on the matrix unit; the running sums of the decays made outside it), the one-row update over the pool's layer for a decode step, the recurrence with no cache; and the write of both states behind the call's last real row |
+| `mtpu/gdn/out` | the RMSNorm a head, float32 statistics, ONE learned scale [D] (the scale itself, not 1 + w); the output gate SiLU(z); the layer's last product, rows x [H D, h] |
+| `mtpu/attn/gate` | the attention's output gate (`cfg.attn_output_gate`): the attention's output times sigmoid of the gate that wq's second half of a head's columns made, float32, ahead of wo (`models/attention.py`) |
+| `mtpu/rope/partial` | a rotary over the first `cfg.rotary_dim` channels of a head, the others passed as they are (`models/rope.py`) |
 | `mtpu/moe/latent_in` | experts in a latent (`cfg.moe_latent_size`): rows x [h, latent] ahead of the routing's gather (`models/moe.py`) |
 | `mtpu/moe/latent_out` | the tokens' weighted sums x [latent, h], behind the combination |
-| `mtpu/moe/shared` | the shared experts' MLP, added beside the routed sum (`n_shared_experts`) |
+| `mtpu/moe/shared` | the shared experts' MLP, added beside the routed sum (`n_shared_experts`), with their own gate sigmoid(x . w) a token inside it (`moe_shared_expert_gate`) |
 | `mtpu/mla/q` | latent attention's query: down-projection, norm, up-projection, the rotary on its rope part |
 | `mtpu/mla/latent` | the latent row: down-projection, norm over kv_lora_rank, the rotary on the shared key, the write into the cache |
 | `mtpu/mla/attend_expanded` | the expanded form: keys and values of every head from the rows, causal attention from position 0 (training; a prefill at offset 0) |
@@ -196,7 +203,10 @@ a head of every slot; a slot's share of it is
 (`.kda_state_nbytes()`: the delta rule's float32 matrices a head of every
 slot; a slot's share of it is `serve_kda_state_bytes_per_slot`). The device
 trace names the chunked rule's kernel calls `%_kda_chunk.N`
-(`serve_kda_scan_ms_per_step`, `kda_chunk_roofline_pct`).
+(`serve_kda_scan_ms_per_step`, `kda_chunk_roofline_pct`). `gdn_state_bytes`
+(`.gdn_state_nbytes()`) is the same for a Gated DeltaNet rule's matrices a
+value head (`serve_gdn_state_bytes_per_slot`), whose kernel calls are
+`%_gdn_chunk.N` (`serve_gdn_scan_ms_per_step`, `gdn_chunk_roofline_pct`).
 `prefill_chunks` counts the chunk programs dispatched. `admits_total`,
 `admits_early` and `early_admit_declined_prefilling` (placements by `_admit`,
 those made while a decode window ran, and windows that ended with a prompt

@@ -14,9 +14,13 @@ pytest.register_assert_rewrite(
     "benchmark.tests.test_ssd_kinds", "benchmark.tests.test_ssd_roofline",
     "benchmark.tests.test_request_timeline",
     "benchmark.tests.test_kda_kinds", "benchmark.tests.test_kda_roofline",
-    "benchmark.tests.test_reference_kimi_linear")
+    "benchmark.tests.test_reference_kimi_linear",
+    "benchmark.tests.test_gdn_kinds", "benchmark.tests.test_gdn_roofline",
+    "benchmark.tests.test_reference_qwen3_next")
 
 from benchmark.tests.test_conv_kinds import *  # noqa: E402,F401,F403
+from benchmark.tests.test_gdn_kinds import *  # noqa: E402,F401,F403
+from benchmark.tests.test_gdn_roofline import *  # noqa: E402,F401,F403
 from benchmark.tests.test_hc_kinds import *  # noqa: E402,F401,F403
 from benchmark.tests.test_kda_kinds import *  # noqa: E402,F401,F403
 from benchmark.tests.test_kda_roofline import *  # noqa: E402,F401,F403
@@ -25,6 +29,7 @@ from benchmark.tests.test_moe_share_roofline import *  # noqa: E402,F401,F403
 from benchmark.tests.test_program_spans import *  # noqa: E402,F401,F403
 from benchmark.tests.test_reference import *  # noqa: E402,F401,F403
 from benchmark.tests.test_reference_kimi_linear import *  # noqa: E402,F401,F403
+from benchmark.tests.test_reference_qwen3_next import *  # noqa: E402,F401,F403
 from benchmark.tests.test_request_timeline import *  # noqa: E402,F401,F403
 from benchmark.tests.test_ssd_kinds import *  # noqa: E402,F401,F403
 from benchmark.tests.test_ssd_roofline import *  # noqa: E402,F401,F403

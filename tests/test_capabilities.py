@@ -165,6 +165,26 @@ def test_a_width_is_refused_as_given():
     dead_width.validate(MODELS["regions"]())
 
 
+@pytest.mark.parametrize("feature", sorted(REFUSED["conv-state"]))
+def test_the_conv_state_row_holds_for_a_delta_rule_beside_keys_and_values(
+        feature):
+    """Qwen3-Next (PR 60: `linear_attention` | `full_attention`, a matrix a
+    value head beside keys and values in one `ConvKVCache`) is of the
+    `conv-state` kind, no new one: the row's refusals, each with the row's
+    own reason, and nothing else refused."""
+    model = MODEL_PRESETS["qwen3-next-tiny"]()
+    assert capabilities.pool_kind(model, 64) == "conv-state"
+    cells = capabilities.refusals(_serving(**ON[feature]), model)
+    assert ("conv-state", feature) in [(row, f) for row, f, _ in cells]
+    lfm2 = capabilities.refusals(_serving(**ON[feature]),
+                                 MODELS["conv-state"]())
+    assert [m for r, f, m in cells if r == "conv-state"] == \
+        [m for r, f, m in lfm2 if r == "conv-state"]
+    with pytest.raises(AssertionError, match="a state of fixed size"):
+        _serving(**ON[feature]).validate(model)
+    _serving(prefill_chunk=16).validate(model)
+
+
 @pytest.mark.parametrize("feature", sorted(REFUSED["streams"]))
 def test_the_streams_refusals_hold_over_the_latent_pool(feature):
     """Xing4.0 is of both rows (and has dropless experts): the kind's reason

@@ -33,6 +33,17 @@ array is made (`serving/engine.py::_prefill_fn`; the size test that used
 to choose between the two forms is gone with its bound). The two `decode`
 and the two `plain_loop` digests came out as they were at 31bbf77: the
 proof that no decode and no training program changed.
+
+PR 60 opened models/attention.py (an output gate, a zero-centred norm a
+head, a rotary over part of a head, the flash kernel over folded rows of
+several kv heads), models/moe.py (a gate on the shared expert), models/
+rope.py, ops/kda_chunk.py and ops/flash_attention*.py for a fifth state kind
+("linear_attention", models/gated_delta.py). All twelve digests above came
+out as they were. The pattern models' `decode` and `chunk` programs (the
+engine's decode step and `generation.prefill_chunk` over a slot's cache at a
+traced offset: `PATTERN_AT_PARENT`), taken at its parent's tree (bc8a185)
+with `pattern_digests()`, are held beside them from here on; `PYTHONPATH=.
+python tests/test_jaxpr_unchanged.py` prints both tables.
 """
 import hashlib
 import re
@@ -64,6 +75,19 @@ AT_PARENT = {
     "xing4.0-29b-a4b-tiny": {"decode": "82beae2cd9e46c81",
                              "prefill": "21603a803e31715d",
                              "plain_loop": "3c572c42d9b52012"},
+}
+
+
+# the models whose layers follow `cfg.layer_types`: their served programs
+PATTERN_AT_PARENT = {
+    "kimi-linear-tiny": {"decode": "e57c32f4a3ae314c",
+                         "chunk": "821f54af91e2004c"},
+    "lfm2-8b-a1b-tiny": {"decode": "47c962e8eda7c907",
+                         "chunk": "86176a780940a85b"},
+    "jamba2-3b-tiny": {"decode": "db08124a4273ec2c",
+                       "chunk": "a42439af7aa15326"},
+    "nemotron-3-super-tiny": {"decode": "f99cf24fb657fce7",
+                              "chunk": "33153838e4faff67"},
 }
 
 
@@ -107,11 +131,50 @@ def digests(model):
     return out
 
 
+def _pattern_programs(model):
+    import dataclasses
+    cfg = dataclasses.replace(MODEL_PRESETS[model](), vocab_size=512)
+    params = lm.model_init(jax.random.PRNGKey(0), cfg)
+    gen = Generator(params, cfg, eos_id=0, pad_id=0)
+    serving = ServingConfig(num_slots=SLOTS, max_len=CAP,
+                            prefill_bucket=BUCKET, prefill_chunk=BUCKET,
+                            prefill_max_batch=1).validate(cfg)
+    eng = ServingEngine(gen, serving, start=False)
+    try:
+        state = (eng._p_dec, eng.pool.caches, eng._last_logits, eng._rngs)
+        grid = (eng._d_lengths, eng._d_temps, eng._d_top_ks, eng._d_top_ps)
+        yield "decode", eng._decode_fn, (
+            *state, *grid, eng._d_reject, eng._d_masks, None, None)
+        yield "chunk", eng._chunk_fwd_fn, (
+            eng._p_dec, eng._zero_sub(), jnp.zeros((1, BUCKET), jnp.int32),
+            jnp.int32(6), jnp.int32(BUCKET + 7), None, None)
+    finally:
+        eng.close()
+
+
+def pattern_digests(model):
+    out = {}
+    with jax.default_matmul_precision("highest"):
+        for name, fn, args in _pattern_programs(model):
+            text = re.sub(r"0x[0-9a-f]+", "0x",
+                          str(jax.make_jaxpr(fn)(*args)))
+            out[name] = hashlib.sha256(text.encode()).hexdigest()[:16]
+    return out
+
+
 @pytest.mark.parametrize("model", sorted(AT_PARENT))
 def test_programs_are_the_parents(model):
     assert digests(model) == AT_PARENT[model]
 
 
+@pytest.mark.parametrize("model", sorted(PATTERN_AT_PARENT))
+def test_pattern_programs_are_the_parents(model):
+    assert pattern_digests(model) == PATTERN_AT_PARENT[model]
+
+
 if __name__ == "__main__":
     import json
+    import sys
+    models = sys.argv[1:] or sorted(PATTERN_AT_PARENT)
     print(json.dumps({m: digests(m) for m in sorted(AT_PARENT)}, indent=4))
+    print(json.dumps({m: pattern_digests(m) for m in models}, indent=4))

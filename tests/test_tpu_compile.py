@@ -844,3 +844,150 @@ def test_kimi_linear_served_programs_copy_no_state_and_no_latent_layer(
             + 2 * batch * row * cap * 2, (name, memory)
         if name == "decode":
             assert memory.temp_size_in_bytes < nbytes, (name, memory)
+
+
+# ---- PR 60: a delta rule with one decay a head beside keys and values -----
+
+@pytest.mark.parametrize("batch,rows,chunk", [(1, 4096, 64), (1, 512, 64),
+                                              (2, 1024, 128)])
+def test_gdn_chunk_at_qwen3_next_widths(one_chip, batch, rows, chunk):
+    """The scalar-decay chunk kernel (PR 60) at Qwen3-Next's widths: 16 key
+    heads under 32 value heads of 128 channels, a decay a head a row, a
+    state of [32, 128, 128] float32 a sequence in and out, bf16 rows: two
+    value heads a grid step over the ONE key head they read."""
+    from megatron_tpu.ops.kda_chunk import _gdn_chunk, kda_block_heads
+
+    def S(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    f32 = jnp.float32
+    assert kda_block_heads(32, 128, 128) == 2
+    text = jax.jit(functools.partial(_gdn_chunk, chunk=chunk)).lower(
+        S((batch, rows, 16, 128)), S((batch, rows, 16, 128)),
+        S((batch, rows, 32, 128)), S((batch, rows, 32), f32),
+        S((batch, rows, 32), f32), S((batch, 32, 128, 128), f32)
+    ).compile().as_text()
+    # the trace finds the kernel by this name (benchmark/gdn_roofline.py)
+    assert any("%_gdn_chunk" in line and "tpu_custom_call" in line
+               for line in text.splitlines())
+    # q and k of a key head are read through the block index: no array of
+    # q's or k's rows at the value heads' width is made beside v and o
+    wide = re.findall(rf"= bf16\[{batch},{rows + (-rows % chunk)},4096\]"
+                      r"\S* (?!parameter|custom-call)", text)
+    assert len(wide) <= 2, wide
+
+
+def test_flash_kernel_reads_folded_rows_at_an_offset(one_chip):
+    """A 4,096-row chunk of 16 heads over 2 kv heads of 256 channels against
+    the folded rows [32,768, 2 x 256] of a slot, at a traced offset: the
+    kernel indexes kv head g's channels as a block of the row, and no
+    transposed or cut copy of the rows is made."""
+    from megatron_tpu.ops.flash_attention import _flash_attention_offset
+
+    def S(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    compiled = jax.jit(functools.partial(
+        _flash_attention_offset, scale=1 / 16.0, block_kv=512,
+        use_pallas=True, sliding_window=None, kv_heads_major=False,
+        kv_folded=2)).lower(
+            S((1, 4096, 16, 256)), S((1, 32768, 512)), S((1, 32768, 512)),
+            S((), jnp.int32), S((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    made = [line for line in text.splitlines()
+            if re.search(r"bf16\[1,(2,32768,256|32768,2,256)\]", line)
+            and " copy(" in line]
+    assert made == [], made
+    # the queries' and the output's transposes, and nothing like the rows
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 32768 * 512 * 2
+
+
+def _qwen3_next_programs(one_chip, monkeypatch):
+    """The three served programs of a `qwen3-next-tiny` whose linear heads
+    and attention heads are as wide as the published ones (1 key head under
+    2 value heads of 128: the chunk kernel's shape rule holds; 4 heads over
+    2 kv heads of 256: the folded flash form's), over a pool of 256 slots of
+    384 positions, compiled for the chip with the cache donated as the
+    engine donates it."""
+    import dataclasses
+
+    from megatron_tpu.config import MODEL_PRESETS
+    from megatron_tpu.inference.generation import (init_kv_caches,
+                                                   prefill_chunk)
+    from megatron_tpu.models import language_model as lm
+
+    cfg = dataclasses.replace(
+        MODEL_PRESETS["qwen3-next-tiny"](), compute_dtype="bfloat16",
+        params_dtype="bfloat16", gdn_key_heads=1, gdn_value_heads=2,
+        gdn_key_head_dim=128, gdn_value_head_dim=128, kv_channels=256,
+        attention_impl="flash")
+    slots, cap, bucket = 256, 384, 128
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    on_chip = functools.partial(_on_chip, one_chip)
+    params = on_chip(jax.eval_shape(
+        lambda: lm.model_init(jax.random.PRNGKey(0), cfg)))
+    pool = on_chip(jax.eval_shape(lambda: init_kv_caches(
+        cfg, slots, cap, per_slot_offsets=True)))
+    one = on_chip(jax.eval_shape(lambda: init_kv_caches(cfg, 1, cap)))
+    rope = lm.make_rope(cfg, cap)
+    ids = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one_chip)
+
+    def forward(params, tokens, caches):
+        return lm.model_forward(params, tokens, cfg, rope=rope,
+                                kv_caches=caches)
+
+    def chunk(params, tokens, caches, last, nxt):
+        return prefill_chunk(params, tokens, caches, cfg, rope=rope,
+                             last_idx=last, next_offset=nxt)
+    return cfg, (slots, cap, bucket), {
+        "decode": jax.jit(forward, donate_argnums=2).lower(
+            params, ids(slots, 1), pool).compile(),
+        "prefill": jax.jit(forward, donate_argnums=2).lower(
+            params, ids(1, bucket), one).compile(),
+        "chunk": jax.jit(chunk, donate_argnums=2).lower(
+            params, ids(1, bucket), one, ids(), ids()).compile()}
+
+
+def test_qwen3_next_served_programs_copy_no_state_and_make_no_scores(
+        one_chip, monkeypatch):
+    """No program of the three makes a copy of the rule's state (float32 [6
+    linear layers, batch, 2, 128, 128]); a prefill and a chunk run the
+    scalar-decay kernel and the flash kernel over the folded rows, and make
+    no [heads, rows, max_seq] array of scores; the cache is aliased whole."""
+    cfg, (slots, cap, bucket), programs = _qwen3_next_programs(one_chip,
+                                                               monkeypatch)
+    for name, compiled in programs.items():
+        batch = slots if name == "decode" else 1
+        text = compiled.as_text()
+        state = (6, batch, 2, 128, 128)
+        for line in _made_in_memory(text):
+            m = _RESULT.match(line)
+            if not m:
+                continue
+            dims = tuple(int(d) for d in m.group(2).split(",") if d)
+            if dims == state:
+                assert m.group(3) in (
+                    "parameter", "get-tuple-element", "bitcast",
+                    "dynamic-update-slice", "while", "tuple",
+                    "custom-call") or (
+                    m.group(3) == "fusion"
+                    and ("dynamic-update-slice" in line
+                         or "kind=kCustom" in line)) or (
+                    name != "decode"
+                    and m.group(3) in ("copy-start", "copy-done")), \
+                    (name, line[:300])
+            if name != "decode":
+                # [heads, rows, max_seq] scores, in any order of the three
+                assert sorted(d for d in dims if d != 1) != \
+                    sorted((cfg.num_attention_heads, bucket, cap)), \
+                    (name, line[:300])
+        if name != "decode":
+            assert len(_kernel_calls(text, "_gdn_chunk")) >= 1, name
+            assert len(_kernel_calls(text, "_flash_attention_offset")) >= 1, \
+                name
+        memory = compiled.memory_analysis()
+        nbytes = 6 * batch * 2 * 128 * 128 * 4
+        assert memory.alias_size_in_bytes >= nbytes \
+            + 2 * 2 * batch * cap * 512 * 2, (name, memory)
+        if name == "decode":
+            assert memory.temp_size_in_bytes < nbytes, (name, memory)
