@@ -43,17 +43,34 @@ one unit lower triangular system a chunk. A and B are NOT made as (K
 e^G)(K e^-G)^T: e^-G passes float32 after eleven rows of g = -8. Every
 exponent taken here is a difference G_r - G_i with i <= r, which is <= 0:
 
-- between sub-chunks of `SUB` rows, e^{G_r - G_i} = e^{G_r - G*} e^{G* -
-  G_i} with G* the sum behind the row before r's sub-chunk: both factors <=
-  1, one product a strip of `SUB` rows;
+- between sub-chunks of `SUB` rows, by halves: at each level the rows of a
+  block's second half against the columns of its first, e^{G_r - G_i} =
+  e^{G_r - G*} e^{G* - G_i} with G* the sum at the first half's last row:
+  both factors <= 1, ONE product a level for A and B together;
 - inside a sub-chunk, the difference itself, a column at a time (`SUB`
-  columns: the rows of every sub-chunk against their own sub-chunk's j-th).
+  columns a CHUNK: the rows of every sub-chunk against their own
+  sub-chunk's j-th, and from the sub-chunk's second half on only the rows
+  of that half: the others lie above the diagonal).
+
+The running sums G are made in the kernel from g as it comes (PR 61; up to
+PR 60 a `jnp.cumsum` outside wrote and the kernel read again 67 MB a
+4,096-row call): inside blocks of eight rows by shifted adds, the blocks'
+totals carried one behind the other, so a sum is a few roundings from the
+sequential one whatever its size.
 
 The system is solved in blocks of `SUB`: the unit lower triangular diagonal
 blocks D are inverted exactly in float32 (forward substitution on the
-vector unit, every block at once), and with N = D^-1 L_off strictly BLOCK
-lower, (I + N)^-1 is the finite product (I - N)(I + N^2)(I + N^4).. over
-the chunk's sub-chunks, applied to D^-1 times the right-hand side.
+vector unit, every block of every head of a grid step a step at a time),
+and with N = D^-1 L_off strictly BLOCK lower, (I + N)^-1 is the finite
+product (I - N)(I + N^2)(I + N^4).. over the chunk's sub-chunks, applied to
+D^-1 times the right-hand side.
+
+A grid step takes `kda_block_heads` heads and walks them STAGE BY STAGE
+(every head's sums, then every head's A and B, inverses, solve, outputs:
+`_solve_and_out` takes lists): the heads' chains of dependent products are
+independent, and written side by side the compiler overlaps them; written
+head after head it ran them one behind the other (PERF.md section 6, PR
+61). Every operand is read before and every result stored after the work.
 
 Precision (`flash_attention_pallas.py`'s rule): products take their
 operands in the rows' dtype (bf16 on the chip) and accumulate in float32;
@@ -71,15 +88,17 @@ exponential, the diagonal blocks' inverses and every accumulator float32.
     ONE product K K^T and one Q K^T a chunk a key head on the matrix unit
     (operands as they come, float32 sums: exact for bf16 rows) times a [C,
     C] matrix of exponentials of differences, each <= 0 where it is kept (i
-    <= r); where (c) walks `SUB` columns a sub-chunk on the vector unit.
+    <= r); where (c) walks `SUB` columns a chunk on the vector unit.
     The solve, the state's path and the grid are (c)'s. `gdn_chunk` is its
     `kda_chunk`; off the chip it is form (a) with g broadcast over the
     channels and q and k repeated.
 
 No option chooses between (a) and (c), and none sets the chunk: `CHUNK` rows
-a grid step (the kernel on the chip took 64 and 128 alike, 4.6 ms a
-4,096-row call; PERF.md section 6, PR 58), `kda_block_heads` is the kernel's
-shape rule, and (c) runs where it holds on a TPU.
+a grid step (PR 58's kernel took 64 and 128 alike on the chip: 3.2 ms a
+4,096-row call, 4.6 ms for the whole jitted function with the running sums
+XLA then made outside it; PERF.md section 6, PR 58 and PR 61),
+`kda_block_heads` is the kernel's shape rule, and (c) runs where it holds
+on a TPU.
 """
 from __future__ import annotations
 
@@ -91,6 +110,7 @@ import jax.numpy as jnp
 LANES = 128
 SUB = 16
 CHUNK = 64
+HEADS_A_STEP = 4
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -131,10 +151,11 @@ def kda_recurrent(q, k, v, g, beta, h0=None):
 def kda_block_heads(heads: int, d_k: int, d_v: int, *, aligned: bool = True):
     """The kernel's shape rule: the heads a grid step takes, or None where
     the kernel does not take the shape (form (a) then): on the chip
-    (`aligned`) heads of whole lane tiles."""
+    (`aligned`) heads of whole lane tiles. As many as divide the heads, up
+    to `HEADS_A_STEP`: their chains overlap inside a grid step."""
     if aligned and (d_k % LANES or d_v % LANES):
         return None
-    return 2 if heads % 2 == 0 else 1
+    return next(hb for hb in (HEADS_A_STEP, 2, 1) if heads % hb == 0)
 
 
 def kda_chunk(q, k, v, g, beta, h0=None, *, chunk: int = CHUNK,
@@ -162,69 +183,186 @@ def _dot(a, b, dims, dtype, precision=None):
                                precision=precision)
 
 
-def _unit_lower_inverse(low_own, eye_rows, base: int = 0):
-    """The inverse of one unit lower triangular diagonal block of `SUB`
-    rows, exact in float32 on the vector unit. `low_own` [SUB, SUB] is the
-    block's strictly lower part (or, with `base`, the block's rows of the
-    whole strictly lower matrix, [SUB, C], the block's columns from `base`
-    on); `eye_rows` [SUB, C] the block's rows of the identity. Forward
-    substitution, right-looking: once row m of the inverse is whole, every
-    later row i sheds L[i, m] times it. Returns the block's rows of the
-    block diagonal inverse, [SUB, C]."""
-    x = eye_rows
+def _running_sums(g):
+    """The running sums over the rows of g [C, W] float32, each row with
+    itself: inside blocks of eight rows (a vector register's) by three
+    shifted adds, then the blocks' totals carried one behind the other, so
+    that a sum stays a few roundings from the sequential one (a shifted add
+    over the whole chunk is log2(C) roundings at the size of the largest
+    sum apart from its neighbour's, and the neighbours' difference is what
+    is used)."""
+    from jax.experimental.pallas import tpu as pltpu
+    row = jax.lax.broadcasted_iota(jnp.int32, g.shape, 0)
+    step = 1
+    while step < 8:
+        g = g + jnp.where(row % 8 >= step, pltpu.roll(g, step, 0), 0.0)
+        step *= 2
+    blocks, carry = [g[:8]], g[7:8]
+    for t in range(1, g.shape[0] // 8):
+        blocks.append(g[8 * t:8 * t + 8] + carry)
+        carry = carry + g[8 * t + 7:8 * t + 8]
+    return jnp.concatenate(blocks, axis=0)
+
+
+def _block_inverses(lows, eye, chunk: int):
+    """D^-1 for every head of a grid step: `lows` the heads' strictly lower
+    matrices [C, C]; returns each head's block diagonal inverse [C, C]. The
+    unit lower triangular diagonal blocks of `SUB` rows are inverted exactly
+    in float32 on the vector unit by forward substitution, right-looking:
+    once row m of a block's inverse is whole, every later row i sheds L[i,
+    m] times it. One step of EVERY block of every head at a time: the
+    blocks' chains of `SUB` - 1 dependent steps are independent."""
+    subs = chunk // SUB
+    bases = [i * SUB for _ in lows for i in range(subs)]
+    own = [low[i * SUB:(i + 1) * SUB] for low in lows for i in range(subs)]
+    xs = [eye[base:base + SUB] for base in bases]             # [SUB, C]
     for m in range(SUB - 1):
-        x = x - low_own[:, base + m:base + m + 1] * x[m:m + 1]
-    return x
+        xs = [x - rows[:, base + m:base + m + 1] * x[m:m + 1]
+              for x, rows, base in zip(xs, own, bases)]
+    return [jnp.concatenate(xs[j * subs:(j + 1) * subs], axis=0)
+            for j in range(len(lows))]
 
 
-def _solve_and_out(kf, qf, big_g, beta, low, b_mat, x_d, v, state, *,
-                   chunk: int, dtype):
-    """What both chunk kernels do once A (as `low` = tril(Diag(beta) A, -1)),
-    B (`b_mat`, lower with its diagonal) and the diagonal blocks' inverses
-    (`x_d`) are made: the state's part, the block solve, the chunk's
-    outputs and the state behind its last row. `big_g` [C, d_k] (a decay a
-    channel) or [C, 1] (a decay a head): the running sums. Returns (o [C,
-    d_v] float32, the new state [d_k, d_v] float32)."""
+def _solve_and_out(kf, qf, big_g, beta, low, b_mat, v_ref, o_ref, state_ref,
+                   *, d_v: int, chunk: int, dtype):
+    """What both chunk kernels do once A (as `low` = tril(Diag(beta) A, -1))
+    and B (`b_mat`, lower with its diagonal) are made: the diagonal blocks'
+    inverses, the state's part, the block solve, the chunk's outputs into
+    `o_ref` and the state behind its last row into `state_ref`. Every
+    argument but the refs a LIST over the heads of a grid step, and every
+    stage written for all of them before the next; nothing is stored
+    before the last head's last product. `big_g` [C, d_k] (a decay a
+    channel) or [C, 1] (a decay a head): the running sums."""
     row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     subs = chunk // SUB
+    n = range(len(kf))
+    v = [v_ref[0, :, j * d_v:(j + 1) * d_v] for j in n]
+    state = [state_ref[0, j] for j in n]
+    x_d = _block_inverses(
+        low, jnp.where(row == col, 1.0, 0.0).astype(F32), chunk)    # D^-1
     # ---- the state's part, float32 -------------------------------------
-    e_g = jnp.exp(big_g)                                     # <= 1
-    from_state = _dot(jnp.concatenate([kf * e_g, qf * e_g], axis=0),
-                      state, ((1,), (0,)), F32, HIGHEST)
-    rhs = beta * (v.astype(F32) - from_state[:chunk])
+    e_g = [jnp.exp(big_g[j]) for j in n]                     # <= 1
+    from_state = [_dot(jnp.concatenate([kf[j] * e_g[j], qf[j] * e_g[j]],
+                                       axis=0),
+                       state[j], ((1,), (0,)), F32, HIGHEST) for j in n]
+    rhs = [beta[j] * (v[j].astype(F32) - from_state[j][:chunk]) for j in n]
     # ---- the solve: T = D (I + N), N = D^-1 L_off -----------------------
-    u = _dot(x_d, rhs, ((1,), (0,)), dtype)
+    u = [_dot(x_d[j], rhs[j], ((1,), (0,)), dtype) for j in n]
     if subs > 1:
-        off = jnp.where(row // SUB != col // SUB, low, 0.0)
-        n1 = _dot(x_d, off, ((1,), (0,)), dtype)
-        u = u - _dot(n1, u, ((1,), (0,)), dtype)
+        off = row // SUB != col // SUB
+        n1 = [_dot(x_d[j], jnp.where(off, low[j], 0.0), ((1,), (0,)), dtype)
+              for j in n]
+        u = [u[j] - _dot(n1[j], u[j], ((1,), (0,)), dtype) for j in n]
         power = 2
         while power < subs:          # (I + N^2)(I + N^4)..
-            n1 = _dot(n1, n1, ((1,), (0,)), dtype)
-            u = u + _dot(n1, u, ((1,), (0,)), dtype)
+            n1 = [_dot(n1[j], n1[j], ((1,), (0,)), dtype) for j in n]
+            u = [u[j] + _dot(n1[j], u[j], ((1,), (0,)), dtype) for j in n]
             power *= 2
     # ---- out -------------------------------------------------------------
-    o = from_state[chunk:] + _dot(b_mat, u, ((1,), (0,)), dtype)
-    end = big_g[chunk - 1:chunk]                             # [1, d_k | 1]
-    k_end = kf * jnp.exp(end - big_g)                        # <= 1
-    if big_g.shape[1] == 1:
-        # ONE decay a head: e^{G_C} along the lanes, picked out of the
-        # column's broadcast by its row (Mosaic broadcasts a [1, 1] in one
-        # direction only, and folds two broadcasts into one)
-        wide = jnp.broadcast_to(e_g, (chunk, state.shape[1]))
-        last = jax.lax.broadcasted_iota(jnp.int32, wide.shape, 0) == chunk - 1
-        decay = jnp.sum(jnp.where(last, wide, 0.0), axis=0, keepdims=True)
-    else:
-        decay = jnp.exp(end).reshape(-1, 1)                  # [d_k, 1]
-    return o, decay * state + _dot(k_end, u, ((0,), (0,)), dtype)
+    o = [from_state[j][chunk:] + _dot(b_mat[j], u[j], ((1,), (0,)), dtype)
+         for j in n]
+    new = []
+    for j in n:
+        end = big_g[j][chunk - 1:chunk]                      # [1, d_k | 1]
+        k_end = kf[j] * jnp.exp(end - big_g[j])              # <= 1
+        if big_g[j].shape[1] == 1:
+            # ONE decay a head: e^{G_C} along the lanes, picked out of the
+            # column's broadcast by its row (Mosaic broadcasts a [1, 1] in
+            # one direction only, and folds two broadcasts into one)
+            wide = jnp.broadcast_to(e_g[j], (chunk, state[j].shape[1]))
+            last = jax.lax.broadcasted_iota(
+                jnp.int32, wide.shape, 0) == chunk - 1
+            decay = jnp.sum(jnp.where(last, wide, 0.0), axis=0,
+                            keepdims=True)
+        else:
+            decay = jnp.exp(end).reshape(-1, 1)              # [d_k, 1]
+        new.append(decay * state[j]
+                   + _dot(k_end, u[j], ((0,), (0,)), dtype))
+    for j in n:
+        o_ref[0, :, j * d_v:(j + 1) * d_v] = o[j].astype(o_ref.dtype)
+        state_ref[0, j] = new[j]
+
+
+def _rows_of(t, picks, span: int):
+    """[len(picks) * span, W]: row picks[b] of t under the b-th block of
+    `span` rows."""
+    return jnp.concatenate([jnp.broadcast_to(t[p:p + 1], (span, t.shape[1]))
+                            for p in picks], axis=0)
+
+
+def _between_sub_chunks(qf, kf, big_g, chunk: int, dtype):
+    """A and B between the sub-chunks of a chunk, by halves: at each level
+    the rows of a block's second half against the columns of its first, G*
+    the sum at the first half's last row: k_r e^{G_r - G*} against k_i
+    e^{G* - G_i}, both factors <= 1 and ONE exponential (e^{-|G - G*|}: the
+    first half's rows lie above G*, the second's below). [C, C] each, zero
+    inside a sub-chunk and above the diagonal."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    a = jnp.zeros((chunk, chunk), F32)
+    b = jnp.zeros((chunk, chunk), F32)
+    half = SUB
+    while half < chunk:
+        span = 2 * half
+        ref = _rows_of(big_g, range(half - 1, chunk, span), span)
+        scale = jnp.exp(-jnp.abs(big_g - ref))
+        ks = kf * scale
+        both = _dot(jnp.concatenate([qf * scale, ks], axis=0), ks,
+                    ((1,), (1,)), dtype)                     # [2C, C]
+        at = (row // span == col // span) & (row % span >= half) \
+            & (col % span < half)
+        a = jnp.where(at, both[chunk:], a)
+        b = jnp.where(at, both[:chunk], b)
+        half = span
+    return a, b
+
+
+def _inside_sub_chunks(qf, kf, big_g, chunk: int):
+    """A and B inside the sub-chunks, float32 on the vector unit: the rows
+    of EVERY sub-chunk against their own sub-chunk's j-th, `SUB` columns a
+    chunk. The exponent is the difference itself, <= 0 where it is kept (at
+    and under the diagonal) and clamped there above it. The columns of a
+    sub-chunk's second half meet only that half's rows (the first half's
+    lie above the diagonal). [C, C] each, only the sub-chunks' own blocks
+    written; what is above the diagonal there is the caller's to drop."""
+    subs, half = chunk // SUB, SUB // 2
+
+    def columns(g_s, k_s, q_s, first: int):
+        """Columns first .. first + half - 1 of every sub-chunk against
+        the rows given: `span` rows a sub-chunk."""
+        span = g_s.shape[0] // subs
+        lane = jax.lax.broadcasted_iota(jnp.int32, (subs * span, chunk), 1)
+        a = b = jnp.zeros((subs * span, chunk), F32)
+        for jj in range(first, first + half):
+            picks = range(jj, chunk, SUB)
+            w = jnp.exp(jnp.minimum(
+                g_s - _rows_of(big_g, picks, span), 0.0)) \
+                * _rows_of(kf, picks, span)
+            at = lane % SUB == jj
+            a = jnp.where(at, jnp.sum(k_s * w, axis=1, keepdims=True), a)
+            b = jnp.where(at, jnp.sum(q_s * w, axis=1, keepdims=True), b)
+        return a, b
+
+    def second(t):      # the second halves of the sub-chunks, [C / 2, W]
+        return jnp.concatenate([t[i * SUB + half:(i + 1) * SUB]
+                                for i in range(subs)], axis=0)
+    early = columns(big_g, kf, qf, 0)
+    late = columns(second(big_g), second(kf), second(qf), half)
+    # the two write different columns: a second half's rows are their sum
+    return tuple(jnp.concatenate([
+        part for i in range(subs) for part in (
+            whole[i * SUB:i * SUB + half],
+            whole[i * SUB + half:(i + 1) * SUB]
+            + lower[i * half:(i + 1) * half])], axis=0)
+        for whole, lower in zip(early, late))
 
 
 def _chunk_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, h0_ref, o_ref,
                   state_ref, *, heads: int, d_k: int, d_v: int, chunk: int):
     from jax.experimental import pallas as pl
     dtype = q_ref.dtype
-    subs = chunk // SUB
+    n = range(heads)
 
     @pl.when(pl.program_id(2) == 0)
     def _():
@@ -232,58 +370,23 @@ def _chunk_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, h0_ref, o_ref,
 
     row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    eye = jnp.where(row == col, 1.0, 0.0).astype(F32)
-    # a strip of SUB rows against the chunk, and against its own block
-    col_s = jax.lax.broadcasted_iota(jnp.int32, (SUB, chunk), 1)
-    row_o = jax.lax.broadcasted_iota(jnp.int32, (SUB, SUB), 0)
-    col_o = jax.lax.broadcasted_iota(jnp.int32, (SUB, SUB), 1)
-    for j in range(heads):
-        at_k = slice(j * d_k, (j + 1) * d_k)
-        at_v = slice(j * d_v, (j + 1) * d_v)
-        qf = q_ref[0, :, at_k].astype(F32)                   # [C, d_k]
-        kf = k_ref[0, :, at_k].astype(F32)
-        big_g = g_ref[0, :, at_k]                            # running sums
-        beta = beta_ref[0, 0, :, j:j + 1]                    # [C, 1]
-        # ---- A, B and the diagonal blocks' inverses, a strip of SUB rows
-        # at a time, every exponent a difference <= 0 ---------------------
-        strips_a, strips_b, strips_x = [], [], []
-        for i in range(subs):
-            rows = slice(i * SUB, (i + 1) * SUB)
-            g_i, k_i, q_i = big_g[rows], kf[rows], qf[rows]
-            a_i = jnp.zeros((SUB, chunk), F32)
-            b_i = jnp.zeros((SUB, chunk), F32)
-            own = jnp.zeros((SUB, SUB), F32)
-            # inside the sub-chunk: its rows against its own jj-th
-            for jj in range(SUB):
-                w = jnp.exp(jnp.minimum(g_i - g_i[jj:jj + 1], 0.0)) \
-                    * k_i[jj:jj + 1]
-                a_col = jnp.sum(k_i * w, axis=1, keepdims=True)
-                a_i = jnp.where(col_s == i * SUB + jj, a_col, a_i)
-                own = jnp.where(col_o == jj, a_col, own)
-                b_i = jnp.where(col_s == i * SUB + jj,
-                                jnp.sum(q_i * w, axis=1, keepdims=True), b_i)
-            if i:
-                # against the rows before: G* the sum behind the last of them
-                ref = big_g[i * SUB - 1:i * SUB]             # [1, d_k]
-                down = jnp.exp(g_i - ref)                    # <= 1
-                up = kf * jnp.exp(jnp.minimum(ref - big_g, 0.0))
-                before = col_s < i * SUB
-                a_i = jnp.where(before, _dot(k_i * down, up, ((1,), (1,)),
-                                             dtype), a_i)
-                b_i = jnp.where(before, _dot(q_i * down, up, ((1,), (1,)),
-                                             dtype), b_i)
-            strips_a.append(a_i)
-            strips_b.append(b_i)
-            strips_x.append(_unit_lower_inverse(
-                jnp.where(row_o > col_o, beta[rows] * own, 0.0), eye[rows]))
-        low = jnp.where(row > col,
-                        beta * jnp.concatenate(strips_a, axis=0), 0.0)
-        b_mat = jnp.where(row >= col, jnp.concatenate(strips_b, axis=0), 0.0)
-        x_d = jnp.concatenate(strips_x, axis=0)              # D^-1
-        o, state_ref[0, j] = _solve_and_out(
-            kf, qf, big_g, beta, low, b_mat, x_d, v_ref[0, :, at_v],
-            state_ref[0, j], chunk=chunk, dtype=dtype)
-        o_ref[0, :, at_v] = o.astype(o_ref.dtype)
+    own = row // SUB == col // SUB
+    sums = _running_sums(g_ref[0])                  # every head's at once
+    qf = [q_ref[0, :, j * d_k:(j + 1) * d_k].astype(F32) for j in n]
+    kf = [k_ref[0, :, j * d_k:(j + 1) * d_k].astype(F32) for j in n]
+    big_g = [sums[:, j * d_k:(j + 1) * d_k] for j in n]      # [C, d_k]
+    beta = [beta_ref[0, 0, :, j:j + 1] for j in n]           # [C, 1]
+    # ---- A and B, every exponent a difference <= 0 -----------------------
+    off = [_between_sub_chunks(qf[j], kf[j], big_g[j], chunk, dtype)
+           for j in n]
+    ins = [_inside_sub_chunks(qf[j], kf[j], big_g[j], chunk) for j in n]
+    low = [jnp.where(row > col,
+                     beta[j] * jnp.where(own, ins[j][0], off[j][0]), 0.0)
+           for j in n]
+    b_mat = [jnp.where(row >= col, jnp.where(own, ins[j][1], off[j][1]), 0.0)
+             for j in n]
+    _solve_and_out(kf, qf, big_g, beta, low, b_mat, v_ref, o_ref, state_ref,
+                   d_v=d_v, chunk=chunk, dtype=dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -305,10 +408,6 @@ def _kda_chunk(q, k, v, g, beta, h0, *, chunk=CHUNK, interpret=False):
             for t in (q, k, v, g, beta))
     total = rows + pad
     n = total // chunk
-    # the running sums of g inside each chunk, made outside (a cumulative
-    # sum over [rows, H d_k] float32) as ops/ssd_scan.py makes its own
-    run = jnp.cumsum(g.astype(F32).reshape(batch, n, chunk, heads * d_k),
-                     axis=2).reshape(batch, total, heads * d_k)
     # beta a column a head: [batch, blocks of heads, rows, heads a block]
     cols = beta.astype(F32).reshape(batch, total, heads // hb, hb) \
         .swapaxes(1, 2)
@@ -349,7 +448,9 @@ def _kda_chunk(q, k, v, g, beta, h0, *, chunk=CHUNK, interpret=False):
         o, last = call(
             q.reshape(batch, total, heads * d_k),
             k.reshape(batch, total, heads * d_k).astype(q.dtype),
-            v.reshape(batch, total, heads * d_v), run, cols, h0.astype(F32))
+            v.reshape(batch, total, heads * d_v),
+            g.astype(F32).reshape(batch, total, heads * d_k), cols,
+            h0.astype(F32))
     return o.reshape(batch, total, heads, d_v)[:, :rows], last
 
 
@@ -404,7 +505,7 @@ def _gdn_chunk_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, h0_ref, o_ref,
     read (`heads` a multiple of `key_heads`, or ONE key head)."""
     from jax.experimental import pallas as pl
     dtype = q_ref.dtype
-    subs = chunk // SUB
+    n = range(heads)
 
     @pl.when(pl.program_id(2) == 0)
     def _():
@@ -413,34 +514,27 @@ def _gdn_chunk_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, h0_ref, o_ref,
     row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     eye = jnp.where(row == col, 1.0, 0.0).astype(F32)
-    products = {}       # a key head's K K^T and Q K^T, made once
-    for j in range(heads):
-        jk = j * key_heads // heads
-        at_k = slice(jk * d_k, (jk + 1) * d_k)
-        at_v = slice(j * d_v, (j + 1) * d_v)
-        if jk not in products:
-            q_j, k_j = q_ref[0, :, at_k], k_ref[0, :, at_k]
-            products[jk] = (
-                q_j.astype(F32), k_j.astype(F32),
-                _dot(k_j, k_j, ((1,), (1,)), dtype),          # [C, C]
-                _dot(q_j, k_j, ((1,), (1,)), dtype))
-        qf, kf, kk, qk = products[jk]
-        big_g = g_ref[0, 0, :, j:j + 1]                      # [C, 1] sums
-        beta = beta_ref[0, 0, :, j:j + 1]                    # [C, 1]
-        # G along the columns: the identity picks each row's own sum
-        g_cols = jnp.sum(eye * big_g, axis=0, keepdims=True)  # [1, C]
-        # e^{G_r - G_i}: <= 1 wherever it is kept (i <= r)
-        decay = jnp.exp(jnp.minimum(big_g - g_cols, 0.0))
-        low = jnp.where(row > col, beta * (kk * decay), 0.0)
-        b_mat = jnp.where(row >= col, qk * decay, 0.0)
-        x_d = jnp.concatenate([
-            _unit_lower_inverse(low[i * SUB:(i + 1) * SUB],
-                                eye[i * SUB:(i + 1) * SUB], i * SUB)
-            for i in range(subs)], axis=0)                   # D^-1
-        o, state_ref[0, j] = _solve_and_out(
-            kf, qf, big_g, beta, low, b_mat, x_d, v_ref[0, :, at_v],
-            state_ref[0, j], chunk=chunk, dtype=dtype)
-        o_ref[0, :, at_v] = o.astype(o_ref.dtype)
+    sums = _running_sums(g_ref[0, 0])               # every head's at once
+    # a key head's rows, K K^T and Q K^T, made once for the heads over it
+    rows = [(q_ref[0, :, jk * d_k:(jk + 1) * d_k],
+             k_ref[0, :, jk * d_k:(jk + 1) * d_k]) for jk in range(key_heads)]
+    kk = [_dot(k_j, k_j, ((1,), (1,)), dtype) for _, k_j in rows]  # [C, C]
+    qk = [_dot(q_j, k_j, ((1,), (1,)), dtype) for q_j, k_j in rows]
+    of = [j * key_heads // heads for j in n]
+    qf = [rows[jk][0].astype(F32) for jk in of]
+    kf = [rows[jk][1].astype(F32) for jk in of]
+    big_g = [sums[:, j:j + 1] for j in n]                    # [C, 1] sums
+    beta = [beta_ref[0, 0, :, j:j + 1] for j in n]           # [C, 1]
+    # G along the columns: the identity picks each row's own sum; then
+    # e^{G_r - G_i}: <= 1 wherever it is kept (i <= r)
+    decay = [jnp.exp(jnp.minimum(
+        big_g[j] - jnp.sum(eye * big_g[j], axis=0, keepdims=True), 0.0))
+        for j in n]
+    low = [jnp.where(row > col, beta[j] * (kk[of[j]] * decay[j]), 0.0)
+           for j in n]
+    b_mat = [jnp.where(row >= col, qk[of[j]] * decay[j], 0.0) for j in n]
+    _solve_and_out(kf, qf, big_g, beta, low, b_mat, v_ref, o_ref, state_ref,
+                   d_v=d_v, chunk=chunk, dtype=dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -471,9 +565,6 @@ def _gdn_chunk(q, k, v, g, beta, h0, *, chunk=CHUNK, interpret=False):
 
     def cols(t):    # a column a head: [batch, blocks of heads, rows, hb]
         return t.reshape(batch, total, heads // hb, hb).swapaxes(1, 2)
-    # the running sums of g inside each chunk, made outside
-    run = jnp.cumsum(g.astype(F32).reshape(batch, n, chunk, heads),
-                     axis=2).reshape(batch, total, heads)
     by_k = pl.BlockSpec((1, chunk, kb * d_k),
                         lambda bi, hi, ci: (bi, ci, hi * hb // ratio // kb))
     by_v = pl.BlockSpec((1, chunk, hb * d_v), lambda bi, hi, ci: (bi, ci, hi))
@@ -506,6 +597,6 @@ def _gdn_chunk(q, k, v, g, beta, h0, *, chunk=CHUNK, interpret=False):
         o, last = call(
             q.reshape(batch, total, key_heads * d_k),
             k.reshape(batch, total, key_heads * d_k).astype(q.dtype),
-            v.reshape(batch, total, heads * d_v), cols(run),
+            v.reshape(batch, total, heads * d_v), cols(g.astype(F32)),
             cols(beta.astype(F32)), h0.astype(F32))
     return o.reshape(batch, total, heads, d_v)[:, :rows], last

@@ -164,12 +164,12 @@ models/kda.py, models/gated_delta.py and models/rope.py):
 | `mtpu/kda/proj` | a Kimi Delta Attention layer's two first products, rows x [h, 3 H D] (q, k, v) and rows x [h, r + r + H] (the decay's and the output gate's low-rank inputs, beta) (`models/kda.py`) |
 | `mtpu/kda/conv` | the read of the layer's two states (the three depthwise kernels' last inputs, the rule's [heads, head_dim, head_dim] float32 matrices; a row each slot), the taps over [state ; rows] of q, k and v accumulated in float32, SiLU, the L2 norm a head of q and k, q's 1 / sqrt(D) |
 | `mtpu/kda/gate` | the log-decay a channel, -exp(A_log) softplus(f W_fb + dt_bias) in float32, beta = sigmoid, and the padding rows' decay and beta set to 0 (the rule's step is then the identity) |
-| `mtpu/kda/scan` | the gated delta rule: the kernel `_kda_chunk` for a prefill or a chunk (ops/kda_chunk.py; the running sums of the decays made outside it), the one-row update over the pool's layer for a decode step, the recurrence with no cache; and the write of both states behind the call's last real row, one update in place a layer each |
+| `mtpu/kda/scan` | the gated delta rule: the kernel `_kda_chunk` for a prefill or a chunk (ops/kda_chunk.py; the running sums of the decays made inside it since PR 61: what is outside the Pallas call in the jitted function is beta's transpose and the operands' reshapes), the one-row update over the pool's layer for a decode step, the recurrence with no cache; and the write of both states behind the call's last real row, one update in place a layer each |
 | `mtpu/kda/out` | the RMSNorm a head, float32 statistics, ONE learned scale [D]; the output gate sigmoid(z W_gb + b); the layer's last product, rows x [H D, h] |
 | `mtpu/gdn/proj` | a Gated DeltaNet layer's two first products, rows x [h, 2 H_k D + 2 H D] (q, k, v and the output gate's z) and rows x [h, 2 H] (beta's and the decay's inputs) (`models/gated_delta.py`) |
 | `mtpu/gdn/conv` | the read of the layer's two states (the depthwise kernel's last inputs, the rule's [value heads, D, D] float32 matrices; a row each slot), the taps over [state ; rows] of q, k and v together accumulated in float32, SiLU, the L2 norm a head of q and k, q's 1 / sqrt(D) |
 | `mtpu/gdn/gate` | the log-decay a HEAD, -exp(A_log) softplus(a + dt_bias) in float32, beta = sigmoid, and the padding rows' decay and beta set to 0 |
-| `mtpu/gdn/scan` | the gated delta rule with one decay a head: the kernel `_gdn_chunk` for a prefill or a chunk (ops/kda_chunk.py, form (d): one K K^T and one Q K^T a chunk a key head on the matrix unit; the running sums of the decays made outside it), the one-row update over the pool's layer for a decode step, the recurrence with no cache; and the write of both states behind the call's last real row |
+| `mtpu/gdn/scan` | the gated delta rule with one decay a head: the kernel `_gdn_chunk` for a prefill or a chunk (ops/kda_chunk.py, form (d): one K K^T and one Q K^T a chunk a key head on the matrix unit; the running sums of the decays made inside it since PR 61), the one-row update over the pool's layer for a decode step, the recurrence with no cache; and the write of both states behind the call's last real row |
 | `mtpu/gdn/out` | the RMSNorm a head, float32 statistics, ONE learned scale [D] (the scale itself, not 1 + w); the output gate SiLU(z); the layer's last product, rows x [H D, h] |
 | `mtpu/attn/gate` | the attention's output gate (`cfg.attn_output_gate`): the attention's output times sigmoid of the gate that wq's second half of a head's columns made, float32, ahead of wo (`models/attention.py`) |
 | `mtpu/rope/partial` | a rotary over the first `cfg.rotary_dim` channels of a head, the others passed as they are (`models/rope.py`) |

@@ -26,7 +26,11 @@ TOL = 1e-4
 DECAYS = {"typical": {}, "near_0": dict(scale=1e-3),
           "minus_8": dict(const=-8.0), "mixed_to_minus_40": dict(scale=20.0)}
 HEADS = {"2_under_4": (2, 4), "4_under_4": (4, 4), "1_under_4": (1, 4),
-         "1_under_3": (1, 3)}
+         "1_under_3": (1, 3),
+         # four value heads a grid step over two, four and ONE key head;
+         # a ratio of three under two heads a step: one head a step
+         "4_under_8": (4, 8), "8_under_8": (8, 8), "2_under_8": (2, 8),
+         "2_under_6": (2, 6)}
 
 
 def _rows(seed, batch=2, rows=96, hk=2, hv=4, d=16, scale=1.0, const=None):
@@ -77,10 +81,11 @@ def test_scalar_form_is_the_definition_is_the_channel_form(decay, heads):
     assert bool((o == want_o).all()) and bool((s == want_s).all())
 
 
-@pytest.mark.parametrize("chunk", [16, 32])
+@pytest.mark.parametrize("chunk", [16, 32, 128])
 def test_smaller_chunks_and_a_carried_state(chunk):
     """Two calls of 64 and 32 rows, the second entered with the state the
-    first left, are one call of 96."""
+    first left, are one call of 96 (under a chunk of 128 neither call is a
+    whole chunk)."""
     q, k, v, g, beta, h0 = _rows(2)
     want_o, want_s = gdn_recurrent(q, k, v, g, beta, h0)
     cut = lambda t, a, b: t[:, a:b]                              # noqa: E731
@@ -92,6 +97,19 @@ def test_smaller_chunks_and_a_carried_state(chunk):
     for bad in (48, 24):
         with pytest.raises(AssertionError, match="whole sub-chunks"):
             gdn_chunk(q, k, v, g, beta, h0, chunk=bad, interpret=True)
+
+
+@pytest.mark.parametrize("decay", ["minus_8", "mixed_to_minus_40"])
+def test_every_row_of_a_128_row_chunk(decay):
+    """One whole chunk of 128 rows under decays whose running sums reach
+    the thousands: every row finite and the definition's (the sums are made
+    in the kernel, a few roundings from the sequential ones: PR 61)."""
+    args = _rows(6, rows=128, hk=2, hv=8, **DECAYS[decay])
+    want_o, want_s = kda_recurrent(*_definition(*args))
+    got_o, got_s = gdn_chunk(*args, chunk=128, interpret=True)
+    assert bool(jnp.isfinite(got_o).all()) and bool(jnp.isfinite(got_s).all())
+    assert float(jnp.abs(got_o - want_o).max(axis=(0, 2, 3)).max()) < TOL
+    assert _close(got_s, want_s)
 
 
 def test_one_row_step_is_the_recurrence():
@@ -106,7 +124,8 @@ def test_one_row_step_is_the_recurrence():
     assert _close(state, want_s, 1e-6)
 
 
-@pytest.mark.parametrize("form", ["kernel", "recurrence", "step"])
+@pytest.mark.parametrize("form", ["kernel", "kernel_128", "recurrence",
+                                  "step"])
 def test_padding_rows_leave_the_state_bit_for_bit(form):
     """beta = 0 and g = 0: the state behind 40 real rows and 24 such rows
     is the state behind the 40, to the bit."""
@@ -114,10 +133,11 @@ def test_padding_rows_leave_the_state_bit_for_bit(form):
     real = (jnp.arange(64) < 40)[None, :, None]
     g, beta = jnp.where(real, g, 0.0), jnp.where(real, beta, 0.0)
     cut = lambda t: t[:, :40]                                    # noqa: E731
-    if form == "kernel":
+    if form.startswith("kernel"):
+        chunk = 128 if form == "kernel_128" else 32
         _, want = gdn_chunk(*(cut(t) for t in (q, k, v, g, beta)), h0,
-                            chunk=32, interpret=True)
-        _, got = gdn_chunk(q, k, v, g, beta, h0, chunk=32, interpret=True)
+                            chunk=chunk, interpret=True)
+        _, got = gdn_chunk(q, k, v, g, beta, h0, chunk=chunk, interpret=True)
     elif form == "recurrence":
         _, want = gdn_recurrent(*(cut(t) for t in (q, k, v, g, beta)), h0)
         _, got = gdn_recurrent(q, k, v, g, beta, h0)

@@ -17,8 +17,9 @@ from benchmark.reference import kimi_linear as reference
 from megatron_tpu.config import MODEL_PRESETS
 from megatron_tpu.inference.generation import init_kv_caches
 from megatron_tpu.models.kda import kda_apply, kda_init
-from megatron_tpu.ops.kda_chunk import (CHUNK, SUB, kda_block_heads, kda_chunk,
-                                        kda_recurrent, kda_step)
+from megatron_tpu.ops.kda_chunk import (CHUNK, HEADS_A_STEP, SUB,
+                                        _running_sums, kda_block_heads,
+                                        kda_chunk, kda_recurrent, kda_step)
 
 TOL = 1e-4
 DECAYS = {"typical": {}, "near_0": dict(scale=1e-3), "minus_8": dict(const=-8.0),
@@ -44,10 +45,11 @@ def _close(a, b, tol=TOL):
     return float(jnp.abs(a - b).max()) < tol
 
 
-@pytest.mark.parametrize("chunk", [16, 32, 64])
+@pytest.mark.parametrize("chunk", [16, 32, 64, 128])
 @pytest.mark.parametrize("decay", sorted(DECAYS))
 def test_chunk_kernel_is_the_recurrence(decay, chunk):
-    """96 rows: whole chunks of 16 and 32, a padded tail under 64."""
+    """96 rows: whole chunks of 16 and 32, a padded tail under 64, not one
+    whole chunk of 128."""
     args = _rows(1, **DECAYS[decay])
     want_o, want_s = kda_recurrent(*args)
     got_o, got_s = kda_chunk(*args, chunk=chunk, interpret=True)
@@ -72,6 +74,56 @@ def test_chunked_run_carries_its_state(decay):
         assert _close(s2, want_s)
 
 
+@pytest.mark.parametrize("chunk", [16, 64, 128])
+def test_running_sums_in_the_kernel_are_cumsum(chunk):
+    """The kernel makes a chunk's running sums itself (PR 61): over two
+    chunks of a grid they are `jnp.cumsum` inside each chunk, starting
+    again at the boundary, at sums of a few thousand as at sums near 0."""
+    from jax.experimental import pallas as pl
+
+    def kernel(g_ref, out_ref):
+        out_ref[...] = _running_sums(g_ref[...])
+    keys = jax.random.split(jax.random.PRNGKey(7), 2)
+    g = -jax.nn.softplus(jax.random.normal(keys[0], (2 * chunk, 256))) \
+        * jnp.exp(4 * jax.random.normal(keys[1], (1, 256)))
+    block = pl.BlockSpec((chunk, 256), lambda i: (i, 0))
+    got = pl.pallas_call(kernel, grid=(2,), in_specs=[block],
+                         out_specs=block, out_shape=g, interpret=True)(g)
+    want = jnp.cumsum(g.reshape(2, chunk, 256), axis=1).reshape(g.shape)
+    assert float(jnp.abs(want).max()) > 1e3
+    assert bool((jnp.abs(got - want) <= 2e-6 * jnp.abs(want)).all())
+    # the row behind the boundary holds its own g alone
+    assert bool((got[chunk] == g[chunk]).all())
+
+
+@pytest.mark.parametrize("decay", ["minus_8", "mixed_to_minus_40"])
+def test_every_row_of_a_128_row_chunk(decay):
+    """One whole chunk of 128 rows (eight sub-chunks, three levels of
+    halves between them) under decays that pass float32 as e^-G within a
+    dozen rows: every row finite and the recurrence's, none of the levels'
+    exponents ever above 0."""
+    args = _rows(6, rows=128, **DECAYS[decay])
+    want_o, want_s = kda_recurrent(*args)
+    got_o, got_s = kda_chunk(*args, chunk=128, interpret=True)
+    assert bool(jnp.isfinite(got_o).all()) and bool(jnp.isfinite(got_s).all())
+    assert float(jnp.abs(got_o - want_o).max(axis=(0, 2, 3)).max()) < TOL
+    assert float(jnp.abs(want_o).min(axis=(0, 2, 3)).max()) > 0
+    assert _close(got_s, want_s)
+
+
+@pytest.mark.parametrize("heads,a_step", [(3, 1), (6, 2), (8, 4), (12, 4)])
+def test_heads_a_grid_step(heads, a_step):
+    """Four heads a grid step where four divide the heads, two where two
+    do, one under an odd count: the same rows' results whatever the step
+    takes."""
+    assert kda_block_heads(heads, 128, 128) == a_step
+    assert kda_block_heads(heads, 16, 16, aligned=False) == a_step
+    args = _rows(8, batch=1, rows=80, heads=heads)
+    want_o, want_s = kda_recurrent(*args)
+    got_o, got_s = kda_chunk(*args, chunk=32, interpret=True)
+    assert _close(got_o, want_o) and _close(got_s, want_s)
+
+
 def test_one_row_step_is_the_recurrence():
     q, k, v, g, beta, h0 = _rows(3, rows=5)
     want_o, want_s = kda_recurrent(q, k, v, g, beta, h0)
@@ -84,7 +136,8 @@ def test_one_row_step_is_the_recurrence():
     assert _close(state, want_s, 1e-6)
 
 
-@pytest.mark.parametrize("form", ["kernel", "recurrence", "step"])
+@pytest.mark.parametrize("form", ["kernel", "kernel_128", "recurrence",
+                                  "step"])
 def test_padding_rows_leave_the_state_bit_for_bit(form):
     """beta = 0 and g = 0: the rule's step is (I - 0) Diag(1) S, and the
     state behind 40 real rows and 24 such rows is the state behind the 40,
@@ -94,11 +147,14 @@ def test_padding_rows_leave_the_state_bit_for_bit(form):
     g = jnp.where(real[..., None], g, 0.0)
     beta = jnp.where(real, beta, 0.0)
     cut = lambda t: t[:, :40]                                    # noqa: E731
-    if form == "kernel":
+    if form.startswith("kernel"):
         # 40 rows is two sub-chunks and a half: the kernel pads them itself
+        # (in chunks of 32: the padding rows fill a chunk's tail; of 128:
+        # real and padding rows share ONE chunk, four heads a grid step)
+        chunk = 128 if form == "kernel_128" else 32
         _, want = kda_chunk(*(cut(t) for t in (q, k, v, g, beta)), h0,
-                            chunk=32, interpret=True)
-        _, got = kda_chunk(q, k, v, g, beta, h0, chunk=32, interpret=True)
+                            chunk=chunk, interpret=True)
+        _, got = kda_chunk(q, k, v, g, beta, h0, chunk=chunk, interpret=True)
     elif form == "recurrence":
         _, want = kda_recurrent(*(cut(t) for t in (q, k, v, g, beta)), h0)
         _, got = kda_recurrent(q, k, v, g, beta, h0)
@@ -110,10 +166,11 @@ def test_padding_rows_leave_the_state_bit_for_bit(form):
 
 
 def test_the_kernels_shape_rule():
-    assert kda_block_heads(32, 128, 128) == 2
+    assert kda_block_heads(32, 128, 128) == HEADS_A_STEP == 4
     assert kda_block_heads(3, 128, 128) == 1
     assert kda_block_heads(4, 16, 16) is None            # narrow heads
-    assert kda_block_heads(4, 16, 16, aligned=False) == 2
+    assert kda_block_heads(4, 16, 16, aligned=False) == 4
+    assert kda_block_heads(2, 16, 16, aligned=False) == 2
     assert SUB == 16 and CHUNK == 64
     # where the rule does not hold the recurrence runs
     args = _rows(5, rows=48)

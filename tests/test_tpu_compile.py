@@ -722,14 +722,14 @@ def test_kda_chunk_at_kimi_linear_widths(one_chip, batch, rows, chunk):
     4,096-row chunk and shorter ones of 32 heads of 128 key and value
     channels, chunks of 64 rows (four sub-chunks of 16) or 128, a state of
     [32, 128, 128] float32 a sequence in and out, bf16 rows, float32
-    log-decays: two heads a grid step, 128-channel slices of a 256-lane
-    block."""
+    log-decays (the kernel makes their running sums: PR 61): four heads a
+    grid step, 128-channel slices of a 512-lane block."""
     from megatron_tpu.ops.kda_chunk import _kda_chunk, kda_block_heads
 
     def S(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     f32 = jnp.float32
-    assert kda_block_heads(32, 128, 128) == 2
+    assert kda_block_heads(32, 128, 128) == 4
     by_rows = (batch, rows, 32, 128)
     text = jax.jit(functools.partial(_kda_chunk, chunk=chunk)).lower(
         S(by_rows), S(by_rows), S(by_rows), S(by_rows, f32),
@@ -853,14 +853,14 @@ def test_kimi_linear_served_programs_copy_no_state_and_no_latent_layer(
 def test_gdn_chunk_at_qwen3_next_widths(one_chip, batch, rows, chunk):
     """The scalar-decay chunk kernel (PR 60) at Qwen3-Next's widths: 16 key
     heads under 32 value heads of 128 channels, a decay a head a row, a
-    state of [32, 128, 128] float32 a sequence in and out, bf16 rows: two
-    value heads a grid step over the ONE key head they read."""
+    state of [32, 128, 128] float32 a sequence in and out, bf16 rows: four
+    value heads a grid step over the TWO key heads they read."""
     from megatron_tpu.ops.kda_chunk import _gdn_chunk, kda_block_heads
 
     def S(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     f32 = jnp.float32
-    assert kda_block_heads(32, 128, 128) == 2
+    assert kda_block_heads(32, 128, 128) == 4
     text = jax.jit(functools.partial(_gdn_chunk, chunk=chunk)).lower(
         S((batch, rows, 16, 128)), S((batch, rows, 16, 128)),
         S((batch, rows, 32, 128)), S((batch, rows, 32), f32),
@@ -874,6 +874,47 @@ def test_gdn_chunk_at_qwen3_next_widths(one_chip, batch, rows, chunk):
     wide = re.findall(rf"= bf16\[{batch},{rows + (-rows % chunk)},4096\]"
                       r"\S* (?!parameter|custom-call)", text)
     assert len(wide) <= 2, wide
+
+
+@pytest.mark.parametrize("form", ["kda", "gdn"])
+def test_delta_rule_kernels_are_counted_by_the_benchmarks_readers(one_chip,
+                                                                  form):
+    """PR 61 changed what the chunk kernels take (g itself where the running
+    sums were, four heads a grid step): the compiled kernel's line at the
+    cells' widths, as a trace names it, is still FOUND and COUNTED by the
+    benchmark's accepted readers (`benchmark/kda_roofline.py`,
+    `gdn_roofline.py`: the rule's own 12.9 GFLOP; 206 MB for a decay a
+    channel, 106 MB for a decay a head under 16 key heads), so that a
+    changed operand list fails here and not a check on the chip."""
+    from benchmark import gdn_roofline, kda_roofline
+    from megatron_tpu.ops.kda_chunk import _gdn_chunk, _kda_chunk
+
+    def S(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    f32 = jnp.float32
+    rows, heads, d = 4096, 32, 128
+    if form == "kda":
+        reader, kernel, name = kda_roofline, _kda_chunk, "_kda_chunk"
+        found, key_heads, by_g = kda_roofline.is_kda_chunk, heads, (d,)
+        nbytes = rows * heads * (3 * d * 2 + d * 2 + d * 4 + 4)
+    else:
+        reader, kernel, name = gdn_roofline, _gdn_chunk, "_gdn_chunk"
+        found, key_heads, by_g = gdn_roofline.is_gdn_chunk, 16, ()
+        nbytes = rows * (2 * key_heads * d * 2 + heads * (2 * d * 2 + 2 * 4))
+    nbytes += 2 * heads * d * d * 4
+    text = jax.jit(kernel).lower(
+        S((1, rows, key_heads, d)), S((1, rows, key_heads, d)),
+        S((1, rows, heads, d)), S((1, rows, heads) + by_g, f32),
+        S((1, rows, heads), f32), S((1, heads, d, d), f32)
+    ).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if f"%{name}" in line and "tpu_custom_call" in line]
+    assert len(calls) == 1, [c[:300] for c in calls]
+    traced = _as_traced(calls[0])
+    assert found(traced), traced[:400]
+    assert reader.counts(traced) == (6.0 * rows * heads * d * d,
+                                     float(nbytes)), traced[:600]
+    assert round(nbytes / 1e6) == (206 if form == "kda" else 106)
 
 
 def test_flash_kernel_reads_folded_rows_at_an_offset(one_chip):
